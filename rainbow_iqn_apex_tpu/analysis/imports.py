@@ -4,7 +4,7 @@ Several subsystems promise jax-free IMPORT in their docstrings and lean on
 it operationally: respawned actor/league children must start in ~0.3s
 (parallel/elastic.py consumers), router front-end processes own no device
 (serving/fleet, serving/net), and the offline tooling (obs_report,
-relay_watch, lint_jsonl) must run on boxes with no jax install at all.
+obs/attribution, lint_jsonl) must run on boxes with no jax install at all.
 The PEP-562 lazy package ``__init__``s exist exactly to protect this — and
 a single eager ``from .apex import ...`` regression silently re-taints
 every consumer (the PR-4 lesson).
@@ -22,7 +22,7 @@ three modules deep names every hop.  Suppression: ``# jax-ok: <reason>``
 on the offending import line.
 
 Self-hosting: ``analysis/*`` is itself in the declared set, and
-scripts/obs_report.py + scripts/relay_watch.py are checked through their
+scripts/obs_report.py + scripts/lint_jsonl.py are checked through their
 repo-relative paths (the ISSUE-14 satellite).
 """
 
@@ -53,6 +53,7 @@ JAX_FREE_MODULES: Tuple[str, ...] = (
     "rainbow_iqn_apex_tpu/analysis/",
     "rainbow_iqn_apex_tpu/league/",
     "rainbow_iqn_apex_tpu/obs/__init__.py",
+    "rainbow_iqn_apex_tpu/obs/attribution.py",
     "rainbow_iqn_apex_tpu/obs/export.py",
     "rainbow_iqn_apex_tpu/obs/health.py",
     "rainbow_iqn_apex_tpu/obs/pipeline_trace.py",
@@ -74,7 +75,6 @@ JAX_FREE_MODULES: Tuple[str, ...] = (
     "scripts/lint_jsonl.py",
     "scripts/obs_report.py",
     "scripts/obs_top.py",
-    "scripts/relay_watch.py",
 )
 
 # PEP-562 lazy package __init__s: importing the PACKAGE must stay jax-free

@@ -209,7 +209,7 @@ class Config:
     # consumed per learn step (one [G*B] GEMM, per-group IS normalisation,
     # G-sequential priority write-back order) — the batch-64/128 TPU knob
     # that keeps the reference's batch-32 PER stratum width (SURVEY §7
-    # "prioritized sampling throughput"; docs/SCALING.md)
+    # "prioritized sampling throughput"; docs/DESIGN.md)
     learning_rate: float = 6.25e-5
     adam_eps: float = 1.5e-4
     max_grad_norm: float = 10.0  # 0 disables clipping
@@ -267,7 +267,8 @@ class Config:
     priority_weight: float = 0.4  # beta_0, annealed to 1 over training
     priority_eps: float = 1e-6
     replay_shards: int = 1  # host-DRAM shards (Redis-shard equivalent)
-    use_native_sumtree: bool = True  # C++ core; falls back to NumPy if unbuilt
+    use_native_sumtree: bool = True  # C++ core (replay/native.py); a build
+    # failure raises.  False = the NumPy sum-tree, the fuzz tests' reference
 
     # ---- Ape-X topology (SURVEY §2 rows 7-8) --------------------------------------
     role: str = "single"  # "single" | "apex" | "anakin" (HBM-resident replay)

@@ -45,12 +45,12 @@ from rainbow_iqn_apex_tpu.envs.base import Env, TimeStep
 G = 10  # logic grid is GxG for every game
 
 # render intensities (distinct so the conv net can tell entities apart)
-I_PLAYER = jnp.uint8(140)
-I_BALL = jnp.uint8(255)
-I_BRICK = jnp.uint8(90)
-I_ENEMY = jnp.uint8(200)
-I_GOLD = jnp.uint8(255)
-I_BULLET = jnp.uint8(255)
+I_PLAYER = np.uint8(140)
+I_BALL = np.uint8(255)
+I_BRICK = np.uint8(90)
+I_ENEMY = np.uint8(200)
+I_GOLD = np.uint8(255)
+I_BULLET = np.uint8(255)
 
 
 def _upscale(grid: jnp.ndarray, cell: int) -> jnp.ndarray:
@@ -253,8 +253,8 @@ class FreewayGame(DeviceGame):
     num_actions = 3  # 0=stay 1=up 2=down
     CHICKEN_COL = 4
     # per-lane (speed, direction): car advances every `speed` ticks
-    SPEEDS = jnp.array([2, 3, 2, 4, 2, 3, 4, 2], jnp.int32)
-    DIRS = jnp.array([1, -1, 1, -1, -1, 1, -1, 1], jnp.int32)
+    SPEEDS = np.array([2, 3, 2, 4, 2, 3, 4, 2], np.int32)
+    DIRS = np.array([1, -1, 1, -1, -1, 1, -1, 1], np.int32)
 
     def __init__(self, cap: int = 500):
         self.cap = cap
@@ -268,8 +268,10 @@ class FreewayGame(DeviceGame):
 
     def _lane_dynamics(self, s):
         """(speeds [8], dirs [8]) — the variant subclass reads them from the
-        per-level state instead of the class constants."""
-        return self.SPEEDS, self.DIRS
+        per-level state instead of the class constants (NumPy, so that
+        importing this module never brings up a backend; callers index the
+        result with traced lanes, hence the conversion here)."""
+        return jnp.asarray(self.SPEEDS), jnp.asarray(self.DIRS)
 
     def step(self, s: FreewayState, action, key):
         move = jnp.array([0, -1, 1], jnp.int32)[action]

@@ -366,8 +366,14 @@ def run_sweep(base_args: List[str], games: Optional[List[str]] = None,
     ``resume_rows`` seeds from the existing per_game.csv (games being rerun
     excluded), so restarting a killed sweep with only its unfinished games
     cannot overwrite the finished ones."""
-    from rainbow_iqn_apex_tpu.atari57 import train_one_game, write_results_csv
+    from rainbow_iqn_apex_tpu.atari57 import (
+        SweepChildFailed,
+        pin_sweep_parent_to_cpu,
+        train_one_game,
+        write_results_csv,
+    )
 
+    pin_sweep_parent_to_cpu()  # baselines + salvage below: never on the chip
     games = games or JAXSUITE
     per_game: Dict[str, float] = {}
     baselines: Dict[str, Dict] = {}
@@ -404,16 +410,24 @@ def run_sweep(base_args: List[str], games: Optional[List[str]] = None,
     for game in games:
         args = [*base_args, *(per_game_args or {}).get(game, [])]
         run_id = f"jaxsuite_{game}"
-        summary = train_one_game(f"jaxgame:{game}", run_id, args)
+        try:
+            summary = train_one_game(f"jaxgame:{game}", run_id, args)
+        except SweepChildFailed as e:
+            # a non-zero exit is a failed game, never a salvage: whatever
+            # checkpoint sits under this run id is not this run's result
+            failed.append(game)
+            rows.append({"game": game, "score_mean": None, "error": str(e)})
+            flush()
+            continue
         raw = summary.get("eval_score_mean")
         extra = dict(summary)
         salvaged = False
         if raw is None:
-            # an interrupted/killed training still leaves periodic
-            # checkpoints — score the latest one rather than dropping hours
-            # of training (a wind-down cut mid-sweep is a normal event on
-            # budgeted boxes); ANY salvage failure becomes an error row so
-            # one broken game can never abort the remaining sweep
+            # a training that ended without a final score (wound down
+            # early) still leaves periodic checkpoints — score the latest
+            # one rather than dropping hours of training; ANY salvage
+            # failure becomes an error row so one broken game can never
+            # abort the remaining sweep
             try:
                 raw, ck_extra = eval_checkpoint_fused(
                     args, run_id, game, episodes=baseline_episodes,
@@ -660,9 +674,14 @@ def run_generalization(base_args: List[str],
     across-level spread, and a level-bootstrap of the gap's sign (VERDICT
     r4: a ±2-point two-pool gap at 16-level pools is indistinguishable from
     pool-difficulty variance)."""
-    from rainbow_iqn_apex_tpu.atari57 import train_one_game
+    from rainbow_iqn_apex_tpu.atari57 import (
+        SweepChildFailed,
+        pin_sweep_parent_to_cpu,
+        train_one_game,
+    )
     from rainbow_iqn_apex_tpu.envs.device_games import VARIANT_GAMES
 
+    pin_sweep_parent_to_cpu()  # checkpoint evals below: never on the chip
     games = list(games or sorted(VARIANT_GAMES))
     unsupported = [g for g in games if g not in VARIANT_GAMES]
     if unsupported:
@@ -684,7 +703,12 @@ def run_generalization(base_args: List[str],
     for g in games:
         run_id = f"jaxsuite_{g}_var"
         args = [*base_args, *(per_game_args or {}).get(g, [])]
-        summary = train_one_game(f"jaxgame:{g}@var", run_id, args)
+        try:
+            summary = train_one_game(f"jaxgame:{g}@var", run_id, args)
+        except SweepChildFailed as e:
+            rows.append({"game": g, "error": str(e)})
+            flush()
+            continue
         trained_ok = summary.get("eval_score_mean") is not None
         try:
             # both splits are scored from the checkpoint anyway, so an

@@ -2,7 +2,7 @@
 
 Emitted by both apex drivers at the metrics cadence (schema kind "games",
 obs/schema.py), consumed by scripts/obs_report.py's `games:` section and
-scripts/relay_watch.py's per-game phase tallies.  Jax-free: the baseline
+obs/attribution.py's per-game tallies.  Jax-free: the baseline
 lookup is deferred to call time so respawned children / offline tools can
 import this module without the device runtime.
 """

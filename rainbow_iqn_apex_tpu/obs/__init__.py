@@ -186,7 +186,7 @@ class RunObs:
         """Emit 'timing' + 'health' rows for the window ending now."""
         self._steps.set(step)
         self._frames.set(frames)
-        self._sample_device_gauges(self.registry, self.role)
+        device_bytes = self._sample_device_gauges(self.registry, self.role)
         stats = self.timer.stats()
         timing: Dict[str, Any] = {
             f"learn_{k}": round(float(v), 6) for k, v in stats.items()
@@ -198,6 +198,8 @@ class RunObs:
         timing["compiles"] = int(
             self.registry.counter("jax_compiles_total", "jax").get()
         )
+        if device_bytes:  # per local device; absent on backends without stats
+            timing["device_bytes_in_use"] = device_bytes
         self.metrics.log("timing", step=step, frames=frames, **timing)
         self.tracer.reset_exemplars()
         self.health.tick(step, frames, **gauges)

@@ -3,7 +3,7 @@
 
 Per-process observability (obs/) stayed strictly per-process through PR 17:
 every role writes its own JSONL and serves its own /metrics, and the only
-cross-process views are offline (obs_report, relay_watch).  This package
+cross-process views are offline (obs_report, obs/attribution).  This package
 makes telemetry a first-class fleet service on the existing substrate, the
 same move PR 16 made for replay:
 

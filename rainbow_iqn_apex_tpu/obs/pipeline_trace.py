@@ -292,7 +292,7 @@ class PipelineTracer:
 
 # --------------------------------------------------------------------------
 # Critical-path analysis over span_link rows (shared by obs_report and
-# relay_watch — the verdict string must not drift between the two).
+# obs/attribution — the verdict string must not drift between the two).
 # --------------------------------------------------------------------------
 
 def critical_path(rows: Iterable[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
@@ -337,7 +337,7 @@ def critical_path(rows: Iterable[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
 
 
 def format_critical_path(cp: Optional[Dict[str, Any]]) -> Optional[str]:
-    """One-line rendering shared by obs_report and relay_watch:
+    """One-line rendering shared by obs_report and obs/attribution:
     ``gather 61% (sampler-starved)``."""
     if not cp:
         return None

@@ -221,16 +221,20 @@ def install_compile_counter(registry: MetricRegistry) -> bool:
         return _compile_listener_installed
 
 
-def sample_device_gauges(registry: MetricRegistry, role: str = "") -> None:
-    """Device-memory gauges from the first local device.  memory_stats() is
-    None on CPU and may be absent on exotic backends — silently a no-op
-    there (the gauges simply never appear)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:  # pragma: no cover
-        return
-    if not stats:
-        return
+def sample_device_gauges(registry: MetricRegistry, role: str = "") -> list:
+    """Device-memory gauges over every local device.  Each gauge holds the
+    fullest chip's figure (the one an OOM would hit first), and
+    ``device_bytes_in_use_min`` the emptiest chip's, so replay or batches
+    piling onto one chip of a mesh show as a gap between the two.  Returns
+    the per-device ``bytes_in_use`` list for the timing row.  memory_stats()
+    is None on CPU — a no-op there (the gauges simply never appear)."""
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if not all(stats):
+        return []
     for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
-        if key in stats:
-            registry.gauge(f"device_{key}", role).set(float(stats[key]))
+        if all(key in s for s in stats):
+            registry.gauge(f"device_{key}", role).set(
+                float(max(s[key] for s in stats)))
+    in_use = [int(s.get("bytes_in_use", 0)) for s in stats]
+    registry.gauge("device_bytes_in_use_min", role).set(float(min(in_use)))
+    return in_use

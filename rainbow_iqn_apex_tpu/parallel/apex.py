@@ -1397,7 +1397,7 @@ def train_apex(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, Any]:
                             # per-game breakdown (docs/MULTITASK.md): learn
                             # share, replay occupancy, latest eval score,
                             # human-normalized aggregate — the row obs_report
-                            # `games:` and relay_watch key on
+                            # `games:` and obs/attribution key on
                             metrics.log(
                                 "games", step=step, frames=frames,
                                 schedule=cfg.multitask_schedule,

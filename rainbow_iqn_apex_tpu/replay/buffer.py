@@ -16,8 +16,9 @@ TPU-first design notes:
   and n-step assembly rely on — is preserved per lane, with one global
   sum-tree over all slots.  This replaces the reference's one-process-one-
   buffer adjacency assumption without giving up dedup.
-- The sum-tree hot path can be served by the C++ core (replay/native.py)
-  with identical layout; `SumTree` is the NumPy fallback.
+- The sum-tree hot path is served by the C++ core (replay/native.py) with
+  identical layout; `use_native=False` selects the NumPy `SumTree`, which
+  the fuzz tests keep as the reference.  A core that cannot be built raises.
 """
 
 from __future__ import annotations
@@ -102,17 +103,13 @@ class PrioritizedReplay:
             from rainbow_iqn_apex_tpu.replay.native import (
                 NativeSumTree,
                 ReplayCore,
-                native_available,
             )
 
-            if native_available():
-                self.tree = NativeSumTree(capacity)
-                # rb_assemble's per-window scratch is sized for history<=16
-                # (any sane stack depth); deeper stacks use the NumPy path
-                if history <= 16:
-                    self._core = ReplayCore(self)
-            else:
-                self.tree = SumTree(capacity)
+            self.tree = NativeSumTree(capacity)  # NativeBuildError if unbuilt
+            # rb_assemble's per-window scratch is sized for history<=16
+            # (any sane stack depth); deeper stacks use the NumPy path
+            if history <= 16:
+                self._core = ReplayCore(self)
         else:
             self.tree = SumTree(capacity)
 

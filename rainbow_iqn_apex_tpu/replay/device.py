@@ -1,9 +1,9 @@
 """Device-resident prioritized replay: the ring, the priorities, and every
 sample/update in HBM, so a learner step needs ZERO per-step host transfer.
 
-Why this exists (round-2 measurement, docs/STATUS.md): on the TPU the full
-learn step is 0.53 ms but feeding it a host-sampled batch costs 5-8 ms of
-host->device transfer — the learner is >90% transfer-bound.  The reference
+Why this exists: feeding the learn step a host-sampled batch costs a
+host->device transfer every step (its share of the step on a directly
+attached chip is not measured; ROADMAP S1/S2).  The reference
 solves replay with a NETWORK hop (Redis, SURVEY.md §2 row 6); the host-DRAM
 shards (replay/buffer.py) replace that hop with a PCIe hop; this module
 removes the hop entirely for the capacity that fits in HBM: an Atari-shaped
@@ -346,17 +346,6 @@ class DeviceReplay:
         )
 
 
-def _shard_map():
-    """jax.shard_map (stable since jax 0.6; replication checks on — every
-    out_spec below is either shard-varying or provably replicated)."""
-    try:
-        return jax.shard_map
-    except AttributeError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 def build_device_learn_sharded(cfg, num_actions: int, local_replay: DeviceReplay, mesh, axis: str = "dp"):
     """Multi-chip Anakin: the HBM replay lane-sharded over the mesh's dp axis,
     the learn step dp-sharded as usual — zero host traffic per step on every
@@ -390,7 +379,6 @@ def build_device_learn_sharded(cfg, num_actions: int, local_replay: DeviceReplay
         obs=P(axis), action=P(axis), reward=P(axis),
         next_obs=P(axis), discount=P(axis), weight=P(axis),
     )
-    smap = _shard_map()
 
     def _draw_assemble(ds_loc, key, beta):
         """Per-shard fixed-quota draw; with cfg.sample_groups > 1 each shard
@@ -429,12 +417,12 @@ def build_device_learn_sharded(cfg, num_actions: int, local_replay: DeviceReplay
             max_priority=jax.lax.pmax(ds_loc.max_priority, axis)
         )
 
-    draw_assemble = smap(
+    draw_assemble = jax.shard_map(
         _draw_assemble, mesh=mesh,
         in_specs=(state_spec, P(), P()),
         out_specs=(P(axis), batch_spec),
     )
-    write_back = smap(
+    write_back = jax.shard_map(
         _write_back, mesh=mesh,
         in_specs=(state_spec, P(axis), P(axis)),
         out_specs=state_spec,

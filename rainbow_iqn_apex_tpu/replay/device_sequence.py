@@ -380,15 +380,6 @@ def device_seq_shardings(mesh, axis: str = "dp"):
     )
 
 
-def _shard_map():
-    try:
-        return jax.shard_map
-    except AttributeError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 def _unstack(gs: DeviceSeqState) -> DeviceSeqState:
     return jax.tree.map(lambda x: x[0], gs)
 
@@ -406,14 +397,13 @@ def build_sharded_seq_append(replay: DeviceSequenceReplay, mesh,
     PER-DEVICE lane count and capacity."""
     P = jax.sharding.PartitionSpec
     state_spec = device_seq_specs(axis)
-    smap = _shard_map()
 
     def _append(gs, frames, actions, rewards, terms, truncs, c, h):
         s = replay.append(_unstack(gs), frames, actions, rewards, terms,
                           truncs, c, h)
         return _restack(s)
 
-    return smap(
+    return jax.shard_map(
         _append, mesh=mesh,
         in_specs=(state_spec, P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis)),
@@ -450,7 +440,6 @@ def build_device_r2d2_learn_sharded(cfg, num_actions: int,
         obs=P(axis), action=P(axis), reward=P(axis), done=P(axis),
         valid=P(axis), init_c=P(axis), init_h=P(axis), weight=P(axis),
     )
-    smap = _shard_map()
 
     def _draw_assemble(gs, key, beta):
         """Per-shard fixed-quota draw; cfg.sample_groups > 1 draws G groups
@@ -486,12 +475,12 @@ def build_device_r2d2_learn_sharded(cfg, num_actions: int,
             s = local_replay.update_priorities(s, idx, td_mix)
         return _restack(s)
 
-    draw_assemble = smap(
+    draw_assemble = jax.shard_map(
         _draw_assemble, mesh=mesh,
         in_specs=(state_spec, P(), P()),
         out_specs=(P(axis), batch_spec),
     )
-    write_back = smap(
+    write_back = jax.shard_map(
         _write_back, mesh=mesh,
         in_specs=(state_spec, P(axis), P(axis)),
         out_specs=state_spec,

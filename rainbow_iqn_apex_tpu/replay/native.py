@@ -3,15 +3,18 @@
 v1: sum-tree set/find hot loops (sumtree.cc).  v2 adds the fused per-tick
 append and per-batch assembly paths (replay_core.cc).  Builds one shared
 library on first use with g++ (toolchain is baked into the image; no
-pip/pybind11 needed) and caches it next to the sources.  Falls back silently
-to the NumPy implementation when no compiler is available —
-``native_available()`` is the gate.
+pip/pybind11 needed) and caches it next to the sources.  A build or load
+failure raises ``NativeBuildError`` carrying g++'s stderr: whoever asked for
+the native core (``use_native_sumtree=True``, the default) gets it or an
+error, never a silent NumPy substitute.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Tuple
@@ -25,26 +28,47 @@ _SRCS = (
     os.path.join(_HERE, "native", "sumtree.cc"),
     os.path.join(_HERE, "native", "replay_core.cc"),
 )
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC")
+
+
+class NativeBuildError(RuntimeError):
+    """The C++ replay core could not be compiled or loaded on this host."""
+
+
+def _host_signature() -> bytes:
+    """What ``-march=native`` resolves to here: the architecture plus the
+    CPU's model and feature flags.  Part of the artifact name, so a binary
+    compiled for another machine's CPU (a checkout copied between hosts
+    carries its untracked ``.so`` files along) is never picked up."""
+    lines = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen = set()
+            for line in f:
+                field = line.split(":", 1)[0].strip()
+                if field in ("model name", "flags", "Features") and field not in seen:
+                    seen.add(field)
+                    lines.append(line.strip())
+    except OSError:
+        lines.append(platform.processor())
+    return "\n".join(lines).encode()
 
 
 def _so_path() -> str:
-    """Cache path keyed by source hash: a stale or foreign-host binary (built
-    with -march=native elsewhere) is never loaded — any source change or
-    fresh checkout gets its own artifact name and triggers a rebuild."""
-    import hashlib
-
+    """Artifact name keyed by sources, flags and host CPU: any source change,
+    fresh checkout or other machine gets its own name and a rebuild."""
     h = hashlib.sha256()
     for src in _SRCS:
         with open(src, "rb") as f:
             h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_host_signature())
     return os.path.join(_HERE, "native", f"_replay_{h.hexdigest()[:16]}.so")
 
 
-_SO = _so_path()
-
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
+_error: Optional[NativeBuildError] = None
 
 _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 _f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
@@ -53,60 +77,94 @@ _i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
 _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 
 
-def _build_and_load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+def _compile(so: str) -> None:
+    # build under a private name, then rename: a concurrent process never
+    # dlopens a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        proc = subprocess.run(
+            ["g++", *_FLAGS, *_SRCS, "-o", tmp],
+            capture_output=True, text=True, timeout=120,
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"could not run g++: {e!r}") from e
+    if proc.returncode != 0:
+        raise NativeBuildError(
+            f"g++ exited {proc.returncode} building the replay core:\n"
+            f"{proc.stderr.strip()}")
+    os.replace(tmp, so)
+
+
+def _declare(lib: ctypes.CDLL) -> None:
+    lib.st_set.argtypes = [_f64p, ctypes.c_int64, _i64p, _f64p, ctypes.c_int64]
+    lib.st_set.restype = None
+    lib.st_find_prefix.argtypes = [
+        _f64p, ctypes.c_int64, ctypes.c_int64, _f64p, _i64p, ctypes.c_int64,
+    ]
+    lib.st_find_prefix.restype = None
+    lib.st_sample.argtypes = [
+        _f64p, ctypes.c_int64, ctypes.c_int64, _f64p, _i64p, _f64p,
+        ctypes.c_int64,
+    ]
+    lib.st_sample.restype = None
+    i64 = ctypes.c_int64
+    lib.rb_append_tick.argtypes = [
+        _u8p, _i32p, _f32p, _u8p, _u8p,  # frames/actions/rewards/term/cuts
+        _f64p, i64,  # tree, span
+        i64, i64, i64, i64, i64, i64, i64,  # lanes seg pos filled hist n fb
+        _u8p, _i32p, _f32p, _u8p,  # new frame/action/reward/terminal
+        ctypes.c_void_p, ctypes.c_void_p,  # truncs?, priorities?
+        ctypes.c_double, ctypes.c_double,  # eps, omega
+        ctypes.POINTER(ctypes.c_double),  # max_priority (inout)
+    ]
+    lib.rb_append_tick.restype = None
+    lib.rb_assemble.argtypes = [
+        _u8p, _i32p, _f32p, _u8p, _u8p,
+        i64, i64, i64, i64, i64,  # seg filled hist n fb
+        _f32p,  # gammas
+        _i64p, i64,  # idx, batch
+        _u8p, _u8p, _i32p, _f32p, _f32p,  # outputs
+    ]
+    lib.rb_assemble.restype = None
+
+
+def _build_and_load() -> ctypes.CDLL:
+    """The loaded library; built first if this host has no artifact yet.
+    The outcome — library or error — is decided once per process."""
+    global _lib, _error
     with _lock:
-        if _lib is not None or _tried:
+        if _lib is not None:
             return _lib
-        _tried = True
+        if _error is not None:
+            raise _error
         try:
-            if not os.path.exists(_SO):  # name is content-hashed: exists == fresh
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-shared", "-fPIC", *_SRCS,
-                     "-o", _SO],
-                    check=True,
-                    capture_output=True,
-                    timeout=120,
-                )
-            lib = ctypes.CDLL(_SO)
-            lib.st_set.argtypes = [_f64p, ctypes.c_int64, _i64p, _f64p, ctypes.c_int64]
-            lib.st_set.restype = None
-            lib.st_find_prefix.argtypes = [
-                _f64p, ctypes.c_int64, ctypes.c_int64, _f64p, _i64p, ctypes.c_int64,
-            ]
-            lib.st_find_prefix.restype = None
-            lib.st_sample.argtypes = [
-                _f64p, ctypes.c_int64, ctypes.c_int64, _f64p, _i64p, _f64p,
-                ctypes.c_int64,
-            ]
-            lib.st_sample.restype = None
-            i64 = ctypes.c_int64
-            lib.rb_append_tick.argtypes = [
-                _u8p, _i32p, _f32p, _u8p, _u8p,  # frames/actions/rewards/term/cuts
-                _f64p, i64,  # tree, span
-                i64, i64, i64, i64, i64, i64, i64,  # lanes seg pos filled hist n fb
-                _u8p, _i32p, _f32p, _u8p,  # new frame/action/reward/terminal
-                ctypes.c_void_p, ctypes.c_void_p,  # truncs?, priorities?
-                ctypes.c_double, ctypes.c_double,  # eps, omega
-                ctypes.POINTER(ctypes.c_double),  # max_priority (inout)
-            ]
-            lib.rb_append_tick.restype = None
-            lib.rb_assemble.argtypes = [
-                _u8p, _i32p, _f32p, _u8p, _u8p,
-                i64, i64, i64, i64, i64,  # seg filled hist n fb
-                _f32p,  # gammas
-                _i64p, i64,  # idx, batch
-                _u8p, _u8p, _i32p, _f32p, _f32p,  # outputs
-            ]
-            lib.rb_assemble.restype = None
-            _lib = lib
-        except Exception:
-            _lib = None
+            so = _so_path()
+            if not os.path.exists(so):  # name is content+host keyed: exists == fresh
+                _compile(so)
+            try:
+                lib = ctypes.CDLL(so)
+            except OSError as e:
+                raise NativeBuildError(f"could not load {so}: {e}") from e
+        except NativeBuildError as e:
+            _error = e
+            raise
+        _declare(lib)
+        _lib = lib
         return _lib
 
 
 def native_available() -> bool:
-    return _build_and_load() is not None
+    try:
+        _build_and_load()
+    except NativeBuildError:
+        return False
+    return True
+
+
+def loaded_library() -> Optional[str]:
+    """Path of the shared library this process is running, or None when the
+    native core has not been loaded (chip_smoke.py reports it)."""
+    return None if _lib is None else _lib._name
 
 
 class NativeSumTree(SumTree):
@@ -120,8 +178,6 @@ class NativeSumTree(SumTree):
     def __init__(self, capacity: int):
         super().__init__(capacity)
         self._lib = _build_and_load()
-        if self._lib is None:
-            raise RuntimeError("native sum-tree unavailable (no compiler?)")
 
     def set(self, idx: np.ndarray, priority: np.ndarray) -> None:
         idx = np.ascontiguousarray(np.asarray(idx, np.int64).ravel())
@@ -168,8 +224,6 @@ class ReplayCore:
 
     def __init__(self, buf):
         self._lib = _build_and_load()
-        if self._lib is None:
-            raise RuntimeError("native replay core unavailable (no compiler?)")
         self._b = buf
         self._fb = buf.frames.shape[1] * buf.frames.shape[2]
 

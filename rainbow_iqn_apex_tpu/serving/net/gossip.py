@@ -16,7 +16,7 @@ snapshots expire after ``stale_factor`` intervals, so a dead router's stale
 load claims stop skewing dispatch on the monitor's own clock.
 
 jax-free; a `gossip` JSONL row at a low cadence records peer freshness for
-obs_report/relay_watch.
+obs_report/obs.attribution.
 """
 
 from __future__ import annotations

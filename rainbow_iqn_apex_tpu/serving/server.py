@@ -42,6 +42,7 @@ from rainbow_iqn_apex_tpu.serving.swap import (
     restore_params,
 )
 from rainbow_iqn_apex_tpu.utils.checkpoint import Checkpointer
+from rainbow_iqn_apex_tpu.utils.compile_cache import enable_compile_cache
 from rainbow_iqn_apex_tpu.utils.logging import MetricsLogger
 
 
@@ -65,6 +66,7 @@ class PolicyServer:
         metrics_path: Optional[str] = None,
         echo_metrics: bool = False,
     ):
+        enable_compile_cache()  # before the engine's first bucket compiles
         self.cfg = cfg
         self.num_actions = num_actions
         self.metrics = ServeMetrics(

@@ -9,8 +9,8 @@ scheduled target update (inside the learn graph), Orbax checkpoints, JSONL
 metrics, periodic eval.  What changes is WHERE the replay lives: the
 reference keeps it in Redis (a network hop per sample, SURVEY §2 row 6), the
 host trainers here keep it in host DRAM (a PCIe hop), and this one keeps it
-in HBM — zero per-step transfer, which round-2 profiling showed is >90% of
-the learner's wall time on this hardware (docs/STATUS.md).
+in HBM — zero per-step transfer (what share of the host-fed step that
+transfer is on a directly attached chip is not measured; ROADMAP S2).
 
 Per tick, exactly TWO dispatches and ~7 KB/lane of host->device traffic:
   1. act_append: append LAST tick's completed transition into the HBM ring
@@ -435,6 +435,11 @@ def train_anakin_fused(cfg: Config, max_frames: Optional[int] = None) -> Dict[st
                                               local_replay, mesh)
         _lane = NamedSharding(mesh, P("dp"))
         _rep = NamedSharding(mesh, P())
+        # the ring is born sharded: built whole it would sit on the first
+        # chip (twice over while place() copies it out), and a ring sized for
+        # the mesh's memory does not fit one chip's
+        init_replay = jax.jit(
+            replay.init_state, out_shardings=device_replay_shardings(mesh))
 
         def place(carry):
             ts, ds, env_s, ep, stack, frame, keep, frames = carry
@@ -448,6 +453,7 @@ def train_anakin_fused(cfg: Config, max_frames: Optional[int] = None) -> Dict[st
             )
     else:
         learn_fn = build_device_learn(cfg, game.num_actions, replay)
+        init_replay = replay.init_state
         place = lambda carry: carry  # noqa: E731
 
     segment = build_fused_segment(cfg, game, replay, learn_fn)
@@ -458,7 +464,7 @@ def train_anakin_fused(cfg: Config, max_frames: Optional[int] = None) -> Dict[st
     obs_run = RunObs(cfg, metrics, role="learner")
 
     frames = 0
-    ds = replay.init_state()
+    ds = init_replay()
     restored = maybe_resume(cfg, ckpt, ts)
     if restored is not None:
         ts, extra, _ = restored
@@ -499,8 +505,9 @@ def train_anakin_fused(cfg: Config, max_frames: Optional[int] = None) -> Dict[st
                 prev_steps = learn_steps
                 learn_steps = int(ts.step)  # in-graph counter is authoritative
             # the segment IS the dispatch unit here; the int(ts.step) readback
-            # above already synced, so the lap needs no extra block
-            obs_run.after_learn_step(learn_steps)
+            # above already synced, so the lap needs no extra block.  units:
+            # the timing row's steps_per_sec counts learn steps, not segments
+            obs_run.after_learn_step(learn_steps, units=learn_steps - prev_steps)
             for r in np.asarray(out_ret)[~np.isnan(np.asarray(out_ret))]:
                 returns.append(float(r))
 

@@ -298,10 +298,11 @@ def train_anakin_r2d2(cfg: Config,
             cfg, game.num_actions, local_replay, mesh
         )
         append_fn = build_sharded_seq_append(local_replay, mesh)
-        ss0 = jax.device_put(
-            stack_seq_shards(local_replay.init_state(), n_dev),
-            device_seq_shardings(mesh),
-        )
+        # born sharded (see train_anakin_fused): never whole on the first chip
+        ss0 = jax.jit(
+            lambda: stack_seq_shards(local_replay.init_state(), n_dev),
+            out_shardings=device_seq_shardings(mesh),
+        )()
         _lane = NamedSharding(mesh, P("dp"))
         _rep = NamedSharding(mesh, P())
 
@@ -372,7 +373,8 @@ def train_anakin_r2d2(cfg: Config,
                 frames += T * lanes
                 prev_steps = learn_steps
                 learn_steps = int(ts.step)
-            obs_run.after_learn_step(learn_steps)
+            # units: steps_per_sec counts learn steps, not segments
+            obs_run.after_learn_step(learn_steps, units=learn_steps - prev_steps)
             for r in np.asarray(out_ret)[~np.isnan(np.asarray(out_ret))]:
                 returns.append(float(r))
 

@@ -8,8 +8,8 @@ Coverage map (the ISSUE's test satellite):
 2. Meta-test: the full-package run is finding-free against the checked-in
    baseline — which is asserted EMPTY (no grandfathered debt at merge).
 3. Self-hosting: the jax-free checker's declared set covers analysis/*
-   itself plus scripts/obs_report.py + scripts/relay_watch.py, and all of
-   it verifies clean.
+   itself plus scripts/obs_report.py + obs/attribution.py, and all of it
+   verifies clean.
 4. Regression pins for the real findings this PR fixed (elastic beat
    counters, gossip counters, RemoteTransport version, router cadence
    stamp, Agent.act hand-off, notice/actor/adopt row kinds).
@@ -157,7 +157,7 @@ def test_jaxfree_self_hosting_declared_set():
         "rainbow_iqn_apex_tpu/analysis/configcheck.py",
         "rainbow_iqn_apex_tpu/analysis/runner.py",
         "scripts/obs_report.py",
-        "scripts/relay_watch.py",
+        "rainbow_iqn_apex_tpu/obs/attribution.py",
         "scripts/lint_jsonl.py",
     ):
         assert must in declared, must

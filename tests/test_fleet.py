@@ -645,26 +645,13 @@ def test_fleet_row_kinds_validate_and_fold_into_health(tmp_path):
         assert validate_row(r) == [], r
 
 
-def test_relay_watch_attribution_tallies_fleet_rows(tmp_path):
-    """A phase that drove a fleet (the bench soak) gets its route/scale/
-    rollout activity attributed in its phase_done row, like the heal
-    tallies."""
-    import importlib.util
+def test_health_attribution_tallies_fleet_rows(tmp_path):
+    """A run that drove a fleet (the bench soak) gets its route/scale/
+    rollout activity attributed in the summary, like the heal tallies."""
     import json
-    import os
-    import sys
 
-    spec = importlib.util.spec_from_file_location(
-        "relay_watch_for_fleet",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "scripts", "relay_watch.py"))
-    mod = importlib.util.module_from_spec(spec)
-    saved_argv = sys.argv
-    sys.argv = ["relay_watch.py"]
-    try:
-        spec.loader.exec_module(mod)
-    finally:
-        sys.argv = saved_argv
+    from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
+
     run = tmp_path / "runs" / "r0"
     run.mkdir(parents=True)
     with open(run / "metrics.jsonl", "w") as f:
@@ -675,7 +662,7 @@ def test_relay_watch_attribution_tallies_fleet_rows(tmp_path):
                             "engines": 3}) + "\n")
         f.write(json.dumps({"kind": "rollout", "event": "publish",
                             "version": 2}) + "\n")
-    attr = mod.health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
+    attr = health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
     assert attr["fleet"] == {"route": 2, "scale": 1, "rollout": 1}
     assert attr["rows"] == 1  # health rows unaffected
 

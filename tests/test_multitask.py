@@ -350,20 +350,8 @@ def test_obs_report_games_section():
                        "loss": 0.0}])["games"] == {}
 
 
-def test_relay_watch_per_game_tallies(tmp_path, monkeypatch):
-    # relay_watch parses argv at import; load it side-effect free the way
-    # tests/test_relay_watch.py does
-    import importlib.util
-    import sys
-
-    spec = importlib.util.spec_from_file_location(
-        "relay_watch_mt_test",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "scripts", "relay_watch.py"))
-    mod = importlib.util.module_from_spec(spec)
-    monkeypatch.setattr(sys, "argv", ["relay_watch.py"])
-    spec.loader.exec_module(mod)
-    health_attribution = mod.health_attribution
+def test_health_attribution_per_game_tallies(tmp_path):
+    from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
 
     path = tmp_path / "metrics.jsonl"
     rows = [

@@ -4,7 +4,7 @@ TransportServer loop over real loopback sockets, lease-driven remote
 discovery with BOUNDED liveness probes, router federation via UDP gossip,
 wire weight rollouts (int8-delta, backward refusal at both ends, bit-exact
 digests), and the obs folding (net/gossip rows -> schema/lint/RunHealth/
-obs_report/relay_watch).  Everything here is jax-free: engines are protocol
+obs_report/health_attribution).  Everything here is jax-free: engines are protocol
 fakes driving the REAL sockets — `make net-smoke` runs the multi-process
 fleet against real PolicyServers on top."""
 
@@ -930,33 +930,15 @@ def test_runhealth_folds_reconnect_storm_as_degraded():
     assert health.tick(step=4)["status"] == "ok"
 
 
-def _load_relay_watch(monkeypatch):
-    """relay_watch guards its argv at import (it is a long-running daemon
-    script); load it the way tests/test_relay_watch.py does."""
-    import importlib.util
-    import os
-    import sys
-
-    spec = importlib.util.spec_from_file_location(
-        "relay_watch_under_net_test",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "scripts", "relay_watch.py"))
-    mod = importlib.util.module_from_spec(spec)
-    monkeypatch.setattr(sys, "argv", ["relay_watch.py"])
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_obs_report_net_section_and_relay_watch_tally(tmp_path, monkeypatch):
+def test_obs_report_net_section_and_health_attribution_tally(tmp_path):
     import os
     import sys
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
     from scripts.obs_report import aggregate, render
 
+    from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
     from rainbow_iqn_apex_tpu.utils.logging import MetricsLogger
-
-    health_attribution = _load_relay_watch(monkeypatch).health_attribution
 
     path = str(tmp_path / "metrics.jsonl")
     logger = MetricsLogger(path, run_id="t", echo=False)
@@ -979,6 +961,6 @@ def test_obs_report_net_section_and_relay_watch_tally(tmp_path, monkeypatch):
     assert peer["bytes_sent"] == 1234 and peer["disconnects"] == 1
     text = render(report)
     assert "net:" in text and "127.0.0.1:7001" in text
-    # relay_watch attribution tallies the same kinds
+    # health_attribution tallies the same kinds
     att = health_attribution(path)
     assert att["net"] == {"net": 3, "gossip": 1, "flaps": 1}

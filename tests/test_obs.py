@@ -445,20 +445,9 @@ def test_policy_server_serves_metrics_and_healthz():
         urllib.request.urlopen(f"http://127.0.0.1:{port}/healthz", timeout=2)
 
 
-def test_relay_watch_health_attribution(tmp_path):
-    import importlib.util
+def test_health_attribution(tmp_path):
+    from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
 
-    spec = importlib.util.spec_from_file_location(
-        "relay_watch_for_obs",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "scripts", "relay_watch.py"))
-    mod = importlib.util.module_from_spec(spec)
-    saved_argv = sys.argv
-    sys.argv = ["relay_watch.py"]
-    try:
-        spec.loader.exec_module(mod)
-    finally:
-        sys.argv = saved_argv
     run = tmp_path / "runs" / "r0"
     run.mkdir(parents=True)
     with open(run / "metrics.jsonl", "w") as f:
@@ -466,10 +455,10 @@ def test_relay_watch_health_attribution(tmp_path):
         f.write(json.dumps({"kind": "health", "status": "degraded"}) + "\n")
         f.write(json.dumps({"kind": "learn", "step": 1}) + "\n")
         f.write("garbage line\n")
-    attr = mod.health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
+    attr = health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
     assert attr["rows"] == 2 and attr["counts"]["degraded"] == 1
     assert attr["last"] == "degraded" and attr["worst"] == "degraded"
-    empty = mod.health_attribution(str(tmp_path / "nope" / "*.jsonl"))
+    empty = health_attribution(str(tmp_path / "nope" / "*.jsonl"))
     assert empty["rows"] == 0 and empty["worst"] is None
 
 
@@ -564,20 +553,9 @@ def test_health_fenced_actor_holds_degraded_until_resume():
     assert h.tick(20)["status"] == "ok"
 
 
-def test_relay_watch_health_attribution_counts_heals(tmp_path):
-    import importlib.util
+def test_health_attribution_counts_heals(tmp_path):
+    from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
 
-    spec = importlib.util.spec_from_file_location(
-        "relay_watch_for_elastic",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "scripts", "relay_watch.py"))
-    mod = importlib.util.module_from_spec(spec)
-    saved_argv = sys.argv
-    sys.argv = ["relay_watch.py"]
-    try:
-        spec.loader.exec_module(mod)
-    finally:
-        sys.argv = saved_argv
     run = tmp_path / "runs" / "r0"
     run.mkdir(parents=True)
     with open(run / "metrics.jsonl", "w") as f:
@@ -589,7 +567,7 @@ def test_relay_watch_health_attribution_counts_heals(tmp_path):
         f.write(json.dumps({"kind": "actor_fenced", "action": "fence",
                             "lag": 3, "max_lag": 2}) + "\n")
         f.write(json.dumps({"kind": "health", "status": "ok"}) + "\n")
-    attr = mod.health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
+    attr = health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
     assert attr["rows"] == 2 and attr["last"] == "ok"
     assert attr["heals"] == {"host_alive": 1, "shard_readmit": 1,
                              "actor_fenced": 1}

@@ -590,22 +590,12 @@ def test_untraced_apex_run_emits_no_spans(tmp_path):
     assert not [r for r in rows if r["kind"] == "span_link"]
 
 
-# --------------------------------------------------------- relay_watch
+# --------------------------------------------------- health_attribution
 
 
-def test_relay_watch_trace_tally_and_critical_path_echo(tmp_path):
-    import importlib.util
+def test_health_attribution_trace_tally_and_critical_path_echo(tmp_path):
+    from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
 
-    spec = importlib.util.spec_from_file_location(
-        "relay_watch_for_trace",
-        os.path.join(REPO, "scripts", "relay_watch.py"))
-    mod = importlib.util.module_from_spec(spec)
-    saved_argv = sys.argv
-    sys.argv = ["relay_watch.py"]
-    try:
-        spec.loader.exec_module(mod)
-    finally:
-        sys.argv = saved_argv
     run = tmp_path / "runs" / "r0"
     run.mkdir(parents=True)
     with open(run / "metrics.jsonl", "w") as f:
@@ -619,9 +609,9 @@ def test_relay_watch_trace_tally_and_critical_path_echo(tmp_path):
             "kind": "span_link", "stage": "learn_step", "trace_id": "l0-4",
             "span_id": 2, "parent_id": 0, "t0": 0.0, "dur_ms": 39.0,
             "host": 0}) + "\n")
-    attr = mod.health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
+    attr = health_attribution(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
     assert attr["trace"] == {"span_link": 2, "lag": 1}
     assert attr["critical_path"] == "gather 61% (sampler-starved)"
-    # untraced phases echo None, not a crash
-    empty = mod.health_attribution(str(tmp_path / "nope" / "*.jsonl"))
+    # untraced runs echo None, not a crash
+    empty = health_attribution(str(tmp_path / "nope" / "*.jsonl"))
     assert empty["critical_path"] is None and empty["trace"]["span_link"] == 0

@@ -642,22 +642,9 @@ class TestObsSurface:
         assert q["active"] is False and q["last_agreement"] == 0.5
         assert "quant:" in render(report)
 
-    def test_relay_watch_tallies_quant_rows(self, tmp_path, monkeypatch):
-        import importlib.util
-        import sys
-
+    def test_health_attribution_tallies_quant_rows(self, tmp_path):
+        from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
         from rainbow_iqn_apex_tpu.utils.logging import MetricsLogger
-
-        # relay_watch validates argv at import; load it the way
-        # tests/test_relay_watch.py does (side-effect-free)
-        spec = importlib.util.spec_from_file_location(
-            "relay_watch_quant_test",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "scripts", "relay_watch.py"))
-        mod = importlib.util.module_from_spec(spec)
-        monkeypatch.setattr(sys, "argv", ["relay_watch.py"])
-        spec.loader.exec_module(mod)
-        health_attribution = mod.health_attribution
 
         path = str(tmp_path / "metrics.jsonl")
         logger = MetricsLogger(path, run_id="quant_test", echo=False)

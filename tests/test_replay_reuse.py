@@ -307,10 +307,8 @@ def test_publish_boundaries_mid_reuse_drain_cleanly(tmp_path):
     crossing still fires once (cadence_hit), each boundary drains the ring
     between fused dispatches, and the run completes with versions
     advancing.  The learn rows' reuse fields fold into health rows +
-    obs_report's pipeline line + relay_watch's tally."""
-    import importlib.util
-    import sys as _sys
-
+    obs_report's pipeline line + health_attribution's tally."""
+    from rainbow_iqn_apex_tpu.obs.attribution import health_attribution
     from rainbow_iqn_apex_tpu.parallel.apex import train_apex
     from scripts.lint_jsonl import lint_line
     from scripts.obs_report import aggregate
@@ -345,19 +343,7 @@ def test_publish_boundaries_mid_reuse_drain_cleanly(tmp_path):
     report = aggregate(rows)
     assert report["pipeline"]["replay_ratio"] == 4
     assert report["pipeline"]["reuse_clip_frac"] is not None
-    # relay_watch parses argv at import (the real watcher's typo guard) —
-    # load it the way test_relay_watch.py does, argv scrubbed
-    spec = importlib.util.spec_from_file_location(
-        "relay_watch_for_reuse",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "scripts", "relay_watch.py"))
-    rw = importlib.util.module_from_spec(spec)
-    argv, _sys.argv = _sys.argv, ["relay_watch.py"]
-    try:
-        spec.loader.exec_module(rw)
-    finally:
-        _sys.argv = argv
-    tally = rw.health_attribution(path)
+    tally = health_attribution(path)
     assert tally["reuse"]["rows"] == len(learn_rows)
     assert tally["reuse"]["replay_ratio"] == 4
 

@@ -28,6 +28,10 @@ from rainbow_iqn_apex_tpu.config import parse_config
 
 def main(argv=None) -> int:
     cfg = parse_config(argv)
+    if cfg.role != "standby":  # the standby stays jax-free until it takes over
+        from rainbow_iqn_apex_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     if cfg.process_count > 1:
         # Pod mode: every host runs this same program (--process-id differs);
         # jax.distributed couples them the way Redis coupled the reference's
