@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from rainbow_iqn_apex_tpu.models.layers import ConvTrunk, CosineTauEmbedding, NoisyLinear
+from rainbow_iqn_apex_tpu.obs import device_scopes
 
 Dtype = Any
 
@@ -57,46 +58,48 @@ class RainbowIQN(nn.Module):
         if obs.dtype == jnp.uint8:
             obs = obs.astype(self.compute_dtype) * (1.0 / 255.0)
 
-        phi = ConvTrunk(compute_dtype=self.compute_dtype)(obs)  # [B, F]
+        with jax.named_scope(device_scopes.NET_TRUNK):
+            phi = ConvTrunk(compute_dtype=self.compute_dtype)(obs)  # [B, F]
         feat = phi.shape[-1]
 
         if taus is None:
             taus = jax.random.uniform(
                 self.make_rng("taus"), (batch, num_taus), jnp.float32
             )
-        psi = CosineTauEmbedding(
-            features=feat,
-            num_cosines=self.num_cosines,
-            compute_dtype=self.compute_dtype,
-        )(taus)  # [B, N, F]
-
-        # Hadamard merge, then fold taus into batch: [B*N, F] for one big GEMM.
-        h = phi[:, None, :].astype(self.compute_dtype) * psi
-        h = h.reshape(batch * num_taus, feat)
-
-        def head(name: str, out_dim: int) -> jnp.ndarray:
-            h1 = NoisyLinear(
-                self.hidden_size,
-                sigma0=self.noisy_sigma0,
-                use_noise=self.use_noise,
+        with jax.named_scope(device_scopes.IQN_HEAD):
+            psi = CosineTauEmbedding(
+                features=feat,
+                num_cosines=self.num_cosines,
                 compute_dtype=self.compute_dtype,
-                name=f"{name}_hidden",
-            )(h)
-            h1 = nn.relu(h1)
-            return NoisyLinear(
-                out_dim,
-                sigma0=self.noisy_sigma0,
-                use_noise=self.use_noise,
-                compute_dtype=self.compute_dtype,
-                name=f"{name}_out",
-            )(h1)
+            )(taus)  # [B, N, F]
 
-        if self.dueling:
-            value = head("value", 1)  # [B*N, 1]
-            adv = head("advantage", self.num_actions)  # [B*N, A]
-            q = value + adv - adv.mean(axis=-1, keepdims=True)
-        else:
-            q = head("q", self.num_actions)
+            # Hadamard merge, then fold taus into batch: [B*N, F] for one big GEMM.
+            h = phi[:, None, :].astype(self.compute_dtype) * psi
+            h = h.reshape(batch * num_taus, feat)
+
+            def head(name: str, out_dim: int) -> jnp.ndarray:
+                h1 = NoisyLinear(
+                    self.hidden_size,
+                    sigma0=self.noisy_sigma0,
+                    use_noise=self.use_noise,
+                    compute_dtype=self.compute_dtype,
+                    name=f"{name}_hidden",
+                )(h)
+                h1 = nn.relu(h1)
+                return NoisyLinear(
+                    out_dim,
+                    sigma0=self.noisy_sigma0,
+                    use_noise=self.use_noise,
+                    compute_dtype=self.compute_dtype,
+                    name=f"{name}_out",
+                )(h1)
+
+            if self.dueling:
+                value = head("value", 1)  # [B*N, 1]
+                adv = head("advantage", self.num_actions)  # [B*N, A]
+                q = value + adv - adv.mean(axis=-1, keepdims=True)
+            else:
+                q = head("q", self.num_actions)
 
         quantiles = q.reshape(batch, num_taus, self.num_actions).astype(jnp.float32)
         return quantiles, taus

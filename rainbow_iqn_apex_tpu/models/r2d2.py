@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from rainbow_iqn_apex_tpu.models.layers import ConvTrunk, NoisyLinear
+from rainbow_iqn_apex_tpu.obs import device_scopes
 
 Dtype = Any
 LSTMState = Tuple[jnp.ndarray, jnp.ndarray]  # (c, h), each [B, lstm_size]
@@ -75,9 +76,10 @@ class R2D2Net(nn.Module):
             obs_seq = obs_seq.astype(self.compute_dtype) * (1.0 / 255.0)
 
         # conv trunk over the folded [B*T] batch: one large GEMM per layer
-        phi = ConvTrunk(compute_dtype=self.compute_dtype)(
-            obs_seq.reshape(B * T, *obs_seq.shape[2:])
-        )
+        with jax.named_scope(device_scopes.NET_TRUNK):
+            phi = ConvTrunk(compute_dtype=self.compute_dtype)(
+                obs_seq.reshape(B * T, *obs_seq.shape[2:])
+            )
         phi = phi.reshape(B, T, -1).astype(jnp.float32)  # LSTM carries in fp32
 
         xs = (
@@ -93,7 +95,9 @@ class R2D2Net(nn.Module):
             in_axes=0,
             out_axes=0,
         )
-        final_state, outs = scan(features=self.lstm_size, name="lstm")(state, xs)
+        with jax.named_scope(device_scopes.LSTM_SCAN):
+            final_state, outs = scan(
+                features=self.lstm_size, name="lstm")(state, xs)
         feat = jnp.moveaxis(outs, 0, 1).reshape(B * T, self.lstm_size)  # [B*T, L]
 
         def head(name: str, out_dim: int) -> jnp.ndarray:

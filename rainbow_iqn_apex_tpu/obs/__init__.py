@@ -7,6 +7,8 @@ fed by one process-wide metric surface:
   trace.py      `with span("learn_step"):` host spans aligned with XLA
                 traces, jax compile counters, device-memory gauges, and the
                 --trace-dir step-windowed profiler capture
+  device_scopes.py  the names the compiled programs wrap their work in
+                (jax.named_scope), and device time of a capture by name
   health.py     heartbeats + fault rows + stalls + sheds folded into one
                 periodic 'health' row with status in {ok, degraded, failing}
   export.py     Prometheus text exposition + stdlib /metrics + /healthz
@@ -142,6 +144,7 @@ class RunObs:
             getattr(cfg, "trace_start_step", 0),
             getattr(cfg, "trace_num_steps", 1),
             logger=metrics,
+            tracer=self.tracer,
         )
         install_compile_counter(self.registry)
         self.http: Optional[ObsHTTPServer] = None
@@ -197,6 +200,9 @@ class RunObs:
         }
         timing["compiles"] = int(
             self.registry.counter("jax_compiles_total", "jax").get()
+        )
+        timing["compile_cache_hits"] = int(
+            self.registry.counter("jax_compile_cache_hits_total", "jax").get()
         )
         if device_bytes:  # per local device; absent on backends without stats
             timing["device_bytes_in_use"] = device_bytes

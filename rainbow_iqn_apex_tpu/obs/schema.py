@@ -56,6 +56,14 @@ REQUIRED_KEYS: Dict[str, frozenset] = {
     "timing": frozenset({"step"}),  # StepTimer + span aggregates
     "span": frozenset({"name", "span_id", "parent_id", "dur_ms"}),
     "trace": frozenset({"event", "step"}),  # --trace-dir window open/close
+    "device_time": frozenset({"step", "steps"}),  # the --trace-dir capture
+    # reduced when its window closes (obs/device_scopes.py): window_s/
+    # busy_s/idle_share of the device, `programs` run in the window,
+    # scope_ms_per_step/path_ms_per_step (device self time a learn step by
+    # the programs' scope names), outside_tick_ms_per_dispatch,
+    # unresolved_share, and idle_gaps (each gap over 1 ms with the host
+    # span that covers it).  Absent on a backend with no device plane (CPU);
+    # carries `error` alone where the capture could not be reduced
     # elasticity rows (parallel/elastic.py; docs/RESILIENCE.md "heal"):
     "host_alive": frozenset({"alive_host", "epoch"}),  # lease revival edge
     "shard_readmit": frozenset({"shard", "epoch"}),  # drop_shard reversed

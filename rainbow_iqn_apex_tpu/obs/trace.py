@@ -12,10 +12,12 @@
     armed, the host span shows up as a named region in the XLA trace viewer
     aligned with the device timeline.
 
-Also here: the jax-side gauges (compile/retrace counts via jax.monitoring,
-device memory via Device.memory_stats) and TraceWindow — the step-windowed
-profiler capture that finally wires utils/profiling.device_trace into the
-train loops (--trace-dir; the hooks were dead code before this).
+Also here: the jax-side gauges (compile and cache-hit counts via
+jax.monitoring, device memory via Device.memory_stats) and TraceWindow — the
+step-windowed profiler capture of --trace-dir, which reduces its own capture
+to one 'device_time' row when it closes (obs/device_scopes.py): device busy
+and idle share, milliseconds a learn step by the program's scope names, and
+the long idle gaps by the host span that covers them.
 """
 
 from __future__ import annotations
@@ -25,7 +27,7 @@ import itertools
 import threading
 import time
 import weakref
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional
 
 import jax
 
@@ -54,10 +56,12 @@ class Tracer:
         self.role = role
         self._seen: set = set()
         self._seen_lock = threading.Lock()
+        self.names: set = set()  # every span name this run has opened
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs: Any):
         sid = next(_span_ids)
+        self.names.add(name)
         stack = _stack()
         parent = stack[-1] if stack else 0
         stack.append(sid)
@@ -114,14 +118,22 @@ class TraceWindow:
     the window opens the first time the counter reaches ``start_step`` and
     closes ``num_steps`` later (or at ``close()``, so a short run still
     flushes a partial capture).  Resume-safe: a restored run whose counter
-    is already past the window never arms."""
+    is already past the window never arms.
+
+    When the window closes the capture is reduced (obs/device_scopes.py) and
+    logged as one 'device_time' row; the .xplane.pb stays where it is.  The
+    scopes of the row come from the text of the compiled programs the loop
+    registered with ``add_program``; the idle gaps are named by ``tracer``'s
+    spans.  A capture with no device plane (the CPU backend) logs no row."""
 
     def __init__(self, logdir: str, start_step: int, num_steps: int,
-                 logger=None):
+                 logger=None, tracer: Optional["Tracer"] = None):
         self.logdir = logdir or None
         self.start_step = int(start_step)
         self.num_steps = max(int(num_steps), 1)
         self.logger = logger
+        self.tracer = tracer
+        self._programs: list = []
         self._armed = bool(self.logdir)
         self._stack: Optional[contextlib.ExitStack] = None
         self._opened_at: Optional[int] = None
@@ -129,6 +141,15 @@ class TraceWindow:
     @property
     def active(self) -> bool:
         return self._stack is not None
+
+    def add_program(self, module_text: Callable[[], str]) -> None:
+        """Register a compiled program whose operations the 'device_time' row
+        should put down to scopes: ``module_text()`` returns its text
+        (``jitted.lower(*args).compile().as_text()``, which costs no compile
+        for a program that has run) and is called only when a capture is
+        reduced."""
+        if self._armed:
+            self._programs.append(module_text)
 
     def step(self, step: int) -> None:
         if not self._armed:
@@ -152,13 +173,64 @@ class TraceWindow:
     def _finish(self, step: int) -> None:
         stack, self._stack = self._stack, None
         self._armed = False
+        steps = step - (self._opened_at or step)
         try:
             stack.close()  # stops the profiler; writes the xplane artifacts
         finally:
             if self.logger is not None:
                 self.logger.log("trace", event="trace_captured", step=step,
-                                steps=step - (self._opened_at or step),
-                                logdir=self.logdir)
+                                steps=steps, logdir=self.logdir)
+        if self.logger is not None:
+            row = self.device_time(steps)
+            if row is not None:
+                self.logger.log("device_time", step=step, **row)
+        self._programs.clear()  # the closures hold the loop's frame
+
+    def device_time(self, steps: int) -> Optional[Dict[str, Any]]:
+        """The payload of the 'device_time' row for the capture in
+        ``logdir``, which held ``steps`` learn steps; None where the capture
+        holds no device plane.  Never raises: a reduction that fails says so
+        in the row it returns."""
+        from rainbow_iqn_apex_tpu.obs import device_scopes
+
+        try:
+            events = device_scopes.load_capture(self.logdir)
+            red = events and device_scopes.reduce_events(
+                events, [text() for text in self._programs],
+                self.tracer.names if self.tracer is not None else ())
+        except Exception as e:  # a broken capture must not end the run
+            return {"steps": steps, "error": f"{type(e).__name__}: {e}"}
+        if not red:
+            return None
+        ms = lambda seconds, per: round(1e3 * seconds / max(per, 1), 6)  # noqa: E731
+        note = {}
+        if self._programs and not red["scoped_instructions"]:
+            # the cache's key leaves metadata out, so an executable cached
+            # before the scopes (or before a rename) is loaded with its own
+            note["note"] = ("the compiled text names no scope: the executable "
+                            "came from a compile cache written without them; "
+                            "clear the cache directory")
+        return {
+            **note,
+            "steps": steps,
+            "dispatches": red["dispatches"],
+            "chips": red["chips"],
+            "window_s": red["window_s"],
+            "busy_s": red["busy_s"],
+            "idle_share": round(red["idle_share"], 4),
+            "programs": red["programs"],
+            "scope_ms_per_step": {
+                k: ms(v, steps) for k, v in sorted(red["by_scope"].items())},
+            "path_ms_per_step": {
+                k: ms(v, steps) for k, v in sorted(red["by_path"].items())},
+            "outside_tick_ms_per_dispatch": ms(
+                red["outside_tick_s"], red["dispatches"]),
+            "unresolved_share": round(
+                100.0 * red["unresolved_s"] / red["total_s"], 4)
+            if red["total_s"] else 0.0,
+            "idle_gaps": red["idle_gaps"],
+            "idle_gap_ms_by_span": red["idle_gap_ms_by_span"],
+        }
 
     def close(self, step: int = 0) -> None:
         if self._stack is not None:
@@ -175,8 +247,20 @@ _compile_listener_installed = False
 _compile_lock = threading.Lock()
 
 
+# the events of this JAX (0.9): `compiler.compile_or_get_cached` runs inside
+# one BACKEND_COMPILE duration, be it a backend compile or a load from the
+# persistent cache, and records CACHE_HIT inside it when it was a load
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_pending_hit = threading.local()  # both events come from the compiling thread
+
+
 def install_compile_counter(registry: MetricRegistry) -> bool:
-    """Count XLA compiles/retraces into ``jax_compiles_total`` (role "jax").
+    """Count into ``registry`` (role "jax") the programs this process had to
+    compile, ``jax_compiles_total`` with their seconds in ``jax_compile_s``,
+    and apart from them the programs it loaded from the persistent
+    compilation cache, ``jax_compile_cache_hits_total``.  A run on a warm
+    cache shows hits and no compiles; a compile after warm-up is a retrace.
 
     jax.monitoring has no unregister, so ONE module-level listener fans out
     to a WeakSet of live registries — per-run registries drop out when their
@@ -193,30 +277,29 @@ def install_compile_counter(registry: MetricRegistry) -> bool:
         _compile_listener_attempted = True
 
         def _on_event(event: str, **kw) -> None:
-            if "compil" not in event:
+            if event != CACHE_HIT:
+                return
+            _pending_hit.value = True
+            for reg in list(_compile_registries):
+                reg.counter("jax_compile_cache_hits_total", "jax").inc()
+
+        def _on_duration(event: str, duration: float, **kw) -> None:
+            if event != BACKEND_COMPILE:
+                return
+            if getattr(_pending_hit, "value", False):
+                _pending_hit.value = False  # this one was a load, not a compile
                 return
             for reg in list(_compile_registries):
                 reg.counter("jax_compiles_total", "jax").inc()
-
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            if "compil" not in event:
-                return
-            for reg in list(_compile_registries):
                 reg.histogram("jax_compile_s", "jax").observe(duration)
 
         try:
             from jax import monitoring
 
             monitoring.register_event_listener(_on_event)
-            _compile_listener_installed = True
-        except Exception:  # pragma: no cover - older/newer jax API drift
-            pass
-        try:
-            from jax import monitoring
-
             monitoring.register_event_duration_secs_listener(_on_duration)
             _compile_listener_installed = True
-        except Exception:  # pragma: no cover
+        except Exception:  # pragma: no cover - older/newer jax API drift
             pass
         return _compile_listener_installed
 

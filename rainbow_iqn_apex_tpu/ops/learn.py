@@ -30,6 +30,7 @@ from flax import struct
 
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.models.iqn import RainbowIQN, greedy_action, q_values
+from rainbow_iqn_apex_tpu.obs import device_scopes
 from rainbow_iqn_apex_tpu.ops.losses import quantile_huber_loss
 
 Params = Any
@@ -265,6 +266,7 @@ def build_learn_step(
     net = make_network(cfg, num_actions)
     tx = make_optimizer(cfg)
 
+    @jax.named_scope(device_scopes.LEARN_STEP)
     def learn_step(
         state: TrainState,
         batch: Batch,
@@ -277,17 +279,18 @@ def build_learn_step(
                 weight_scale)
 
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(device_scopes.OPTIMIZER):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
 
-        # Hard target copy on schedule, folded into the same XLA graph.
-        step = state.step + 1
-        do_copy = (step % cfg.target_update_period == 0).astype(jnp.float32)
-        target_params = jax.tree.map(
-            lambda t, o: do_copy * o + (1.0 - do_copy) * t,
-            state.target_params,
-            params,
-        )
+            # Hard target copy on schedule, folded into the same XLA graph.
+            step = state.step + 1
+            do_copy = (step % cfg.target_update_period == 0).astype(jnp.float32)
+            target_params = jax.tree.map(
+                lambda t, o: do_copy * o + (1.0 - do_copy) * t,
+                state.target_params,
+                params,
+            )
 
         grad_norm = optax.global_norm(grads)
         info = {

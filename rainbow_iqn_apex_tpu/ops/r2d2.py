@@ -23,6 +23,7 @@ from flax import struct
 
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.models.r2d2 import LSTMState, R2D2Net
+from rainbow_iqn_apex_tpu.obs import device_scopes
 from rainbow_iqn_apex_tpu.ops.learn import make_optimizer
 from rainbow_iqn_apex_tpu.ops.losses import huber
 
@@ -186,6 +187,7 @@ def build_r2d2_learn_step(
             "never saw"
         )
 
+    @jax.named_scope(device_scopes.LEARN_STEP)
     def learn_step(state: R2D2TrainState, batch: SequenceBatch, key: chex.PRNGKey):
         k_on, k_tgt = jax.random.split(key)
         if history > 1 and batch.obs.shape[-1] == 1:
@@ -260,15 +262,16 @@ def build_r2d2_learn_step(
             return loss, aux
 
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        step = state.step + 1
-        do_copy = (step % cfg.target_update_period == 0).astype(jnp.float32)
-        target_params = jax.tree.map(
-            lambda t, o: do_copy * o + (1.0 - do_copy) * t,
-            state.target_params,
-            params,
-        )
+        with jax.named_scope(device_scopes.OPTIMIZER):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+            step = state.step + 1
+            do_copy = (step % cfg.target_update_period == 0).astype(jnp.float32)
+            target_params = jax.tree.map(
+                lambda t, o: do_copy * o + (1.0 - do_copy) * t,
+                state.target_params,
+                params,
+            )
         grad_norm = optax.global_norm(grads)
         info = {
             "loss": loss,

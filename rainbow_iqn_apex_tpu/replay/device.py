@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from rainbow_iqn_apex_tpu.obs import device_scopes
 from rainbow_iqn_apex_tpu.ops.learn import Batch
 
 
@@ -204,6 +205,7 @@ class DeviceReplay:
         stacks = stacks * valid[:, :, None, None].astype(jnp.uint8)
         return jnp.moveaxis(stacks, 1, -1)  # [B, H, W, h]
 
+    @jax.named_scope(device_scopes.REPLAY_DRAW)
     def draw(
         self, state: DeviceReplayState, key: chex.PRNGKey, batch_size: int
     ) -> jnp.ndarray:
@@ -219,6 +221,7 @@ class DeviceReplay:
             jnp.searchsorted(cdf, u, side="right"), 0, p.shape[0] - 1
         ).astype(jnp.int32)
 
+    @jax.named_scope(device_scopes.REPLAY_GATHER)
     def assemble(
         self,
         state: DeviceReplayState,
@@ -311,10 +314,11 @@ class DeviceReplay:
         batch, prob = self.assemble(
             state, idx.reshape(-1), beta, with_weight=False
         )
-        n_stored = (state.filled * self.lanes).astype(jnp.float32)
-        w = (n_stored * prob) ** (-beta)
-        w = w.reshape(groups, batch_size)
-        w = w / w.max(axis=1, keepdims=True)  # per-group, as sequential steps
+        with jax.named_scope(device_scopes.REPLAY_GATHER):
+            n_stored = (state.filled * self.lanes).astype(jnp.float32)
+            w = (n_stored * prob) ** (-beta)
+            w = w.reshape(groups, batch_size)
+            w = w / w.max(axis=1, keepdims=True)  # per-group, as sequential steps
         return idx, batch.replace(weight=w.reshape(-1)), prob
 
     # ------------------------------------------------------------- priorities
@@ -332,6 +336,7 @@ class DeviceReplay:
             state = self.update_priorities(state, idx[g], td[g])
         return state
 
+    @jax.named_scope(device_scopes.REPLAY_WRITEBACK)
     def update_priorities(
         self, state: DeviceReplayState, idx: jnp.ndarray, td_abs: jnp.ndarray
     ) -> DeviceReplayState:
@@ -397,12 +402,16 @@ def build_device_learn_sharded(cfg, num_actions: int, local_replay: DeviceReplay
             idx = local_replay.draw(ds_loc, k, b_loc)
         batch, prob = local_replay.assemble(ds_loc, idx, beta, with_weight=False)
         # globally consistent IS weights over the shard mixture
-        n_global = (ds_loc.filled * local_replay.lanes * n_dev).astype(jnp.float32)
-        nq = jnp.maximum(n_global * prob / n_dev, 1e-12)
-        w = nq ** (-beta)
-        wg = w.reshape(groups, b_loc)
-        wmax = jax.lax.pmax(wg.max(axis=1), axis)  # [G] per-group global max
-        w = (wg / wmax[:, None]).reshape(-1)
+        with jax.named_scope(device_scopes.REPLAY_GATHER):
+            n_global = (
+                ds_loc.filled * local_replay.lanes * n_dev).astype(jnp.float32)
+            nq = jnp.maximum(n_global * prob / n_dev, 1e-12)
+            w = nq ** (-beta)
+            wg = w.reshape(groups, b_loc)
+            with jax.named_scope(device_scopes.GRAD_ALLREDUCE):
+                # [G] per-group global max
+                wmax = jax.lax.pmax(wg.max(axis=1), axis)
+            w = (wg / wmax[:, None]).reshape(-1)
         return idx, batch.replace(weight=w)
 
     def _write_back(ds_loc, idx, td_abs):
@@ -413,9 +422,11 @@ def build_device_learn_sharded(cfg, num_actions: int, local_replay: DeviceReplay
         else:
             ds_loc = local_replay.update_priorities(ds_loc, idx, td_abs)
         # keep the replicated max_priority scalar shard-consistent
-        return ds_loc.replace(
-            max_priority=jax.lax.pmax(ds_loc.max_priority, axis)
-        )
+        with jax.named_scope(device_scopes.REPLAY_WRITEBACK), \
+                jax.named_scope(device_scopes.GRAD_ALLREDUCE):
+            return ds_loc.replace(
+                max_priority=jax.lax.pmax(ds_loc.max_priority, axis)
+            )
 
     draw_assemble = jax.shard_map(
         _draw_assemble, mesh=mesh,

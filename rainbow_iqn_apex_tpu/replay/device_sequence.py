@@ -27,6 +27,7 @@ import chex
 import jax
 import jax.numpy as jnp
 
+from rainbow_iqn_apex_tpu.obs import device_scopes
 from rainbow_iqn_apex_tpu.ops.r2d2 import SequenceBatch
 
 
@@ -218,6 +219,7 @@ class DeviceSequenceReplay:
         ).astype(jnp.float32)
         return jnp.where(p.sum() > 0.0, p, uniform)
 
+    @jax.named_scope(device_scopes.REPLAY_DRAW)
     def draw(self, s: DeviceSeqState, key: chex.PRNGKey,
              batch_size: int) -> jnp.ndarray:
         """Stratified proportional draw over ring priorities (mirror of
@@ -231,6 +233,7 @@ class DeviceSequenceReplay:
             jnp.searchsorted(cdf, u, side="right"), 0, p.shape[0] - 1
         ).astype(jnp.int32)
 
+    @jax.named_scope(device_scopes.REPLAY_GATHER)
     def assemble(
         self, s: DeviceSeqState, idx: jnp.ndarray, beta: jnp.ndarray,
         *, with_weight: bool = True,
@@ -276,12 +279,14 @@ class DeviceSequenceReplay:
         idx = jax.vmap(lambda k: self.draw(s, k, batch_size))(keys)
         batch, prob = self.assemble(s, idx.reshape(-1), beta,
                                     with_weight=False)
-        w = (jnp.maximum(s.filled, 1).astype(jnp.float32) * prob) ** (-beta)
-        w = w.reshape(groups, batch_size)
-        w = w / w.max(axis=1, keepdims=True)
+        with jax.named_scope(device_scopes.REPLAY_GATHER):
+            w = (jnp.maximum(s.filled, 1).astype(jnp.float32) * prob) ** (-beta)
+            w = w.reshape(groups, batch_size)
+            w = w / w.max(axis=1, keepdims=True)
         return idx, batch.replace(weight=w.reshape(-1)), prob
 
     # ------------------------------------------------------------- priorities
+    @jax.named_scope(device_scopes.REPLAY_WRITEBACK)
     def update_priorities(
         self, s: DeviceSeqState, idx: jnp.ndarray, td_mix: jnp.ndarray
     ) -> DeviceSeqState:
@@ -457,12 +462,15 @@ def build_device_r2d2_learn_sharded(cfg, num_actions: int,
         else:
             idx = local_replay.draw(s, k, b_loc)
         batch, prob = local_replay.assemble(s, idx, beta, with_weight=False)
-        n_global = jax.lax.psum(s.filled, axis).astype(jnp.float32)
-        nq = jnp.maximum(jnp.maximum(n_global, 1.0) * prob / n_dev, 1e-12)
-        w = nq ** (-beta)
-        wg = w.reshape(groups, b_loc)
-        wmax = jax.lax.pmax(wg.max(axis=1), axis)
-        w = (wg / wmax[:, None]).reshape(-1)
+        with jax.named_scope(device_scopes.REPLAY_GATHER):
+            with jax.named_scope(device_scopes.GRAD_ALLREDUCE):
+                n_global = jax.lax.psum(s.filled, axis).astype(jnp.float32)
+            nq = jnp.maximum(jnp.maximum(n_global, 1.0) * prob / n_dev, 1e-12)
+            w = nq ** (-beta)
+            wg = w.reshape(groups, b_loc)
+            with jax.named_scope(device_scopes.GRAD_ALLREDUCE):
+                wmax = jax.lax.pmax(wg.max(axis=1), axis)
+            w = (wg / wmax[:, None]).reshape(-1)
         return idx, batch.replace(weight=w)
 
     def _write_back(gs, idx, td_mix):

@@ -33,7 +33,7 @@ import numpy as np
 from rainbow_iqn_apex_tpu.agents.agent import put_frames
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.envs import make_vector_env
-from rainbow_iqn_apex_tpu.obs import RunObs
+from rainbow_iqn_apex_tpu.obs import RunObs, device_scopes
 from rainbow_iqn_apex_tpu.ops.learn import build_act_step, init_train_state
 from rainbow_iqn_apex_tpu.parallel.multihost import shift_stack
 from rainbow_iqn_apex_tpu.replay.device import DeviceReplay, build_device_learn
@@ -270,14 +270,17 @@ def build_fused_segment(cfg: Config, game, replay: DeviceReplay, learn_fn):
     def tick(carry, k):
         ts, ds, env_s, ep, stack, frame, keep, frames = carry
         ka, ks, kl = jax.random.split(k, 3)
-        stack = shift_stack(stack, frame, keep)
-        actions, _q = act_fn(ts.params, stack, ka)
-        env_s, ep, nframe, reward, term, trunc, out_ret = env_step(
-            env_s, ep, actions, ks
-        )
+        with jax.named_scope(device_scopes.TICK_ACT):
+            stack = shift_stack(stack, frame, keep)
+            actions, _q = act_fn(ts.params, stack, ka)
+        with jax.named_scope(device_scopes.TICK_ENV):
+            env_s, ep, nframe, reward, term, trunc, out_ret = env_step(
+                env_s, ep, actions, ks
+            )
         # the completed transition, appended the same tick (the host loop's
         # lag-one bookkeeping exists only because its env stepped off-device)
-        ds = replay.append(ds, frame, actions, reward, term, trunc)
+        with jax.named_scope(device_scopes.TICK_APPEND):
+            ds = replay.append(ds, frame, actions, reward, term, trunc)
         frames = frames + lanes
 
         stored = jnp.minimum(ds.filled, seg) * lanes
@@ -304,7 +307,8 @@ def build_fused_segment(cfg: Config, game, replay: DeviceReplay, learn_fn):
             nanv = jnp.full((learns_per_tick,), jnp.nan, jnp.float32)
             return ts, ds, (nanv, nanv, nanv)
 
-        ts, ds, infos = jax.lax.cond(warm, do_learn, no_learn, (ts, ds))
+        with jax.named_scope(device_scopes.TICK_LEARN):
+            ts, ds, infos = jax.lax.cond(warm, do_learn, no_learn, (ts, ds))
         keep = (~(term | trunc)).astype(jnp.uint8)
         out = (out_ret, infos[0], infos[1], infos[2])
         return (ts, ds, env_s, ep, stack, nframe, keep, frames), out
@@ -495,6 +499,10 @@ def train_anakin_fused(cfg: Config, max_frames: Optional[int] = None) -> Dict[st
     def crossed(interval: int, before: int, after: int) -> bool:
         return interval > 0 and before // interval != after // interval
 
+    # --trace-dir: the capture's 'device_time' row names the segment's work
+    # by scope from the compiled text (no compile: the program has run)
+    obs_run.trace_window.add_program(
+        lambda: segment.lower(carry, k).compile().as_text())
     try:
         while frames < total_frames:
             key, k = jax.random.split(key)

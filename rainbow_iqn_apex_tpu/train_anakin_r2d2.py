@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from rainbow_iqn_apex_tpu.config import Config
-from rainbow_iqn_apex_tpu.obs import RunObs
+from rainbow_iqn_apex_tpu.obs import RunObs, device_scopes
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     build_r2d2_act_step,
     init_r2d2_state,
@@ -115,12 +115,15 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
         ts, ss, env_s, ep, stack, frame, keep, c, h, frames = carry
         ka, ks, kl = jax.random.split(k, 3)
         pre_c, pre_h = c, h  # stored-state replay keeps the PRE-act state
-        stack = shift_stack(stack, frame, keep)
-        actions, _q, (c, h) = act_fn(ts.params, stack, (c, h), ka)
-        env_s, ep, nframe, reward, term, trunc, out_ret = env_step(
-            env_s, ep, actions, ks
-        )
-        ss = append(ss, frame, actions, reward, term, trunc, pre_c, pre_h)
+        with jax.named_scope(device_scopes.TICK_ACT):
+            stack = shift_stack(stack, frame, keep)
+            actions, _q, (c, h) = act_fn(ts.params, stack, (c, h), ka)
+        with jax.named_scope(device_scopes.TICK_ENV):
+            env_s, ep, nframe, reward, term, trunc, out_ret = env_step(
+                env_s, ep, actions, ks
+            )
+        with jax.named_scope(device_scopes.TICK_APPEND):
+            ss = append(ss, frame, actions, reward, term, trunc, pre_c, pre_h)
         frames = frames + lanes
 
         # warm gate (sum/min are shard-aware: filled is [n_dev] when the
@@ -152,7 +155,9 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
             nanv = jnp.full((lpt,), jnp.nan, jnp.float32)
             return ts, ss, (nanv, nanv, nanv)
 
-        ts, ss, infos = jax.lax.cond(warm & due, do_learn, no_learn, (ts, ss))
+        with jax.named_scope(device_scopes.TICK_LEARN):
+            ts, ss, infos = jax.lax.cond(
+                warm & due, do_learn, no_learn, (ts, ss))
 
         cut_keep = (~(term | trunc)).astype(jnp.uint8)
         kf = cut_keep.astype(jnp.float32)[:, None]
@@ -364,6 +369,10 @@ def train_anakin_r2d2(cfg: Config,
     def crossed(interval: int, before: int, after: int) -> bool:
         return interval > 0 and before // interval != after // interval
 
+    # --trace-dir: the capture's 'device_time' row names the segment's work
+    # by scope from the compiled text (no compile: the program has run)
+    obs_run.trace_window.add_program(
+        lambda: segment.lower(carry, k).compile().as_text())
     try:
         while frames < total_frames:
             key, k = jax.random.split(key)

@@ -1,14 +1,14 @@
 #!/usr/bin/env python
-"""Batch-scaling + MFU study of the device-resident PER learner.
+"""Batch-scaling study of the device-resident PER learner.
 
 For each batch size, builds the fused sample->learn->write-back graph
 (replay/device.py) at the reference Atari workload shape, times jitted
 50-step lax.scan segments, and reports steps/s, samples/s (consumed
-transitions/s), per-step model FLOPs (XLA's own cost analysis when the
-backend exposes it) and the implied MFU against the chip's bf16 peak,
-looked up by ``device_kind`` — a device that is not in the table is an
-error, not a default.  A soft internal budget is checked between device
-calls.
+transitions/s) and ms/step.  A soft internal budget is checked between
+device calls.  It reports no utilization: the FLOPs of a learn step come
+from the shape functions of benchmarks/flops.py and the chip's peaks from
+benchmarks/peaks.json (`learn_mfu`, PERF.md), not from XLA's
+cost_analysis().
 
 Usage: python scripts/bench_scaling.py [total_budget_seconds=420] [batches]
        e.g. python scripts/bench_scaling.py 420 32,64,128,256
@@ -42,10 +42,6 @@ BATCHES = [_parse_point(b) for b in
             else "32,64,128,256,32x2,32x4").split(",")]
 T0 = time.monotonic()
 
-# Published bf16 peak FLOP/s of one chip, keyed by jax's device_kind.
-# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16).
-PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
-
 
 def left() -> float:
     return BUDGET - (time.monotonic() - T0)
@@ -65,11 +61,6 @@ def main() -> None:
 
     platform = jax.devices()[0].platform
     kind = jax.devices()[0].device_kind
-    if kind not in PEAK_BF16_FLOPS:
-        raise SystemExit(
-            f"bench_scaling: no published bf16 peak for device_kind {kind!r} "
-            f"(platform {platform}); add it to PEAK_BF16_FLOPS with its source")
-    peak_flops = PEAK_BF16_FLOPS[kind]
     emit(phase="hello", platform=platform, device_kind=kind,
          device_count=len(jax.devices()), budget_s=BUDGET, batches=BATCHES)
 
@@ -136,17 +127,6 @@ def main() -> None:
                 tick, (ts, ds), jax.random.split(key, SCAN)
             )
             return ts, losses[-1]
-        flops = None
-        try:
-            lowered = jax.jit(fused).lower(
-                ts, ds0, jax.random.PRNGKey(1), jnp.float32(0.5)
-            )
-            cost = lowered.compile().cost_analysis()
-            if cost:
-                c0 = cost[0] if isinstance(cost, (list, tuple)) else cost
-                flops = float(c0.get("flops", 0.0)) or None
-        except Exception as e:  # noqa: BLE001 — cost analysis is best-effort
-            emit(phase="cost_analysis", batch=label, error=repr(e)[:120])
 
         key = jax.random.PRNGKey(2)
         key, k = jax.random.split(key)
@@ -165,19 +145,15 @@ def main() -> None:
             n_seg += 1
         dt = time.perf_counter() - t0
         sps = n_seg * SCAN / dt
-        row = {
-            "phase": "scale",
-            "batch": label,
-            "steps_per_sec": round(sps, 2),
-            "samples_per_sec": round(sps * b * groups, 1),
-            "ms_per_step": round(1e3 / sps, 3),
-            "platform": platform,
-            "device_kind": kind,
-        }
-        if flops:
-            row["flops_per_step"] = flops
-            row["mfu"] = round(flops * sps / peak_flops, 5)
-        emit(**row)
+        emit(
+            phase="scale",
+            batch=label,
+            steps_per_sec=round(sps, 2),
+            samples_per_sec=round(sps * b * groups, 1),
+            ms_per_step=round(1e3 / sps, 3),
+            platform=platform,
+            device_kind=kind,
+        )
 
     emit(phase="done", elapsed_s=round(time.monotonic() - T0, 1))
 

@@ -85,6 +85,29 @@ def _last_with(rows: List[Dict[str, Any]], kind: str, key: str) -> Dict[str, Any
     return {}
 
 
+def _device_time_lines(dt) -> List[str]:
+    """The 'device_time' row as text: idle share, milliseconds a learn step
+    by scope, what ran outside the tick, and the long idle gaps by span."""
+    if not dt:
+        return []
+    if dt.get("error"):
+        return [f"device_time: capture not reduced ({dt['error']})"]
+    lines = [
+        f"device_time: {dt.get('steps')} learn steps, "
+        f"{dt.get('dispatches')} dispatches on {dt.get('chips')} chip(s); "
+        f"window {dt.get('window_s')}s busy {dt.get('busy_s')}s "
+        f"idle {dt.get('idle_share')}%  outside_tick "
+        f"{dt.get('outside_tick_ms_per_dispatch')}ms/dispatch  "
+        f"unresolved {dt.get('unresolved_share')}%"]
+    for scope, ms in sorted((dt.get("scope_ms_per_step") or {}).items(),
+                            key=lambda kv: -kv[1]):
+        lines.append(f"  scope {scope}: {ms}ms/learn step")
+    for span, ms in sorted((dt.get("idle_gap_ms_by_span") or {}).items(),
+                           key=lambda kv: -kv[1]):
+        lines.append(f"  idle gaps over 1ms under {span}: {round(ms, 3)}ms")
+    return lines
+
+
 def _mean(vals: List[float]) -> float:
     return sum(vals) / len(vals) if vals else 0.0
 
@@ -477,6 +500,12 @@ def aggregate(rows: List[Dict[str, Any]]) -> Dict[str, Any]:
             },
         },
         "compiles": last_timing.get("compiles"),
+        "compile_cache_hits": last_timing.get("compile_cache_hits"),
+        # the --trace-dir capture reduced by the run itself
+        # (obs/device_scopes.py): device time by the program's scope names
+        "device_time": {k: v for k, v in _last(rows, "device_time").items()
+                        if k not in ("t", "ts", "host", "run", "kind",
+                                     "schema")} or None,
         "spans": span_stats,
         "faults": fault_counts,
         # elasticity (docs/RESILIENCE.md "heal"): the detect->heal story in
@@ -603,8 +632,10 @@ def render(report: Dict[str, Any]) -> str:
          f"batch_occupancy={roles['serve']['batch_occupancy_mean']}  "
          f"pad_tax={roles['serve']['pad_fraction_mean']}  "
          f"latency_p99_ms={roles['serve']['latency_p99_ms']}"),
-        f"compiles: {report['compiles']}",
+        (f"compiles: {report['compiles']}  "
+         f"cache_hits: {report.get('compile_cache_hits')}"),
     ]
+    lines.extend(_device_time_lines(report.get("device_time")))
     for name, snap in sorted((report["spans"] or {}).items()):
         lines.append(f"span {name}: {snap}")
     lines.append(f"faults: {report['faults'] or 'none'}")

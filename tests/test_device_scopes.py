@@ -1,0 +1,391 @@
+"""obs/device_scopes: the names the fused programs wrap their work in, read
+back out of the compiled module, and device time put down to them.
+
+The two segment fixtures compile the tiny fused R2D2 and IQN programs once;
+every scope of the table in docs/OBSERVABILITY.md that a program uses has to
+name at least one instruction of its compiled text.
+"""
+
+import re
+
+import jax
+import pytest
+
+from rainbow_iqn_apex_tpu.config import Config
+from rainbow_iqn_apex_tpu.obs import device_scopes as ds
+
+R2D2_SCOPES = (
+    ds.TICK_ACT, ds.TICK_ENV, ds.TICK_APPEND, ds.TICK_LEARN, ds.REPLAY_DRAW,
+    ds.REPLAY_GATHER, ds.REPLAY_WRITEBACK, ds.LEARN_STEP, ds.NET_TRUNK,
+    ds.LSTM_SCAN, ds.OPTIMIZER,
+)
+IQN_SCOPES = (
+    ds.TICK_ACT, ds.TICK_ENV, ds.TICK_APPEND, ds.TICK_LEARN, ds.REPLAY_DRAW,
+    ds.REPLAY_GATHER, ds.REPLAY_WRITEBACK, ds.LEARN_STEP, ds.NET_TRUNK,
+    ds.IQN_HEAD, ds.OPTIMIZER,
+)
+COMMON = dict(
+    env_id="jaxgame:catch", compute_dtype="float32", history_length=2,
+    hidden_size=32, batch_size=8, multi_step=2, gamma=0.9,
+    num_envs_per_actor=4, anakin_segment_ticks=4, learner_devices=1, seed=3,
+)
+
+
+def _r2d2_text() -> str:
+    from rainbow_iqn_apex_tpu import train_anakin_r2d2 as prog
+    from rainbow_iqn_apex_tpu.envs.device_games import make_device_game
+    from rainbow_iqn_apex_tpu.ops.r2d2 import init_r2d2_state
+    from rainbow_iqn_apex_tpu.replay import device_sequence as dseq
+
+    cfg = Config(architecture="r2d2", lstm_size=16, r2d2_burn_in=2,
+                 r2d2_seq_len=6, r2d2_overlap=2, memory_capacity=8 * 64,
+                 learn_start=64, frames_per_learn=2, **COMMON)
+    game = make_device_game("catch")
+    seq_total, stride, capacity, _ = prog._seq_geometry(cfg)
+    replay = dseq.DeviceSequenceReplay(
+        capacity=capacity, seq_len=seq_total, frame_shape=game.frame_shape,
+        lstm_size=cfg.lstm_size, lanes=cfg.num_envs_per_actor, stride=stride)
+    segment = prog.build_fused_r2d2_segment(
+        cfg, game, replay,
+        dseq.build_device_r2d2_learn(cfg, game.num_actions, replay))
+    key = jax.random.PRNGKey(0)
+    carry = jax.eval_shape(
+        lambda k: prog.init_fused_r2d2_carry(
+            cfg, game,
+            init_r2d2_state(cfg, game.num_actions, k, game.frame_shape),
+            replay.init_state(), k), key)
+    return segment.lower(carry, key).compile().as_text()
+
+
+def _iqn_text() -> str:
+    from rainbow_iqn_apex_tpu import train_anakin as prog
+    from rainbow_iqn_apex_tpu.envs.device_games import make_device_game
+    from rainbow_iqn_apex_tpu.ops.learn import init_train_state
+    from rainbow_iqn_apex_tpu.replay.device import (
+        DeviceReplay,
+        build_device_learn,
+    )
+
+    cfg = Config(num_cosines=8, num_tau_samples=4, num_tau_prime_samples=4,
+                 num_quantile_samples=4, memory_capacity=256, learn_start=32,
+                 frames_per_learn=4, **COMMON)
+    game = make_device_game("catch")
+    lanes = cfg.num_envs_per_actor
+    replay = DeviceReplay(
+        lanes=lanes, seg=cfg.memory_capacity // lanes,
+        frame_shape=game.frame_shape, history=cfg.history_length,
+        n_step=cfg.multi_step, gamma=cfg.gamma)
+    segment = prog.build_fused_segment(
+        cfg, game, replay, build_device_learn(cfg, game.num_actions, replay))
+    key = jax.random.PRNGKey(0)
+    carry = jax.eval_shape(
+        lambda k: prog.init_fused_carry(
+            cfg, game, replay,
+            init_train_state(cfg, game.num_actions, k,
+                             (*game.frame_shape, cfg.history_length)),
+            replay.init_state(), k), key)
+    return segment.lower(carry, key).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def texts():
+    return {"r2d2": _r2d2_text(), "iqn": _iqn_text()}
+
+
+@pytest.mark.parametrize(
+    "program,scope",
+    [("r2d2", s) for s in R2D2_SCOPES] + [("iqn", s) for s in IQN_SCOPES])
+def test_compiled_segment_names_every_scope(texts, program, scope):
+    paths = ds.instruction_scopes(texts[program]).values()
+    assert any(scope in p for p in paths), (
+        f"no instruction of the compiled {program} segment is in {scope!r}")
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("r2d2", ds.LSTM_SCAN), ("r2d2", ds.NET_TRUNK), ("iqn", ds.IQN_HEAD)])
+def test_backward_pass_resolves_to_its_scope(texts, program, scope):
+    """An op of the backward pass carries its scope wrapped by autodiff
+    (`transpose(jvp(lstm_scan))`); the path still names the scope, inside
+    the learn step inside the learning tick."""
+    ops = [n for n in re.findall(r'op_name="([^"]*)"', texts[program])
+           if "transpose(jvp(" in n and scope in ds.scope_path(n)]
+    assert ops
+    path = ds.scope_path(ops[0])
+    assert path[0] == ds.TICK_LEARN and ds.LEARN_STEP in path
+    assert path.index(ds.LEARN_STEP) < path.index(scope)
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(segment)/while/body/tick_learn/cond/branch_1_fun/while/body/"
+     "learn_step/transpose(jvp(lstm_scan))/mul",
+     ("tick_learn", "learn_step", "lstm_scan")),
+    ("jit(f)/while/body/closed_call/tick_learn/transpose(jvp(learn_step))/"
+     "lstm_scan/while/body/add", ("tick_learn", "learn_step", "lstm_scan")),
+    ("jit(segment)/while/body/tick_act/R2D2Net/net_trunk/conv_general_dilated",
+     ("tick_act", "net_trunk")),
+    ("jit(learn_step)/jit(main)/mul", ()),  # a jitted function is no scope
+    ("jit(segment)/while/body/vmap(transpose(jvp(replay_gather/x)))/gather",
+     ("replay_gather",)),
+    ("", ()),
+])
+def test_scope_path(op_name, want):
+    assert ds.scope_path(op_name) == want
+
+
+HLO = """HloModule jit_segment, entry_computation_layout={()->f32[]}
+
+%fused_computation (p: f32[4]) -> f32[4] {
+  %inner.1 = f32[4]{0} add(%p, %p), metadata={op_name="jit(segment)/while/body/tick_learn/learn_step/jvp(lstm_scan)/add"}
+}
+
+%wide.while_body.sunk (arg: (s32[], u8[9])) -> (s32[], u8[9]) {
+  %copy.415 = u8[9]{0} copy(%gte.1)
+  %while.9 = (s32[]) while(%t), condition=%cond.9, body=%nested_body
+}
+
+%nested_body (arg: (s32[])) -> (s32[]) {
+  ROOT %copy-done.11 = u8[9]{0} copy-done(%cs)
+}
+
+ENTRY %main (arg0: u8[9]) -> (f32[4]) {
+  %copy.284 = u8[6554,120,80,80]{1,3,2,0} copy(%arg0)
+  %copy.300 = f32[4]{0} copy(%x), metadata={op_name="jit(segment)/jit(main)/while"}
+  %fusion.403 = f32[4]{0} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(segment)/while/body/tick_learn/learn_step/transpose(jvp(lstm_scan))/mul" source_file="x.py" source_line=3}
+  %fusion.7 = f32[4]{0} fusion(%x), kind=kLoop, metadata={op_name="jit(segment)/while/body/tick_learn/replay_draw/cumsum"}
+  %gather.2 = f32[4]{0} gather(%x), metadata={op_name="jit(segment)/while/body/tick_act/net_trunk/conv"}
+  %while.134 = (s32[], u8[9]) while(%t), condition=%cond.1, body=%wide.while_body.sunk, metadata={op_name="jit(segment)/while/body/tick_learn/replay_gather/gather"}
+  ROOT %tuple.9 = (f32[4]{0}) tuple(%fusion.403)
+}
+"""
+
+
+def test_instruction_scopes_by_hand():
+    got = ds.instruction_scopes(HLO)
+    assert got["copy.284"] == ()  # known to the module, named by nobody
+    assert got["copy.300"] == ()
+    assert got["fusion.403"] == ("tick_learn", "learn_step", "lstm_scan")
+    assert got["inner.1"] == ("tick_learn", "learn_step", "lstm_scan")
+    assert got["gather.2"] == ("tick_act", "net_trunk")
+    assert got["tuple.9"] == ()
+    # made by the compiler inside the loop a gather became: the gather's,
+    # through a loop of the compiler's own too
+    assert got["copy.415"] == got["while.134"] == ("tick_learn", "replay_gather")
+    assert got["while.9"] == got["copy-done.11"] == ("tick_learn", "replay_gather")
+    assert not {"fused_computation", "main", "nested_body"} & set(got)
+
+
+def test_attribute_is_exact_by_hand():
+    ops = [
+        ["%copy.284 = u8[6554,120,80,80]{1,3,2,0:T(8,128)(4,1)} copy(u8[...", 0.5],
+        ["%fusion.403 = f32[4]{0} fusion(f32[4]{0} %x), kind=kLoop", 0.25],
+        ["%fusion.7 = f32[4]{0} fusion(...)", 0.125],
+        ["%gather.2 = f32[4]{0} gather(...)", 0.0625],
+        ["%fusion.999 = f32[] fusion()", 0.03125],  # not in the module
+        ["%copy.300", 0.015625],
+    ]
+    a = ds.attribute(ops, ds.instruction_scopes(HLO))
+    assert a["total_s"] == sum(t for _n, t in ops)
+    # three disjoint classes that add up to the input
+    assert a["tick_s"] + a["outside_tick_s"] + a["unresolved_s"] == a["total_s"]
+    assert a["outside_tick_s"] == 0.5 + 0.015625
+    assert a["unresolved_s"] == 0.03125
+    assert a["unresolved"] == [("fusion.999", 0.03125)]
+    assert a["outside"][0] == ("copy.284", 0.5)
+    # a nested path counts in every scope it names
+    assert a["by_scope"] == {
+        "tick_learn": 0.25 + 0.125, "learn_step": 0.25, "lstm_scan": 0.25,
+        "replay_draw": 0.125, "tick_act": 0.0625, "net_trunk": 0.0625}
+
+
+def test_every_named_scope_in_the_program_comes_from_device_scopes():
+    """`grep named_scope rainbow_iqn_apex_tpu/`: every use names a constant
+    of obs/device_scopes.py, and every constant is used."""
+    import pathlib
+
+    root = pathlib.Path(ds.__file__).resolve().parents[1]
+    used = set()
+    for path in root.rglob("*.py"):
+        if path.name == "device_scopes.py":
+            continue
+        for arg in re.findall(r"named_scope\(([^)]*)\)", path.read_text()):
+            assert arg.startswith("device_scopes."), (path, arg)
+            used.add(getattr(ds, arg.split(".", 1)[1]))
+    assert used == set(ds.ALL_SCOPES)
+
+
+# ------------------------------------------ a capture reduced: 'device_time'
+
+DEV, HOST = "/device:TPU:0", "/host:CPU"
+MS = 1e6  # ns
+
+
+def _capture():
+    """One run of `jit_segment` on one chip: a 10 ms ring copy outside the
+    tick, then the scan's `while` 30 ms long holding a 4 ms act op, a 2 ms
+    wait, and an 8 ms LSTM op; then 3 ms idle and a run of a program nobody
+    registered.  The host's `segment` span ends at 30 ms."""
+    return [
+        (DEV, ds.MODULES_LINE, "jit_segment(123)", 0.0, 40 * MS),
+        (DEV, ds.OPS_LINE, "%copy.284 = u8[9]{0} copy(...)", 0.0, 10 * MS),
+        (DEV, ds.OPS_LINE, "%while.1 = () while(...)", 10 * MS, 30 * MS),
+        (DEV, ds.OPS_LINE, "%gather.2 = f32[4]{0} gather(...)", 10 * MS, 4 * MS),
+        (DEV, ds.OPS_LINE, "%fusion.403 = f32[4]{0} fusion(...)", 16 * MS, 8 * MS),
+        (DEV, ds.MODULES_LINE, "jit_other(7)", 43 * MS, 1 * MS),
+        (DEV, ds.OPS_LINE, "%fusion.403 = f32[] fusion()", 43 * MS, 1 * MS),
+        (DEV, "Steps", "0", 0.0, 44 * MS),
+        (HOST, "python3", "segment", 0.0, 30 * MS),
+        (HOST, "python3", "PjitFunction(segment)", 0.0, 1 * MS),
+    ]
+
+
+HLO_RUN = HLO.replace(
+    "ROOT %tuple.9",
+    '%while.1 = () while(%x), metadata={op_name="jit(segment)/while"}\n'
+    "  ROOT %tuple.9")
+
+
+def test_reduce_events_by_hand():
+    r = ds.reduce_events(_capture(), [HLO_RUN], {"segment", "learn_step"})
+    assert r["chips"] == 1 and r["dispatches"] == 1
+    assert r["window_s"] == pytest.approx(0.044)
+    # busy: copy 10 + gather 4 + fusion 8 + the other program's op 1
+    assert r["busy_s"] == pytest.approx(0.023)
+    assert r["idle_share"] == pytest.approx(100 * (1 - 23 / 44))
+    assert r["programs"]["jit_segment"] == {"runs": 1, "device_ms": 40.0}
+    # the while's self time (30 - 4 - 8) is outside the tick with the copy
+    assert r["outside_tick_s"] == pytest.approx(0.010 + 0.018)
+    assert r["tick_s"] == pytest.approx(0.012)
+    # the same instruction name in a program nobody registered is unresolved
+    assert r["unresolved_s"] == pytest.approx(0.001)
+    assert r["total_s"] == pytest.approx(
+        r["tick_s"] + r["outside_tick_s"] + r["unresolved_s"])
+    assert r["by_scope"]["lstm_scan"] == pytest.approx(0.008)
+    assert r["by_path"]["tick_act/net_trunk"] == pytest.approx(0.004)
+    assert ds.seconds(r, ds.TICK_LEARN, ds.LSTM_SCAN) == pytest.approx(0.008)
+    assert ds.seconds(r, ds.TICK_ACT, ds.LSTM_SCAN) == 0.0
+    # gaps over 1 ms between innermost ops, longest first, by the innermost
+    # span that covers their middle: 24-43 ms (the middle is after `segment`
+    # ended) and 14-16 ms
+    assert [(round(g["ms"], 6), g["span"]) for g in r["idle_gaps"]] == [
+        (19.0, "no span"), (2.0, "segment")]
+    assert r["idle_gap_ms_by_span"] == pytest.approx(
+        {"segment": 2.0, "no span": 19.0})
+
+
+def test_reduce_events_without_a_device_plane_is_none():
+    assert ds.reduce_events(
+        [(HOST, "python3", "segment", 0.0, 1.0)], [HLO], {"segment"}) is None
+
+
+def test_device_time_row_is_valid(tmp_path, monkeypatch):
+    """The row TraceWindow logs from a capture with a device plane passes the
+    schema and the strict-JSON lint, and carries per-step milliseconds."""
+    import json
+    import sys
+
+    from rainbow_iqn_apex_tpu.obs import MetricRegistry, Tracer, TraceWindow
+    from rainbow_iqn_apex_tpu.obs.schema import validate_row
+    from rainbow_iqn_apex_tpu.utils.logging import MetricsLogger
+
+    sys.path.insert(0, str(
+        __import__("pathlib").Path(__file__).resolve().parents[1] / "scripts"))
+    from lint_jsonl import lint_file
+
+    monkeypatch.setattr(ds, "load_capture", lambda logdir: _capture())
+    path = str(tmp_path / "m.jsonl")
+    m = MetricsLogger(path, "r", echo=False)
+    tracer = Tracer(MetricRegistry(), None, "learner")
+    tw = TraceWindow(str(tmp_path / "trace"), 1, 2, logger=m, tracer=tracer)
+    tw.add_program(lambda: HLO_RUN)
+    with tracer.span("segment"):
+        tw.step(1)
+    tw.step(3)
+    m.close()
+    assert lint_file(path) == []
+    rows = [json.loads(line) for line in open(path)]
+    assert all(validate_row(r, require_known_kind=True) == [] for r in rows)
+    (row,) = [r for r in rows if r["kind"] == "device_time"]
+    assert row["step"] == 3 and row["steps"] == 2 and row["dispatches"] == 1
+    assert row["scope_ms_per_step"]["lstm_scan"] == pytest.approx(4.0)
+    assert row["path_ms_per_step"]["tick_act/net_trunk"] == pytest.approx(2.0)
+    assert row["outside_tick_ms_per_dispatch"] == pytest.approx(28.0)
+    assert row["unresolved_share"] == pytest.approx(100 * 1 / 41, abs=1e-3)
+    assert row["idle_gaps"][0] == {"ms": 19.0, "span": "no span"}
+    assert validate_row({k: v for k, v in row.items() if k != "steps"}) != []
+    # and scripts/obs_report.py prints it
+    from obs_report import aggregate, find_jsonl, load_rows, render
+
+    text = render(aggregate(load_rows(find_jsonl(str(tmp_path)))[0]))
+    assert "device_time: 2 learn steps, 1.0 dispatches" in text
+    assert "scope lstm_scan: 4.0ms/learn step" in text
+    assert "idle gaps over 1ms under no span: 19.0ms" in text
+
+
+@pytest.mark.parametrize("with_program", [False, True])
+def test_trace_window_on_cpu_logs_no_device_time(tmp_path, with_program):
+    """A real capture on the CPU backend holds no device plane: the window
+    closes cleanly, the .xplane.pb is there, and no 'device_time' row is."""
+    import json
+
+    import jax.numpy as jnp
+
+    from rainbow_iqn_apex_tpu.obs import MetricRegistry, Tracer, TraceWindow
+    from rainbow_iqn_apex_tpu.utils.logging import MetricsLogger
+
+    f = jax.jit(lambda x: (x * 2.0).sum())
+    x = jnp.ones((8,))
+    m = MetricsLogger(str(tmp_path / "m.jsonl"), "r", echo=False)
+    tracer = Tracer(MetricRegistry(), m, "learner")
+    tw = TraceWindow(str(tmp_path / "trace"), 1, 1, logger=m, tracer=tracer)
+    if with_program:
+        tw.add_program(lambda: f.lower(x).compile().as_text())
+    tw.step(1)
+    with tracer.span("learn_step"):
+        f(x).block_until_ready()
+    tw.close(2)
+    m.close()
+    assert not tw.active
+    assert list((tmp_path / "trace").rglob("*.xplane.pb"))
+    kinds = [json.loads(line)["kind"] for line in open(tmp_path / "m.jsonl")]
+    assert "trace" in kinds and "device_time" not in kinds
+
+
+def test_compile_counter_tells_compiles_from_cache_hits(tmp_path):
+    """With a persistent cache: a jitted function called, the in-memory
+    caches cleared, called again: one backend compile, one cache hit."""
+    import jax.numpy as jnp
+
+    from rainbow_iqn_apex_tpu.obs import MetricRegistry, install_compile_counter
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+        reg = MetricRegistry()
+        assert install_compile_counter(reg)
+        compiles = reg.counter("jax_compiles_total", "jax")
+        hits = reg.counter("jax_compile_cache_hits_total", "jax")
+        x = jnp.arange(7, dtype=jnp.float32).block_until_ready()
+        c0, h0 = compiles.get(), hits.get()
+
+        def poly_unlike_any_other_in_the_suite(x):
+            return (x * 3.25 + 1.5).sum() - x[0]
+
+        jax.jit(poly_unlike_any_other_in_the_suite)(x).block_until_ready()
+        assert (compiles.get() - c0, hits.get() - h0) == (1, 0)
+        assert reg.histogram("jax_compile_s", "jax").snapshot()["count"] >= 1
+        jax.clear_caches()
+        jax.jit(poly_unlike_any_other_in_the_suite)(x).block_until_ready()
+        assert (compiles.get() - c0, hits.get() - h0) == (1, 1)
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
