@@ -12,11 +12,14 @@ sampling all live in HBM as one pytree, so the fused R2D2 Anakin tick
 The one structural difference from the host version: the number of sequences
 EMITTED per tick is data-dependent (a lane emits when its builder fills or
 its episode cuts), which XLA cannot express as a dynamic store count.  The
-ring therefore carries ONE scratch row (index C): every lane scatters its
-builder window somewhere each tick — emitting lanes to `(pos + rank) % C`
-(rank = that lane's position among this tick's emitters), non-emitting lanes
-to the scratch row — so shapes stay static and the write is one batched
-scatter.  Sampling and priorities only ever see rows [0, C).
+ring therefore carries ONE scratch row (index C): on a tick where any lane
+emits, every lane scatters its builder window somewhere — emitting lanes to
+`(pos + rank) % C` (rank = that lane's position among this tick's emitters),
+non-emitting lanes to the scratch row — so shapes stay static and the write
+is one batched scatter.  A tick on which no lane emits skips all of that
+under one `lax.cond` and leaves the scratch row as it was.  Sampling,
+priorities, snapshots and the benchmark's `correct` only ever see rows
+[0, C).
 """
 
 from __future__ import annotations
@@ -52,11 +55,17 @@ class DeviceSeqState(NamedTuple):
     buf_c: jnp.ndarray  # [lanes, L, lstm] f32
     buf_h: jnp.ndarray  # [lanes, L, lstm] f32
     buf_len: jnp.ndarray  # [lanes] i32
+    # ticks on which some lane emitted (append's conditional took do_emit)
+    emit_ticks: jnp.ndarray  # scalar i32
 
 
 class DeviceSequenceReplay:
     """Pure-functional sequence replay: all methods are jit-safe
-    (state, ...) -> state transforms over a DeviceSeqState pytree."""
+    (state, ...) -> state transforms over a DeviceSeqState pytree.
+
+    The scratch row (ring index C) is rewritten only on ticks where some
+    lane emits; sampling, priorities, snapshots' readers and the benchmark's
+    `correct` see rows [0, C) alone."""
 
     def __init__(
         self,
@@ -108,6 +117,7 @@ class DeviceSequenceReplay:
             buf_c=jnp.zeros((lanes, L, m), jnp.float32),
             buf_h=jnp.zeros((lanes, L, m), jnp.float32),
             buf_len=jnp.zeros((lanes,), jnp.int32),
+            emit_ticks=jnp.int32(0),
         )
 
     # ------------------------------------------------------------- appending
@@ -123,87 +133,82 @@ class DeviceSequenceReplay:
         lstm_h: jnp.ndarray,
     ) -> DeviceSeqState:
         """One lockstep tick of all lanes (mirror of _append_locked,
-        replay/sequence.py): builder scatter, then emit full/cut windows into
-        the ring via the scratch-row batched scatter, then carry-over."""
+        replay/sequence.py): the one-step builder writes on every tick; the
+        emit work (zero-pad, ring scatter, max-priority insertion, overlap
+        carry-over) only on a tick where some lane emits."""
         lanes, L, C, stride = self.lanes, self.L, self.capacity, self.stride
         lane = jnp.arange(lanes)
         k = s.buf_len  # [lanes] write offsets, in [0, L-1]
-
-        bf = s.buf_frames.at[lane, k].set(frames)
-        ba = s.buf_actions.at[lane, k].set(actions.astype(jnp.int32))
-        br = s.buf_rewards.at[lane, k].set(rewards.astype(jnp.float32))
-        bd = s.buf_dones.at[lane, k].set(terminals)
-        bc = s.buf_c.at[lane, k].set(lstm_c.astype(jnp.float32))
-        bh = s.buf_h.at[lane, k].set(lstm_h.astype(jnp.float32))
         klen = k + 1  # post-write lengths
 
         cut = terminals | truncations
         emit = cut | (klen == L)
-
-        # ring slots: emitters take pos+rank (mod C), others the scratch row
-        rank = jnp.cumsum(emit.astype(jnp.int32)) - 1
-        n_emit = emit.sum().astype(jnp.int32)
-        slots = jnp.where(emit, (s.pos + rank) % C, C)
-
-        steps = jnp.arange(L)
-        valid_mask = steps[None, :] < klen[:, None]  # [lanes, L]
-
-        def zpad(buf, mask):
-            return jnp.where(mask, buf, jnp.zeros_like(buf))
-
-        vm = valid_mask
-        frames_row = zpad(bf, vm[..., None, None])
-        actions_row = zpad(ba, vm)
-        rewards_row = zpad(br, vm)
-        dones_row = zpad(bd, vm)
-
-        st = s._replace(
-            buf_frames=bf, buf_actions=ba, buf_rewards=br, buf_dones=bd,
-            buf_c=bc, buf_h=bh,
-        )
-        st = st._replace(
-            frames=st.frames.at[slots].set(frames_row),
-            actions=st.actions.at[slots].set(actions_row),
-            rewards=st.rewards.at[slots].set(rewards_row),
-            dones=st.dones.at[slots].set(dones_row),
-            valids=st.valids.at[slots].set(vm),
-            init_c=st.init_c.at[slots].set(bc[:, 0]),
-            init_h=st.init_h.at[slots].set(bh[:, 0]),
-        )
-        # max-priority insertion for emitted slots (clip scratch writes away
-        # by scattering into a length-C+1 view and dropping the tail)
-        pri_ext = jnp.concatenate([st.priority, jnp.zeros((1,), jnp.float32)])
-        pri_ext = pri_ext.at[slots].set(
-            jnp.where(emit, st.max_priority, pri_ext[slots])
-        )
-        st = st._replace(
-            priority=pri_ext[:C],
-            pos=(s.pos + n_emit) % C,
-            filled=jnp.minimum(s.filled + n_emit, C),
-        )
-
-        # ---- builder carry-over -------------------------------------------
         # flush (cut): restart empty.  full (no cut): keep last L-stride
         # steps.  neither: just the incremented length.
-        tail = L - stride
-        shifted = jax.tree.map(
-            lambda b: jnp.roll(b, -stride, axis=1),
-            (bf, ba, br, bd, bc, bh),
+        new_len = jnp.where(cut, 0, jnp.where(emit, L - stride, klen))
+        st = s._replace(
+            buf_frames=s.buf_frames.at[lane, k].set(frames),
+            buf_actions=s.buf_actions.at[lane, k].set(
+                actions.astype(jnp.int32)),
+            buf_rewards=s.buf_rewards.at[lane, k].set(
+                rewards.astype(jnp.float32)),
+            buf_dones=s.buf_dones.at[lane, k].set(terminals),
+            buf_c=s.buf_c.at[lane, k].set(lstm_c.astype(jnp.float32)),
+            buf_h=s.buf_h.at[lane, k].set(lstm_h.astype(jnp.float32)),
+            buf_len=new_len.astype(jnp.int32),
         )
 
-        def pick(orig, shift):
-            sel = emit & ~cut  # overlap carry-over
-            sh = jnp.reshape(sel, (lanes,) + (1,) * (orig.ndim - 1))
-            return jnp.where(sh, shift, orig)
+        def do_emit(st: DeviceSeqState) -> DeviceSeqState:
+            # ring slots: emitters take pos+rank (mod C), the rest the
+            # scratch row
+            rank = jnp.cumsum(emit.astype(jnp.int32)) - 1
+            n_emit = emit.sum().astype(jnp.int32)
+            slots = jnp.where(emit, (st.pos + rank) % C, C)
 
-        bf2, ba2, br2, bd2, bc2, bh2 = (
-            pick(o, sh) for o, sh in zip((bf, ba, br, bd, bc, bh), shifted)
-        )
-        new_len = jnp.where(cut, 0, jnp.where(emit, tail, klen))
-        return st._replace(
-            buf_frames=bf2, buf_actions=ba2, buf_rewards=br2, buf_dones=bd2,
-            buf_c=bc2, buf_h=bh2, buf_len=new_len.astype(jnp.int32),
-        )
+            vm = jnp.arange(L)[None, :] < klen[:, None]  # [lanes, L] valid
+
+            def zpad(buf, mask):
+                return jnp.where(mask, buf, jnp.zeros_like(buf))
+
+            # max-priority insertion for emitted slots (clip scratch writes
+            # away by scattering into a length-C+1 view and dropping the
+            # tail)
+            pri_ext = jnp.concatenate(
+                [st.priority, jnp.zeros((1,), jnp.float32)])
+            pri_ext = pri_ext.at[slots].set(
+                jnp.where(emit, st.max_priority, pri_ext[slots])
+            )
+
+            # overlap carry-over: a full, uncut lane keeps its last
+            # L-stride steps at the front of its builder
+            keep_tail = emit & ~cut
+
+            def carry_over(buf):
+                sel = jnp.reshape(keep_tail, (lanes,) + (1,) * (buf.ndim - 1))
+                return jnp.where(sel, jnp.roll(buf, -stride, axis=1), buf)
+
+            return st._replace(
+                frames=st.frames.at[slots].set(
+                    zpad(st.buf_frames, vm[..., None, None])),
+                actions=st.actions.at[slots].set(zpad(st.buf_actions, vm)),
+                rewards=st.rewards.at[slots].set(zpad(st.buf_rewards, vm)),
+                dones=st.dones.at[slots].set(zpad(st.buf_dones, vm)),
+                valids=st.valids.at[slots].set(vm),
+                init_c=st.init_c.at[slots].set(st.buf_c[:, 0]),
+                init_h=st.init_h.at[slots].set(st.buf_h[:, 0]),
+                priority=pri_ext[:C],
+                pos=(st.pos + n_emit) % C,
+                filled=jnp.minimum(st.filled + n_emit, C),
+                emit_ticks=st.emit_ticks + 1,
+                buf_frames=carry_over(st.buf_frames),
+                buf_actions=carry_over(st.buf_actions),
+                buf_rewards=carry_over(st.buf_rewards),
+                buf_dones=carry_over(st.buf_dones),
+                buf_c=carry_over(st.buf_c),
+                buf_h=carry_over(st.buf_h),
+            )
+
+        return jax.lax.cond(emit.any(), do_emit, lambda st: st, st)
 
     # -------------------------------------------------------------- sampling
     def _effective_priority(self, s: DeviceSeqState) -> jnp.ndarray:

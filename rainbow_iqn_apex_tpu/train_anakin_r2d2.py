@@ -238,8 +238,10 @@ def _maybe_restore_replay(cfg: Config, ss: DeviceSeqState) -> DeviceSeqState:
     z = snapshot_io.load(path)
     if tuple(z["frames"].shape) != tuple(ss.frames.shape):
         return ss  # geometry change: degrade to cold replay (host-path rule)
-    return DeviceSeqState(
-        **{f: jnp.asarray(z[f]) for f in DeviceSeqState._fields}
+    # a field the snapshot predates (emit_ticks) keeps its fresh value
+    return ss._replace(
+        **{f: jnp.asarray(z[f]) for f in DeviceSeqState._fields
+           if f in z.files}
     )
 
 
@@ -369,6 +371,13 @@ def train_anakin_r2d2(cfg: Config,
     def crossed(interval: int, before: int, after: int) -> bool:
         return interval > 0 and before // interval != after // interval
 
+    def emit_ticks_of(ss: DeviceSeqState) -> float:
+        """Ticks on which append's conditional ran its emit branch (mean of
+        the shards' counters over a mesh); read only when a row is logged."""
+        return float(np.mean(np.asarray(ss.emit_ticks)))
+
+    row_emit_ticks, row_frames = emit_ticks_of(ss), frames
+
     # --trace-dir: the capture's 'device_time' row names the segment's work
     # by scope from the compiled text (no compile: the program has run)
     obs_run.trace_window.add_program(
@@ -389,6 +398,10 @@ def train_anakin_r2d2(cfg: Config,
 
             if crossed(cfg.metrics_interval, prev_steps, learn_steps):
                 l = np.asarray(loss)
+                emit_ticks = emit_ticks_of(ss)
+                emit_share = (emit_ticks - row_emit_ticks) / (
+                    (frames - row_frames) // lanes)
+                row_emit_ticks, row_frames = emit_ticks, frames
                 metrics.log(
                     "learn",
                     step=learn_steps,
@@ -400,6 +413,7 @@ def train_anakin_r2d2(cfg: Config,
                     grad_norm=float(np.nanmean(np.asarray(grad_norm)))
                     if np.any(~np.isnan(np.asarray(grad_norm))) else float("nan"),
                     mean_return=float(np.mean(returns)) if returns else float("nan"),
+                    append_emit_tick_share=emit_share,
                 )
                 obs_run.periodic(learn_steps, frames)
             if crossed(cfg.eval_interval, prev_steps, learn_steps):

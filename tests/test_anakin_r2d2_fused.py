@@ -82,6 +82,30 @@ def test_fused_r2d2_smoke_end_to_end(tmp_path):
     assert all(np.isfinite(r["loss"]) for r in train_rows)
 
 
+def test_learn_row_reports_append_emit_tick_share(tmp_path):
+    """On freeway the lanes run in lockstep (no terminals, time limit far
+    off): with 10-step windows every 6 ticks the append's conditional takes
+    its emit branch on ticks 10, 16, 22, ... and skips the rest.  Each
+    `learn` row reports the share of emitting ticks since the row before."""
+    cfg = _cfg(tmp_path, env_id="jaxgame:freeway", metrics_interval=1,
+               learn_start=80, hidden_size=32, lstm_size=16, batch_size=8)
+    T, lanes = cfg.anakin_segment_ticks, cfg.num_envs_per_actor
+    train_anakin_r2d2(cfg, max_frames=6 * T * lanes)
+    rows = [json.loads(l) for l in open(
+        os.path.join(cfg.results_dir, cfg.run_id, "metrics.jsonl"))]
+    learn = [r for r in rows if r["kind"] == "learn"]
+    assert len(learn) >= 3
+    emitting = lambda t: t >= 10 and (t - 10) % 6 == 0  # noqa: E731
+    before = 0
+    for r in learn:
+        now = r["frames"] // lanes
+        want = sum(emitting(t) for t in range(before + 1, now + 1))
+        assert r["append_emit_tick_share"] == pytest.approx(
+            want / (now - before))
+        assert 0.0 < r["append_emit_tick_share"] < 1.0
+        before = now
+
+
 def test_hostfed_anakin_r2d2_smoke(tmp_path):
     """Non-jaxgame envs dispatch to the host-fed loop: env on host, sequence
     ring + LSTM + stack device-resident, lag-one appends."""

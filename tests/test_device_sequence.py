@@ -6,6 +6,8 @@ pin the in-graph mirror to it tick by tick: ring rows (zero-padding,
 two-channel cuts, overlap carry-over with exact stored LSTM states),
 max-priority insertion order, assemble weights, and eta-mix write-back."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,17 +38,22 @@ def _make_pair():
     return host, dev
 
 
-def _trace(rng, ticks, p_term=0.1, p_trunc=0.07):
-    for _ in range(ticks):
-        term = rng.random(LANES) < p_term
+def _trace(rng, ticks, p_term=0.1, p_trunc=0.07, trunc_all_at=(),
+           lanes=LANES):
+    """Per-tick append inputs, in append's argument order.  Random cuts per
+    lane, plus a truncation of every lane on the (1-based) ticks of
+    `trunc_all_at`."""
+    for t in range(1, ticks + 1):
+        term = rng.random(lanes) < p_term
+        trunc = (rng.random(lanes) < p_trunc) & ~term
         yield dict(
-            frames=rng.integers(0, 255, (LANES, H, W), dtype=np.uint8),
-            actions=rng.integers(0, 4, LANES).astype(np.int32),
-            rewards=rng.normal(size=LANES).astype(np.float32),
+            frames=rng.integers(0, 255, (lanes, H, W), dtype=np.uint8),
+            actions=rng.integers(0, 4, lanes).astype(np.int32),
+            rewards=rng.normal(size=lanes).astype(np.float32),
             terminals=term,
-            truncations=(rng.random(LANES) < p_trunc) & ~term,
-            lstm_c=rng.normal(size=(LANES, LSTM)).astype(np.float32),
-            lstm_h=rng.normal(size=(LANES, LSTM)).astype(np.float32),
+            truncations=trunc | (t in trunc_all_at),
+            lstm_c=rng.normal(size=(lanes, LSTM)).astype(np.float32),
+            lstm_h=rng.normal(size=(lanes, LSTM)).astype(np.float32),
         )
 
 
@@ -107,6 +114,175 @@ def test_ring_matches_host_no_cuts():
         np.asarray(ds.init_c)[sl], host.init_c[sl], rtol=1e-6
     )
     assert np.asarray(ds.valids)[sl].all()  # full windows only
+
+
+# --------------------------------------------------------------------------
+# the conditional emit: every tick against the host, on the traffics the
+# fused trainers produce
+# --------------------------------------------------------------------------
+
+TICKS = 40
+# _trace arguments for the traffics the fused trainers produce
+TRAFFIC = {
+    # freeway between time limits: all lanes emit on the same ticks (6, 9,
+    # 12, ...), every other tick is quiet
+    "lockstep": dict(p_term=0.0, p_trunc=0.0),
+    # freeway's time limit: every lane truncated on ticks 8 and 29 (mid-window
+    # and on a tick that would have emitted anyway)
+    "lockstep_truncation": dict(p_term=0.0, p_trunc=0.0,
+                                trunc_all_at=(8, 29)),
+    # catch, breakout: lanes fall out of lockstep
+    "random_cuts": dict(),
+}
+
+
+def _assert_equals_host(ds, host, where):
+    """Bit for bit: ring rows [0, C) (the scratch row C is the device's own
+    business), priorities, cursors, and each lane's live builder prefix."""
+    for name in ("frames", "actions", "rewards", "dones", "valids", "init_c",
+                 "init_h"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(ds, name))[:CAP], getattr(host, name)[:CAP],
+            err_msg=f"{name} {where}")
+    np.testing.assert_array_equal(
+        np.asarray(ds.priority),
+        host.tree.get(np.arange(CAP)).astype(np.float32),
+        err_msg=f"priority {where}")
+    assert int(ds.pos) == host.pos, where
+    assert int(ds.filled) == host.filled, where
+    assert float(ds.max_priority) == np.float32(host.max_priority), where
+    lens = np.asarray(ds.buf_len)
+    np.testing.assert_array_equal(lens, host._buf_len, err_msg=where)
+    for lane, n in enumerate(lens):
+        for name in ("frames", "actions", "rewards", "dones", "c", "h"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(ds, "buf_" + name))[lane, :n],
+                getattr(host, "_buf_" + name)[lane, :n],
+                err_msg=f"builder {name} lane {lane} {where}")
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_device", "shard_map"])
+@pytest.mark.parametrize("traffic", sorted(TRAFFIC))
+def test_every_tick_matches_host(traffic, sharded):
+    """After EVERY tick, quiet or emitting, the device ring equals the host
+    SequenceReplay; under build_sharded_seq_append each shard equals a host
+    replay of its own fed that shard's lanes.  `emit_ticks` counts the ticks
+    on which some lane (of the shard) emitted."""
+    from rainbow_iqn_apex_tpu.replay.device_sequence import (
+        build_sharded_seq_append,
+        device_seq_shardings,
+        stack_seq_shards,
+    )
+
+    n_dev = 2 if sharded else 1
+    if len(jax.devices()) < n_dev:
+        pytest.skip("needs 2 devices")
+    hosts = [_make_pair()[0] for _ in range(n_dev)]
+    dev = _make_pair()[1]
+    if sharded:
+        from jax.sharding import Mesh
+
+        mesh = Mesh(np.array(jax.devices()[:n_dev]), ("dp",))
+        append = jax.jit(build_sharded_seq_append(dev, mesh))
+        ds = jax.device_put(stack_seq_shards(dev.init_state(), n_dev),
+                            device_seq_shardings(mesh))
+    else:
+        append = jax.jit(dev.append)
+        ds = dev.init_state()
+    rng = np.random.default_rng(13)
+    emit_ticks = np.zeros(n_dev, np.int64)
+    trace = _trace(rng, TICKS, lanes=n_dev * LANES, **TRAFFIC[traffic])
+    for t, x in enumerate(trace, 1):
+        ds = append(ds, *(jnp.asarray(v) for v in x.values()))
+        for d, host in enumerate(hosts):
+            mine = {k: v[d * LANES:(d + 1) * LANES] for k, v in x.items()}
+            emit_ticks[d] += (mine["terminals"] | mine["truncations"]
+                              | (host._buf_len + 1 == L)).any()
+            host.append_batch(
+                mine["frames"], mine["actions"], mine["rewards"],
+                mine["terminals"], mine["lstm_c"], mine["lstm_h"],
+                truncations=mine["truncations"],
+            )
+            shard = jax.tree.map(lambda a: np.asarray(a)[d], ds) \
+                if sharded else ds
+            _assert_equals_host(shard, host, f"tick {t} shard {d}")
+    np.testing.assert_array_equal(
+        np.asarray(ds.emit_ticks).reshape(n_dev), emit_ticks)
+    assert 0 < emit_ticks.min() and emit_ticks.max() < TICKS  # both branches
+
+
+def test_emit_ticks_counts_ticks_not_sequences():
+    """Three lanes in lockstep emit on ticks 6, 9 and 12: three emitting
+    ticks, nine sequences."""
+    host, dev = _make_pair()
+    ds = _drive(host, dev, 12, p_term=0.0, p_trunc=0.0)
+    assert int(ds.emit_ticks) == 3
+    assert int(ds.filled) == 9
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (list, tuple)) else [v]):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def test_append_is_one_conditional_with_an_empty_skip():
+    """The emit work sits under ONE cond whose skip branch is empty, and
+    nothing outside it makes a builder-sized array but the one-step
+    scatters."""
+    _, dev = _make_pair()
+    x = next(_trace(np.random.default_rng(0), 1))
+    jaxpr = jax.make_jaxpr(dev.append)(dev.init_state(), *x.values()).jaxpr
+    builder = LANES * L * H * W
+    conds, big = [], []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "cond":
+                conds.append(eqn)
+                continue  # what is inside runs on emitting ticks only
+            if eqn.primitive.name != "scatter":
+                big.extend(
+                    (eqn.primitive.name, v.aval.shape) for v in eqn.outvars
+                    if np.prod(v.aval.shape, dtype=np.int64) >= builder)
+            for sub in _sub_jaxprs(eqn):
+                walk(sub)
+
+    walk(jaxpr)
+    assert len(conds) == 1
+    assert big == []
+    skip, do_emit = conds[0].params["branches"]
+    assert len(skip.jaxpr.eqns) == 0
+    assert any(e.primitive.name == "scatter" and
+               e.outvars[0].aval.shape == (CAP + 1, L, H, W)
+               for e in do_emit.jaxpr.eqns)
+
+
+def test_restore_accepts_a_snapshot_without_emit_ticks(tmp_path):
+    """A snapshot written before `emit_ticks` existed restores every field it
+    has and leaves the counter at its fresh value."""
+    from rainbow_iqn_apex_tpu import train_anakin_r2d2 as prog
+    from rainbow_iqn_apex_tpu.config import Config
+    from rainbow_iqn_apex_tpu.replay import snapshot_io
+
+    host, dev = _make_pair()
+    ds = _drive(host, dev, 17)
+    cfg = Config(checkpoint_dir=str(tmp_path), run_id="r", snapshot_replay=True)
+    old = {f: np.asarray(v) for f, v in ds._asdict().items()
+           if f != "emit_ticks"}
+    os.makedirs(os.path.dirname(prog._replay_snapshot_path(cfg)))
+    snapshot_io.atomic_savez(prog._replay_snapshot_path(cfg), **old)
+    got = prog._maybe_restore_replay(cfg, dev.init_state())
+    assert int(got.emit_ticks) == 0
+    for f, v in old.items():
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)), v, f)
+    # and the round trip of a current snapshot keeps the counter
+    prog._save_replay(cfg, ds)
+    got = prog._maybe_restore_replay(cfg, dev.init_state())
+    assert int(got.emit_ticks) == int(ds.emit_ticks) > 0
 
 
 def test_assemble_matches_host_sample_fields():
