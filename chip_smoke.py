@@ -13,6 +13,10 @@ native 80x80 frames, from seeded random weights:
   apex     train_agent_apex.main --role apex --env-id toy:catch (host env
            lanes, native replay core, prefetch, write-back ring, lane-sharded
            actor step, cross-mesh weight publish)
+  core     train_agent_apex.main --architecture r2d2 --role anakin with
+           --core-config at the published widths (the Kimi-Linear recurrent
+           core, configs/cores/): a few dispatches of the fused R2D2 segment,
+           finite loss, no token dropped by the expert layers
   reference  the checkpoint's Q-values on 8 fixed frames, chip at the
            configured bf16 against float32 on the host CPU backend
 
@@ -57,6 +61,14 @@ APEX = [
     "--memory-capacity", "131072", "--learn-start", "2048",
     "--t-max", "6144", "--metrics-interval", "100",
     "--weight-publish-interval", "100", *COMMON,
+]
+CORE = [
+    "--architecture", "r2d2", "--role", "anakin", "--env-id",
+    "jaxgame:freeway", "--run-id", "core",
+    "--core-config", "configs/cores/kimi_linear_48b_a3b.json",
+    "--num-envs-per-actor", "16", "--memory-capacity", "24576",
+    "--learn-start", "1920", "--t-max", "4096", "--metrics-interval", "1",
+    "--checkpoint-interval", "0", *COMMON,
 ]
 
 
@@ -265,6 +277,16 @@ def run(devices) -> int:
         row["replay_core"] = f"native ({lib})" if lib else "numpy"
         if lib is None or not lib.startswith(HERE + os.sep):
             failures.append(f"apex: configured the native replay core, ran {lib}")
+
+    if train_phase("core", CORE) is not None:
+        learn = [r for r in read_rows("core") if r["kind"] == "learn"]
+        dropped = [r.get("moe_tokens_dropped") for r in learn]
+        report["phases"]["core"].update(
+            moe_tokens_dropped=max(dropped, default=None),
+            moe_held_assign_share=[r.get("moe_held_assign_share")
+                                   for r in learn][-1:])
+        if not learn or any(d != 0 for d in dropped):
+            failures.append(f"core: moe_tokens_dropped {dropped}")
 
     ref, row, _ = phase("reference", reference_check, failures)
     if ref is not None:

@@ -189,6 +189,9 @@ class Config:
     compute_dtype: str = "bfloat16"  # MXU-friendly compute; params stay fp32
     # R2D2 (stretch) ----------------------------------------------------------------
     lstm_size: int = 512
+    # the recurrent core: a file under configs/cores/ (models/cores.py), as
+    # given or relative to the repository's root; "" is the LSTM above
+    core_config: str = ""
     r2d2_burn_in: int = 40
     r2d2_seq_len: int = 80  # trained steps per sequence (after burn-in)
     r2d2_overlap: int = 40  # stride = burn_in + seq_len - overlap

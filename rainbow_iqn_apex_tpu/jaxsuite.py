@@ -520,13 +520,12 @@ def eval_checkpoint_per_level(base_args: List[str], run_id: str,
             a, _q, lstm = act_fn(aux[0], stack, lstm, key)
             return a, lstm
 
-        def actor_init(n):
-            z = jnp.zeros((n, cfg.lstm_size), jnp.float32)
-            return (z, z)
+        from rainbow_iqn_apex_tpu.models.cores import make_core
 
         run = build_rollout(game, action_fn, lanes, T,
                             history=cfg.history_length,
-                            actor_init=actor_init, init_fn=init_fn)
+                            actor_init=make_core(cfg).initial_state,
+                            init_fn=init_fn)
         ts = init_r2d2_state(cfg, game.num_actions, jax.random.PRNGKey(0),
                              (h, w))
     else:

@@ -1,0 +1,150 @@
+"""The recurrent core of `R2D2Net`, behind one interface.
+
+A core sits between the conv trunk and the dueling heads:
+
+    core(x [B, T, F] float32, state, resets [B, T] bool) -> (y [B, T, F'], state)
+    core.initial_state(batch) -> state      (all zeros; zeroing a lane's
+                                             leaves resets that lane)
+    core.stored_width                        width of EACH of the ring's two
+                                             stored-state columns
+    core.to_stored(state) -> (c, h)          what the ring keeps of a state
+    core.from_stored(c, h) -> state          a sequence's start state
+
+`resets[b, t]` zeroes lane b's state BEFORE step t.  A core is a plain
+(hashable) object; called inside `R2D2Net.__call__` it builds its flax
+modules in the net's scope, so the LSTM's parameters stay `lstm/cell/...`
+leaf for leaf.
+
+Two cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
+(c, h) of every sequence start) and `models/kimi_linear.KimiLinearCore`
+(zero start state: the ring's state columns have width 0).  `Config
+.core_config` names the file of the second; none is the LSTM.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import json
+import os
+from typing import Any, Tuple
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from rainbow_iqn_apex_tpu.obs import device_scopes
+
+LSTMState = Tuple[jnp.ndarray, jnp.ndarray]  # (c, h), each [B, lstm_size]
+CORE_STATS = "core_stats"  # flax collection a core sows its counters in
+
+
+class _ResettableLSTMStep(nn.Module):
+    """One LSTM step with an optional pre-step state reset (episode cut)."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, carry: LSTMState, xs):
+        x_t, reset_t = xs  # [B, F], [B] bool
+        c, h = carry
+        keep = (1.0 - reset_t.astype(jnp.float32))[:, None]
+        c, h = c * keep, h * keep
+        (c, h), out = nn.OptimizedLSTMCell(features=self.features, name="cell")(
+            (c, h), x_t
+        )
+        return (c, h), out
+
+
+@dataclasses.dataclass(frozen=True)
+class LSTMCore:
+    """`lax.scan` over an `OptimizedLSTMCell` step; the state is (c, h)."""
+
+    features: int = 512
+
+    stat_names = ()  # counters the core sows (CORE_STATS), by name
+
+    @property
+    def stored_width(self) -> int:
+        return self.features
+
+    def initial_state(self, batch: int) -> LSTMState:
+        # two buffers: the fused segment donates its carry, and one array
+        # donated twice is a runtime error
+        return (jnp.zeros((batch, self.features), jnp.float32),
+                jnp.zeros((batch, self.features), jnp.float32))
+
+    def to_stored(self, state: LSTMState) -> LSTMState:
+        return state
+
+    def from_stored(self, init_c, init_h) -> LSTMState:
+        return (init_c, init_h)
+
+    def __call__(self, x, state: LSTMState, resets):
+        xs = (jnp.moveaxis(x, 1, 0), jnp.moveaxis(resets, 1, 0))  # [T, B, .]
+        scan = nn.scan(
+            _ResettableLSTMStep,
+            variable_broadcast="params",
+            split_rngs={"params": False},
+            in_axes=0,
+            out_axes=0,
+        )
+        with jax.named_scope(device_scopes.LSTM_SCAN):
+            state, outs = scan(features=self.features, name="lstm")(state, xs)
+        return jnp.moveaxis(outs, 0, 1), state
+
+
+def zero_lanes(state: Any, keep: jnp.ndarray) -> Any:
+    """`state` with the lanes where `keep` [B] is 0 back at the initial
+    (zero) state; every leaf leads with the lane axis."""
+    kf = keep.astype(jnp.float32)
+    return jax.tree.map(
+        lambda s: s * kf.reshape((-1,) + (1,) * (s.ndim - 1)), state)
+
+
+def reduce_stats(collection) -> dict:
+    """{counter: scalar} of what the core's layers sowed in one pass: a
+    `*_max_*` counter by its largest, a `*_dropped` by its sum, any other by
+    its mean over the layers."""
+    by_name = {}
+    for path, v in jax.tree_util.tree_leaves_with_path(collection):
+        name = [k.key for k in path if hasattr(k, "key")][-1]
+        by_name.setdefault(name, []).append(v)
+    how = lambda n: (jnp.max if "_max_" in n else  # noqa: E731
+                     jnp.sum if n.endswith("_dropped") else jnp.mean)
+    return {n: how(n)(jnp.stack(v)).astype(jnp.float32)
+            for n, v in by_name.items()}
+
+
+def state_bytes_per_lane(core) -> int:
+    shapes = jax.eval_shape(lambda: core.initial_state(1))
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: str, compute_dtype: str):
+    from rainbow_iqn_apex_tpu.models.kimi_linear import (
+        KimiLinearConfig,
+        KimiLinearCore,
+    )
+
+    found = path if os.path.exists(path) else os.path.join(_ROOT, path)
+    with open(found) as f:
+        cc = json.load(f)
+    if cc.get("model_type") != "kimi_linear":
+        raise ValueError(
+            f"{path}: no core for model_type {cc.get('model_type')!r}")
+    return KimiLinearCore(KimiLinearConfig.from_dict(cc),
+                          jnp.dtype(compute_dtype))
+
+
+def make_core(cfg):
+    """The core `cfg` asks for: `core_config` (a file under configs/cores/,
+    found as given or relative to the repository's root), else the LSTM."""
+    if cfg.core_config:
+        return _load(cfg.core_config, cfg.compute_dtype)
+    return LSTMCore(cfg.lstm_size)

@@ -1,4 +1,5 @@
-"""R2D2 recurrent Q-network (flax): conv trunk -> LSTM -> dueling noisy head.
+"""R2D2 recurrent Q-network (flax): conv trunk -> recurrent core -> dueling
+noisy head.  The core is the LSTM unless one is given (models/cores.py).
 
 Parity: the reference's R2D2 stretch configuration (BASELINE.json:10,
 SURVEY.md §7 step 7; Kapturowski et al., "Recurrent Experience Replay in
@@ -24,28 +25,11 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from rainbow_iqn_apex_tpu.models.cores import LSTMCore
 from rainbow_iqn_apex_tpu.models.layers import ConvTrunk, NoisyLinear
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
 Dtype = Any
-LSTMState = Tuple[jnp.ndarray, jnp.ndarray]  # (c, h), each [B, lstm_size]
-
-
-class _ResettableLSTMStep(nn.Module):
-    """One LSTM step with an optional pre-step state reset (episode cut)."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self, carry: LSTMState, xs):
-        x_t, reset_t = xs  # [B, F], [B] bool
-        c, h = carry
-        keep = (1.0 - reset_t.astype(jnp.float32))[:, None]
-        c, h = c * keep, h * keep
-        (c, h), out = nn.OptimizedLSTMCell(features=self.features, name="cell")(
-            (c, h), x_t
-        )
-        return (c, h), out
 
 
 class R2D2Net(nn.Module):
@@ -58,19 +42,22 @@ class R2D2Net(nn.Module):
     dueling: bool = True
     use_noise: bool = True
     compute_dtype: Dtype = jnp.bfloat16
+    core: Any = None  # models/cores.py; None is LSTMCore(lstm_size)
 
-    def initial_state(self, batch: int) -> LSTMState:
-        z = jnp.zeros((batch, self.lstm_size), jnp.float32)
-        return (z, z)
+    def the_core(self):
+        return self.core if self.core is not None else LSTMCore(self.lstm_size)
+
+    def initial_state(self, batch: int):
+        return self.the_core().initial_state(batch)
 
     @nn.compact
     def __call__(
         self,
         obs_seq: jnp.ndarray,  # [B, T, H, W, C] uint8 (or float in [0,1])
-        state: LSTMState,
+        state: Any,
         resets: Optional[jnp.ndarray] = None,  # [B, T] bool: reset state BEFORE step t
-    ) -> Tuple[jnp.ndarray, LSTMState]:
-        """Returns (q_values [B, T, A] fp32, final LSTM state)."""
+    ) -> Tuple[jnp.ndarray, Any]:
+        """Returns (q_values [B, T, A] fp32, the core's final state)."""
         B, T = obs_seq.shape[:2]
         if obs_seq.dtype == jnp.uint8:
             obs_seq = obs_seq.astype(self.compute_dtype) * (1.0 / 255.0)
@@ -80,25 +67,11 @@ class R2D2Net(nn.Module):
             phi = ConvTrunk(compute_dtype=self.compute_dtype)(
                 obs_seq.reshape(B * T, *obs_seq.shape[2:])
             )
-        phi = phi.reshape(B, T, -1).astype(jnp.float32)  # LSTM carries in fp32
-
-        xs = (
-            jnp.moveaxis(phi, 1, 0),  # [T, B, F]
-            jnp.moveaxis(
-                resets if resets is not None else jnp.zeros((B, T), bool), 1, 0
-            ),
-        )
-        scan = nn.scan(
-            _ResettableLSTMStep,
-            variable_broadcast="params",
-            split_rngs={"params": False},
-            in_axes=0,
-            out_axes=0,
-        )
-        with jax.named_scope(device_scopes.LSTM_SCAN):
-            final_state, outs = scan(
-                features=self.lstm_size, name="lstm")(state, xs)
-        feat = jnp.moveaxis(outs, 0, 1).reshape(B * T, self.lstm_size)  # [B*T, L]
+        phi = phi.reshape(B, T, -1).astype(jnp.float32)  # cores carry fp32
+        if resets is None:
+            resets = jnp.zeros((B, T), bool)
+        outs, final_state = self.the_core()(phi, state, resets)
+        feat = outs.reshape(B * T, outs.shape[-1])  # [B*T, L]
 
         def head(name: str, out_dim: int) -> jnp.ndarray:
             h1 = NoisyLinear(

@@ -51,6 +51,14 @@ REPLAY_WRITEBACK = "replay_writeback"  # the priority scatter
 LEARN_STEP = "learn_step"  # forward, loss, backward, optimizer, target copy
 NET_TRUNK = "net_trunk"  # conv trunk
 LSTM_SCAN = "lstm_scan"  # the lax.scan over the LSTM cell (R2D2)
+# ---- the Kimi-Linear core (models/kimi_linear.py)
+CORE_LAYER = "core_layer"  # one pre-norm block: mixer + feed-forward
+KDA_SCAN = "kda_scan"  # the chunked delta-rule recurrence of a sequence
+MLA_ATTN = "mla_attn"  # scores, mask, softmax, values over the latent window
+MOE_ROUTE = "moe_route"  # router, top-k, the sort by held expert
+MOE_EXPERTS = "moe_experts"  # gather, the grouped products, scatter-add
+MOE_SHARED = "moe_shared"  # the shared expert
+CORE_STEP = "core_step"  # the KDA recurrence of one step (the actor's tick)
 IQN_HEAD = "iqn_head"  # tau embedding + the tau-folded heads (IQN)
 OPTIMIZER = "optimizer"  # tx.update, apply_updates, the target copy
 GRAD_ALLREDUCE = "grad_allreduce"  # psum/pmax/pmean of the sharded builders
@@ -58,7 +66,8 @@ GRAD_ALLREDUCE = "grad_allreduce"  # psum/pmax/pmean of the sharded builders
 TICK_SCOPES = (TICK_ACT, TICK_ENV, TICK_APPEND, TICK_LEARN)
 ALL_SCOPES = TICK_SCOPES + (
     REPLAY_DRAW, REPLAY_GATHER, REPLAY_WRITEBACK, LEARN_STEP, NET_TRUNK,
-    LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE,
+    LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
+    MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 

@@ -22,7 +22,12 @@ import optax
 from flax import struct
 
 from rainbow_iqn_apex_tpu.config import Config
-from rainbow_iqn_apex_tpu.models.r2d2 import LSTMState, R2D2Net
+from rainbow_iqn_apex_tpu.models.cores import (
+    CORE_STATS,
+    make_core,
+    reduce_stats,
+)
+from rainbow_iqn_apex_tpu.models.r2d2 import R2D2Net
 from rainbow_iqn_apex_tpu.obs import device_scopes
 from rainbow_iqn_apex_tpu.ops.learn import make_optimizer
 from rainbow_iqn_apex_tpu.ops.losses import huber
@@ -108,6 +113,7 @@ def make_r2d2_network(cfg: Config, num_actions: int, use_noise: bool = True) -> 
         dueling=cfg.dueling,
         use_noise=use_noise,
         compute_dtype=jnp.dtype(cfg.compute_dtype),
+        core=make_core(cfg),
     )
 
 
@@ -140,14 +146,15 @@ def _unroll(
     batch: SequenceBatch,
     burn_in: int,
     noise_key: chex.PRNGKey,
-) -> jnp.ndarray:
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Burn-in (stop-grad) then train unroll; returns q [B, T, A] for the
-    train slice.  LSTM state resets where a step follows a terminal."""
+    train slice and the counters the core sowed over it.  The core's state
+    resets where a step follows a terminal."""
     # reset BEFORE step t when the previous step ended the episode
     prev_done = jnp.concatenate(
         [jnp.zeros_like(batch.done[:, :1]), batch.done[:, :-1]], axis=1
     )
-    state: LSTMState = (batch.init_c, batch.init_h)
+    state = net.the_core().from_stored(batch.init_c, batch.init_h)
     kb, kt = jax.random.split(noise_key)
     if burn_in > 0:
         _, state = net.apply(
@@ -158,14 +165,15 @@ def _unroll(
             rngs={"noise": kb},
         )
         state = jax.lax.stop_gradient(state)
-    q, _ = net.apply(
+    (q, _), sown = net.apply(
         {"params": params},
         batch.obs[:, burn_in:],
         state,
         resets=prev_done[:, burn_in:],
         rngs={"noise": kt},
+        mutable=[CORE_STATS],
     )
-    return q  # [B, T, A]
+    return q, reduce_stats(sown)  # [B, T, A]
 
 
 def build_r2d2_learn_step(
@@ -196,13 +204,13 @@ def build_r2d2_learn_step(
         T = batch.obs.shape[1] - burn  # train slice length
 
         def loss_fn(params):
-            q_on = _unroll(net, params, batch, burn, k_on)  # [B, T, A]
+            q_on, core_stats = _unroll(net, params, batch, burn, k_on)  # [B, T, A]
             # Double-Q selection reuses the online unroll (stop-grad) rather
             # than paying a third full conv+LSTM unroll for an independent
             # noise draw — selection and evaluation already use different
             # nets, which is where double-Q's bias correction comes from.
             q_sel = jax.lax.stop_gradient(q_on)
-            q_tgt = _unroll(net, state.target_params, batch, burn, k_tgt)
+            q_tgt, _ = _unroll(net, state.target_params, batch, burn, k_tgt)
 
             a = batch.action[:, burn:]  # [B, T]
             r = batch.reward[:, burn:]
@@ -258,6 +266,7 @@ def build_r2d2_learn_step(
             aux = {
                 "priorities": priorities,
                 "q_mean": (q_taken * v).sum() / jnp.maximum(v.sum(), 1.0),
+                "core_stats": core_stats,
             }
             return loss, aux
 
@@ -281,6 +290,7 @@ def build_r2d2_learn_step(
             # on-device NaN/Inf guard flag (same contract as ops/learn.py:
             # checked host-side at the write-back ring boundary)
             "finite": jnp.isfinite(loss) & jnp.isfinite(grad_norm),
+            **aux["core_stats"],
         }
         return (
             R2D2TrainState(
@@ -325,7 +335,7 @@ def build_r2d2_act_step(
     it on the actor side)."""
     net = make_r2d2_network(cfg, num_actions, use_noise=use_noise)
 
-    def act_step(params, obs, state: LSTMState, key):
+    def act_step(params, obs, state, key):
         q, new_state = net.apply(
             {"params": params},
             obs[:, None],  # [B, 1, H, W, C]

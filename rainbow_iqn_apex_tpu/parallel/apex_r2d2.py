@@ -27,6 +27,7 @@ import numpy as np
 from rainbow_iqn_apex_tpu.agents.agent import FrameStacker
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.envs import make_vector_env
+from rainbow_iqn_apex_tpu.models.cores import make_core, zero_lanes
 from rainbow_iqn_apex_tpu.obs import RunObs
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     R2D2TrainState,
@@ -122,12 +123,16 @@ class R2D2ApexDriver(QuantPublishMixin):
         # sequence counts are not lockstep across hosts, so each row's N is
         # its own host's estimate, folded into nq per row)
         self._global_is_weights = make_global_is_weights(self._batch_sh)
-        # act: obs + (c, h) lane-sharded; params replicated on the actor mesh
+        # act: obs + the core's state lane-sharded; params replicated on the
+        # actor mesh
+        self.core = core = make_core(cfg)
+        state_sh = jax.tree.map(
+            lambda _: lane_sh, jax.eval_shape(lambda: core.initial_state(1)))
         act_fn = build_r2d2_act_step(cfg, num_actions, use_noise=True)
         self._act = jax.jit(
             act_fn,
-            in_shardings=(rep_a, lane_sh, (lane_sh, lane_sh), rep_a),
-            out_shardings=(lane_sh, lane_sh, (lane_sh, lane_sh)),
+            in_shardings=(rep_a, lane_sh, state_sh, rep_a),
+            out_shardings=(lane_sh, lane_sh, state_sh),
         )
         # device-resident frame stacking (shared shift with ApexDriver): the
         # host ships ONE [L, H, W] frame per tick; cut lanes are zeroed
@@ -140,17 +145,17 @@ class R2D2ApexDriver(QuantPublishMixin):
         self._stack_act = jax.jit(
             stack_act,
             in_shardings=(
-                rep_a, lane_sh, lane_sh, lane_sh, (lane_sh, lane_sh), rep_a,
+                rep_a, lane_sh, lane_sh, lane_sh, state_sh, rep_a,
             ),
-            out_shardings=(lane_sh, lane_sh, (lane_sh, lane_sh), lane_sh),
+            out_shardings=(lane_sh, lane_sh, state_sh, lane_sh),
             donate_argnums=1,
         )
         self.actor_stack = None  # created lazily at the first act_frames
         # device-side episode-cut mask for the carried state
         self._mask_state = jax.jit(
-            lambda st, keep: jax.tree.map(lambda x: x * keep[:, None], st),
-            in_shardings=((lane_sh, lane_sh), lane_sh),
-            out_shardings=(lane_sh, lane_sh),
+            zero_lanes,
+            in_shardings=(state_sh, lane_sh),
+            out_shardings=state_sh,
         )
         if cfg.bf16_weight_sync:
             self._cast = jax.jit(
@@ -171,8 +176,8 @@ class R2D2ApexDriver(QuantPublishMixin):
             act_q_fn = wrap_act_quantized(act_fn)
             self._act_q = jax.jit(
                 act_q_fn,
-                in_shardings=(rep_a, lane_sh, (lane_sh, lane_sh), rep_a),
-                out_shardings=(lane_sh, lane_sh, (lane_sh, lane_sh)),
+                in_shardings=(rep_a, lane_sh, state_sh, rep_a),
+                out_shardings=(lane_sh, lane_sh, state_sh),
             )
 
             def stack_act_q(qparams, stack, frame, keep, lstm_state, key):
@@ -183,11 +188,11 @@ class R2D2ApexDriver(QuantPublishMixin):
             self._stack_act_q = jax.jit(
                 stack_act_q,
                 in_shardings=(
-                    rep_a, lane_sh, lane_sh, lane_sh, (lane_sh, lane_sh),
+                    rep_a, lane_sh, lane_sh, lane_sh, state_sh,
                     rep_a,
                 ),
                 out_shardings=(
-                    lane_sh, lane_sh, (lane_sh, lane_sh), lane_sh,
+                    lane_sh, lane_sh, state_sh, lane_sh,
                 ),
                 donate_argnums=1,
             )
@@ -196,13 +201,10 @@ class R2D2ApexDriver(QuantPublishMixin):
             self._gate_actq = jax.jit(act_q_fn)
         # lanes is the GLOBAL lane count; each host materialises only its
         # local rows (make_array == device_put when single-process)
-        local_zeros = np.zeros(
-            (lanes // jax.process_count(), cfg.lstm_size), np.float32
-        )
-        self.lstm_state = (
-            self._put_lanes(local_zeros),
-            self._put_lanes(local_zeros),
-        )
+        self.lstm_state = jax.tree.map(
+            lambda x: self._put_lanes(np.zeros(x.shape, np.float32)),
+            jax.eval_shape(
+                lambda: core.initial_state(lanes // jax.process_count())))
         self.weights_version = 0
         self.actor_weights_version = 0
         self.publish_weights()
@@ -220,8 +222,7 @@ class R2D2ApexDriver(QuantPublishMixin):
         n = min(len(obs_batch), max(int(self.cfg.quant_calib_batch), 1))
         obs = np.asarray(obs_batch[:n], np.uint8)
         self._calib_obs = jnp.asarray(obs)
-        zeros = jnp.zeros((n, self.cfg.lstm_size), jnp.float32)
-        self._calib_state = (zeros, zeros)
+        self._calib_state = self.core.initial_state(n)
 
     def _gate_actions(self, params, qparams):
         a32, _, _ = self._gate_act32(
@@ -269,8 +270,8 @@ class R2D2ApexDriver(QuantPublishMixin):
         act = self._act_q if self._actor_quant else self._act
         if self._multihost:
             with hostsync.sanctioned():
-                pre_c = _local_rows(self.lstm_state[0])
-                pre_h = _local_rows(self.lstm_state[1])
+                pre_c, pre_h = (
+                    _local_rows(x) for x in self.core.to_stored(self.lstm_state))
             x = self._put_lanes(as_actor_input(obs, self.cfg.history_length))
             a, _q, self.lstm_state = act(
                 self.actor_params, x, self.lstm_state, self._next_key()
@@ -278,8 +279,8 @@ class R2D2ApexDriver(QuantPublishMixin):
             with hostsync.sanctioned():
                 return _local_rows(a), (pre_c, pre_h)
         with hostsync.sanctioned():
-            pre_c = np.asarray(self.lstm_state[0])
-            pre_h = np.asarray(self.lstm_state[1])
+            pre_c, pre_h = (
+                np.asarray(x) for x in self.core.to_stored(self.lstm_state))
         x = as_actor_input(obs, self.cfg.history_length)
         a, _q, self.lstm_state = act(
             self.actor_params, x, self.lstm_state, self._next_key()
@@ -301,11 +302,11 @@ class R2D2ApexDriver(QuantPublishMixin):
         reset separately via reset_lanes (the loop's existing contract)."""
         with hostsync.sanctioned():  # stored-state snapshot (actor half)
             if self._multihost:
-                pre_c = _local_rows(self.lstm_state[0])
-                pre_h = _local_rows(self.lstm_state[1])
+                pre_c, pre_h = (
+                    _local_rows(x) for x in self.core.to_stored(self.lstm_state))
             else:
-                pre_c = np.asarray(self.lstm_state[0])
-                pre_h = np.asarray(self.lstm_state[1])
+                pre_c, pre_h = (
+                    np.asarray(x) for x in self.core.to_stored(self.lstm_state))
         if self.actor_stack is None:
             h, w = frames.shape[1], frames.shape[2]
             self.actor_stack = self._put_lanes(
@@ -497,7 +498,7 @@ def train_apex_r2d2(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, 
         capacity=max(cfg.memory_capacity // (seq_total * nproc), 64),
         seq_len=seq_total,
         frame_shape=env.frame_shape,
-        lstm_size=cfg.lstm_size,
+        lstm_size=driver.core.stored_width,
         lanes=lanes,
         stride=max(seq_total - cfg.r2d2_overlap, 1),
         priority_exponent=cfg.priority_exponent,

@@ -38,6 +38,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from rainbow_iqn_apex_tpu.config import Config
+from rainbow_iqn_apex_tpu.models.cores import (
+    make_core,
+    state_bytes_per_lane,
+    zero_lanes,
+)
 from rainbow_iqn_apex_tpu.obs import RunObs, device_scopes
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     build_r2d2_act_step,
@@ -95,8 +100,9 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
     """Jitted (carry, key) -> (carry, outs) scanning anakin_segment_ticks of
     shift_stack -> recurrent act -> env.step -> sequence append -> gated
     learn.  carry = (ts, ss, env_states, ep_returns, stack, frame, keep,
-    lstm_c, lstm_h, frames); outs = per-tick (ep_return [L], loss/q_mean/
-    grad_norm [learns_per_tick], NaN when cold or off-cadence).
+    core_state, frames); outs = per-tick (ep_return [L], loss/q_mean/
+    grad_norm [learns_per_tick], then one entry per counter of the core's
+    `stat_names`; NaN when cold or off-cadence).
 
     `append_fn` defaults to replay.append; the sharded path passes the
     shard_map'd build_sharded_seq_append so each device's lanes emit into
@@ -110,14 +116,17 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
     env_step = batched_reset_step(game)
     append = append_fn or replay.append
     bw = cfg.priority_weight
+    core = make_core(cfg)
+    out_names = ("loss", "q_mean", "grad_norm") + tuple(core.stat_names)
 
     def tick(carry, k):
-        ts, ss, env_s, ep, stack, frame, keep, c, h, frames = carry
+        ts, ss, env_s, ep, stack, frame, keep, state, frames = carry
         ka, ks, kl = jax.random.split(k, 3)
-        pre_c, pre_h = c, h  # stored-state replay keeps the PRE-act state
+        # stored-state replay keeps (what the core stores of) the PRE-act state
+        pre_c, pre_h = core.to_stored(state)
         with jax.named_scope(device_scopes.TICK_ACT):
             stack = shift_stack(stack, frame, keep)
-            actions, _q, (c, h) = act_fn(ts.params, stack, (c, h), ka)
+            actions, _q, state = act_fn(ts.params, stack, state, ka)
         with jax.named_scope(device_scopes.TICK_ENV):
             env_s, ep, nframe, reward, term, trunc, out_ret = env_step(
                 env_s, ep, actions, ks
@@ -142,8 +151,7 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
             def one(cr, kk):
                 ts, ss = cr
                 ts, ss, info = learn_fn(ts, ss, kk, beta)
-                return (ts, ss), (info["loss"], info["q_mean"],
-                                  info["grad_norm"])
+                return (ts, ss), tuple(info[n] for n in out_names)
 
             (ts, ss), infos = jax.lax.scan(
                 one, (ts, ss), jax.random.split(kl, lpt)
@@ -153,17 +161,16 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
         def no_learn(args):
             ts, ss = args
             nanv = jnp.full((lpt,), jnp.nan, jnp.float32)
-            return ts, ss, (nanv, nanv, nanv)
+            return ts, ss, (nanv,) * len(out_names)
 
         with jax.named_scope(device_scopes.TICK_LEARN):
             ts, ss, infos = jax.lax.cond(
                 warm & due, do_learn, no_learn, (ts, ss))
 
         cut_keep = (~(term | trunc)).astype(jnp.uint8)
-        kf = cut_keep.astype(jnp.float32)[:, None]
-        c, h = c * kf, h * kf  # LSTM zero-reset on episode cut
-        out = (out_ret, infos[0], infos[1], infos[2])
-        return (ts, ss, env_s, ep, stack, nframe, cut_keep, c, h, frames), out
+        state = zero_lanes(state, cut_keep)  # zero-reset on episode cut
+        return (ts, ss, env_s, ep, stack, nframe, cut_keep, state,
+                frames), (out_ret, *infos)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def segment(carry, key):
@@ -184,11 +191,8 @@ def init_fused_r2d2_carry(cfg: Config, game, ts, ss, key, frames: int = 0):
     stack = jnp.zeros((lanes, h, w, cfg.history_length), jnp.uint8)
     frame = jax.vmap(game.render)(env_s)
     keep = jnp.ones(lanes, jnp.uint8)
-    # two distinct buffers: the segment donates its carry, and donating one
-    # array twice (aliased c == h) is a runtime error
-    c = jnp.zeros((lanes, cfg.lstm_size), jnp.float32)
-    h = jnp.zeros((lanes, cfg.lstm_size), jnp.float32)
-    return (ts, ss, env_s, ep, stack, frame, keep, c, h, jnp.int32(frames))
+    state = make_core(cfg).initial_state(lanes)
+    return (ts, ss, env_s, ep, stack, frame, keep, state, jnp.int32(frames))
 
 
 def build_fused_r2d2_eval(cfg: Config, game, episodes: int,
@@ -205,12 +209,9 @@ def build_fused_r2d2_eval(cfg: Config, game, episodes: int,
         a, _q, lstm = act_fn(params, stack, lstm, key)
         return a, lstm
 
-    def actor_init(n):
-        z = jnp.zeros((n, cfg.lstm_size), jnp.float32)
-        return (z, z)
-
     return build_rollout(game, action_fn, episodes, max_ticks,
-                         history=cfg.history_length, actor_init=actor_init)
+                         history=cfg.history_length,
+                         actor_init=make_core(cfg).initial_state)
 
 
 def _replay_snapshot_path(cfg: Config) -> str:
@@ -270,6 +271,7 @@ def train_anakin_r2d2(cfg: Config,
     h, w = game.frame_shape
     seq_total, stride, capacity, _ = _seq_geometry(cfg)
     _learn_cadence(cfg)  # validate divisibility before building anything
+    core = make_core(cfg)
 
     key = jax.random.PRNGKey(cfg.seed)
     key, k_init, k_env = jax.random.split(key, 3)
@@ -295,7 +297,7 @@ def train_anakin_r2d2(cfg: Config,
         mesh = Mesh(np.array(jax.devices()[:n_dev]), ("dp",))
         local_replay = DeviceSequenceReplay(
             capacity=capacity // n_dev, seq_len=seq_total,
-            frame_shape=(h, w), lstm_size=cfg.lstm_size,
+            frame_shape=(h, w), lstm_size=core.stored_width,
             lanes=lanes // n_dev, stride=stride,
             priority_exponent=cfg.priority_exponent,
             priority_eps=cfg.priority_eps,
@@ -314,10 +316,10 @@ def train_anakin_r2d2(cfg: Config,
         _rep = NamedSharding(mesh, P())
 
         def place(carry):
-            ts, ss, env_s, ep, stack, frame, keep, c, hh, frames = carry
+            ts, ss, env_s, ep, stack, frame, keep, state, frames = carry
             lane_tree = jax.tree.map(
                 lambda x: jax.device_put(x, _lane),
-                (env_s, ep, stack, frame, keep, c, hh),
+                (env_s, ep, stack, frame, keep, state),
             )
             return (
                 jax.device_put(ts, _rep),
@@ -328,7 +330,7 @@ def train_anakin_r2d2(cfg: Config,
     else:
         replay = DeviceSequenceReplay(
             capacity=capacity, seq_len=seq_total, frame_shape=(h, w),
-            lstm_size=cfg.lstm_size, lanes=lanes, stride=stride,
+            lstm_size=core.stored_width, lanes=lanes, stride=stride,
             priority_exponent=cfg.priority_exponent,
             priority_eps=cfg.priority_eps,
         )
@@ -376,7 +378,12 @@ def train_anakin_r2d2(cfg: Config,
         the shards' counters over a mesh); read only when a row is logged."""
         return float(np.mean(np.asarray(ss.emit_ticks)))
 
+    def nanmean(x) -> float:
+        x = np.asarray(x)
+        return float(np.nanmean(x)) if np.any(~np.isnan(x)) else float("nan")
+
     row_emit_ticks, row_frames = emit_ticks_of(ss), frames
+    state_bytes = state_bytes_per_lane(core)
 
     # --trace-dir: the capture's 'device_time' row names the segment's work
     # by scope from the compiled text (no compile: the program has run)
@@ -386,7 +393,8 @@ def train_anakin_r2d2(cfg: Config,
         while frames < total_frames:
             key, k = jax.random.split(key)
             with obs_run.span("segment", ticks=T):
-                carry, (out_ret, loss, q_mean, grad_norm) = segment(carry, k)
+                carry, (out_ret, loss, q_mean, grad_norm, *counters) = segment(
+                    carry, k)
                 ts, ss = carry[0], carry[1]
                 frames += T * lanes
                 prev_steps = learn_steps
@@ -397,7 +405,6 @@ def train_anakin_r2d2(cfg: Config,
                 returns.append(float(r))
 
             if crossed(cfg.metrics_interval, prev_steps, learn_steps):
-                l = np.asarray(loss)
                 emit_ticks = emit_ticks_of(ss)
                 emit_share = (emit_ticks - row_emit_ticks) / (
                     (frames - row_frames) // lanes)
@@ -407,13 +414,14 @@ def train_anakin_r2d2(cfg: Config,
                     step=learn_steps,
                     frames=frames,
                     fps=metrics.fps(frames),
-                    loss=float(np.nanmean(l)) if np.any(~np.isnan(l)) else float("nan"),
-                    q_mean=float(np.nanmean(np.asarray(q_mean)))
-                    if np.any(~np.isnan(np.asarray(q_mean))) else float("nan"),
-                    grad_norm=float(np.nanmean(np.asarray(grad_norm)))
-                    if np.any(~np.isnan(np.asarray(grad_norm))) else float("nan"),
+                    loss=nanmean(loss),
+                    q_mean=nanmean(q_mean),
+                    grad_norm=nanmean(grad_norm),
                     mean_return=float(np.mean(returns)) if returns else float("nan"),
                     append_emit_tick_share=emit_share,
+                    core_state_bytes_per_lane=state_bytes,
+                    **{n: nanmean(v)
+                       for n, v in zip(core.stat_names, counters)},
                 )
                 obs_run.periodic(learn_steps, frames)
             if crossed(cfg.eval_interval, prev_steps, learn_steps):
@@ -455,9 +463,10 @@ def _train_anakin_r2d2_hostfed(cfg: Config,
     env = make_vector_env(cfg.env_id, lanes, seed=cfg.seed)
     h, w = env.frame_shape
     seq_total, stride, capacity, learn_start_seqs = _seq_geometry(cfg)
+    core = make_core(cfg)
     replay = DeviceSequenceReplay(
         capacity=capacity, seq_len=seq_total, frame_shape=(h, w),
-        lstm_size=cfg.lstm_size, lanes=lanes, stride=stride,
+        lstm_size=core.stored_width, lanes=lanes, stride=stride,
         priority_exponent=cfg.priority_exponent,
         priority_eps=cfg.priority_eps,
     )
@@ -475,10 +484,9 @@ def _train_anakin_r2d2_hostfed(cfg: Config,
         if prev is not None:
             ss = replay.append(ss, *prev)
         stack = shift_stack(stack, frame, keep)
-        kf = keep.astype(jnp.float32)[:, None]
-        c, h2 = lstm[0] * kf, lstm[1] * kf
-        pre = (c, h2)
-        a, _q, lstm = act_fn(params, stack, (c, h2), key)
+        lstm = zero_lanes(lstm, keep)
+        pre = core.to_stored(lstm)
+        a, _q, lstm = act_fn(params, stack, lstm, key)
         return a, stack, ss, lstm, pre
 
     learn = jax.jit(
@@ -502,9 +510,7 @@ def _train_anakin_r2d2_hostfed(cfg: Config,
     learn_steps = int(ts.step)
 
     stack = jnp.zeros((lanes, h, w, cfg.history_length), jnp.uint8)
-    z1 = jnp.zeros((lanes, cfg.lstm_size), jnp.float32)
-    z2 = jnp.zeros((lanes, cfg.lstm_size), jnp.float32)
-    lstm = (z1, z2)
+    lstm = core.initial_state(lanes)
     obs = env.reset()
     prev_cuts = np.zeros(lanes, bool)
     prev = None

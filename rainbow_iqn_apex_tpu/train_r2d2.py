@@ -19,6 +19,7 @@ import numpy as np
 from rainbow_iqn_apex_tpu.agents.agent import FrameStacker
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.envs import make_env, make_vector_env
+from rainbow_iqn_apex_tpu.models.cores import make_core, zero_lanes
 from rainbow_iqn_apex_tpu.obs import RunObs
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     as_actor_input,
@@ -45,6 +46,7 @@ class R2D2Agent:
     def __init__(self, cfg: Config, num_actions: int, frame_shape, key, train=True):
         self.cfg = cfg
         self.num_actions = num_actions
+        self.core = make_core(cfg)
         key, k_init = jax.random.split(key)
         self.key = key
         self.state = init_r2d2_state(cfg, num_actions, k_init, frame_shape)
@@ -63,8 +65,7 @@ class R2D2Agent:
         return k
 
     def initial_lstm_state(self, batch: int):
-        z = jnp.zeros((batch, self.cfg.lstm_size), jnp.float32)
-        return (z, z)
+        return self.core.initial_state(batch)
 
     def act(self, obs, lstm_state, eval_mode=False):
         """obs [B, H, W] u8 (history 1) or [B, H, W, hist] stacked ->
@@ -87,9 +88,8 @@ class R2D2Agent:
 
 def _mask_reset(lstm_state, terminals: np.ndarray):
     """Zero the (c, h) rows of lanes whose episode just ended."""
-    keep = jnp.asarray(1.0 - terminals.astype(np.float32))[:, None]
-    c, h = lstm_state
-    return (c * keep, h * keep)
+    return zero_lanes(
+        lstm_state, jnp.asarray(1.0 - np.asarray(terminals, np.float32)))
 
 
 def evaluate_r2d2(cfg: Config, agent: R2D2Agent, episodes: Optional[int] = None,
@@ -143,7 +143,7 @@ def train_r2d2(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, Any]:
         capacity=max(cfg.memory_capacity // seq_total, 64),
         seq_len=seq_total,
         frame_shape=env.frame_shape,
-        lstm_size=cfg.lstm_size,
+        lstm_size=agent.core.stored_width,
         lanes=lanes,
         stride=max(seq_total - cfg.r2d2_overlap, 1),
         priority_exponent=cfg.priority_exponent,
@@ -173,7 +173,8 @@ def train_r2d2(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, Any]:
 
     try:
         while frames < total_frames:
-            state_c, state_h = np.asarray(lstm_state[0]), np.asarray(lstm_state[1])
+            state_c, state_h = (
+                np.asarray(x) for x in agent.core.to_stored(lstm_state))
             stacked = stacker.push(obs)  # actor sees the frame-stacked input
             with obs_run.span("act"):
                 actions, lstm_state = agent.act(stacked, lstm_state)

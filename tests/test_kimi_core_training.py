@@ -1,0 +1,199 @@
+"""The R2D2 agent with the Kimi-Linear core (`Config.core_config`) through
+the normal paths: the learn step against the plain reference, the fused
+segment, the host-fed loops and the CLI, at tiny widths (the trunk's 2,304
+features at 80x80 frames are the core's hidden size, as in the published
+configuration)."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from rainbow_iqn_apex_tpu.config import Config
+from rainbow_iqn_apex_tpu.models.cores import (
+    LSTMCore,
+    make_core,
+    state_bytes_per_lane,
+)
+from rainbow_iqn_apex_tpu.ops.r2d2 import (
+    SequenceBatch,
+    build_r2d2_act_step,
+    build_r2d2_learn_step,
+    init_r2d2_state,
+)
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+TINY = os.path.join(HERE, "fixtures", "kimi_core_tiny.json")
+
+
+def _cfg(tmp_path, **kw):
+    base = dict(
+        env_id="jaxgame:freeway", architecture="r2d2", role="anakin",
+        core_config=TINY, compute_dtype="float32", history_length=2,
+        hidden_size=32, r2d2_burn_in=4, r2d2_seq_len=8, r2d2_overlap=4,
+        batch_size=4, learning_rate=1e-3, multi_step=2, gamma=0.9,
+        memory_capacity=12 * 40, learn_start=12 * 8, frames_per_learn=2,
+        target_update_period=100, num_envs_per_actor=4,
+        anakin_segment_ticks=8, learner_devices=1, metrics_interval=1,
+        eval_interval=0, checkpoint_interval=0, eval_episodes=2,
+        max_grad_norm=1e6,
+        results_dir=str(tmp_path / "results"),
+        checkpoint_dir=str(tmp_path / "ckpt"), seed=3,
+    )
+    base.update(kw)
+    return Config(**base)
+
+
+def _rows(cfg):
+    path = os.path.join(cfg.results_dir, cfg.run_id, "metrics.jsonl")
+    return [json.loads(line) for line in open(path)]
+
+
+def test_the_core_comes_from_the_config(tmp_path):
+    cfg = _cfg(tmp_path)
+    core = make_core(cfg)
+    assert core.stored_width == 0 and core.kc.hidden == 2304
+    assert make_core(cfg.replace(core_config="")) == LSTMCore(cfg.lstm_size)
+    # 4 KDA layers of S [2, 8, 8] + tails [3, 48], one MLA window [12, 20+1]
+    assert state_bytes_per_lane(core) == 4 * (4 * (128 + 144)) + 4 * 12 * 21
+    published = make_core(cfg.replace(
+        core_config="configs/cores/kimi_linear_48b_a3b.json"))
+    kda = 32 * 128 * 128 + 3 * 3 * 4096
+    assert state_bytes_per_lane(published) == 4 * (4 * kda + 120 * 577)
+
+
+def test_learn_step_loss_and_gradient_match_the_reference(tmp_path):
+    from benchmarks.references import r2d2_kimi
+
+    cfg = _cfg(tmp_path, history_length=4, batch_size=2)
+    with open(TINY) as f:
+        cc = json.load(f)
+    hp = {k: getattr(cfg, k) for k in (
+        "r2d2_burn_in", "multi_step", "gamma", "r2d2_eta",
+        "value_rescale_eps", "history_length")}
+    b, length, actions = 2, cfg.r2d2_burn_in + cfg.r2d2_seq_len, 3
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    frames = jax.random.bits(ks[0], (b, length, 80, 80), jnp.uint8)
+    done = np.zeros((b, length), bool)
+    done[0, 2], done[1, 7] = True, True  # cuts in the burn-in and after it
+    batch = {
+        "frames": frames,
+        "action": jax.random.randint(ks[1], (b, length), 0, actions),
+        "reward": jax.random.normal(ks[2], (b, length)),
+        "done": jnp.asarray(done),
+        "valid": jnp.ones((b, length), bool),
+        "weight": jnp.asarray([1.0, 0.5]),
+    }
+    ts = init_r2d2_state(cfg, actions, ks[3], (80, 80))
+    ts = ts.replace(target_params=init_r2d2_state(
+        cfg, actions, ks[4], (80, 80)).params)
+    zero = jnp.zeros((b, 0), jnp.float32)
+    seq = SequenceBatch(
+        obs=frames[..., None], action=batch["action"],
+        reward=batch["reward"], done=batch["done"], valid=batch["valid"],
+        init_c=zero, init_h=zero, weight=batch["weight"])
+    new, info = jax.jit(build_r2d2_learn_step(cfg, actions))(ts, seq, ks[5])
+    (loss, _), grads = jax.value_and_grad(r2d2_kimi.loss_fn, has_aux=True)(
+        ts.params, ts.target_params, batch, ks[5], hp, cc)
+    assert float(info["loss"]) == pytest.approx(float(loss), rel=1e-4)
+    assert float(info["moe_tokens_dropped"]) == 0.0
+    mu = [s for s in jax.tree.leaves(
+        new.opt_state, is_leaf=lambda x: hasattr(x, "mu"))
+        if hasattr(s, "mu")][0].mu
+    for (path, m), g in zip(jax.tree_util.tree_leaves_with_path(mu),
+                            jax.tree.leaves(grads)):
+        got, want = np.asarray(m) / 0.1, np.asarray(g)
+        assert np.abs(got - want).max() <= 2e-3 * max(
+            np.abs(want).max(), 1e-6), jax.tree_util.keystr(path)
+
+
+def test_fused_segment_trains_with_the_core(tmp_path):
+    from rainbow_iqn_apex_tpu.train_anakin_r2d2 import train_anakin_r2d2
+
+    cfg = _cfg(tmp_path)
+    summary = train_anakin_r2d2(cfg, max_frames=4 * 8 * 12)
+    assert summary["learn_steps"] > 4
+    learn = [r for r in _rows(cfg) if r["kind"] == "learn"]
+    assert all(np.isfinite(r["loss"]) for r in learn)
+    assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
+    assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
+    assert all(r["moe_expert_load_max_over_mean"] >= 1.0 for r in learn)
+    assert learn[0]["core_state_bytes_per_lane"] == state_bytes_per_lane(
+        make_core(cfg))
+
+
+@pytest.mark.parametrize("role,learners", [
+    ("anakin", 1), ("single", 1), ("apex", 4)])
+def test_host_fed_roles_train_with_the_core(tmp_path, role, learners):
+    """The host-fed anakin loop, `train_r2d2` and the apex R2D2 driver carry
+    the core's state pytree per lane and a ring without stored state."""
+    import train_agent_apex
+
+    rc = train_agent_apex.main([
+        "--role", role, "--architecture", "r2d2", "--env-id", "toy:catch",
+        "--core-config", TINY, "--compute-dtype", "float32",
+        "--history-length", "2", "--hidden-size", "32",
+        "--r2d2-burn-in", "2", "--r2d2-seq-len", "6", "--r2d2-overlap", "4",
+        "--batch-size", "4", "--multi-step", "2", "--memory-capacity", "800",
+        "--learn-start", "64", "--frames-per-learn", "2",
+        "--num-envs-per-actor", "4", "--anakin-segment-ticks", "8",
+        "--learner-devices", str(learners), "--eval-episodes", "1",
+        "--eval-interval", "0", "--checkpoint-interval", "0",
+        "--metrics-interval", "1", "--t-max", "160", "--run-id", role,
+        "--results-dir", str(tmp_path / "results"),
+        "--checkpoint-dir", str(tmp_path / "ckpt"),
+    ])
+    assert rc == 0
+    rows = [json.loads(line) for line in open(
+        tmp_path / "results" / role / "metrics.jsonl")]
+    # a row logged before its step's loss came back carries null
+    losses = [r["loss"] for r in rows
+              if r["kind"] == "learn" and r["loss"] is not None]
+    assert losses and all(np.isfinite(x) for x in losses)
+
+
+def test_cli_runs_the_fused_trainer_with_core_config(tmp_path):
+    import train_agent_apex
+
+    rc = train_agent_apex.main([
+        "--role", "anakin", "--architecture", "r2d2",
+        "--env-id", "jaxgame:freeway", "--core-config", TINY,
+        "--compute-dtype", "float32", "--history-length", "2",
+        "--hidden-size", "32", "--r2d2-burn-in", "4", "--r2d2-seq-len", "8",
+        "--r2d2-overlap", "4", "--batch-size", "4", "--multi-step", "2",
+        "--memory-capacity", "480", "--learn-start", "96",
+        "--frames-per-learn", "2", "--num-envs-per-actor", "4",
+        "--anakin-segment-ticks", "8", "--learner-devices", "1",
+        "--eval-episodes", "1", "--eval-interval", "0",
+        "--checkpoint-interval", "0", "--metrics-interval", "1",
+        "--t-max", "320", "--run-id", "cli",
+        "--results-dir", str(tmp_path / "results"),
+        "--checkpoint-dir", str(tmp_path / "ckpt"),
+    ])
+    assert rc == 0
+    rows = [json.loads(line) for line in open(
+        tmp_path / "results" / "cli" / "metrics.jsonl")]
+    learn = [r for r in rows if r["kind"] == "learn"]
+    assert learn and all(r["moe_tokens_dropped"] == 0.0 for r in learn)
+
+
+def test_act_step_carries_the_state_and_a_cut_resets_it(tmp_path):
+    from rainbow_iqn_apex_tpu.models.cores import zero_lanes
+
+    cfg = _cfg(tmp_path)
+    core = make_core(cfg)
+    ts = init_r2d2_state(cfg, 3, jax.random.PRNGKey(1), (80, 80))
+    act = jax.jit(build_r2d2_act_step(cfg, 3, use_noise=False))
+    obs = jax.random.bits(jax.random.PRNGKey(2), (2, 80, 80, 2), jnp.uint8)
+    state = core.initial_state(2)
+    _, q0, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
+    _, q1, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
+    assert np.abs(np.asarray(q1 - q0)).max() > 0  # the state matters
+    state = zero_lanes(state, jnp.asarray([0, 1], jnp.uint8))
+    _, q2, _ = act(ts.params, obs, state, jax.random.PRNGKey(3))
+    np.testing.assert_allclose(np.asarray(q2[0]), np.asarray(q0[0]),
+                               rtol=1e-5, atol=1e-6)
+    assert np.abs(np.asarray(q2[1] - q0[1])).max() > 0
