@@ -12,7 +12,9 @@ Per-lane state, all float32, zero = initial (models/cores.zero_lanes):
 
 Sequences run the KDA recurrence chunked (`kda_chunked`: WY form, the
 in-chunk decay products taken relative to sub-block starts so that no
-exponent is positive); one step (`T == 1`, the actor) runs it as written.
+exponent is positive; on a TPU the in-chunk preparation is a tile kernel,
+models/kda_tile.py, of which `_prep_plain` is the definition); one step
+(`T == 1`, the actor) runs it as written.
 An episode cut inside a sequence is a segment boundary: steps interact only
 within a segment, in the chunk, the convolutions and the attention mask.
 
@@ -30,13 +32,16 @@ The plain reference is tests/reference_kimi_linear_core.py.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
+from rainbow_iqn_apex_tpu.models import kda_tile
 from rainbow_iqn_apex_tpu.models.cores import CORE_STATS as STATS
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
@@ -187,21 +192,16 @@ def _decay_pairs(x, y, g_cum, block: int, dtype):
     return off + diag.reshape(*lead, c, c)
 
 
-def kda_chunked(q, k, v, g, beta, seg, s0, chunk: int, block: int, dtype):
-    """The KDA recurrence over a sequence, chunk by chunk.
+def _prep_plain(q, k, v, g, beta, seg, c: int, block: int, dtype):
+    """The in-chunk preparation, as written: what the chunk scan reads.
 
-    q, k [B, T, H, dk] (l2-normalised), v [B, T, H, dv], g [B, T, H, dk] the
-    per-step log decay (<= 0), beta [B, T, H], seg [B, T] segment ids (0 =
-    the segment `s0` belongs to), s0 [B, H, dk, dv].
-    Returns (o [B, T, H, dv] before the 1/sqrt(dk), final state)."""
-    b, t, h, dk = q.shape
-    c = chunk if t >= chunk else -(-t // block) * block
-    pad = -t % c
-    if pad:
-        zp = lambda z: jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))  # noqa: E731
-        q, k, v, g, beta = zp(q), zp(k), zp(v), zp(g), zp(beta)
-        seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
-    n = (t + pad) // c
+    q, k, g [B, T, H, dk], v [B, T, H, dv], beta [B, T, H], seg [B, T], T a
+    multiple of the chunk c.  Returns, chunk-major, u [N, B, H, C, dv] and wk
+    [N, B, H, C, dk] (the WY factors: (1 + a) [u | wk] = beta [v | k decayed
+    from the chunk's start]), qg, a_qk [N, B, H, C, C], k_end (k decayed to
+    the chunk's end) and s_keep [N, B, H, dk] (the state's own decay)."""
+    b, t, h, _ = q.shape
+    n = t // c
     # [B, N, H, C, .]
     ch = lambda z: jnp.swapaxes(z.reshape(b, n, c, h, -1), 2, 3)  # noqa: E731
     q, k, v, g = ch(q), ch(k), ch(v), ch(g)
@@ -227,6 +227,67 @@ def kda_chunked(q, k, v, g, beta, seg, s0, chunk: int, block: int, dtype):
     k_end = k * jnp.exp(g_last - g_cum) * (
         seg == seg[..., -1:]).astype(jnp.float32)[..., None]
     s_keep = jnp.exp(g_last[..., 0, :]) * m0[..., -1:]  # [B, N, H, dk]
+    # the scan multiplies all but u and s_keep on `dtype` operands
+    return tuple(jnp.moveaxis(z, 1, 0).astype(dt) for z, dt in (
+        (u, jnp.float32), (wk, dtype), (qg, dtype), (a_qk, dtype),
+        (k_end, dtype), (s_keep, jnp.float32)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _prep_fused(q, k, v, g, beta, seg, c, block, dtype):
+    """`_prep_plain` by the tile kernel (models/kda_tile.py)."""
+    return kda_tile.prepare(q, k, v, g, beta, seg, c, block, dtype)
+
+
+def _prep_fused_fwd(q, k, v, g, beta, seg, c, block, dtype):
+    return (kda_tile.prepare(q, k, v, g, beta, seg, c, block, dtype),
+            (q, k, v, g, beta, seg))
+
+
+def _prep_fused_bwd(c, block, dtype, saved, cot):
+    seg = saved[-1]
+    return (*kda_tile.prepare_vjp(*saved, cot, c, block, dtype),
+            np.zeros(seg.shape, jax.dtypes.float0))
+
+
+_prep_fused.defvjp(_prep_fused_fwd, _prep_fused_bwd)
+
+
+def _chunk_len(t: int, chunk: int, block: int) -> int:
+    """A sequence of t steps runs in chunks of this many: `chunk`, or a
+    short sequence's whole sub-blocks."""
+    return chunk if t >= chunk else -(-t // block) * block
+
+
+def kda_prep_fused(dk: int, dv: int, chunk: int, block: int) -> bool:
+    """Whether a sequence's preparation runs as the tile kernel: on a TPU,
+    with no mesh that could split the batch (a `pallas_call` has no
+    partitioning rule), at shapes the kernel takes.  Otherwise the plain
+    path, which is the definition."""
+    return (jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1
+            and kda_tile.takes(dk, dv, chunk, block))
+
+
+def kda_chunked(q, k, v, g, beta, seg, s0, chunk: int, block: int, dtype):
+    """The KDA recurrence over a sequence, chunk by chunk.
+
+    q, k [B, T, H, dk] (l2-normalised), v [B, T, H, dv], g [B, T, H, dk] the
+    per-step log decay (<= 0), beta [B, T, H], seg [B, T] segment ids (0 =
+    the segment `s0` belongs to), s0 [B, H, dk, dv].
+    Returns (o [B, T, H, dv] before the 1/sqrt(dk), final state)."""
+    b, t, h, dk = q.shape
+    c = _chunk_len(t, chunk, block)
+    pad = -t % c
+    if pad:
+        zp = lambda z: jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))  # noqa: E731
+        q, k, v, g, beta = zp(q), zp(k), zp(v), zp(g), zp(beta)
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
+    n = (t + pad) // c
+    with jax.named_scope(device_scopes.KDA_PREP):
+        prep = (_prep_fused if kda_prep_fused(dk, v.shape[-1], c, block)
+                else _prep_plain)
+        xs = prep(q, k, v, g, beta, seg, c, block, dtype)
 
     def one(s, xs):
         u_n, wk_n, qg_n, aqk_n, kend_n, keep_n = xs
@@ -236,9 +297,7 @@ def kda_chunked(q, k, v, g, beta, seg, s0, chunk: int, block: int, dtype):
         s = keep_n[..., None] * s + _mm("bhck,bhcv->bhkv", kend_n, w, dtype)
         return s, o
 
-    mv = lambda z: jnp.moveaxis(z, 1, 0)  # noqa: E731
-    s, o = jax.lax.scan(
-        one, s0, (mv(u), mv(wk), mv(qg), mv(a_qk), mv(k_end), mv(s_keep)))
+    s, o = jax.lax.scan(one, s0, xs)
     o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3).reshape(b, n * c, h, -1)
     return o[:, :t], s
 
@@ -297,6 +356,8 @@ class _KDA(nn.Module):
             with jax.named_scope(device_scopes.KDA_SCAN):
                 o, s = kda_chunked(q, k, v, g, beta, seg, state["S"],
                                    kc.chunk, kc.block, cd)
+            self.sow(STATS, "kda_fused_tile_share", float(kda_prep_fused(
+                dk, dk, _chunk_len(t, kc.chunk, kc.block), kc.block)))
         o = _RMSNorm(kc.eps, name="o_norm")(o / math.sqrt(dk))
         gate = jax.nn.sigmoid(low("g_a", "g_b", d))
         y = _Linear(kc.hidden, cd, name="o_proj")(gate * o.reshape(b, t, d))
@@ -508,7 +569,7 @@ class KimiLinearCore:
 
     stored_width = 0  # zero start state: the ring stores no state
     stat_names = ("moe_expert_load_max_over_mean", "moe_held_assign_share",
-                  "moe_tokens_dropped")
+                  "moe_tokens_dropped", "kda_fused_tile_share")
 
     def initial_state(self, batch: int):
         kc, z = self.kc, lambda *s: jnp.zeros((batch, *s), jnp.float32)  # noqa: E731

@@ -54,6 +54,7 @@ LSTM_SCAN = "lstm_scan"  # the lax.scan over the LSTM cell (R2D2)
 # ---- the Kimi-Linear core (models/kimi_linear.py)
 CORE_LAYER = "core_layer"  # one pre-norm block: mixer + feed-forward
 KDA_SCAN = "kda_scan"  # the chunked delta-rule recurrence of a sequence
+KDA_PREP = "kda_prep"  # inside it: the in-chunk preparation (WY factors)
 MLA_ATTN = "mla_attn"  # scores, mask, softmax, values over the latent window
 MOE_ROUTE = "moe_route"  # router, top-k, the sort by held expert
 MOE_EXPERTS = "moe_experts"  # gather, the grouped products, scatter-add
@@ -67,7 +68,7 @@ TICK_SCOPES = (TICK_ACT, TICK_ENV, TICK_APPEND, TICK_LEARN)
 ALL_SCOPES = TICK_SCOPES + (
     REPLAY_DRAW, REPLAY_GATHER, REPLAY_WRITEBACK, LEARN_STEP, NET_TRUNK,
     LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
-    MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
+    KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 

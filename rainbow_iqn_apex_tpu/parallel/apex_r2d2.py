@@ -44,6 +44,7 @@ from rainbow_iqn_apex_tpu.parallel.mesh import (
     learner_mesh,
     replicated,
     split_devices,
+    traced_under,
 )
 from rainbow_iqn_apex_tpu.parallel.multihost import (
     global_is_nq,
@@ -115,7 +116,7 @@ class R2D2ApexDriver(QuantPublishMixin):
 
         self._batch_sh = batch_sharding(self.lmesh, "dp")
         self._learn = jax.jit(
-            build_r2d2_learn_step(cfg, num_actions),
+            traced_under(self.lmesh, build_r2d2_learn_step(cfg, num_actions)),
             in_shardings=(rep_l, self._batch_sh, rep_l),
             donate_argnums=0,
         )

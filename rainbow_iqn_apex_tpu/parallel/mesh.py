@@ -27,6 +27,7 @@ items; the jit/NamedSharding migration itself is long done.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -75,3 +76,16 @@ def batch_sharding(mesh: Mesh, axis: str = "dp") -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+def traced_under(mesh: Mesh, fn):
+    """`fn`, traced with `mesh` as the context's abstract mesh: code inside
+    can then see (`jax.sharding.get_abstract_mesh()`) that its arrays may be
+    split over devices, which a jitted function's tracers do not say.  The
+    axes stay `Auto`: partitioning is the compiler's, as without it."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return wrapped

@@ -435,6 +435,7 @@ def build_device_r2d2_learn_sharded(cfg, num_actions: int,
     weights are pmax-normalised across shards.  The gradient all-reduce
     stays GSPMD-inserted from the batch sharding."""
     from rainbow_iqn_apex_tpu.ops.r2d2 import SequenceBatch, build_r2d2_learn_step
+    from rainbow_iqn_apex_tpu.parallel.mesh import traced_under
 
     P = jax.sharding.PartitionSpec
     n_dev = mesh.shape[axis]
@@ -444,7 +445,8 @@ def build_device_r2d2_learn_sharded(cfg, num_actions: int,
         )
     b_loc = cfg.batch_size // n_dev
     groups = getattr(cfg, "sample_groups", 1)
-    learn_step = build_r2d2_learn_step(cfg, num_actions)
+    # the batch is split over `axis`: the model sees the mesh as it is traced
+    learn_step = traced_under(mesh, build_r2d2_learn_step(cfg, num_actions))
     state_spec = device_seq_specs(axis)
     batch_spec = SequenceBatch(
         obs=P(axis), action=P(axis), reward=P(axis), done=P(axis),

@@ -1,0 +1,195 @@
+"""The tile kernel of the KDA chunk's preparation (models/kda_tile.py) against
+the plain `kda_chunked`, which is its definition: under `interpret=True` on
+the CPU at small batch and head counts that keep d 128, chunk 40 and block 8;
+the choice between the two paths; where a device trace files each; and the
+kernels compiled at the published widths for a described v5e."""
+
+import functools
+import json
+import os
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from rainbow_iqn_apex_tpu.models import kda_tile
+from rainbow_iqn_apex_tpu.models import kimi_linear as kl
+from rainbow_iqn_apex_tpu.models.cores import CORE_STATS, reduce_stats
+from rainbow_iqn_apex_tpu.obs import device_scopes
+
+B, H, D, CHUNK, BLOCK = 2, 2, 128, 40, 8
+
+
+def inputs(steps, cuts, s0_scale, d=D, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed + steps), 8)
+    unit = lambda z: z / jnp.linalg.norm(z, axis=-1, keepdims=True)  # noqa: E731
+    shape = (B, steps, H, d)
+    q, k = unit(jax.random.normal(ks[0], shape)), unit(
+        jax.random.normal(ks[1], shape))
+    v = jax.random.normal(ks[2], shape)
+    # a head's decay rate spans A_log's range; some steps' sums tie in float32
+    g = -jnp.exp(jax.random.uniform(ks[3], (B, steps, H, 1), minval=-6.0,
+                                    maxval=0.5)) * jax.random.uniform(ks[4], shape)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, steps, H)))
+    resets = np.zeros((B, steps), bool)
+    if cuts == "one":
+        resets[np.arange(B), [steps // 3, steps - 7]] = True
+    elif cuts == "random":
+        resets = np.asarray(jax.random.uniform(ks[6], (B, steps)) < 0.08)
+    seg = jnp.cumsum(jnp.asarray(resets, jnp.int32), axis=1)
+    s0 = s0_scale * jax.random.normal(ks[7], (B, H, d, d))
+    return q, k, v, g, beta, s0, seg
+
+
+def _loss(q, k, v, g, beta, s0, seg, chunk, block):
+    o, s = kl.kda_chunked(q, k, v, g, beta, seg, s0, chunk, block, jnp.float32)
+    w = jnp.cos(0.37 * jnp.arange(o.size, dtype=jnp.float32)).reshape(o.shape)
+    return (o * w).sum() + jnp.sin(s).sum(), (o, s)
+
+
+def _value_and_grads(*args, chunk=CHUNK, block=BLOCK):
+    return jax.value_and_grad(
+        functools.partial(_loss, chunk=chunk, block=block),
+        argnums=(0, 1, 2, 3, 4, 5), has_aux=True)(*args)
+
+
+plain = jax.jit(_value_and_grads)
+
+
+@jax.jit
+def fused(*args):
+    with mock.patch.object(kl, "kda_prep_fused", lambda *a: True):
+        return _value_and_grads(*args)
+
+
+def close(a, b, tol=2e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    scale = max(float(np.abs(b).max()), 1e-6)
+    assert float(np.abs(a - b).max()) <= tol * scale, (
+        float(np.abs(a - b).max()), scale)
+
+
+@pytest.mark.parametrize("s0_scale", [0.0, 0.3], ids=["zero_s0", "s0"])
+@pytest.mark.parametrize("cuts", ["lockstep", "one", "random"])
+@pytest.mark.parametrize("steps", [40, 80, 120, 100])
+def test_kernel_matches_the_plain_path(steps, cuts, s0_scale):
+    """Outputs, final state and the gradients with respect to q, k, v, g,
+    beta and s0; 100 steps are padded to 120."""
+    args = inputs(steps, cuts, s0_scale)
+    (_, (o, s)), grads = plain(*args)
+    with pltpu.force_tpu_interpret_mode():
+        (_, (o_k, s_k)), grads_k = fused(*args)
+    close(o_k, o)
+    close(s_k, s)
+    for got, want in zip(grads_k, grads):
+        close(got, want)
+
+
+@pytest.mark.parametrize("d,chunk,block", [(64, 40, 8), (128, 12, 4)])
+def test_shapes_the_kernel_does_not_take_fall_back(d, chunk, block):
+    """On a TPU at these shapes `kda_chunked` still runs the plain path (a
+    kernel call would not lower here) and gives the plain path's numbers."""
+    assert not kda_tile.takes(d, d, chunk, block)
+    args = inputs(24, "random", 0.3, d=d)
+    want = _value_and_grads(*args, chunk=chunk, block=block)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert kl.kda_prep_fused(D, D, CHUNK, BLOCK)
+        assert not kl.kda_prep_fused(d, d, chunk, block)
+        got = _value_and_grads(*args, chunk=chunk, block=block)
+    jax.tree.map(functools.partial(close, tol=1e-6), got, want)
+
+
+def test_the_path_is_chosen_by_platform_mesh_and_shape():
+    """CPU: plain, and the core counts no fused tile.  TPU: the kernel,
+    unless the function is traced under a mesh of several devices (the
+    `_sharded` builders and the apex learner trace their learn step so)."""
+    from rainbow_iqn_apex_tpu.parallel.mesh import traced_under
+
+    assert not kl.kda_prep_fused(D, D, CHUNK, BLOCK)
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "fixtures", "kimi_core_tiny.json")) as f:
+        kc = kl.KimiLinearConfig.from_dict({**json.load(f), "hidden_size": 32})
+    core = kl.KimiLinearCore(kc, jnp.float32)
+    x, resets = jnp.ones((2, 16, 32)), jnp.zeros((2, 16), bool)
+    stack = kl._Stack(kc, jnp.float32)
+    state = core.initial_state(2)
+    params = stack.init(jax.random.PRNGKey(0), x, state, resets)["params"]
+    _, sown = stack.apply({"params": params}, x, state, resets,
+                          mutable=[CORE_STATS])
+    assert float(reduce_stats(sown)["kda_fused_tile_share"]) == 0.0
+    assert "kda_fused_tile_share" in core.stat_names
+
+    chosen = lambda: kl.kda_prep_fused(D, D, CHUNK, BLOCK)  # noqa: E731
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert chosen()
+        several = Mesh(np.array(jax.devices()[:4]), ("dp",))
+        assert not traced_under(several, chosen)()
+        assert traced_under(Mesh(np.array(jax.devices()[:1]), ("dp",)),
+                            chosen)()
+
+
+def scan_in_scope(q, k, v, g, beta, s0, seg):
+    with jax.named_scope(device_scopes.KDA_SCAN):
+        return kl.kda_chunked(q, k, v, g, beta, seg, s0, CHUNK, BLOCK,
+                              jnp.bfloat16)
+
+
+def paths_of(text, opcode):
+    scopes = device_scopes.instruction_scopes(text)
+    return {scopes[line.split("=")[0].strip().lstrip("%").split()[-1]]
+            for line in text.splitlines() if f" {opcode}(" in line}
+
+
+def test_the_plain_preparation_is_filed_under_kda_prep():
+    text = jax.jit(scan_in_scope).lower(
+        *inputs(80, "one", 0.3)).compile().as_text()
+    scopes = device_scopes.instruction_scopes(text)
+    assert ("kda_scan", "kda_prep") in set(scopes.values())
+    assert paths_of(text, "while") == {("kda_scan",)}
+
+
+# ---------------------------------------------------------------------------
+# compiled for a described TPU v5e, no chip attached (nothing runs)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("steps", [80, 120])
+def test_kernels_compile_at_the_published_widths_and_carry_the_scope(
+        one_chip, steps):
+    """Forward and backward kernels lower for the chip at H 32, d 128, chunk
+    40 (Mosaic refuses what interpret mode lets through: a slice of a mask, a
+    misaligned block), and `instruction_scopes` files both custom calls under
+    kda_scan/kda_prep, where `kda_scan_device_ms` and the `device_time` row
+    find them."""
+    b, h = 2, 32
+    shaped = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    args = (*(shaped(b, steps, h, D) for _ in range(4)), shaped(b, steps, h),
+            shaped(b, h, D, D), shaped(b, steps, dt=jnp.int32))
+
+    def grads(*a):
+        return jax.grad(lambda *z: sum(
+            (y.astype(jnp.float32) ** 2).sum() for y in scan_in_scope(*z)),
+            argnums=(0, 1, 2, 3, 4, 5))(*a)
+
+    with mock.patch.object(kl, "kda_prep_fused", lambda *a: True):
+        text = jax.jit(grads).lower(*args).compile().as_text()
+    scopes = device_scopes.instruction_scopes(text)
+    kernels = {name: path for name, path in scopes.items()
+               if name.startswith("kda_tile")}
+    assert {n.split(".")[0] for n in kernels} == {"kda_tile", "kda_tile_vjp"}
+    assert set(kernels.values()) == {("kda_scan", "kda_prep")}, kernels
