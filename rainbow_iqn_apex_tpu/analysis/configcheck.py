@@ -19,6 +19,13 @@ Four drift classes this repo has paid for by hand:
 4. **doc refs** (``doc-drift``): a backticked ``cfg.<name>`` in docs/*.md
    must name a real Config field — the PR-8 "pmap-era" stale-doc incident
    class as a test failure.
+5. **fields nothing uses**: the converse of 1.  Every ``Config`` field must
+   be named by some Python file other than config.py — the package,
+   scripts/, benchmarks/, tests/ or a root entry point — as an attribute
+   read (``x.field``, whatever ``x`` is called: the config travels as
+   ``cfg``, ``c``, ``args``, ``self._cfg``), a ``getattr`` name or a
+   keyword argument.  By name only, so it proves a field dead, never
+   alive: a field that only a test asserts the default of still passes.
 
 Suppression: ``# drift-ok: <reason>`` (code) / ``<!-- drift-ok: reason -->``
 on the same line (docs).
@@ -75,7 +82,6 @@ DEFAULT_OFF: Dict[str, object] = {
     "replay_net_port": 0,
     "replay_net_advertise": "",
     "replay_net_remote": False,
-    "mesh_shape": "",
     "coordinator_address": "",
     "snapshot_replay": False,
     "resume": "",
@@ -290,6 +296,78 @@ def check_repo(
             )
     findings.extend(apply_pragmas(cfg_module, off_findings))
     return findings
+
+
+# where a field's user may live, besides the root's entry points
+_USE_SUBDIRS = ("rainbow_iqn_apex_tpu", "scripts", "benchmarks", "tests")
+
+
+def _names_used(tree: ast.AST) -> Set[str]:
+    """Every name a file reads as an attribute, hands to ``getattr`` /
+    ``hasattr`` as a literal, or passes as a keyword argument."""
+    used: Set[str] = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Attribute) and isinstance(node.ctx, ast.Load):
+            used.add(node.attr)
+        elif isinstance(node, ast.Call):
+            used.update(k.arg for k in node.keywords if k.arg)
+            if (
+                isinstance(node.func, ast.Name)
+                and node.func.id in ("getattr", "hasattr")
+                and len(node.args) >= 2
+                and isinstance(node.args[1], ast.Constant)
+                and isinstance(node.args[1].value, str)
+            ):
+                used.add(node.args[1].value)
+    return used
+
+
+def check_field_use(
+    repo_root: str,
+    modules: Sequence[SourceModule] = (),
+    config_path: str = CONFIG_PATH,
+) -> List[Finding]:
+    """A ``Config`` field no Python file besides config.py names (class 5
+    of the module docstring): it is a CLI flag that does nothing.
+    ``modules`` are files the caller has parsed already."""
+    cfg_module = SourceModule(os.path.join(repo_root, config_path), repo_root)
+    fields = {
+        item.target.id: item.lineno
+        for node in cfg_module.tree.body
+        if isinstance(node, ast.ClassDef) and node.name == "Config"
+        for item in node.body
+        if isinstance(item, ast.AnnAssign) and isinstance(item.target, ast.Name)
+    }
+    parsed = {m.abspath: m.tree for m in modules}
+    used: Set[str] = set()
+    for path in iter_package_files(
+        repo_root,
+        subdirs=_USE_SUBDIRS,
+        extra=[n for n in os.listdir(repo_root) if n.endswith(".py")],
+    ):
+        path = os.path.abspath(path)
+        if path == cfg_module.abspath:
+            continue
+        tree = parsed.get(path)
+        if tree is None:
+            with open(path, encoding="utf-8") as fh:
+                tree = ast.parse(fh.read(), filename=path)
+        used |= _names_used(tree)
+    findings = [
+        Finding(
+            analyzer=ANALYZER,
+            path=config_path,
+            line=lineno,
+            key=f"{ANALYZER}:{config_path}:unused.{field}",
+            message=(
+                f"Config.{field} is read by nothing: no module, script, "
+                f"benchmark file, test or entry point names it"
+            ),
+        )
+        for field, lineno in sorted(fields.items())
+        if field not in used
+    ]
+    return apply_pragmas(cfg_module, findings)
 
 
 def check_docs(

@@ -79,6 +79,7 @@ def run_all(
         findings.extend(imports.check_repo(repo_root))
     if configcheck.ANALYZER in wanted:
         findings.extend(configcheck.check_repo(repo_root, modules=modules))
+        findings.extend(configcheck.check_field_use(repo_root, modules))
     if configcheck.DOC_ANALYZER in wanted:
         findings.extend(configcheck.check_docs(repo_root))
     if wirecheck.ANALYZER in wanted:

@@ -279,7 +279,6 @@ class Config:
     actor_id: int = 0
     num_envs_per_actor: int = 16  # batched vector-env width per actor loop
     weight_publish_interval: int = 400  # learner steps between weight publishes
-    weight_poll_interval: int = 400  # actor frames between weight pulls
     device_frame_stack: bool = True  # apex actors: keep the frame stack on
     # device (ship one [L,H,W] frame/tick, shift+reset inside the jitted act
     # step) instead of host-side FrameStacker shifting — 4x less transfer
@@ -296,7 +295,6 @@ class Config:
     initial_priority_from_actor: bool = True  # Ape-X: actors compute initial TD
 
     # ---- device mesh / sharding (TPU-native; replaces Redis TCP, SURVEY §5) -------
-    mesh_shape: str = ""  # e.g. "dp=8" or "dp=4,actor=4"; "" = all devices dp
     learner_devices: int = 0  # 0 = all devices are learner devices
     bf16_weight_sync: bool = True  # cast params to bf16 for the actor broadcast
     # ---- multi-host (jax.distributed over DCN; replaces remote Redis actors) ------
@@ -334,7 +332,8 @@ class Config:
     # full base snapshot (bf16 under ml_dtypes, else fp32) plus int8
     # per-tensor-scaled deltas against the last reconstruction —
     # subscribers rebuild bit-exact; >=3x fewer bytes/publish than fp32
-    # full (gated in `make perf-smoke`).  "off" = today's full publishes.
+    # full (tests/test_quantize.py::test_delta_bytes_beat_fp32_3x).  "off" =
+    # today's full publishes.
     publish_base_interval: int = 10  # publishes between full base snapshots
     # (the delta chain a late joiner replays is at most this long)
 
@@ -409,8 +408,6 @@ class Config:
     # shard server owns — multitask pins game-major shard blocks to servers
     # by spacing bases (shards-per-game apart), the multi-host multi-game
     # composition
-    replay_net_shard_count: int = 0  # shards this server owns; 0 = all
-    # `replay_shards` (the single-server topology)
     replay_net_ring_depth: int = 2  # server-side sample-ahead: pre-assembled,
     # pre-ENCODED batches kept per connected sampler so `sample` answers
     # from the event loop instead of queueing behind appends; 0 disables

@@ -56,7 +56,7 @@ class ObsRelay:
     Construct via ``from_config`` (None when ``cfg.obs_net`` is off — the
     house default-off seam), then ``logger.add_observer(relay.observe)``.
     ``attach`` does both.  Direct ``collector_addr`` bypasses lease
-    discovery (tests/bench)."""
+    discovery (tests)."""
 
     def __init__(
         self,

@@ -22,8 +22,8 @@ Two strictly separated cost tiers:
   the off-mode trajectories).
 * **span emission** is SAMPLED 1-in-N (``Config.trace_sample_every``;
   0 = off, the default): only every Nth unit of work emits ``span_link``
-  rows, so the learn-loop overhead stays within the <=3% bench gate
-  (the ``trace_overhead`` bench row) while flows remain reconstructible.
+  rows, which bounds what tracing adds to the learn loop while flows
+  remain reconstructible.
 
 Trace ids are deterministic strings ``"<kind><host>-<unit>"`` (e.g.
 ``"a0-512"`` = host 0's append tick 512, ``"l0-40"`` = learn step 40,

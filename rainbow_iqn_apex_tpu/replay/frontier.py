@@ -4,10 +4,9 @@ mirrored into HBM, with one fused XLA draw kernel over it.
 Why this exists (ISSUE 6; ROADMAP "in-network experience sampling",
 arXiv:2110.13506): PR 5 made the learner's *write-back* side issue zero
 blocking transfers per step, but the *sample* side still walked host
-sum-trees and assembled batches in NumPy on every step — the flat
-0.17–0.36 learn_steps/s host_feed bench rows were sample-side-bound, and
-the PR 5 prefetch starvation gauges exist precisely to prove it.  This
-module moves the DRAW off the host path:
+sum-trees and assembled batches in NumPy on every step, on one host
+thread (the PR 5 prefetch starvation gauges show when the learner outruns
+it).  This module moves the DRAW off the host path:
 
 - ``DeviceSampleFrontier`` mirrors every shard's tree-space priority leaves
   into one device vector ``[num_shards * shard_capacity]`` and draws

@@ -28,10 +28,11 @@ utils/quantize.py): instead of handing every engine the full params tree,
 ``publish`` encodes one `WeightPacket` — a periodic full base snapshot plus
 int8 per-tensor deltas against the last reconstruction — and fans THAT out
 (`FleetEngine.adopt_packet`); at fleet scale the broadcast cost drops >=3x
-vs fp32 full publishes (the `weight_publish` bench row / `make perf-smoke`
-gate).  Packet application is bit-exact and versioned, so monotonicity,
-backward refusal and the staleness fence are untouched; late joiners and
-gap-hit engines are caught up by ``sync()`` replaying the chain-from-base.
+vs fp32 full publishes
+(tests/test_quantize.py::test_delta_bytes_beat_fp32_3x).  Packet
+application is bit-exact and versioned, so monotonicity, backward refusal
+and the staleness fence are untouched; late joiners and gap-hit engines
+are caught up by ``sync()`` replaying the chain-from-base.
 ``compression="off"`` (default) fans out the raw params object exactly as
 before.
 """

@@ -1,7 +1,7 @@
 """Where XLA's persistent compilation cache lives.
 
 Every entry point that compiles (train_agent_apex.main, test_agent.main,
-bench.py, PolicyServer start-up, chip_smoke.py) calls
+PolicyServer start-up, chip_smoke.py) calls
 ``enable_compile_cache()`` once before its first jit.  The directory is part
 of the cache key, so it must not move between runs: either the operator
 places it with ``JAX_COMPILATION_CACHE_DIR`` (JAX reads that variable itself

@@ -6,8 +6,8 @@ Why a seam at all: the learner loop's throughput floor is the device step
 time only while the host never blocks on a device value mid-loop
 (docs/PERFORMANCE.md sync-point inventory).  One reintroduced
 ``float(info["loss"])`` or ``int(state.step)`` silently re-serializes the
-whole pipeline — the exact regression BENCH_r01-r05 measured.  The seam
-makes that failure loud:
+whole pipeline — the regression this repo once shipped (a per-step
+``float(loss)`` in the learn loop).  The seam makes that failure loud:
 
 - ``to_host(x)`` / ``scalar(x)``: the sanctioned materialization calls
   (WritebackRing retirement, supervisor snapshots, cadence reads).  Inside a

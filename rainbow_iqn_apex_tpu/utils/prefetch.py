@@ -127,11 +127,11 @@ class SampleAheadPusher(BatchPrefetcher):
     bounded queue — the learner never initiates sampling, it only pops.
 
     Mechanics per worker turn: keep ``draw_ahead`` index BLOCKS (each
-    ``draw_block`` stratified batches in one fused dispatch — the dispatch
-    overhead amortisation the sample_path bench row measures) in flight on
-    device; materialize the oldest block on THIS thread (the guard flags
-    are thread-local, so the learner's ``forbid_host_sync()`` region is
-    untouched); then gather one batch per turn through ``assemble_fn``.
+    ``draw_block`` stratified batches in one fused dispatch, which
+    amortises the dispatch overhead) in flight on device; materialize the
+    oldest block on THIS thread (the guard flags are thread-local, so the
+    learner's ``forbid_host_sync()`` region is untouched); then gather one
+    batch per turn through ``assemble_fn``.
 
     Extra gauges on the shared registry (role ``prefetch``; surfaced in
     obs_report's ``pipeline:`` line):

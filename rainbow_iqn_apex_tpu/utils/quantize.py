@@ -269,7 +269,7 @@ class WeightPacket:
     leaves: Dict[str, Tuple[np.ndarray, Optional[np.ndarray]]]
 
     def nbytes(self) -> int:
-        """Logical wire bytes: payload + scales (the bench/row number)."""
+        """Logical wire bytes: payload + scales."""
         total = 0
         for data, scale in self.leaves.values():
             total += data.nbytes + (scale.nbytes if scale is not None else 0)

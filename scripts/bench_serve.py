@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Load-generator bench for the policy server (serving/): N synthetic client
 threads drive `PolicyServer.act` as fast as the server completes them, with a
-weight hot-swap fired mid-run, and the result is printed as JSON rows in the
-bench.py idiom (one object per line, flushed immediately, LAST line is the
-headline requests/sec).
+weight hot-swap fired mid-run, and the result is printed as JSON rows
+(one object per line, flushed immediately, LAST line is the headline
+requests/sec).
 
 What is measured: end-to-end serving throughput and latency through the real
 stack — bounded queue, deadline coalescing, bucket padding, lane-sharded
@@ -33,8 +33,7 @@ lint-clean run dir of route/scale/rollout/serve JSONL.
 every hop (serving/net/): engines behind `TransportServer`s, the router
 dispatching through `RemoteTransport`s, rollouts shipped as int8-delta
 packets over the wire with bit-exact adoption gated per engine.  Emits one
-``net_soak`` row (aggregate rps, p99, rollout bytes over the wire vs fp32)
-for the BENCH_r*.json trajectory.
+``net_soak`` row (aggregate rps, p99, rollout bytes over the wire vs fp32).
 
 ``--quant`` runs the fp32-vs-int8 serving comparison (`make quant-smoke`):
 the same fixed load through a fp32 engine and a quantized one
@@ -604,7 +603,7 @@ def fleet_soak(args) -> int:
     if net_capture is not None:
         # wire weight-rollout economics: bytes the int8-delta packets
         # actually shipped vs what fp32-full would have — the QuaRL/PR-8
-        # ratio measured ACROSS a socket, for the BENCH_r*.json trajectory
+        # ratio measured ACROSS a socket
         from rainbow_iqn_apex_tpu.utils.quantize import tree_bytes
 
         fp32_total = tree_bytes(state.params) * net_capture["publishes"]

@@ -65,8 +65,7 @@ detection cadence), mailbox weight versions are strictly monotone across
 the takeover, zero stale adoptions (every adoption digest-checked against
 the publisher's own reconstruction), the successor's post-takeover state
 is bitwise equal to a plain kill->resume replay from the same checkpoint,
-and the whole run dir lints.  Emits one report-only ``failover_mttr``
-bench row (scripts/bench_diff.py REPORTED).
+and the whole run dir lints.  Emits one report-only ``failover_mttr`` row.
 """
 
 from __future__ import annotations
@@ -1138,8 +1137,7 @@ def failover_main(args) -> int:
     if lint_errors:
         failures.append(f"lint errors: {lint_errors[:5]}")
 
-    # report-only bench row (scripts/bench_diff.py REPORTED): MTTR is
-    # machine-weather, never gated on trajectory
+    # report-only row: MTTR is machine-weather, never gated
     bench = {
         "path": "failover_mttr",
         "metric": "failover_mttr_s",

@@ -28,9 +28,9 @@ emission side: every ``logger.log("<kind>", ...)`` literal in the package
 and scripts/ must name a registered kind, so registry and emitters move
 in the same commit.  Replay-reuse runs (cfg.replay_ratio > 1) extend
 ``learn``/``health``/``lag`` rows with optional payload keys under the
-same strict-JSON rules; the bench rows perf-smoke lints carry no ``kind``
-and skip schema validation by design.  The telemetry-plane soak
-(`make obsnet-smoke`) lints its run dir the same way: relay/collector
+same strict-JSON rules; rows that carry no ``kind`` (the load generator's,
+scripts/bench_serve.py) skip schema validation by design.  The
+telemetry-plane soak (`make obsnet-smoke`) lints its run dir the same way: relay/collector
 lifecycle ``obs_net`` rows, SLO-edge ``alert`` rows, and the collector's
 periodic ``fleet_health`` fold all validate through this one registry.
 """

@@ -5,7 +5,7 @@ import numpy as np
 
 
 def hot_learn(info):
-    loss = float(info["loss"])  # the classic BENCH_r01-r05 regression
+    loss = float(info["loss"])  # the classic regression: a per-step float(loss)
     pri = np.asarray(info["priorities"])  # device pull outside sanctioned()
     steps = info["steps"].item()  # scalar sync
     return loss, pri, steps

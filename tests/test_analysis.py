@@ -271,6 +271,12 @@ def test_default_off_families_hold():
         assert defaults[field] == configcheck.DEFAULT_OFF[field]
 
 
+def test_every_config_field_has_a_reader():
+    # a field nothing names is a CLI flag that does nothing
+    fs = configcheck.check_field_use(REPO)
+    assert fs == [], keys(fs)
+
+
 def test_doc_fixtures():
     pos = configcheck.check_docs(
         REPO, doc_paths=[f"{FIXTURES}/doc_positive.md"]

@@ -1,8 +1,8 @@
 """Pipeline tracing & lag attribution (ISSUE 9, obs/pipeline_trace.py):
 sampled causal spans, always-on lag metrics, the critical-path analyzer,
-the Perfetto exporter, RunHealth propagation-budget folding, the bench_diff
-regression gate, and a traced end-to-end apex run whose JSONL lints, exports
-and yields a critical_path verdict."""
+the Perfetto exporter, RunHealth propagation-budget folding, and a traced
+end-to-end apex run whose JSONL lints, exports and yields a critical_path
+verdict."""
 
 import json
 import os
@@ -431,69 +431,6 @@ def test_trace_export_no_spans_exits_1(tmp_path):
     with open(path, "w") as f:
         f.write(json.dumps({"kind": "learn", "step": 1}) + "\n")
     assert trace_export.main([path, "-o", str(tmp_path / "t.json")]) == 1
-
-
-# --------------------------------------------------------- bench_diff
-
-
-def _bench_row(path, **kw):
-    row = {"metric": f"{path}_metric", "value": 1.0, "unit": "u",
-           "vs_baseline": None, "path": path}
-    row.update(kw)
-    return row
-
-
-def test_bench_diff_gates_ratio_regressions(tmp_path):
-    import bench_diff
-
-    baseline = {
-        "n": 9, "cmd": "bench", "rc": 0,
-        "tail": "\n".join(json.dumps(r) for r in [
-            _bench_row("apex_loop", speedup_vs_depth0=1.5),
-            _bench_row("sample_path", speedup_vs_host=2.0),
-            _bench_row("weight_publish", ratio_vs_fp32=3.6),
-        ]),
-        "parsed": _bench_row("host_feed", value=0.3),
-    }
-    bpath = str(tmp_path / "BENCH_r09.json")
-    json.dump(baseline, open(bpath, "w"))
-
-    def current(**overrides):
-        rows = {
-            "apex_loop": _bench_row("apex_loop", speedup_vs_depth0=1.45),
-            "sample_path": _bench_row("sample_path", speedup_vs_host=1.9),
-            "weight_publish": _bench_row("weight_publish", ratio_vs_fp32=3.5),
-        }
-        rows.update(overrides)
-        p = str(tmp_path / "cur.jsonl")
-        with open(p, "w") as f:
-            for r in rows.values():
-                f.write(json.dumps(r) + "\n")
-        return p
-
-    # within 20%: ok
-    assert bench_diff.main([current(), "--baseline", bpath]) == 0
-    # a >20% regression on a gated ratio fails
-    bad = current(sample_path=_bench_row("sample_path",
-                                         speedup_vs_host=1.5))
-    assert bench_diff.main([bad, "--baseline", bpath]) == 1
-    # a timed-out row is skipped, not treated as zero
-    timed = current(sample_path=_bench_row("sample_path", status="timeout"))
-    assert bench_diff.main([timed, "--baseline", bpath]) == 0
-    # a row missing from the BASELINE is skipped (r05-era baselines)
-    old = {"n": 5, "tail": "", "parsed": _bench_row("host_feed", value=0.2)}
-    old_p = str(tmp_path / "BENCH_r05.json")
-    json.dump(old, open(old_p, "w"))
-    assert bench_diff.main([current(), "--baseline", old_p]) == 0
-
-
-def test_bench_diff_newest_baseline_selection(tmp_path):
-    import bench_diff
-
-    for n in (1, 5, 9):
-        json.dump({"tail": "", "parsed": {}},
-                  open(tmp_path / f"BENCH_r{n:02d}.json", "w"))
-    assert bench_diff.newest_baseline(str(tmp_path)).endswith("BENCH_r09.json")
 
 
 # -------------------------------------------- end-to-end traced apex run

@@ -148,8 +148,7 @@ class TestDeltaCodec:
 
     def test_delta_bytes_beat_fp32_3x(self):
         """The acceptance ratio at unit scale: >= 3x fewer bytes/publish
-        than fp32 full, amortized over a base interval (the same math the
-        weight_publish bench row gates in make perf-smoke)."""
+        than fp32 full, amortized over a base interval."""
         rng = np.random.default_rng(4)
         enc = Q.DeltaEncoder(base_interval=10)
         tree = _tree()

@@ -362,25 +362,3 @@ def test_prefetcher_exports_queue_gauges():
     finally:
         pf.close()
 
-
-# -------------------------------------------------------------- bench smoke
-def test_apex_loop_bench_micro(monkeypatch):
-    """The bench harness runs end to end at micro size and emits a
-    well-formed row (the >=25% speedup itself is asserted by `make
-    perf-smoke`, not tier-1 — a loaded CI box must not flake the suite)."""
-    import bench
-
-    monkeypatch.setenv("BENCH_AL_ITERS", "4")
-    monkeypatch.setenv("BENCH_AL_REPS", "1")
-    monkeypatch.setenv("BENCH_AL_MAX_REPS", "1")
-    monkeypatch.setenv("BENCH_AL_TICKS", "2")
-    monkeypatch.setenv("BENCH_AL_LANES", "8")
-    monkeypatch.setenv("BENCH_AL_ENV_US", "0")
-    rows = bench._measure_apex_loop()
-    assert len(rows) == 1
-    row = rows[0]
-    assert row["metric"] == "apex_loop_steps_per_sec"
-    assert row["path"] == "apex_loop"
-    assert row["value"] > 0 and row["depth0_steps_per_sec"] > 0
-    assert row["depth"] == Config().writeback_depth
-    assert row["n_iters"] == 4 and row["reps"] == 1
