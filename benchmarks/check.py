@@ -155,9 +155,15 @@ def compare(cand, ref, params0):
     return numbers
 
 
-def verdict(numbers, limits):
+def verdict(numbers, limits, read_only=()):
     """(correct, [(name, value, limit)]) — every number beside its limit; a
-    number without a limit, or a limit without its number, is not correct."""
+    number without a limit, or a limit without its number, is not correct.
+    `read_only` names the numbers a cell reads and does not compare (its
+    file's `read_not_compared`: no control and no fault gives them an upper
+    reading, so a limit could only fail sound runs); they may have no limit."""
+    if set(read_only) & set(limits):
+        raise ValueError("a number is compared or read only, not both")
+    numbers = {n: v for n, v in numbers.items() if n not in read_only}
     rows = [(n, numbers.get(n, float("nan")), limits.get(n, float("nan")))
             for n in sorted(set(numbers) | set(limits))]
     ok = all(np.isfinite(v) and np.isfinite(lim) and v <= lim

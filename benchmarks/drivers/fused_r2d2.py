@@ -9,12 +9,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks import check, ringfill, weights
+from benchmarks import check, ringfill, ringrows, weights
 from benchmarks.drivers.fused_base import FusedDriver
 from benchmarks.references import r2d2 as ref
 
-RING_ROWS = ("frames", "actions", "rewards", "dones", "valids",
-             "init_c", "init_h")
 
 
 class Driver(FusedDriver):
@@ -39,7 +37,7 @@ class Driver(FusedDriver):
         h, w = game.frame_shape
         seq_total, stride, capacity, gate = prog._seq_geometry(cfg)
         self.period, self.learns_per_tick = prog._learn_cadence(cfg)
-        self.capacity, self.seq_total = capacity, seq_total
+        self.capacity = capacity
         # the seed fills all but the last `lanes` rows; the lanes' own first
         # sequences fill those, which opens the trainer's gate
         self.seeded = capacity - self.lanes
@@ -70,13 +68,11 @@ class Driver(FusedDriver):
             """Weights, optimizer state, the seeded ring and the lanes, from
             the seed, on the device in one program."""
             ts = self.seeded_train_state(R2D2TrainState, shapes, k_init)
-            ss, n = replay.init_state(), self.seeded
+            n = self.seeded
             k_fill = jax.random.fold_in(k_init, 2)  # self.k_fill, traced
-            rows = ringfill.fill(
-                {name: getattr(ss, name) for name in RING_ROWS},
-                k_fill, n, game.num_actions)
+            ss = ringfill.fill(
+                replay, replay.init_state(), k_fill, n, game.num_actions)
             ss = ss._replace(
-                **rows,
                 priority=ss.priority.at[:n].set(
                     ringfill.priorities(k_fill, jnp.arange(n))),
                 pos=jnp.int32(n), filled=jnp.int32(n))
@@ -96,8 +92,8 @@ class Driver(FusedDriver):
         lanes appended (the window overwrites them), priorities, params."""
         ts, ss = self.carry[0], self.carry[1]
         n, c = self.seeded, self.capacity
-        self.snap = {name: np.asarray(getattr(ss, name)[n:c])
-                     for name in RING_ROWS}
+        self.snap = jax.tree.map(
+            np.asarray, ringrows.read_rows(self.replay, ss, n, c))
         self.snap["filled"] = int(ss.filled)
         self.snapshot_state(ts, ss.priority)
 
@@ -109,24 +105,30 @@ class Driver(FusedDriver):
             ringfill.priorities(self.k_fill, np.arange(n)), np.float64)
         return np.concatenate([seeded, np.ones(self.snap["filled"] - n)])
 
+    def drawn_rows(self, idx):
+        """Ring rows `idx` as the reference takes them: made again from the
+        seed, and a row the lanes appended from the host copy.  Both are
+        logical rows by construction."""
+        n, replay = self.seeded, self.replay
+        made = ringfill.rows(
+            self.k_fill, np.minimum(idx, n - 1), replay.L, replay.frame_shape,
+            replay.lstm_size, self.num_actions)
+        rows = {name: np.array(made[name]) for name in ringrows.FIELDS}
+        for name, row in rows.items():
+            row[idx >= n] = self.snap[name][idx[idx >= n] - n]
+        return rows
+
     def reference_side(self, mode=None, touched=None):
-        """The reference draws from the priorities above and makes the drawn
-        rows again from the seed; a draw that lands on a row the lanes
-        appended takes that row from the host copy."""
-        hp, snap, n = self.fields, self.snap, self.seeded
-        shape = (self.seq_total, self.replay.frame_shape, hp["lstm_size"],
-                 self.num_actions)
+        """The reference draws from the priorities above and takes the drawn
+        rows from `drawn_rows`, never from the program's ring."""
+        hp, snap = self.fields, self.snap
 
         def sample(priority, key, beta, touched):
             u01 = np.asarray(jax.random.uniform(key, (hp["batch_size"],)))
             idx, margin = ref.stratified_draw(priority, u01)
             idx = check.settle_edges(idx, margin, priority, touched)
             w = ref.is_weights(priority, idx, snap["filled"], beta)
-            made = ringfill.rows(self.k_fill, np.minimum(idx, n - 1), *shape)
-            rows = {name: np.array(made[name]) for name in RING_ROWS}
-            for name in RING_ROWS:
-                rows[name][idx >= n] = snap[name][idx[idx >= n] - n]
-            return ref.gather(rows, np.arange(len(idx)), w), idx
+            return ref.gather(self.drawn_rows(idx), np.arange(len(idx)), w), idx
 
         return check.follow(self.params0, self.target0, self.step_keys(),
                             sample, ref.loss_fn,

@@ -27,12 +27,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks import check, harness, ringfill, weights_core
+from benchmarks import check, harness, weights_core
 from benchmarks.drivers import fused_r2d2
 from benchmarks.drivers.fused_base import ADAM_B1
 from benchmarks.references import nets, r2d2 as ref, r2d2_kimi
 
 REF_BLOCK = 1  # sequences a block of the reference's first step
+# The target network is the online one with its value head's bias this much
+# higher, as after a target update in a run whose returns are rising: every
+# seed's first TD errors then have the same mean, about 0.7, and the first
+# gradient the same size.  With a target drawn apart (as the LSTM cell's
+# driver does) the mean TD error is the difference of two nets' mean values,
+# a draw round zero of its own on every seed, the gradient's size goes with
+# it, and what `correct` reads against that gradient went by the seed's draw:
+# 1.2e-4 to 1.0e-2 over 16 seeds, and a sound run failed (PERF.md, section 6).
+TARGET_AHEAD = 1.0
 # `check.compare` widens every tree it is given to float64, twice over: four
 # trees of 513M numbers are 30 GiB of a 40 GiB host (more after a cold
 # compile).  A leaf larger than THIN_OVER elements is therefore compared on
@@ -90,7 +99,9 @@ class Driver(fused_r2d2.Driver):
             top_k=self.core_cc["num_experts_per_token"],
             first_expert=self.core_cc.get("first_expert_here", 0))
         params = make(shapes, k_init)
-        target = make(shapes, jax.random.fold_in(k_init, 1))
+        ahead = params["value_out"]["b_mu"] + TARGET_AHEAD
+        target = {**params,
+                  "value_out": {**params["value_out"], "b_mu": ahead}}
         return state_class(
             params=params, target_params=target,
             opt_state=make_optimizer(self.cfg).init(params),
@@ -131,7 +142,7 @@ class Driver(fused_r2d2.Driver):
                 "params_after": self.snap["params_after"]}
 
     def reference_side(self, mode=None, touched=None):
-        hp, snap, n = self.fields, self.snap, self.seeded
+        hp, snap = self.fields, self.snap
         steps = self.step_keys()
         if len(steps) != 1:
             raise ValueError(
@@ -143,20 +154,18 @@ class Driver(fused_r2d2.Driver):
         idx, margin = ref.stratified_draw(priority, u01)
         idx = check.settle_edges(idx, margin, priority, touched)
         weight = ref.is_weights(priority, idx, snap["filled"], beta)
-        made = ringfill.rows(
-            self.k_fill, np.minimum(idx, n - 1), self.seq_total,
-            self.replay.frame_shape, hp["lstm_size"], self.num_actions)
-        rows = {name: np.array(made[name]) for name in fused_r2d2.RING_ROWS}
-        for name in fused_r2d2.RING_ROWS:
-            rows[name][idx >= n] = snap[name][idx[idx >= n] - n]
-        batch = ref.gather(rows, np.arange(len(idx)), weight)
+        batch = ref.gather(self.drawn_rows(idx), np.arange(len(idx)), weight)
 
         _host_gb("as the reference starts")
         if self.target0 is not None:  # the host's copy is needed no longer
             self.target_dev = jax.tree.map(jnp.asarray, self.target0)
             self.target0 = None
         params, target = self.params_dev, self.target_dev
-        total = len(idx)
+        # the fault "half": the reference put in the program's place with
+        # the second half of the batch left out and the mean taken over the
+        # rest (tools/calibrate.py --fault reads it; no run of a cell does)
+        half, mode = mode == "half", None if mode == "half" else mode
+        total = len(idx) // 2 if half else len(idx)
         block = min(REF_BLOCK, total)
         grad = jax.jit(jax.value_and_grad(
             lambda p, t, b, k: r2d2_kimi.loss_fn(
@@ -189,7 +198,8 @@ class Driver(fused_r2d2.Driver):
                    lambda g: np.asarray(thin(g * scale), np.float32), acc)}
         written = (np.concatenate(prio) + hp["priority_eps"]) ** hp[
             "priority_exponent"]
-        priority[idx] = np.where(priority[idx] > 0, written, 0.0)
+        seen = idx[:total]
+        priority[seen] = np.where(priority[seen] > 0, written, 0.0)
         out["priority_after"] = priority
         _host_gb("as the reference ends")
         return out

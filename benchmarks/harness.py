@@ -5,6 +5,7 @@ Everything that belongs to one cell, configuration, traffic mix, driver or
 per-layer metric is a file of its own, found by the name in BENCHMARK.json:
 
   workloads/<cell>.json   config, traffic, chips, the limits of `correct`
+                          (and `read_not_compared`: numbers printed, not judged)
   configs/<config>.json   source, driver, the program's Config fields
   traffic/<traffic>.json  the env and lane fields of the traffic mix
   drivers/<driver>.py     class Driver (builds and drives the program)
@@ -131,9 +132,12 @@ def measure(drv, seconds: float, trace_dir=None):
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *, t0: float,
-        devices=None, make_driver=None, out=sys.stdout) -> int:
-    """`devices` and `make_driver` are for tests: given devices skip the look
-    for a chip, a given factory stands in for the cell's driver."""
+        keep_trace: bool = False, devices=None, make_driver=None,
+        out=sys.stdout) -> int:
+    """`keep_trace` leaves a traced run's profile under `OUT_DIR` (a look by
+    hand; no number depends on it).  `devices` and `make_driver` are for
+    tests: given devices skip the look for a chip, a given factory stands in
+    for the cell's driver."""
     import jax
 
     from benchmarks import check
@@ -184,7 +188,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t0: float,
         from benchmarks import trace_reduce
 
         reduced = trace_reduce.reduce_dir(trace_dir, chips)
-        if not os.environ.get("BENCH_KEEP_TRACE"):  # a debugging aid only
+        if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
         with open(os.path.join(HERE, "peaks.json")) as f:
             peaks = json.load(f)
@@ -216,8 +220,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t0: float,
     numbers["window_steps_missing"] = float(abs(owed - win["steps"]))
     numbers["first_steps_missing"] = float(
         abs(len(ref["loss"]) - drv.first_learning["steps"]))
-    correct, rows = check.verdict(numbers, wl["limits"])
+    read_only = wl.get("read_not_compared", ())
+    correct, rows = check.verdict(numbers, wl["limits"], read_only)
     correct = correct and win["failed"] == 0
+    for name in read_only:
+        print(f"read {name} = {numbers[name]:.6g} (not compared)",
+              file=sys.stderr)
     for name, value, limit in rows:
         print(f"compared {name} = {value:.6g} (limit {limit:.6g})",
               file=sys.stderr)
@@ -226,6 +234,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t0: float,
     if breakdown is not None:
         result["breakdown"] = breakdown
     number = lambda x: float(x) if np.isfinite(x) else None  # noqa: E731
+    if read_only:
+        result["read_not_compared"] = {n: number(numbers[n]) for n in read_only}
     result["compared"] = {n: {"value": number(v), "limit": number(lim)}
                           for n, v, lim in rows}
     print(json.dumps(result), file=out, flush=True)

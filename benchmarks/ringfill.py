@@ -17,6 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from benchmarks import ringrows
+
 CUT_EVERY = 6  # freeway cuts at 500 ticks: every sixth sequence of a lane
 REWARD_RATE = 0.02  # a crossing every fifty steps or so
 STATE_SCALE = 0.25  # stored LSTM states: small, like an actor's early ones
@@ -63,26 +65,23 @@ def rows(key, ids, seq_len, frame_shape, lstm_size, num_actions):
     return jax.vmap(one)(jnp.asarray(ids, jnp.int32))
 
 
-def fill(arrays, key, n_rows, num_actions):
-    """`arrays` (the ring's row arrays by field name, rows leading) with rows
-    [0, n_rows) made from the seed; traced inside the caller's jit.  Whole
-    chunks go in place under a loop, the rest in one piece."""
-    seq_len, h, w = arrays["frames"].shape[1:]
-    make = lambda ids: rows(  # noqa: E731
-        key, ids, seq_len, (h, w), arrays["init_c"].shape[1], num_actions)
-
-    def put(arrs, new, start):
-        return {name: jax.lax.dynamic_update_slice_in_dim(
-            arrs[name], new[name].astype(arrs[name].dtype), start, 0)
-            for name in arrs}
+def fill(replay, state, key, n_rows, num_actions):
+    """The replay's `state` with ring rows [0, n_rows) made from the seed;
+    traced inside the caller's jit.  The geometry is the replay's own and the
+    rows go in through `ringrows.write_rows`, so how the ring stores them is
+    not this file's business.  Whole chunks go in place under a loop, the
+    rest in one piece."""
+    def put(st, ids, start):
+        made = rows(key, ids, replay.L, replay.frame_shape, replay.lstm_size,
+                    num_actions)
+        return ringrows.write_rows(replay, st, made, start)
 
     n_chunks = n_rows // CHUNK
-    arrays = jax.lax.fori_loop(
+    state = jax.lax.fori_loop(
         0, n_chunks,
-        lambda c, arrs: put(arrs, make(c * CHUNK + jnp.arange(CHUNK)),
-                            c * CHUNK),
-        dict(arrays))
+        lambda c, st: put(st, c * CHUNK + jnp.arange(CHUNK), c * CHUNK),
+        state)
     done = n_chunks * CHUNK
     if done < n_rows:
-        arrays = put(arrays, make(jnp.arange(done, n_rows)), done)
-    return arrays
+        state = put(state, jnp.arange(done, n_rows), done)
+    return state

@@ -1,4 +1,4 @@
-"""python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+"""python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1> [--keep-trace]
 
 One run of one cell of BENCHMARK.json on the TPU this process is started on.
 Prints one JSON object as the last line of standard output; see
@@ -23,11 +23,14 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--keep-trace", action="store_true",
+                    help="leave a --trace 1 run's profile under "
+                         "chiprun_out/benchmarks/<cell>/trace")
     args = ap.parse_args()
     from benchmarks import harness
 
     return harness.run(args.workload, args.seed, args.seconds,
-                       bool(args.trace), t0=T0)
+                       bool(args.trace), t0=T0, keep_trace=args.keep_trace)
 
 
 if __name__ == "__main__":

@@ -79,3 +79,12 @@ def test_a_broken_timed_path_is_not_correct(cell):
     assert results["sound"]["correct"] is True
     assert results["broken"]["correct"] is False
     assert list(results["sound"])[-1] == "compared"
+
+
+def test_a_number_read_and_not_compared_needs_no_limit_and_fails_nothing():
+    ok, rows = check.verdict({"a": 0.5, "b": 9.0}, {"a": 1.0}, ("b",))
+    assert ok and [name for name, _, _ in rows] == ["a"]
+    assert not check.verdict({"a": 0.5, "b": 9.0}, {"a": 1.0})[0]
+    assert not check.verdict({"b": 9.0}, {"a": 1.0}, ("b",))[0]
+    with pytest.raises(ValueError):
+        check.verdict({"a": 0.5}, {"a": 1.0}, ("a",))

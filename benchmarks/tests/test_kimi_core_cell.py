@@ -35,7 +35,8 @@ def tiny_driver(seed, **kw):
 
 
 def test_program_passes_and_control_fails():
-    limits = tiny.load("workloads", CELL)["limits"]
+    cell = tiny.load("workloads", CELL)
+    limits, read_only = cell["limits"], cell.get("read_not_compared", ())
     exact = {"window_steps_missing": 0.0, "first_steps_missing": 0.0}
     drv = tiny_driver(5)
     drv.warm_up()
@@ -45,12 +46,16 @@ def test_program_passes_and_control_fails():
     prog = drv.program_side()
     ref = drv.reference_side(None, prog["priority_after"] != drv.priority0())
     sound, rows = check.verdict(
-        {**check.compare(prog, ref, drv.params0), **exact}, limits)
+        {**check.compare(prog, ref, drv.params0), **exact}, limits, read_only)
     assert sound, rows
-    control = drv.reference_side("fp8", None)
-    ok, rows = check.verdict(
-        {**check.compare(control, ref, drv.params0), **exact}, limits)
-    assert not ok, rows
+    # the control, and the fault of half the batch left out: each is failed
+    # by the first gradient's angle (the fault also by the draw's write-back)
+    for mode in ("fp8", "half"):
+        numbers = check.compare(
+            drv.reference_side(mode, None), ref, drv.params0)
+        ok, rows = check.verdict({**numbers, **exact}, limits, read_only)
+        assert not ok, rows
+        assert numbers["grad1_median_angle"] > limits["grad1_median_angle"]
 
 
 def test_the_seeded_selection_bias_fixes_the_choice_and_the_held_share():
@@ -85,6 +90,37 @@ def test_the_seeded_selection_bias_fixes_the_choice_and_the_held_share():
         shapes, jax.random.PRNGKey(s), 0.5, top_k=8)["core"]["layer_3"]["moe"][
             "router"]["select_bias"]) for s in (1, 2))
     assert np.any(a != b)  # which experts: from the seed
+
+
+def test_the_target_is_the_online_net_with_its_value_bias_ahead():
+    """Every seed's first TD errors have the same mean: the target differs
+    from the online net in the value head's bias alone, by TARGET_AHEAD."""
+    from benchmarks.drivers.fused_r2d2_core import TARGET_AHEAD
+
+    ts = tiny_driver(2**31 + 7).carry[0]
+    online = dict(jax.tree_util.tree_leaves_with_path(ts.params))
+    differ = {jax.tree_util.keystr(path): np.asarray(leaf) - np.asarray(online[path])
+              for path, leaf in jax.tree_util.tree_leaves_with_path(
+                  ts.target_params)
+              if np.any(np.asarray(leaf) != np.asarray(online[path]))}
+    assert list(differ) == ["['value_out']['b_mu']"]
+    np.testing.assert_allclose(differ["['value_out']['b_mu']"], TARGET_AHEAD,
+                               rtol=1e-6)
+
+
+def test_a_step_that_leaves_its_state_unchanged_is_not_correct():
+    """The harness's whole run over the timed path broken underneath."""
+    from benchmarks.tests.test_correct import _state_unchanged
+
+    broken = _state_unchanged(Driver)
+    out = io.StringIO()
+    rc = harness.run(CELL, 2**31 + 5, 0.5, False, t0=time.perf_counter(),
+                     devices=jax.devices()[:1],
+                     make_driver=lambda _f, _t, seed, chips, **kw: broken(
+                         tiny_fields(), tiny.traffic("freeway-16lanes"), seed,
+                         1, **kw), out=out)
+    assert rc == 0
+    assert json.loads(out.getvalue().splitlines()[-1])["correct"] is False
 
 
 def test_large_leaves_are_compared_on_a_fixed_subset_of_their_elements():

@@ -8,6 +8,8 @@ readings need no window.
 
 Prints one JSON line per seed: {"seed", "sound": {...}, "fp8": {...}?,
 "fp8_correct": false?}; `--leaves` adds the first gradient's readings by leaf.
+`--fault N` reads, on the first N seeds, the reference with half of the batch
+left out (a driver that takes the mode "half"), put in the program's place.
 """
 
 import argparse
@@ -27,6 +29,8 @@ def main() -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--control", type=int, default=0,
                     help="run the control on the first N seeds")
+    ap.add_argument("--fault", type=int, default=0,
+                    help="run the half-batch fault on the first N seeds")
     ap.add_argument("--leaves", action="store_true",
                     help="also print the first gradient's readings by leaf")
     args = ap.parse_args()
@@ -49,14 +53,15 @@ def main() -> int:
         if args.leaves and prog["grad1"] is not None:
             row["sound_leaves"] = check.first_gradient_by_leaf(
                 prog["grad1"], ref["grad1"])
-        if i < args.control:
-            ctrl = drv.reference_side("fp8", None)
-            row["fp8"] = check.compare(ctrl, ref, drv.params0)
-            row["fp8_correct"] = check.verdict(
-                {**row["fp8"], "first_steps_missing": 0.0,
-                 "window_steps_missing": 0.0}, wl["limits"])[0]
+        for mode in ["fp8"] * (i < args.control) + ["half"] * (i < args.fault):
+            ctrl = drv.reference_side(mode, None)
+            row[mode] = check.compare(ctrl, ref, drv.params0)
+            row[mode + "_correct"] = check.verdict(
+                {**row[mode], "first_steps_missing": 0.0,
+                 "window_steps_missing": 0.0}, wl["limits"],
+                wl.get("read_not_compared", ()))[0]
             if args.leaves:
-                row["fp8_leaves"] = check.first_gradient_by_leaf(
+                row[mode + "_leaves"] = check.first_gradient_by_leaf(
                     ctrl["grad1"], ref["grad1"])
         print(json.dumps(row), flush=True)
         del drv, prog, ref
