@@ -20,6 +20,16 @@ is one batched scatter.  A tick on which no lane emits skips all of that
 under one `lax.cond` and leaves the scratch row as it was.  Sampling,
 priorities, snapshots and the benchmark's `correct` only ever see rows
 [0, C).
+
+Frames are stored FLAT: the ring `[C+1, L, h*w]`, the builders
+`[lanes, L, h*w]`.  The device's default layout of a 4-D `[rows, L, h, w]`
+uint8 array puts the rows in the 128 lanes, which no row gather or scatter
+can use, so a loop over it converts the whole ring on the way in and out of
+every dispatch; with the pixels minor the default layout is the one every
+gather, scatter and slice here wants.  `append` takes `[lanes, h, w]` frames
+and `assemble` gives `[B, L, h, w, 1]` observations all the same, and whole
+rows pass in and out in their logical shapes through `write_rows` /
+`read_rows`.
 """
 
 from __future__ import annotations
@@ -36,7 +46,7 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import SequenceBatch
 
 class DeviceSeqState(NamedTuple):
     # sequence ring, one scratch row at index C
-    frames: jnp.ndarray  # [C+1, L, H, W] uint8
+    frames: jnp.ndarray  # [C+1, L, H*W] uint8 (stored flat: module docstring)
     actions: jnp.ndarray  # [C+1, L] int32
     rewards: jnp.ndarray  # [C+1, L] f32
     dones: jnp.ndarray  # [C+1, L] bool
@@ -48,7 +58,7 @@ class DeviceSeqState(NamedTuple):
     filled: jnp.ndarray  # scalar i32
     max_priority: jnp.ndarray  # scalar f32
     # per-lane builders
-    buf_frames: jnp.ndarray  # [lanes, L, H, W] uint8
+    buf_frames: jnp.ndarray  # [lanes, L, H*W] uint8
     buf_actions: jnp.ndarray  # [lanes, L] i32
     buf_rewards: jnp.ndarray  # [lanes, L] f32
     buf_dones: jnp.ndarray  # [lanes, L] bool
@@ -57,6 +67,11 @@ class DeviceSeqState(NamedTuple):
     buf_len: jnp.ndarray  # [lanes] i32
     # ticks on which some lane emitted (append's conditional took do_emit)
     emit_ticks: jnp.ndarray  # scalar i32
+
+
+# the fields `write_rows` / `read_rows` pass: one entry a ring row
+ROW_FIELDS = ("frames", "actions", "rewards", "dones", "valids",
+              "init_c", "init_h")
 
 
 class DeviceSequenceReplay:
@@ -99,7 +114,7 @@ class DeviceSequenceReplay:
             self.capacity, self.L, self.frame_shape, self.lstm_size, self.lanes,
         )
         return DeviceSeqState(
-            frames=jnp.zeros((C + 1, L, h, w), jnp.uint8),
+            frames=jnp.zeros((C + 1, L, h * w), jnp.uint8),
             actions=jnp.zeros((C + 1, L), jnp.int32),
             rewards=jnp.zeros((C + 1, L), jnp.float32),
             dones=jnp.zeros((C + 1, L), bool),
@@ -110,7 +125,7 @@ class DeviceSequenceReplay:
             pos=jnp.int32(0),
             filled=jnp.int32(0),
             max_priority=jnp.float32(1.0),
-            buf_frames=jnp.zeros((lanes, L, h, w), jnp.uint8),
+            buf_frames=jnp.zeros((lanes, L, h * w), jnp.uint8),
             buf_actions=jnp.zeros((lanes, L), jnp.int32),
             buf_rewards=jnp.zeros((lanes, L), jnp.float32),
             buf_dones=jnp.zeros((lanes, L), bool),
@@ -147,7 +162,9 @@ class DeviceSequenceReplay:
         # steps.  neither: just the incremented length.
         new_len = jnp.where(cut, 0, jnp.where(emit, L - stride, klen))
         st = s._replace(
-            buf_frames=s.buf_frames.at[lane, k].set(frames),
+            # the frame in the builder's stored per-step shape, read off it
+            buf_frames=s.buf_frames.at[lane, k].set(
+                frames.reshape((lanes,) + s.buf_frames.shape[2:])),
             buf_actions=s.buf_actions.at[lane, k].set(
                 actions.astype(jnp.int32)),
             buf_rewards=s.buf_rewards.at[lane, k].set(
@@ -167,7 +184,8 @@ class DeviceSequenceReplay:
 
             vm = jnp.arange(L)[None, :] < klen[:, None]  # [lanes, L] valid
 
-            def zpad(buf, mask):
+            def zpad(buf):  # steps past a lane's length read zero
+                mask = vm.reshape(vm.shape + (1,) * (buf.ndim - 2))
                 return jnp.where(mask, buf, jnp.zeros_like(buf))
 
             # max-priority insertion for emitted slots (clip scratch writes
@@ -188,11 +206,10 @@ class DeviceSequenceReplay:
                 return jnp.where(sel, jnp.roll(buf, -stride, axis=1), buf)
 
             return st._replace(
-                frames=st.frames.at[slots].set(
-                    zpad(st.buf_frames, vm[..., None, None])),
-                actions=st.actions.at[slots].set(zpad(st.buf_actions, vm)),
-                rewards=st.rewards.at[slots].set(zpad(st.buf_rewards, vm)),
-                dones=st.dones.at[slots].set(zpad(st.buf_dones, vm)),
+                frames=st.frames.at[slots].set(zpad(st.buf_frames)),
+                actions=st.actions.at[slots].set(zpad(st.buf_actions)),
+                rewards=st.rewards.at[slots].set(zpad(st.buf_rewards)),
+                dones=st.dones.at[slots].set(zpad(st.buf_dones)),
                 valids=st.valids.at[slots].set(vm),
                 init_c=st.init_c.at[slots].set(st.buf_c[:, 0]),
                 init_h=st.init_h.at[slots].set(st.buf_h[:, 0]),
@@ -209,6 +226,31 @@ class DeviceSequenceReplay:
             )
 
         return jax.lax.cond(emit.any(), do_emit, lambda st: st, st)
+
+    # ------------------------------------------------------------ whole rows
+    def write_rows(self, s: DeviceSeqState, rows, start) -> DeviceSeqState:
+        """Ring rows [start, start + n) from `rows` in their logical shapes
+        (`frames [n, L, h, w]`, `actions`/`rewards`/`dones`/`valids` [n, L],
+        `init_c`/`init_h` [n, lstm]), cast to the stored dtypes.  n is
+        static, `start` may be traced; priority, pos, filled and the builders
+        are untouched."""
+        stored = dict(rows, frames=jnp.reshape(
+            rows["frames"], rows["frames"].shape[:2] + s.frames.shape[2:]))
+        return s._replace(**{
+            name: jax.lax.dynamic_update_slice_in_dim(
+                getattr(s, name), stored[name].astype(getattr(s, name).dtype),
+                start, 0)
+            for name in ROW_FIELDS})
+
+    def read_rows(self, s: DeviceSeqState, start: int, stop: int):
+        """Ring rows [start, stop) (static bounds) in the logical shapes
+        `write_rows` takes."""
+        rows = {name: getattr(s, name)[start:stop] for name in ROW_FIELDS}
+        return dict(rows, frames=self._unflat(rows["frames"]))
+
+    def _unflat(self, frames: jnp.ndarray) -> jnp.ndarray:
+        """[n, L, h*w] stored frames in their logical shape [n, L, h, w]."""
+        return frames.reshape(frames.shape[:2] + tuple(self.frame_shape))
 
     # -------------------------------------------------------------- sampling
     def _effective_priority(self, s: DeviceSeqState) -> jnp.ndarray:
@@ -257,8 +299,16 @@ class DeviceSequenceReplay:
             weight = w / w.max()
         else:
             weight = jnp.ones_like(prob)
+        # Whole rows, one slice a row (each is contiguous in the flat ring).
+        # `s.frames[idx]` asks the TPU for gather slices of L*h*w bytes, more
+        # than its gather takes, and the compiler then cuts the whole RING
+        # into column strips first, on every learn step.
+        frames = jax.lax.map(
+            lambda i: jax.lax.dynamic_index_in_dim(s.frames, i, keepdims=False),
+            idx)
         batch = SequenceBatch(
-            obs=s.frames[idx][..., None],
+            # the gathered batch takes its logical shape, never the ring
+            obs=self._unflat(frames)[..., None],
             action=s.actions[idx],
             reward=s.rewards[idx],
             done=s.dones[idx],

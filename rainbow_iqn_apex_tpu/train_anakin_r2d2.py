@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import math
 import os
 from typing import Any, Dict, Optional
 
@@ -237,13 +238,18 @@ def _maybe_restore_replay(cfg: Config, ss: DeviceSeqState) -> DeviceSeqState:
     from rainbow_iqn_apex_tpu.replay import snapshot_io
 
     z = snapshot_io.load(path)
-    if tuple(z["frames"].shape) != tuple(ss.frames.shape):
-        return ss  # geometry change: degrade to cold replay (host-path rule)
     # a field the snapshot predates (emit_ticks) keeps its fresh value
-    return ss._replace(
-        **{f: jnp.asarray(z[f]) for f in DeviceSeqState._fields
-           if f in z.files}
-    )
+    got = {f: z[f] for f in DeviceSeqState._fields if f in z.files}
+    for f in ("frames", "buf_frames"):
+        # a snapshot from before frames were stored flat holds [..., L, H, W]:
+        # the same bytes in the same order, so it is reshaped and taken
+        want = getattr(ss, f).shape
+        if (f in got and got[f].shape[:len(want) - 1] == want[:-1]
+                and got[f].size == math.prod(want)):
+            got[f] = got[f].reshape(want)
+    if any(v.shape != getattr(ss, f).shape for f, v in got.items()):
+        return ss  # geometry change: degrade to cold replay (host-path rule)
+    return ss._replace(**{f: jnp.asarray(v) for f, v in got.items()})
 
 
 def train_anakin_r2d2(cfg: Config,

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from rainbow_iqn_apex_tpu.replay.device_sequence import (
+    ROW_FIELDS,
     DeviceSequenceReplay,
     build_device_r2d2_learn,
 )
@@ -57,6 +58,14 @@ def _trace(rng, ticks, p_term=0.1, p_trunc=0.07, trunc_all_at=(),
         )
 
 
+def _feed_host(host, x, shard):
+    """One tick `x` of `_trace` into a host replay: the lanes of `shard`."""
+    mine = {k: v[shard * LANES:(shard + 1) * LANES] for k, v in x.items()}
+    host.append_batch(
+        mine["frames"], mine["actions"], mine["rewards"], mine["terminals"],
+        mine["lstm_c"], mine["lstm_h"], truncations=mine["truncations"])
+
+
 def _drive(host, dev, ticks, seed=0, p_term=0.1, p_trunc=0.07):
     append = jax.jit(dev.append)
     ds = dev.init_state()
@@ -75,6 +84,12 @@ def _drive(host, dev, ticks, seed=0, p_term=0.1, p_trunc=0.07):
     return ds
 
 
+def _ring_frames(dev, ds):
+    """Ring rows [0, C) of the frames in the host's shape [C, L, H, W]: the
+    device stores them flat and `read_rows` gives the logical shape."""
+    return np.asarray(dev.read_rows(ds, 0, dev.capacity)["frames"])
+
+
 @pytest.mark.parametrize("ticks", [4, 17, 60])
 def test_ring_matches_host(ticks):
     host, dev = _make_pair()
@@ -83,7 +98,7 @@ def test_ring_matches_host(ticks):
     assert int(ds.pos) == host.pos
     n = host.filled
     sl = np.arange(n) if n < CAP else np.arange(CAP)
-    np.testing.assert_array_equal(np.asarray(ds.frames)[sl], host.frames[sl])
+    np.testing.assert_array_equal(_ring_frames(dev, ds)[sl], host.frames[sl])
     np.testing.assert_array_equal(np.asarray(ds.actions)[sl], host.actions[sl])
     np.testing.assert_allclose(
         np.asarray(ds.rewards)[sl], host.rewards[sl], rtol=1e-6
@@ -109,11 +124,153 @@ def test_ring_matches_host_no_cuts():
     ds = _drive(host, dev, 40, seed=3, p_term=0.0, p_trunc=0.0)
     n = min(host.filled, CAP)
     sl = np.arange(n)
-    np.testing.assert_array_equal(np.asarray(ds.frames)[sl], host.frames[sl])
+    np.testing.assert_array_equal(_ring_frames(dev, ds)[sl], host.frames[sl])
     np.testing.assert_allclose(
         np.asarray(ds.init_c)[sl], host.init_c[sl], rtol=1e-6
     )
     assert np.asarray(ds.valids)[sl].all()  # full windows only
+
+
+# --------------------------------------------------------------------------
+# the stored shape (frames flat) and whole rows in and out of it
+# --------------------------------------------------------------------------
+
+
+def test_frames_are_stored_flat():
+    """The ring holds frames [C+1, L, h*w] and the builders [lanes, L, h*w]:
+    the pixels minor, so that the device's default layout serves every row
+    gather and scatter (module docstring); no other field's shape changed."""
+    _, dev = _make_pair()
+    s = dev.init_state()
+    assert s.frames.shape == (CAP + 1, L, H * W) and s.frames.dtype == jnp.uint8
+    assert s.buf_frames.shape == (LANES, L, H * W)
+    assert s.buf_frames.dtype == jnp.uint8
+    assert s.actions.shape == s.valids.shape == (CAP + 1, L)
+    assert s.init_c.shape == s.init_h.shape == (CAP + 1, LSTM)
+    assert s.buf_c.shape == (LANES, L, LSTM) and s.priority.shape == (CAP,)
+
+
+def _seeded_rows(rng, n, lstm):
+    return dict(
+        frames=rng.integers(0, 255, (n, L, H, W), dtype=np.uint8),
+        actions=rng.integers(0, 4, (n, L)).astype(np.int32),
+        rewards=rng.normal(size=(n, L)).astype(np.float32),
+        dones=rng.random((n, L)) < 0.2,
+        valids=rng.random((n, L)) < 0.8,
+        init_c=rng.normal(size=(n, lstm)).astype(np.float32),
+        init_h=rng.normal(size=(n, lstm)).astype(np.float32),
+    )
+
+
+@pytest.mark.parametrize("lstm", [4, 0], ids=["state4", "state0"])
+def test_rows_written_are_the_rows_read(lstm):
+    """`write_rows` under jit with a traced start, then `read_rows`: bit for
+    bit the rows that went in, in their logical shapes, on a ring with and
+    without stored state (a core that stores none has `init_c` [n, 0]); every
+    ring row outside [start, start + n) and every field that is not a row
+    (priority, cursors, the builders, the counter) as it was."""
+    dev = DeviceSequenceReplay(
+        capacity=CAP, seq_len=L, frame_shape=(H, W), lstm_size=lstm,
+        lanes=LANES, stride=STRIDE)
+    n, start = 5, 7
+    s0 = jax.tree.map(  # every entry its own value, so a stray write shows
+        lambda x: (jnp.arange(x.size) % 251).reshape(x.shape).astype(x.dtype),
+        dev.init_state())
+    rows = _seeded_rows(np.random.default_rng(3), n, lstm)
+    # float64 and int64 in: cast to the stored dtypes
+    rows["rewards"] = rows["rewards"].astype(np.float64)
+    rows["actions"] = rows["actions"].astype(np.int64)
+    s1 = jax.jit(dev.write_rows)(s0, rows, jnp.int32(start))
+    assert jax.tree.map(lambda x: (x.shape, x.dtype), s1) == jax.tree.map(
+        lambda x: (x.shape, x.dtype), s0)
+    back = jax.jit(dev.read_rows, static_argnums=(1, 2))(s1, start, start + n)
+    assert set(back) == set(ROW_FIELDS)
+    for name in ROW_FIELDS:
+        stored = getattr(s0, name).dtype
+        assert back[name].shape == rows[name].shape, name
+        assert back[name].dtype == stored, name
+        np.testing.assert_array_equal(
+            np.asarray(back[name]), rows[name].astype(stored), err_msg=name)
+    assert back["frames"].shape == (n, L, H, W)
+    assert back["init_c"].shape == (n, lstm)
+    for name in s0._fields:
+        was, now = np.asarray(getattr(s0, name)), np.asarray(getattr(s1, name))
+        if name in ROW_FIELDS:  # the scratch row CAP among the untouched
+            keep = np.r_[0:start, start + n:CAP + 1]
+            np.testing.assert_array_equal(now[keep], was[keep], err_msg=name)
+        else:
+            np.testing.assert_array_equal(now, was, err_msg=name)
+
+
+def test_rows_written_are_drawn_as_the_host_gives_them():
+    """Rows that entered through `write_rows` come out of `assemble` as the
+    host replay's `obs` [B, L, H, W, 1] of the same rows."""
+    host, dev = _make_pair()
+    ds = _drive(host, dev, 60)
+    assert int(ds.filled) == CAP
+    fresh = dev.write_rows(dev.init_state(), dev.read_rows(ds, 0, CAP), 0)
+    fresh = fresh._replace(priority=ds.priority, filled=ds.filled)
+    hs = host.sample(8, 0.6)
+    idx = jnp.asarray(hs.idx, jnp.int32)
+    batch, _ = jax.jit(dev.assemble)(fresh, idx, jnp.float32(0.6))
+    assert batch.obs.shape == (8, L, H, W, 1)
+    for name, theirs in (("obs", hs.obs), ("action", hs.action),
+                         ("reward", hs.reward), ("done", hs.done),
+                         ("valid", hs.valid), ("init_c", hs.init_c),
+                         ("init_h", hs.init_h)):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(batch, name)), theirs, err_msg=name)
+
+
+def test_stacked_shards_keep_the_stored_shape():
+    """`stack_seq_shards` puts a device dim in front of whatever rank a leaf
+    has: two shards' rings [2, C+1, L, h*w] go through the sharded append and
+    the sharded draw + assemble, each shard reading the rows a host replay of
+    its own lanes holds and giving `obs` [b, L, H, W, 1] of its own ring."""
+    from jax.sharding import Mesh
+
+    from rainbow_iqn_apex_tpu.config import Config
+    from rainbow_iqn_apex_tpu.replay.device_sequence import (
+        build_device_r2d2_learn_sharded,
+        build_sharded_seq_append,
+        device_seq_shardings,
+        stack_seq_shards,
+    )
+
+    n_dev = 2
+    if len(jax.devices()) < n_dev:
+        pytest.skip("needs 2 devices")
+    hosts = [_make_pair()[0] for _ in range(n_dev)]
+    dev = _make_pair()[1]
+    mesh = Mesh(np.array(jax.devices()[:n_dev]), ("dp",))
+    gs = jax.device_put(stack_seq_shards(dev.init_state(), n_dev),
+                        device_seq_shardings(mesh))
+    assert gs.frames.shape == (n_dev, CAP + 1, L, H * W)
+    assert gs.buf_frames.shape == (n_dev, LANES, L, H * W)
+    append = jax.jit(build_sharded_seq_append(dev, mesh))
+    for x in _trace(np.random.default_rng(21), 30, lanes=n_dev * LANES):
+        gs = append(gs, *(jnp.asarray(v) for v in x.values()))
+        for d, host in enumerate(hosts):
+            _feed_host(host, x, d)
+    assert gs.frames.shape == (n_dev, CAP + 1, L, H * W)
+    shards = [jax.tree.map(lambda a: jnp.asarray(np.asarray(a)[d]), gs)
+              for d in range(n_dev)]
+    for d, host in enumerate(hosts):
+        _assert_equals_host(dev, shards[d], host, f"shard {d}")
+    cfg = Config(
+        compute_dtype="float32", history_length=1, hidden_size=32,
+        num_cosines=8, lstm_size=LSTM, r2d2_burn_in=2, r2d2_seq_len=L - 2,
+        batch_size=4 * n_dev, multi_step=1, gamma=0.9,
+    )
+    fused = build_device_r2d2_learn_sharded(cfg, 4, dev, mesh)
+    idx, batch = jax.jit(fused.draw_assemble)(
+        gs, jax.random.PRNGKey(9), jnp.float32(0.6))
+    assert batch.obs.shape == (cfg.batch_size, L, H, W, 1)
+    idx, obs = np.asarray(idx).reshape(n_dev, -1), np.asarray(batch.obs)
+    for d, host in enumerate(hosts):
+        np.testing.assert_array_equal(
+            obs.reshape(n_dev, -1, L, H, W)[d], host.frames[idx[d]],
+            err_msg=f"shard {d}")
 
 
 # --------------------------------------------------------------------------
@@ -136,13 +293,16 @@ TRAFFIC = {
 }
 
 
-def _assert_equals_host(ds, host, where):
+def _assert_equals_host(dev, ds, host, where):
     """Bit for bit: ring rows [0, C) (the scratch row C is the device's own
-    business), priorities, cursors, and each lane's live builder prefix."""
-    for name in ("frames", "actions", "rewards", "dones", "valids", "init_c",
-                 "init_h"):
+    business) read in their logical shapes, priorities, cursors, and each
+    lane's live builder prefix (its frames flat, as the builder stores
+    them)."""
+    rows = dev.read_rows(ds, 0, CAP)
+    assert set(rows) == set(ROW_FIELDS)
+    for name in ROW_FIELDS:
         np.testing.assert_array_equal(
-            np.asarray(getattr(ds, name))[:CAP], getattr(host, name)[:CAP],
+            np.asarray(rows[name]), getattr(host, name)[:CAP],
             err_msg=f"{name} {where}")
     np.testing.assert_array_equal(
         np.asarray(ds.priority),
@@ -155,9 +315,10 @@ def _assert_equals_host(ds, host, where):
     np.testing.assert_array_equal(lens, host._buf_len, err_msg=where)
     for lane, n in enumerate(lens):
         for name in ("frames", "actions", "rewards", "dones", "c", "h"):
+            theirs = getattr(host, "_buf_" + name)[lane, :n]
             np.testing.assert_array_equal(
                 np.asarray(getattr(ds, "buf_" + name))[lane, :n],
-                getattr(host, "_buf_" + name)[lane, :n],
+                theirs.reshape(n, H * W) if name == "frames" else theirs,
                 err_msg=f"builder {name} lane {lane} {where}")
 
 
@@ -206,7 +367,7 @@ def test_every_tick_matches_host(traffic, sharded):
             )
             shard = jax.tree.map(lambda a: np.asarray(a)[d], ds) \
                 if sharded else ds
-            _assert_equals_host(shard, host, f"tick {t} shard {d}")
+            _assert_equals_host(dev, shard, host, f"tick {t} shard {d}")
     np.testing.assert_array_equal(
         np.asarray(ds.emit_ticks).reshape(n_dev), emit_ticks)
     assert 0 < emit_ticks.min() and emit_ticks.max() < TICKS  # both branches
@@ -257,7 +418,7 @@ def test_append_is_one_conditional_with_an_empty_skip():
     skip, do_emit = conds[0].params["branches"]
     assert len(skip.jaxpr.eqns) == 0
     assert any(e.primitive.name == "scatter" and
-               e.outvars[0].aval.shape == (CAP + 1, L, H, W)
+               e.outvars[0].aval.shape == (CAP + 1, L, H * W)
                for e in do_emit.jaxpr.eqns)
 
 
@@ -285,6 +446,94 @@ def test_restore_accepts_a_snapshot_without_emit_ticks(tmp_path):
     assert int(got.emit_ticks) == int(ds.emit_ticks) > 0
 
 
+def _assert_same_state(got, want):
+    """Leaf for leaf: shape, dtype and bytes."""
+    for f in want._fields:
+        a, b = np.asarray(getattr(got, f)), np.asarray(getattr(want, f))
+        assert a.shape == b.shape and a.dtype == b.dtype, f
+        np.testing.assert_array_equal(a, b, err_msg=f)
+
+
+def _as_before_pr31(ds):
+    """A snapshot's arrays as the trainer wrote them until PR 31: `frames`
+    [..., L, H, W] and `buf_frames` [..., L, H, W], every other field as now."""
+    snap = {f: np.asarray(v) for f, v in ds._asdict().items()}
+    for f in ("frames", "buf_frames"):
+        snap[f] = snap[f].reshape(snap[f].shape[:-1] + (H, W))
+    return snap
+
+
+def _snapshot_cfg(tmp_path):
+    from rainbow_iqn_apex_tpu import train_anakin_r2d2 as prog
+    from rainbow_iqn_apex_tpu.config import Config
+
+    cfg = Config(checkpoint_dir=str(tmp_path), run_id="r", snapshot_replay=True)
+    os.makedirs(os.path.dirname(prog._replay_snapshot_path(cfg)))
+    return prog, cfg
+
+
+def test_a_snapshot_restores_bit_for_bit(tmp_path):
+    """What `_save_replay` writes (frames in the stored, flat shape) comes
+    back leaf for leaf, shape, dtype and bytes."""
+    prog, cfg = _snapshot_cfg(tmp_path)
+    host, dev = _make_pair()
+    ds = _drive(host, dev, 17)
+    prog._save_replay(cfg, ds)
+    got = prog._maybe_restore_replay(cfg, dev.init_state())
+    _assert_same_state(got, ds)
+    assert got.frames.shape == (CAP + 1, L, H * W)
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["one_ring", "shards"])
+def test_restore_takes_a_snapshot_from_before_frames_were_flat(tmp_path,
+                                                               stacked):
+    """A snapshot that holds `frames` [C+1, L, H, W] and `buf_frames`
+    [lanes, L, H, W] (the stored shape until PR 31; with a device dim in
+    front for a dp run) is the same bytes in the same order: it is reshaped
+    and taken, not dropped to a cold replay."""
+    from rainbow_iqn_apex_tpu.replay import snapshot_io
+    from rainbow_iqn_apex_tpu.replay.device_sequence import stack_seq_shards
+
+    prog, cfg = _snapshot_cfg(tmp_path)
+    host, dev = _make_pair()
+    ds = _drive(host, dev, 17)
+    fresh = dev.init_state()
+    if stacked:
+        ds, fresh = stack_seq_shards(ds, 2), stack_seq_shards(fresh, 2)
+    old = _as_before_pr31(ds)
+    np.testing.assert_array_equal(  # the old shape is the host's
+        old["frames"].reshape(-1, CAP + 1, L, H, W)[0, :host.filled],
+        host.frames[:host.filled])
+    snapshot_io.atomic_savez(prog._replay_snapshot_path(cfg), **old)
+    got = prog._maybe_restore_replay(cfg, fresh)
+    assert int(np.asarray(got.filled).max()) == host.filled > 0
+    _assert_same_state(got, ds)
+
+
+@pytest.mark.parametrize("change", ["capacity", "seq_len", "pixels", "lanes"])
+def test_restore_degrades_to_cold_on_a_geometry_change(tmp_path, change):
+    """A snapshot of another ring (rows, sequence length, pixels a frame, or
+    lanes) is not taken: the replay starts cold, whichever shape the
+    snapshot's frames have."""
+    from rainbow_iqn_apex_tpu.replay import snapshot_io
+
+    prog, cfg = _snapshot_cfg(tmp_path)
+    host, dev = _make_pair()
+    ds = _drive(host, dev, 17)
+    geo = dict(capacity=CAP, seq_len=L, frame_shape=(H, W), lanes=LANES)
+    geo.update({"capacity": dict(capacity=CAP + 1),
+                "seq_len": dict(seq_len=L + 2),
+                "pixels": dict(frame_shape=(H, W + 1)),
+                "lanes": dict(lanes=LANES - 1)}[change])
+    other = DeviceSequenceReplay(lstm_size=LSTM, stride=STRIDE, **geo)
+    fresh = other.init_state()
+    for snap in (ds._asdict(), _as_before_pr31(ds)):
+        snapshot_io.atomic_savez(prog._replay_snapshot_path(cfg), **snap)
+        got = prog._maybe_restore_replay(cfg, fresh)
+        assert int(got.filled) == 0 and int(got.emit_ticks) == 0
+        _assert_same_state(got, fresh)
+
+
 def test_assemble_matches_host_sample_fields():
     host, dev = _make_pair()
     ds = _drive(host, dev, 50, seed=5)
@@ -293,6 +542,7 @@ def test_assemble_matches_host_sample_fields():
     batch, prob = jax.jit(dev.assemble)(
         ds, jnp.asarray(hs.idx, jnp.int32), jnp.float32(beta)
     )
+    assert batch.obs.shape == (8, L, H, W, 1) and batch.obs.dtype == jnp.uint8
     np.testing.assert_array_equal(np.asarray(batch.obs), hs.obs)
     np.testing.assert_array_equal(np.asarray(batch.action), hs.action)
     np.testing.assert_allclose(np.asarray(batch.reward), hs.reward, rtol=1e-6)

@@ -193,3 +193,48 @@ def test_kernels_compile_at_the_published_widths_and_carry_the_scope(
                if name.startswith("kda_tile")}
     assert {n.split(".")[0] for n in kernels} == {"kda_tile", "kda_tile_vjp"}
     assert set(kernels.values()) == {("kda_scan", "kda_prep")}, kernels
+
+
+def test_the_sequence_rings_loop_needs_no_ring_sized_temporary(one_chip):
+    """`r2d2-fused`'s ring at its real size (6,553 sequences of 120 80x80
+    frames, 5.03 GB; 16 lanes) under a donated scan of append, draw and
+    assemble, compiled for the chip: with frames stored flat the loop runs in
+    the ring's own layout.  A ring stored `[C+1, L, h, w]` is copied whole on
+    entry to the scan and back on exit (5.4 GB of temporaries), and a flat
+    ring gathered `frames[idx]` is cut into column strips on every learn step
+    (3.5 GB); row by row the loop holds 0.18 GB.  It lives in this file
+    because one file holds the suite's compiles for the described chip."""
+    from rainbow_iqn_apex_tpu.replay.device_sequence import DeviceSequenceReplay
+
+    lanes, length, cap, hw, lstm = 16, 120, 6553, (80, 80), 512
+    replay = DeviceSequenceReplay(capacity=cap, seq_len=length, frame_shape=hw,
+                                  lstm_size=lstm, lanes=lanes, stride=40)
+
+    def loop(state, key):
+        def tick(carry, k):
+            s, seen = carry
+            z = jnp.zeros((lanes,))
+            s = replay.append(
+                s, jax.random.bits(k, (lanes,) + hw, jnp.uint8),
+                z.astype(jnp.int32), z, z > 0, z > 0,
+                jnp.zeros((lanes, lstm)), jnp.zeros((lanes, lstm)))
+            batch, _ = replay.assemble(s, replay.draw(s, k, 64),
+                                       jnp.float32(0.5))
+            return (s, seen + batch.obs.astype(jnp.int32).sum()), None
+
+        return jax.lax.scan(tick, (state, jnp.int32(0)),
+                            jax.random.split(key, 4))[0]
+
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    state = shaped(jax.eval_shape(replay.init_state))
+    ring_bytes = state.frames.size
+    assert state.frames.shape == (cap + 1, length, hw[0] * hw[1])
+    compiled = jax.jit(loop, donate_argnums=(0,)).lower(
+        state, shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < ring_bytes / 10
+    ring_ops = {line.split(" = ")[1].split("(")[0].split(" ")[-1]
+                for line in compiled.as_text().splitlines()
+                if f" = u8[{cap + 1},{length}," in line}
+    assert ring_ops.isdisjoint({"copy", "slice", "transpose"}), ring_ops
