@@ -15,10 +15,12 @@ A core sits between the conv trunk and the dueling heads:
 modules in the net's scope, so the LSTM's parameters stay `lstm/cell/...`
 leaf for leaf.
 
-Two cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
-(c, h) of every sequence start) and `models/kimi_linear.KimiLinearCore`
-(zero start state: the ring's state columns have width 0).  `Config
-.core_config` names the file of the second; none is the LSTM.
+Three cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
+(c, h) of every sequence start), and two over the blocks of models/mla_moe.py
+(zero start state: the ring's state columns have width 0):
+`models/kimi_linear.KimiLinearCore` and `models/deepseek_v3.DeepSeekV3Core`,
+where F' is the model's hidden size and not F.  `Config.core_config` names
+the file of either, which says which by its `model_type`; none is the LSTM.
 """
 
 from __future__ import annotations
@@ -127,19 +129,25 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 @functools.lru_cache(maxsize=None)
 def _load(path: str, compute_dtype: str):
+    from rainbow_iqn_apex_tpu.models.deepseek_v3 import (
+        DeepSeekV3Config,
+        DeepSeekV3Core,
+    )
     from rainbow_iqn_apex_tpu.models.kimi_linear import (
         KimiLinearConfig,
         KimiLinearCore,
     )
 
+    families = {"kimi_linear": (KimiLinearConfig, KimiLinearCore),
+                "deepseek_v3": (DeepSeekV3Config, DeepSeekV3Core)}
     found = path if os.path.exists(path) else os.path.join(_ROOT, path)
     with open(found) as f:
         cc = json.load(f)
-    if cc.get("model_type") != "kimi_linear":
+    if cc.get("model_type") not in families:
         raise ValueError(
             f"{path}: no core for model_type {cc.get('model_type')!r}")
-    return KimiLinearCore(KimiLinearConfig.from_dict(cc),
-                          jnp.dtype(compute_dtype))
+    reader, core = families[cc["model_type"]]
+    return core(reader.from_dict(cc), jnp.dtype(compute_dtype))
 
 
 def make_core(cfg):

@@ -1,0 +1,387 @@
+"""The blocks both non-LSTM cores of `R2D2Net` run (interface: models/cores.py):
+a pre-norm residual stack whose mixer is MLA (latent attention over a rolling
+window of latents) and whose feed-forward is a dense SwiGLU in the leading
+layers and sparse experts beside shared ones in the rest.
+
+Two cores are built from them: `models/kimi_linear.py` (three KDA mixers in
+four, defined there, and an un-rotated MLA in the fourth) and
+`models/deepseek_v3.py` (every mixer MLA with decoupled rotary keys, an input
+projection in the embedding's place).  Each reads its own published keys into
+one `CoreConfig`; which mixer a layer runs, whether the rope dimensions are
+rotated and whether the input is projected are read off it.
+
+MLA's per-lane state, float32, zero = initial (models/cores.zero_lanes): the
+window's latents `lat` [B, W, rank + rope] with the rope key UN-rotated, and
+their validity `valid` [B, W].  Where the configuration rotates
+(`rope_theta` > 0) the rotation is applied at use, by the slot: the key in
+slot s of `[window; new]` by s, the query of new step t by W + t.  A score
+depends on the difference of the two positions alone, so this is the
+published rotation by absolute position exactly, with no counter in the
+state and no angle over (W + T) x 1 rad however long a lane runs without a
+cut.  An episode cut inside a sequence is a segment boundary: steps interact
+only within a segment.
+
+The expert layer is told which experts it holds (`experts_here` from
+`first_expert`): it routes over all of them, sorts the assignments that fell
+on its own by expert and runs one grouped (ragged) product per projection.
+No capacity: the row buffer is chosen, by the count, among sizes of which the
+largest holds every assignment, so no token is ever dropped.  What absent
+experts would add is left out (the chip's share of an expert-parallel layer;
+tests/test_kimi_linear_core.py and tests/test_deepseek_v3_core.py add the
+shares up to the uncut layer).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Tuple
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from rainbow_iqn_apex_tpu.models.cores import CORE_STATS as STATS
+from rainbow_iqn_apex_tpu.obs import device_scopes
+
+HI = jax.lax.Precision.HIGHEST
+NEG = -1e30
+# row buffers of the grouped product, as multiples of the token count; the
+# last is top_k, which holds every assignment
+EXPERT_ROWS = (0.5, 2.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class CoreConfig:
+    """What the stack is built from; a family's reader fills it from its own
+    published keys (`KimiLinearConfig`, `DeepSeekV3Config`)."""
+
+    hidden: int
+    layers: int
+    first_dense: int
+    eps: float
+    mla_heads: int
+    nope: int
+    rope: int
+    v_dim: int
+    kv_rank: int
+    window: int
+    dense_width: int
+    experts: int
+    top_k: int
+    expert_width: int
+    shared_width: int
+    route_scale: float
+    experts_here: int
+    first_expert: int
+    rope_theta: float = 0.0  # 0: the rope dimensions are not rotated (NoPE)
+    in_proj: bool = False  # a projection of the trunk's features to `hidden`
+    # the layers (1-based) whose mixer is KDA (models/kimi_linear.py), and
+    # that mixer's sizes
+    kda_layers: Tuple[int, ...] = ()
+    kda_heads: int = 0
+    kda_dim: int = 0
+    conv_kernel: int = 0
+    low_rank: int = 0
+    chunk: int = 0
+    block: int = 0
+
+
+def _mm(eq: str, a, b, dtype):
+    """einsum on `dtype` operands, float32 accumulation."""
+    return jnp.einsum(
+        eq, a.astype(dtype), b.astype(dtype),
+        preferred_element_type=jnp.float32,
+        precision=HI if dtype == jnp.float32 else None)
+
+
+class _Linear(nn.Module):
+    features: int
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.features), jnp.float32)
+        return _mm("...i,io->...o", x, kernel, self.compute_dtype)
+
+
+class _RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps) * scale
+
+
+class _SwiGLU(nn.Module):
+    width: int
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        lin = lambda n, name: _Linear(n, self.compute_dtype, name=name)  # noqa: E731
+        h = jax.nn.silu(lin(self.width, "gate")(x)) * lin(self.width, "up")(x)
+        return lin(x.shape[-1], "down")(h)
+
+
+# ------------------------------------------------------------------- MLA
+def rotate_pairs(u, pos, theta: float):
+    """u [B, S, ..., d] with its adjacent pairs (u_2i, u_2i+1) turned by
+    pos[s] x theta^(-2i/d): the published `rope_interleave` rotation (which
+    permutes to halves and applies `rotate_half`: the same scores)."""
+    d = u.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = pos.astype(jnp.float32)[:, None] * freq  # [S, d/2]
+    angle = angle.reshape((1, pos.shape[0]) + (1,) * (u.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    pairs = u.reshape(*u.shape[:-1], d // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(u.shape)
+
+
+class _MLA(nn.Module):
+    kc: CoreConfig
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, x, state, seg):
+        kc, cd = self.kc, self.compute_dtype
+        b, t, _ = x.shape
+        h, w, rank = kc.mla_heads, kc.window, kc.kv_rank
+        with jax.named_scope(device_scopes.MLA_PROJ):
+            q = _Linear(h * (kc.nope + kc.rope), cd, name="q_proj")(x)
+            q = q.reshape(b, t, h, kc.nope + kc.rope)
+            kva = _Linear(rank + kc.rope, cd, name="kv_a")(x)
+            lat = jnp.concatenate(
+                [_RMSNorm(kc.eps, name="kv_norm")(kva[..., :rank]),
+                 kva[..., rank:]], axis=-1)
+        lat = jnp.concatenate([state["lat"], lat], axis=1)  # [B, W+T, .]
+        seg_all = jnp.concatenate([jnp.zeros((b, w), seg.dtype), seg], axis=1)
+        valid = jnp.concatenate(
+            [state["valid"], jnp.ones((b, t), jnp.float32)], axis=1)
+        with jax.named_scope(device_scopes.MLA_PROJ):
+            kv = _Linear(h * (kc.nope + kc.v_dim), cd, name="kv_b")(
+                lat[..., :rank]).reshape(b, w + t, h, kc.nope + kc.v_dim)
+
+        def rope(u, pos):
+            if not kc.rope_theta:
+                return u
+            with jax.named_scope(device_scopes.MLA_ROPE):
+                return rotate_pairs(u, pos, kc.rope_theta)
+
+        with jax.named_scope(device_scopes.MLA_ATTN):
+            scores = (_mm("bthd,bshd->bhts", q[..., : kc.nope],
+                          kv[..., : kc.nope], cd)
+                      + _mm("bthr,bsr->bhts",
+                            rope(q[..., kc.nope:], w + jnp.arange(t)),
+                            rope(lat[..., rank:], jnp.arange(w + t)), cd))
+            pos_q, pos_k = w + jnp.arange(t)[:, None], jnp.arange(w + t)[None]
+            mask = ((pos_k <= pos_q) & (pos_k > pos_q - w))[None] & (
+                valid[:, None, :] > 0) & (seg_all[:, None, :] == seg[:, :, None])
+            scores = jnp.where(
+                mask[:, None], scores / math.sqrt(kc.nope + kc.rope), NEG)
+            o = _mm("bhts,bshd->bthd", jax.nn.softmax(scores, axis=-1),
+                    kv[..., kc.nope:], cd)
+        with jax.named_scope(device_scopes.MLA_PROJ):
+            y = _Linear(kc.hidden, cd, name="o_proj")(
+                o.reshape(b, t, h * kc.v_dim))
+        self.sow(STATS, "mla_live_key_share", jnp.mean(mask, dtype=jnp.float32))
+        valid = valid * (seg_all == seg[:, -1:])
+        return y, {"lat": lat[:, t:], "valid": valid[:, t:]}
+
+
+# ---------------------------------------------------------- expert layer
+class _Router(nn.Module):
+    experts: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.experts), jnp.float32)
+        bias = self.param("select_bias", nn.initializers.zeros,
+                          (self.experts,), jnp.float32)
+        # float32 throughout: the choice of experts is discrete
+        return jax.nn.sigmoid(jnp.dot(x, kernel, precision=HI)), bias
+
+
+def _stacked_init(key, shape, dtype=jnp.float32):
+    return jax.random.normal(key, shape, dtype) / math.sqrt(shape[1])
+
+
+class _ExpertWeights(nn.Module):
+    """The held experts' stacked kernels (gate, up, down)."""
+
+    count: int
+    width: int
+    hidden: int
+
+    @nn.compact
+    def __call__(self):
+        n, f, w = self.count, self.hidden, self.width
+        return (self.param("gate", _stacked_init, (n, f, w)),
+                self.param("up", _stacked_init, (n, f, w)),
+                self.param("down", _stacked_init, (n, w, f)))
+
+
+def _grouped_swiglu(weights, xs, group_sizes, dtype):
+    """SwiGLU of rows sorted by expert: one ragged product a projection."""
+    gate, up, down = weights
+    rd = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+        a.astype(dtype), w.astype(dtype), group_sizes,
+        preferred_element_type=jnp.float32,
+        precision=HI if dtype == jnp.float32 else None)
+    return rd(jax.nn.silu(rd(xs, gate)) * rd(xs, up), down)
+
+
+class _MoE(nn.Module):
+    kc: CoreConfig
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        kc = self.kc
+        lead, f = x.shape[:-1], x.shape[-1]
+        x = x.reshape(-1, f)
+        n, k, held_n = x.shape[0], kc.top_k, kc.experts_here
+        with jax.named_scope(device_scopes.MOE_ROUTE):
+            s, bias = _Router(kc.experts, name="router")(x)
+            _, idx = jax.lax.top_k(s + bias, k)
+            sel = jnp.take_along_axis(s, idx, axis=-1)
+            w = sel / sel.sum(axis=-1, keepdims=True) * kc.route_scale
+            local = idx - kc.first_expert
+            held = (local >= 0) & (local < held_n)
+            key = jnp.where(held, local, held_n).reshape(-1)
+            order = jnp.argsort(key, stable=True)  # held first, by expert
+            group_sizes = jnp.bincount(key, length=held_n + 1)[:held_n]
+            n_held = group_sizes.sum()
+            w_sorted = (w * held).reshape(-1)[order]
+        weights = _ExpertWeights(held_n, kc.expert_width, f,
+                                 name="experts")()
+        cd = self.compute_dtype
+
+        def with_rows(rows):
+            def run(weights, x, order, w_sorted, group_sizes, n_held):
+                tok = order[:rows] // k
+                # rows past the held assignments belong to no group: the
+                # grouped product leaves them (and, backwards, their input's
+                # gradient) unwritten, so both sides are masked
+                live = (jnp.arange(rows) < n_held)[:, None]
+                xs = jnp.where(live, x.astype(cd)[tok], 0)
+                ys = _grouped_swiglu(
+                    weights, xs, group_sizes.astype(jnp.int32), cd)
+                ys = jnp.where(live, ys * w_sorted[:rows, None], 0.0)
+                return jnp.zeros_like(x).at[tok].add(ys)
+            return run
+
+        most = n * min(k, held_n)  # rows that hold every assignment
+        sizes = [most]
+        if most > 1024:  # a few tokens (the actor): one buffer is enough
+            sizes = sorted({min(most, int(n * r)) for r in EXPERT_ROWS} | {most})
+        with jax.named_scope(device_scopes.MOE_EXPERTS):
+            pick = sum((n_held > r).astype(jnp.int32) for r in sizes[:-1])
+            y = jax.lax.switch(pick, [with_rows(r) for r in sizes], weights,
+                               x, order, w_sorted, group_sizes, n_held)
+        with jax.named_scope(device_scopes.MOE_SHARED):
+            y = y + _SwiGLU(kc.shared_width, self.compute_dtype,
+                            name="shared")(x)
+        load = jnp.bincount(idx.reshape(-1), length=kc.experts)
+        rows_taken = jnp.asarray(sizes, jnp.int32)[pick]
+        self.sow(STATS, "moe_held_assign_share", n_held / (n * k))
+        self.sow(STATS, "moe_expert_load_max_over_mean",
+                 load.max() / (n * k / kc.experts))
+        self.sow(STATS, "moe_tokens_dropped",
+                 (n_held - jnp.minimum(n_held, rows_taken)).astype(jnp.float32))
+        return y.reshape(*lead, f)
+
+
+# ----------------------------------------------------------------- stack
+class _Layer(nn.Module):
+    kc: CoreConfig
+    index: int  # 1-based, as linear_attn_config counts
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, x, state, seg):
+        kc, cd = self.kc, self.compute_dtype
+        hn = _RMSNorm(kc.eps, name="mix_norm")(x)
+        if self.index in kc.kda_layers:
+            from rainbow_iqn_apex_tpu.models.kimi_linear import _KDA
+
+            y, state = _KDA(kc, cd, name="kda")(hn, state, seg)
+        else:
+            y, state = _MLA(kc, cd, name="mla")(hn, state, seg)
+        x = x + y
+        hn = _RMSNorm(kc.eps, name="ffn_norm")(x)
+        if self.index <= kc.first_dense:
+            x = x + _SwiGLU(kc.dense_width, cd, name="ffn")(hn)
+        else:
+            x = x + _MoE(kc, cd, name="moe")(hn)
+        return x, state
+
+
+class _Stack(nn.Module):
+    kc: CoreConfig
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, x, state, resets):
+        kc = self.kc
+        if kc.in_proj:  # where a language model has its embedding
+            with jax.named_scope(device_scopes.CORE_EMBED):
+                x = _Linear(kc.hidden, self.compute_dtype, name="in_proj")(x)
+        elif x.shape[-1] != kc.hidden:
+            raise ValueError(
+                f"the core's hidden size is {kc.hidden} and the trunk feeds "
+                f"it {x.shape[-1]} features: this configuration has no "
+                f"projection between them (80x80 frames give 2,304)")
+        seg = jnp.cumsum(resets.astype(jnp.int32), axis=1)
+        new_state = {}
+        for i in range(1, kc.layers + 1):
+            layer = nn.remat(_Layer)(kc, i, self.compute_dtype,
+                                     name=f"layer_{i}")
+            with jax.named_scope(device_scopes.CORE_LAYER):
+                x, new_state[f"layer_{i}"] = layer(
+                    x, state[f"layer_{i}"], seg)
+        return _RMSNorm(kc.eps, name="final_norm")(x), new_state
+
+
+
+
+class StackCore:
+    """The core interface (models/cores.py) over `_Stack`: zero start state,
+    nothing stored in the ring.  A family's core (`KimiLinearCore`,
+    `DeepSeekV3Core`) is a frozen dataclass of `kc` and `compute_dtype` that
+    names the counters it reports, `stat_names`: each is an output of the
+    compiled segment, so a core lists what its cell reads."""
+
+    stored_width = 0  # zero start state: the ring stores no state
+    moe_stat_names = ("moe_expert_load_max_over_mean", "moe_held_assign_share",
+                      "moe_tokens_dropped")
+
+    def initial_state(self, batch: int):
+        kc, z = self.kc, lambda *s: jnp.zeros((batch, *s), jnp.float32)  # noqa: E731
+        d = kc.kda_heads * kc.kda_dim
+        return {
+            f"layer_{i}": (
+                {"S": z(kc.kda_heads, kc.kda_dim, kc.kda_dim),
+                 "conv": z(kc.conv_kernel - 1, 3 * d)}
+                if i in kc.kda_layers else
+                {"lat": z(kc.window, kc.kv_rank + kc.rope),
+                 "valid": z(kc.window)})
+            for i in range(1, kc.layers + 1)}
+
+    def to_stored(self, state):
+        b = jax.tree.leaves(state)[0].shape[0]
+        return (jnp.zeros((b, 0), jnp.float32), jnp.zeros((b, 0), jnp.float32))
+
+    def from_stored(self, init_c, init_h):
+        return self.initial_state(init_c.shape[0])
+
+    def __call__(self, x, state, resets):
+        return _Stack(self.kc, self.compute_dtype, name="core")(
+            x, state, resets)

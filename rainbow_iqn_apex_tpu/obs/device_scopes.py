@@ -51,11 +51,15 @@ REPLAY_WRITEBACK = "replay_writeback"  # the priority scatter
 LEARN_STEP = "learn_step"  # forward, loss, backward, optimizer, target copy
 NET_TRUNK = "net_trunk"  # conv trunk
 LSTM_SCAN = "lstm_scan"  # the lax.scan over the LSTM cell (R2D2)
-# ---- the Kimi-Linear core (models/kimi_linear.py)
+# ---- the Kimi-Linear and DeepSeek-V3 cores (models/mla_moe.py,
+# models/kimi_linear.py)
+CORE_EMBED = "core_embed"  # the input projection in the embedding's place
 CORE_LAYER = "core_layer"  # one pre-norm block: mixer + feed-forward
 KDA_SCAN = "kda_scan"  # the chunked delta-rule recurrence of a sequence
 KDA_PREP = "kda_prep"  # inside it: the in-chunk preparation (WY factors)
+MLA_PROJ = "mla_proj"  # q, kv_a with kv_norm, kv_b over [window; new], o
 MLA_ATTN = "mla_attn"  # scores, mask, softmax, values over the latent window
+MLA_ROPE = "mla_rope"  # inside it: the rope dimensions turned by their slot
 MOE_ROUTE = "moe_route"  # router, top-k, the sort by held expert
 MOE_EXPERTS = "moe_experts"  # gather, the grouped products, scatter-add
 MOE_SHARED = "moe_shared"  # the shared expert
@@ -69,6 +73,7 @@ ALL_SCOPES = TICK_SCOPES + (
     REPLAY_DRAW, REPLAY_GATHER, REPLAY_WRITEBACK, LEARN_STEP, NET_TRUNK,
     LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
+    CORE_EMBED, MLA_PROJ, MLA_ROPE,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 
