@@ -10,8 +10,14 @@ exploration story.
 
 TPU-first notes:
 - Time unrolling is a `lax.scan` over an `OptimizedLSTMCell` step inside one
-  jit: [B, T, H, W, C] -> conv trunk applied as one [B*T] batch (single big
-  MXU GEMM per layer), then the scan carries only the small LSTM state.
+  jit: [B, T, H, W, C] -> conv trunk applied as one [B*T] batch (one conv a
+  layer over all steps), then the scan carries only the small LSTM state.
+- A learn step hands the trunk the ring's single frames [B, T, H, W, 1] with
+  the history-1 frames before them (`frames_before`), and the first conv
+  reads the history from those (`layers.StemConv`): stacking them per pixel
+  first cost the step four passes over a 4x copy of the batch (PERF.md,
+  PR 33).  The actor's tick feeds one already-stacked step and takes the
+  plain conv; both read the one `Conv_0` kernel.
 - Recurrent state is an explicit (c, h) pair the caller owns — nothing hidden
   in module state, so actor-side stored-state replay and burn-in are pure
   data plumbing.
@@ -26,7 +32,11 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from rainbow_iqn_apex_tpu.models.cores import LSTMCore
-from rainbow_iqn_apex_tpu.models.layers import ConvTrunk, NoisyLinear
+from rainbow_iqn_apex_tpu.models.layers import (
+    ConvTrunk,
+    NoisyLinear,
+    unit_frames,
+)
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
 Dtype = Any
@@ -56,17 +66,24 @@ class R2D2Net(nn.Module):
         obs_seq: jnp.ndarray,  # [B, T, H, W, C] uint8 (or float in [0,1])
         state: Any,
         resets: Optional[jnp.ndarray] = None,  # [B, T] bool: reset state BEFORE step t
+        frames_before: Optional[jnp.ndarray] = None,  # [B, C-1, H, W, 1]
     ) -> Tuple[jnp.ndarray, Any]:
-        """Returns (q_values [B, T, A] fp32, the core's final state)."""
+        """Returns (q_values [B, T, A] fp32, the core's final state).
+
+        With `frames_before`, `obs_seq` is the single frames [B, T, H, W, 1]
+        of a stored sequence and `frames_before` the history-1 frames that
+        precede its first step (zeros at a sequence's start): the trunk reads
+        the history from them and no stack is made (`layers.StemConv`)."""
         B, T = obs_seq.shape[:2]
-        if obs_seq.dtype == jnp.uint8:
-            obs_seq = obs_seq.astype(self.compute_dtype) * (1.0 / 255.0)
+        if frames_before is None:
+            if obs_seq.dtype == jnp.uint8:
+                obs_seq = unit_frames(obs_seq, self.compute_dtype)
+            obs_seq = obs_seq.reshape(B * T, *obs_seq.shape[2:])
 
         # conv trunk over the folded [B*T] batch: one large GEMM per layer
         with jax.named_scope(device_scopes.NET_TRUNK):
             phi = ConvTrunk(compute_dtype=self.compute_dtype)(
-                obs_seq.reshape(B * T, *obs_seq.shape[2:])
-            )
+                obs_seq, frames_before)
         phi = phi.reshape(B, T, -1).astype(jnp.float32)  # cores carry fp32
         if resets is None:
             resets = jnp.zeros((B, T), bool)

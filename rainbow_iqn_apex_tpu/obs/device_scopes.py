@@ -50,6 +50,7 @@ REPLAY_WRITEBACK = "replay_writeback"  # the priority scatter
 # ---- the learn step (ops/learn.py, ops/r2d2.py) and the networks
 LEARN_STEP = "learn_step"  # forward, loss, backward, optimizer, target copy
 NET_TRUNK = "net_trunk"  # conv trunk
+NET_STEM = "net_stem"  # inside it: the first conv with its input side
 LSTM_SCAN = "lstm_scan"  # the lax.scan over the LSTM cell (R2D2)
 # ---- the Kimi-Linear and DeepSeek-V3 cores (models/mla_moe.py,
 # models/kimi_linear.py)
@@ -73,7 +74,7 @@ ALL_SCOPES = TICK_SCOPES + (
     REPLAY_DRAW, REPLAY_GATHER, REPLAY_WRITEBACK, LEARN_STEP, NET_TRUNK,
     LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
-    CORE_EMBED, MLA_PROJ, MLA_ROPE,
+    CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 

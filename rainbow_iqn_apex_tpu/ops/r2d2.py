@@ -27,6 +27,7 @@ from rainbow_iqn_apex_tpu.models.cores import (
     make_core,
     reduce_stats,
 )
+from rainbow_iqn_apex_tpu.models.layers import ConvTrunk, stack_history
 from rainbow_iqn_apex_tpu.models.r2d2 import R2D2Net
 from rainbow_iqn_apex_tpu.obs import device_scopes
 from rainbow_iqn_apex_tpu.ops.learn import make_optimizer
@@ -63,23 +64,35 @@ class SequenceBatch:
 
 
 def stack_seq_frames(obs_seq: jnp.ndarray, history: int) -> jnp.ndarray:
-    """Within-sequence frame stacking on device: [B, L, H, W, 1] ->
-    [B, L, H, W, history], channel k holding the frame from t-(history-1-k).
+    """The definition of what a learn step sees of a stored sequence:
+    [B, L, H, W, 1] -> [B, L, H, W, history], channel k holding the frame
+    from t-(history-1-k).
 
     The R2D2 paper feeds 4-stacked frames AND an LSTM; sequences are stored
-    as single frames (dedup) and the stack is rebuilt here as shifted slices
-    — static shapes, fused by XLA, no extra HBM-resident copies on the host
-    path. Steps earlier than the sequence start zero-pad, which only touches
-    the first history-1 steps of the burn-in region (burn_in >= history-1 in
-    any sane config), whose sole job is LSTM warm-up.
+    as single frames (dedup).  Steps earlier than the sequence start are
+    zero, which only touches the first history-1 steps of the burn-in region
+    (burn_in >= history-1, `build_r2d2_learn_step` insists), whose sole job
+    is LSTM warm-up.  The learn step does not make this array: the trunk's
+    first conv reads the same history from the single frames
+    (`layers.StemConv`), and tests hold it to this function.
     """
     if history <= 1:
         return obs_seq
-    shifted = [
-        jnp.pad(obs_seq[:, : obs_seq.shape[1] - k], ((0, 0), (k, 0), (0, 0), (0, 0), (0, 0)))
-        for k in range(history - 1, -1, -1)
-    ]
-    return jnp.concatenate(shifted, axis=-1)
+    return stack_history(obs_seq, jnp.zeros_like(obs_seq[:, : history - 1]))
+
+
+def stem_from_frames_share(
+    cfg: Config, frame_shape: Tuple[int, int], learner_chips: int = 1
+) -> float:
+    """For the trainers' `learn` rows: 1.0 where the learn step, handed a
+    ring's single frames of `frame_shape` with its batch on `learner_chips`
+    chips, compiles the first conv's reading from the frames
+    (`layers.StemConv`); 0.0 where it runs the plain conv on stacks (history
+    1, a frame the conv's stride does not divide, a batch split over a
+    mesh).  A fact of the trace: the host writes it, the step puts nothing
+    out for it."""
+    return float(cfg.history_length > 1 and ConvTrunk.stem_reads_frames(
+        *frame_shape, chips=learner_chips))
 
 
 def to_device_seq_batch(s) -> "SequenceBatch":
@@ -146,10 +159,18 @@ def _unroll(
     batch: SequenceBatch,
     burn_in: int,
     noise_key: chex.PRNGKey,
+    history: int = 1,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Burn-in (stop-grad) then train unroll; returns q [B, T, A] for the
     train slice and the counters the core sowed over it.  The core's state
-    resets where a step follows a terminal."""
+    resets where a step follows a terminal.  Where `batch.obs` holds single
+    frames of a `history` > 1 (stacked input passes through as it is), each
+    pass is handed the history-1 frames before its first step: zeros, then
+    the burn-in's last ones."""
+    before_burn = before_train = None
+    if history > 1 and batch.obs.shape[-1] == 1:
+        before_train = batch.obs[:, burn_in - (history - 1):burn_in]
+        before_burn = jnp.zeros_like(before_train)
     # reset BEFORE step t when the previous step ended the episode
     prev_done = jnp.concatenate(
         [jnp.zeros_like(batch.done[:, :1]), batch.done[:, :-1]], axis=1
@@ -162,6 +183,7 @@ def _unroll(
             batch.obs[:, :burn_in],
             state,
             resets=prev_done[:, :burn_in],
+            frames_before=before_burn,
             rngs={"noise": kb},
         )
         state = jax.lax.stop_gradient(state)
@@ -170,6 +192,7 @@ def _unroll(
         batch.obs[:, burn_in:],
         state,
         resets=prev_done[:, burn_in:],
+        frames_before=before_train,
         rngs={"noise": kt},
         mutable=[CORE_STATS],
     )
@@ -198,19 +221,18 @@ def build_r2d2_learn_step(
     @jax.named_scope(device_scopes.LEARN_STEP)
     def learn_step(state: R2D2TrainState, batch: SequenceBatch, key: chex.PRNGKey):
         k_on, k_tgt = jax.random.split(key)
-        if history > 1 and batch.obs.shape[-1] == 1:
-            # single-frame stored sequences -> stacked network input
-            batch = batch.replace(obs=stack_seq_frames(batch.obs, history))
         T = batch.obs.shape[1] - burn  # train slice length
 
         def loss_fn(params):
-            q_on, core_stats = _unroll(net, params, batch, burn, k_on)  # [B, T, A]
+            q_on, core_stats = _unroll(
+                net, params, batch, burn, k_on, history)  # [B, T, A]
             # Double-Q selection reuses the online unroll (stop-grad) rather
             # than paying a third full conv+LSTM unroll for an independent
             # noise draw — selection and evaluation already use different
             # nets, which is where double-Q's bias correction comes from.
             q_sel = jax.lax.stop_gradient(q_on)
-            q_tgt, _ = _unroll(net, state.target_params, batch, burn, k_tgt)
+            q_tgt, _ = _unroll(
+                net, state.target_params, batch, burn, k_tgt, history)
 
             a = batch.action[:, burn:]  # [B, T]
             r = batch.reward[:, burn:]

@@ -36,6 +36,7 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import (
     build_r2d2_act_step,
     build_r2d2_learn_step,
     init_r2d2_state,
+    stem_from_frames_share,
     to_device_seq_batch,
 )
 from rainbow_iqn_apex_tpu.parallel.mesh import (
@@ -824,6 +825,8 @@ def train_apex_r2d2(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, 
                             mean_return=float(np.mean(returns)) if returns else float("nan"),
                             sequences=len(memory),
                             staleness=step - last_pub,
+                            stem_from_frames_share=stem_from_frames_share(
+                                cfg, env.frame_shape, driver.lmesh.size),
                         )
                         obs_run.periodic(
                             step,

@@ -48,6 +48,7 @@ from rainbow_iqn_apex_tpu.obs import RunObs, device_scopes
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     build_r2d2_act_step,
     init_r2d2_state,
+    stem_from_frames_share,
 )
 from rainbow_iqn_apex_tpu.parallel.multihost import shift_stack
 from rainbow_iqn_apex_tpu.replay.device_sequence import (
@@ -426,6 +427,8 @@ def train_anakin_r2d2(cfg: Config,
                     mean_return=float(np.mean(returns)) if returns else float("nan"),
                     append_emit_tick_share=emit_share,
                     core_state_bytes_per_lane=state_bytes,
+                    stem_from_frames_share=stem_from_frames_share(
+                        cfg, (h, w), n_dev),
                     **{n: nanmean(v)
                        for n, v in zip(core.stat_names, counters)},
                 )
@@ -596,6 +599,8 @@ def _train_anakin_r2d2_hostfed(cfg: Config,
                             grad_norm=float(info["grad_norm"]),
                             mean_return=float(np.mean(returns))
                             if returns else float("nan"),
+                            stem_from_frames_share=stem_from_frames_share(
+                                cfg, (h, w)),
                         )
                         obs_run.periodic(learn_steps, frames)
                     if cfg.eval_interval and learn_steps % cfg.eval_interval == 0:

@@ -26,6 +26,7 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import (
     build_r2d2_act_step,
     build_r2d2_learn_step,
     init_r2d2_state,
+    stem_from_frames_share,
     to_device_seq_batch,
 )
 from rainbow_iqn_apex_tpu.replay.sequence import SequenceReplay
@@ -221,6 +222,8 @@ def train_r2d2(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, Any]:
                             q_mean=float(info["q_mean"]),
                             mean_return=float(np.mean(returns)) if returns else float("nan"),
                             sequences=len(memory),
+                            stem_from_frames_share=stem_from_frames_share(
+                                cfg, env.frame_shape),
                         )
                         obs_run.periodic(step, frames, replay_size=len(memory))
                     if cfg.checkpoint_interval and step % cfg.checkpoint_interval == 0:

@@ -6,6 +6,7 @@ kernels compiled at the published widths for a described v5e."""
 
 import functools
 import json
+import math
 import os
 from unittest import mock
 
@@ -238,3 +239,63 @@ def test_the_sequence_rings_loop_needs_no_ring_sized_temporary(one_chip):
                 for line in compiled.as_text().splitlines()
                 if f" = u8[{cap + 1},{length}," in line}
     assert ring_ops.isdisjoint({"copy", "slice", "transpose"}), ring_ops
+
+
+def test_the_learn_step_reads_the_first_conv_from_the_stored_frames(one_chip):
+    """`r2d2-fused`'s learn step at its shapes (64 sequences of 120 single
+    80x80 frames, LSTM 512, history 4) compiled for the chip.  Until the stem
+    (`layers.StemConv`) the step wrote the batch's 49 MB of frames four times
+    over as `u8[64,120,80,80,4]`, cast that, and turned the cast to the conv's
+    layout in two `copy` of `bf16[...,80,80,4]`: 3.7 of its 16.1 ms.  Now no
+    array holds the history per pixel, the step's temporaries are no more
+    than they were (1,249,761,792 B), and the first conv is found under
+    net_trunk/net_stem, where the `device_time` row prices it."""
+    import re
+
+    from rainbow_iqn_apex_tpu.config import Config
+    from rainbow_iqn_apex_tpu.ops import r2d2 as ops
+
+    cfg = Config(architecture="r2d2", compute_dtype="bfloat16", lstm_size=512,
+                 hidden_size=512, history_length=4, r2d2_burn_in=40,
+                 r2d2_seq_len=80, r2d2_overlap=40, batch_size=64, multi_step=5)
+    b, length, hw, actions = 64, 120, (80, 80), 3
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    sd = jax.ShapeDtypeStruct
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    state = jax.eval_shape(
+        lambda k: ops.init_r2d2_state(cfg, actions, k, hw), key)
+    assert state.params["ConvTrunk_0"]["Conv_0"]["kernel"].shape == (8, 8, 4, 32)
+    batch = ops.SequenceBatch(
+        obs=sd((b, length, *hw, 1), jnp.uint8),
+        action=sd((b, length), jnp.int32), reward=sd((b, length), jnp.float32),
+        done=sd((b, length), jnp.bool_), valid=sd((b, length), jnp.bool_),
+        init_c=sd((b, 512), jnp.float32), init_h=sd((b, 512), jnp.float32),
+        weight=sd((b,), jnp.float32))
+    compiled = jax.jit(ops.build_r2d2_learn_step(cfg, actions)).lower(
+        shaped(state), shaped(batch), shaped(key)).compile()
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_249_761_792
+    frame_bytes = b * length * hw[0] * hw[1]
+    results = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \(?(\w+)\[([\d,]*)\]\S* (\S+?)\(",
+        text, re.M)
+    assert len(results) > 1000
+    largest_u8 = max(math.prod(int(d) for d in dims.split(",") if d)
+                     for _, dtype, dims, _ in results if dtype == "u8")
+    assert largest_u8 < 4 * frame_bytes, largest_u8
+    stacked_copies = [name for name, dtype, dims, op in results
+                      if op == "copy" and dtype == "bf16"
+                      and dims.endswith(f",{hw[0]},{hw[1]},4")]
+    assert not stacked_copies, stacked_copies
+    scopes = device_scopes.instruction_scopes(text)
+    first_convs = [
+        device_scopes.instruction_name(line.strip().removeprefix("ROOT "))
+        for line in text.splitlines()
+        if " convolution(" in line and "/Conv_0/" in line]
+    assert len(first_convs) >= 2, first_convs
+    for name in first_convs:
+        path = scopes[name]
+        assert ("net_trunk", "net_stem") in set(zip(path, path[1:])), (
+            name, path)
