@@ -15,18 +15,20 @@ A core sits between the conv trunk and the dueling heads:
 modules in the net's scope, so the LSTM's parameters stay `lstm/cell/...`
 leaf for leaf.
 
-Three cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
-(c, h) of every sequence start), and two over the blocks of models/mla_moe.py
-(zero start state: the ring's state columns have width 0):
-`models/kimi_linear.KimiLinearCore` and `models/deepseek_v3.DeepSeekV3Core`,
-where F' is the model's hidden size and not F.  `Config.core_config` names
-the file of either, which says which by its `model_type`; none is the LSTM.
+Four cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
+(c, h) of every sequence start), and three over the blocks of
+models/mla_moe.py (zero start state: the ring's state columns have width 0):
+`models/kimi_linear.KimiLinearCore`, `models/deepseek_v3.DeepSeekV3Core` and
+`models/qwen3_next.Qwen3NextCore`, where F' is the model's hidden size and not
+F.  `Config.core_config` names the file of one, which says which by its
+`model_type`; none is the LSTM.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 import json
 import os
 from typing import Any, Tuple
@@ -127,27 +129,28 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+# model_type -> (module under models/, its reader of the published keys, its
+# core); only the module of the family a file names is imported
+FAMILIES = {
+    "kimi_linear": ("kimi_linear", "KimiLinearConfig", "KimiLinearCore"),
+    "deepseek_v3": ("deepseek_v3", "DeepSeekV3Config", "DeepSeekV3Core"),
+    "qwen3_next": ("qwen3_next", "Qwen3NextConfig", "Qwen3NextCore"),
+}
+
+
 @functools.lru_cache(maxsize=None)
 def _load(path: str, compute_dtype: str):
-    from rainbow_iqn_apex_tpu.models.deepseek_v3 import (
-        DeepSeekV3Config,
-        DeepSeekV3Core,
-    )
-    from rainbow_iqn_apex_tpu.models.kimi_linear import (
-        KimiLinearConfig,
-        KimiLinearCore,
-    )
-
-    families = {"kimi_linear": (KimiLinearConfig, KimiLinearCore),
-                "deepseek_v3": (DeepSeekV3Config, DeepSeekV3Core)}
     found = path if os.path.exists(path) else os.path.join(_ROOT, path)
     with open(found) as f:
         cc = json.load(f)
-    if cc.get("model_type") not in families:
+    if cc.get("model_type") not in FAMILIES:
         raise ValueError(
             f"{path}: no core for model_type {cc.get('model_type')!r}")
-    reader, core = families[cc["model_type"]]
-    return core(reader.from_dict(cc), jnp.dtype(compute_dtype))
+    module, reader, core = FAMILIES[cc["model_type"]]
+    family = importlib.import_module(
+        "rainbow_iqn_apex_tpu.models." + module)
+    return getattr(family, core)(
+        getattr(family, reader).from_dict(cc), jnp.dtype(compute_dtype))
 
 
 def make_core(cfg):

@@ -24,7 +24,7 @@ from typing import Any, Dict
 
 import jax.numpy as jnp
 
-from rainbow_iqn_apex_tpu.models.mla_moe import CoreConfig, StackCore
+from rainbow_iqn_apex_tpu.models.mla_moe import _MLA, CoreConfig, StackCore
 
 
 class DeepSeekV3Config(CoreConfig):
@@ -40,7 +40,7 @@ class DeepSeekV3Config(CoreConfig):
             raise ValueError("a low-rank query projection and a scaled "
                              "rotation are not written")
         return cls(
-            hidden=cc["hidden_size"], layers=cc["layers_here"],
+            hidden=cc["hidden_size"], mixers=(_MLA,) * cc["layers_here"],
             first_dense=cc["first_k_dense_replace"], eps=cc["rms_norm_eps"],
             mla_heads=cc["num_attention_heads"], nope=cc["qk_nope_head_dim"],
             rope=cc["qk_rope_head_dim"], v_dim=cc["v_head_dim"],

@@ -45,6 +45,7 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (  # noqa: F401  (the blocks' ol
     CoreConfig,
     StackCore,
     _Linear,
+    _MLA,
     _mm,
     _MoE,
     _RMSNorm,
@@ -62,8 +63,9 @@ class KimiLinearConfig(CoreConfig):
     def from_dict(cls, cc: Dict[str, Any]) -> "KimiLinearConfig":
         la, assumed = cc["linear_attn_config"], cc.get("assumed", {})
         return cls(
-            hidden=cc["hidden_size"], layers=cc["layers_here"],
-            kda_layers=tuple(la["kda_layers"]),
+            hidden=cc["hidden_size"],
+            mixers=tuple(_KDA if i in la["kda_layers"] else _MLA
+                         for i in range(1, cc["layers_here"] + 1)),
             first_dense=cc["first_k_dense_replace"], eps=cc["rms_norm_eps"],
             kda_heads=la["num_heads"], kda_dim=la["head_dim"],
             conv_kernel=la["short_conv_kernel_size"],
@@ -95,6 +97,25 @@ class _Taps(nn.Module):
             "taps", lambda k, s: jax.random.uniform(k, s, jnp.float32,
                                                     -bound, bound),
             (self.kernel, self.channels))
+
+
+def _causal_conv(z, taps, tail, seg):
+    """Causal depthwise convolution of z [B, T, C] after the last K-1 steps
+    `tail` [B, K-1, C] of the lane's past, over the steps of a step's own
+    segment: out_t = sum_j taps[j] z_{t-j}.  Returns (out, the new tail)."""
+    b, t, _ = z.shape
+    kk = taps.shape[0]
+    zin = jnp.concatenate([tail, z], axis=1)  # [B, K-1+T, C]
+    sin = jnp.concatenate([jnp.zeros((b, kk - 1), seg.dtype), seg], axis=1)
+    conv = sum(
+        taps[j] * zin[:, kk - 1 - j: kk - 1 - j + t]
+        * (sin[:, kk - 1 - j: kk - 1 - j + t] == seg)[..., None]
+        for j in range(kk))
+    return conv, zin[:, t:] * (sin[:, t:] == seg[:, -1:])[..., None]
+
+
+def _a_log_init(key, shape):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
 
 
 def _l2_norm(x):
@@ -252,6 +273,15 @@ class _KDA(nn.Module):
     kc: CoreConfig
     compute_dtype: Any
 
+    layer_name = "kda"
+
+    @staticmethod
+    def zero_state(kc: CoreConfig, batch: int):
+        return {"S": jnp.zeros((batch, kc.kda_heads, kc.kda_dim, kc.kda_dim),
+                               jnp.float32),
+                "conv": jnp.zeros((batch, kc.conv_kernel - 1,
+                                   3 * kc.kda_heads * kc.kda_dim), jnp.float32)}
+
     @nn.compact
     def __call__(self, x, state, seg):
         kc, cd = self.kc, self.compute_dtype
@@ -263,22 +293,13 @@ class _KDA(nn.Module):
             [_Linear(d, cd, name=f"{n}_proj")(x) for n in names], axis=-1)
         taps = jnp.concatenate(
             [_Taps(kk, d, name=f"{n}_conv")() for n in names], axis=-1)
-        zin = jnp.concatenate([state["conv"], z], axis=1)  # [B, K-1+T, 3d]
-        sin = jnp.concatenate(
-            [jnp.zeros((b, kk - 1), seg.dtype), seg], axis=1)
-        conv = sum(
-            taps[j] * zin[:, kk - 1 - j: kk - 1 - j + t]
-            * (sin[:, kk - 1 - j: kk - 1 - j + t] == seg)[..., None]
-            for j in range(kk))
-        tail = zin[:, t:] * (sin[:, t:] == seg[:, -1:])[..., None]
+        conv, tail = _causal_conv(z, taps, state["conv"], seg)
         q, k, v = (y.reshape(b, t, h, dk)
                    for y in jnp.split(jax.nn.silu(conv), 3, axis=-1))
         q, k = _l2_norm(q), _l2_norm(k)
         low = lambda a, bb, n: _Linear(n, cd, name=bb)(  # noqa: E731
             _Linear(kc.low_rank, cd, name=a)(x))
-        a_log = self.param(
-            "A_log", lambda key, s: jnp.log(
-                jax.random.uniform(key, s, jnp.float32, 1.0, 16.0)), (h,))
+        a_log = self.param("A_log", _a_log_init, (h,))
         dt_bias = self.param("dt_bias", _dt_bias_init, (d,))
         g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
             (low("f_a", "f_b", d) + dt_bias).reshape(b, t, h, dk))

@@ -1,14 +1,24 @@
-"""The blocks both non-LSTM cores of `R2D2Net` run (interface: models/cores.py):
-a pre-norm residual stack whose mixer is MLA (latent attention over a rolling
-window of latents) and whose feed-forward is a dense SwiGLU in the leading
-layers and sparse experts beside shared ones in the rest.
+"""The blocks the non-LSTM cores of `R2D2Net` run (interface: models/cores.py):
+a pre-norm residual stack in which every layer names its mixer, and whose
+feed-forward is a dense SwiGLU in the leading layers and sparse experts
+beside shared ones in the rest.  The MLA mixer (latent attention over a
+rolling window of latents) lives here.
 
-Two cores are built from them: `models/kimi_linear.py` (three KDA mixers in
-four, defined there, and an un-rotated MLA in the fourth) and
+Three cores are built from them: `models/kimi_linear.py` (three KDA mixers in
+four, defined there, and an un-rotated MLA in the fourth),
 `models/deepseek_v3.py` (every mixer MLA with decoupled rotary keys, an input
-projection in the embedding's place).  Each reads its own published keys into
-one `CoreConfig`; which mixer a layer runs, whether the rope dimensions are
-rotated and whether the input is projected are read off it.
+projection in the embedding's place) and `models/qwen3_next.py` (three Gated
+DeltaNet mixers in four and a gated softmax attention in the fourth, both
+defined there; softmax routing and a gated shared expert).  Each reads its
+own published keys into one `CoreConfig`; which mixer a layer runs, whether
+the rope dimensions are rotated, how the router scores and whether the input
+is projected are read off it.
+
+A mixer is a flax module `Mixer(kc, compute_dtype)` called as
+`(x [B, T, hidden], state, seg [B, T]) -> (y, state)` that says under which
+name it stands in its layer (`layer_name`) and what its per-lane state is at
+the start (`zero_state(kc, batch)`: float32, zero = initial, every leaf led
+by the lane axis, so models/cores.zero_lanes resets a lane).
 
 MLA's per-lane state, float32, zero = initial (models/cores.zero_lanes): the
 window's latents `lat` [B, W, rank + rope] with the rope key UN-rotated, and
@@ -24,6 +34,9 @@ only within a segment.
 The expert layer is told which experts it holds (`experts_here` from
 `first_expert`): it routes over all of them, sorts the assignments that fell
 on its own by expert and runs one grouped (ragged) product per projection.
+The scores are sigmoids or a softmax over all the experts (`route`), the
+chosen ones' weights their scores over their sum, times `route_scale`; the
+shared expert is added as it is or weighed by a sigmoid gate (`shared_gate`).
 No capacity: the row buffer is chosen, by the count, among sizes of which the
 largest holds every assignment, so no token is ever dropped.  What absent
 experts would add is left out (the chip's share of an expert-parallel layer;
@@ -54,37 +67,55 @@ EXPERT_ROWS = (0.5, 2.0)
 @dataclasses.dataclass(frozen=True)
 class CoreConfig:
     """What the stack is built from; a family's reader fills it from its own
-    published keys (`KimiLinearConfig`, `DeepSeekV3Config`)."""
+    published keys (`KimiLinearConfig`, `DeepSeekV3Config`,
+    `Qwen3NextConfig`).  A mixer's sizes are read by that mixer alone, so a
+    family leaves the others' at their zeros."""
 
     hidden: int
-    layers: int
-    first_dense: int
+    mixers: Tuple[Any, ...]  # the mixer module of each layer, in order
     eps: float
-    mla_heads: int
-    nope: int
-    rope: int
-    v_dim: int
-    kv_rank: int
-    window: int
-    dense_width: int
     experts: int
     top_k: int
     expert_width: int
     shared_width: int
-    route_scale: float
     experts_here: int
-    first_expert: int
-    rope_theta: float = 0.0  # 0: the rope dimensions are not rotated (NoPE)
+    first_expert: int = 0
+    first_dense: int = 0  # the leading layers whose feed-forward is dense
+    dense_width: int = 0
+    route: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax"
+    route_scale: float = 1.0
+    shared_gate: bool = False  # the shared expert weighed by a sigmoid gate
     in_proj: bool = False  # a projection of the trunk's features to `hidden`
-    # the layers (1-based) whose mixer is KDA (models/kimi_linear.py), and
-    # that mixer's sizes
-    kda_layers: Tuple[int, ...] = ()
-    kda_heads: int = 0
-    kda_dim: int = 0
+    window: int = 0  # slots of an attention mixer's rolling window
+    rope_theta: float = 0.0  # 0: the rope dimensions are not rotated (NoPE)
+    # MLA (`_MLA`, here)
+    mla_heads: int = 0
+    nope: int = 0
+    rope: int = 0
+    v_dim: int = 0
+    kv_rank: int = 0
+    # the delta-rule mixers: the convolution and the chunked scan of both,
+    # then KDA's sizes (models/kimi_linear.py) and Gated DeltaNet's
+    # (models/qwen3_next.py)
     conv_kernel: int = 0
-    low_rank: int = 0
     chunk: int = 0
     block: int = 0
+    kda_heads: int = 0
+    kda_dim: int = 0
+    low_rank: int = 0
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    # gated softmax attention (models/qwen3_next.py)
+    attn_heads: int = 0
+    attn_kv_heads: int = 0
+    attn_head_dim: int = 0
+    attn_rotary_dim: int = 0
+
+    @property
+    def layers(self) -> int:
+        return len(self.mixers)
 
 
 def _mm(eq: str, a, b, dtype):
@@ -129,15 +160,22 @@ class _SwiGLU(nn.Module):
 
 
 # ------------------------------------------------------------------- MLA
+def rope_cos_sin(u, pos, theta: float):
+    """(cos, sin) of the angles pos[s] x theta^(-2i/d), i < d/2, shaped to
+    broadcast against u [B, S, ..., d/2]."""
+    d = u.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = pos.astype(jnp.float32)[:, None] * freq  # [S, d/2]
+    angle = angle.reshape((1, pos.shape[0]) + (1,) * (u.ndim - 3) + (d // 2,))
+    return jnp.cos(angle), jnp.sin(angle)
+
+
 def rotate_pairs(u, pos, theta: float):
     """u [B, S, ..., d] with its adjacent pairs (u_2i, u_2i+1) turned by
     pos[s] x theta^(-2i/d): the published `rope_interleave` rotation (which
     permutes to halves and applies `rotate_half`: the same scores)."""
     d = u.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = pos.astype(jnp.float32)[:, None] * freq  # [S, d/2]
-    angle = angle.reshape((1, pos.shape[0]) + (1,) * (u.ndim - 3) + (d // 2,))
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    cos, sin = rope_cos_sin(u, pos, theta)
     pairs = u.reshape(*u.shape[:-1], d // 2, 2)
     a, b = pairs[..., 0], pairs[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
@@ -147,6 +185,14 @@ def rotate_pairs(u, pos, theta: float):
 class _MLA(nn.Module):
     kc: CoreConfig
     compute_dtype: Any
+
+    layer_name = "mla"
+
+    @staticmethod
+    def zero_state(kc: CoreConfig, batch: int):
+        return {"lat": jnp.zeros((batch, kc.window, kc.kv_rank + kc.rope),
+                                 jnp.float32),
+                "valid": jnp.zeros((batch, kc.window), jnp.float32)}
 
     @nn.compact
     def __call__(self, x, state, seg):
@@ -198,6 +244,7 @@ class _MLA(nn.Module):
 # ---------------------------------------------------------- expert layer
 class _Router(nn.Module):
     experts: int
+    scores: str = "sigmoid"  # or "softmax" over all the experts
 
     @nn.compact
     def __call__(self, x):
@@ -206,7 +253,10 @@ class _Router(nn.Module):
         bias = self.param("select_bias", nn.initializers.zeros,
                           (self.experts,), jnp.float32)
         # float32 throughout: the choice of experts is discrete
-        return jax.nn.sigmoid(jnp.dot(x, kernel, precision=HI)), bias
+        logits = jnp.dot(x, kernel, precision=HI)
+        if self.scores == "softmax":
+            return jax.nn.softmax(logits, axis=-1), bias
+        return jax.nn.sigmoid(logits), bias
 
 
 def _stacked_init(key, shape, dtype=jnp.float32):
@@ -249,7 +299,7 @@ class _MoE(nn.Module):
         x = x.reshape(-1, f)
         n, k, held_n = x.shape[0], kc.top_k, kc.experts_here
         with jax.named_scope(device_scopes.MOE_ROUTE):
-            s, bias = _Router(kc.experts, name="router")(x)
+            s, bias = _Router(kc.experts, kc.route, name="router")(x)
             _, idx = jax.lax.top_k(s + bias, k)
             sel = jnp.take_along_axis(s, idx, axis=-1)
             w = sel / sel.sum(axis=-1, keepdims=True) * kc.route_scale
@@ -287,8 +337,11 @@ class _MoE(nn.Module):
             y = jax.lax.switch(pick, [with_rows(r) for r in sizes], weights,
                                x, order, w_sorted, group_sizes, n_held)
         with jax.named_scope(device_scopes.MOE_SHARED):
-            y = y + _SwiGLU(kc.shared_width, self.compute_dtype,
-                            name="shared")(x)
+            shared = _SwiGLU(kc.shared_width, cd, name="shared")(x)
+            if kc.shared_gate:
+                shared = shared * jax.nn.sigmoid(
+                    _Linear(1, cd, name="shared_gate")(x))
+            y = y + shared
         load = jnp.bincount(idx.reshape(-1), length=kc.experts)
         rows_taken = jnp.asarray(sizes, jnp.int32)[pick]
         self.sow(STATS, "moe_held_assign_share", n_held / (n * k))
@@ -309,12 +362,8 @@ class _Layer(nn.Module):
     def __call__(self, x, state, seg):
         kc, cd = self.kc, self.compute_dtype
         hn = _RMSNorm(kc.eps, name="mix_norm")(x)
-        if self.index in kc.kda_layers:
-            from rainbow_iqn_apex_tpu.models.kimi_linear import _KDA
-
-            y, state = _KDA(kc, cd, name="kda")(hn, state, seg)
-        else:
-            y, state = _MLA(kc, cd, name="mla")(hn, state, seg)
+        mixer = kc.mixers[self.index - 1]
+        y, state = mixer(kc, cd, name=mixer.layer_name)(hn, state, seg)
         x = x + y
         hn = _RMSNorm(kc.eps, name="ffn_norm")(x)
         if self.index <= kc.first_dense:
@@ -350,30 +399,20 @@ class _Stack(nn.Module):
         return _RMSNorm(kc.eps, name="final_norm")(x), new_state
 
 
-
-
 class StackCore:
     """The core interface (models/cores.py) over `_Stack`: zero start state,
     nothing stored in the ring.  A family's core (`KimiLinearCore`,
-    `DeepSeekV3Core`) is a frozen dataclass of `kc` and `compute_dtype` that
-    names the counters it reports, `stat_names`: each is an output of the
-    compiled segment, so a core lists what its cell reads."""
+    `DeepSeekV3Core`, `Qwen3NextCore`) is a frozen dataclass of `kc` and
+    `compute_dtype` that names the counters it reports, `stat_names`: each is
+    an output of the compiled segment, so a core lists what its cell reads."""
 
     stored_width = 0  # zero start state: the ring stores no state
     moe_stat_names = ("moe_expert_load_max_over_mean", "moe_held_assign_share",
                       "moe_tokens_dropped")
 
     def initial_state(self, batch: int):
-        kc, z = self.kc, lambda *s: jnp.zeros((batch, *s), jnp.float32)  # noqa: E731
-        d = kc.kda_heads * kc.kda_dim
-        return {
-            f"layer_{i}": (
-                {"S": z(kc.kda_heads, kc.kda_dim, kc.kda_dim),
-                 "conv": z(kc.conv_kernel - 1, 3 * d)}
-                if i in kc.kda_layers else
-                {"lat": z(kc.window, kc.kv_rank + kc.rope),
-                 "valid": z(kc.window)})
-            for i in range(1, kc.layers + 1)}
+        return {f"layer_{i}": mixer.zero_state(self.kc, batch)
+                for i, mixer in enumerate(self.kc.mixers, 1)}
 
     def to_stored(self, state):
         b = jax.tree.leaves(state)[0].shape[0]

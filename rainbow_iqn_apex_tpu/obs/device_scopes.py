@@ -64,7 +64,13 @@ MLA_ROPE = "mla_rope"  # inside it: the rope dimensions turned by their slot
 MOE_ROUTE = "moe_route"  # router, top-k, the sort by held expert
 MOE_EXPERTS = "moe_experts"  # gather, the grouped products, scatter-add
 MOE_SHARED = "moe_shared"  # the shared expert
-CORE_STEP = "core_step"  # the KDA recurrence of one step (the actor's tick)
+CORE_STEP = "core_step"  # the delta-rule recurrence of one step (the actor's tick)
+# ---- the Qwen3-Next core's two mixers (models/qwen3_next.py); its scan wears
+# KDA_SCAN / KDA_PREP / CORE_STEP, its expert layers the MOE_* names
+GDN_MIX = "gdn_mix"  # Gated DeltaNet but its scan: projections, conv, gates, gated norm
+GATTN_PROJ = "gattn_proj"  # q with its gate, k, v, their norms, o
+GATTN_ATTN = "gattn_attn"  # scores, mask, softmax, values over the K/V window, the gate
+GATTN_ROPE = "gattn_rope"  # inside it: the rotary dimensions turned by their slot
 IQN_HEAD = "iqn_head"  # tau embedding + the tau-folded heads (IQN)
 OPTIMIZER = "optimizer"  # tx.update, apply_updates, the target copy
 GRAD_ALLREDUCE = "grad_allreduce"  # psum/pmax/pmean of the sharded builders
@@ -74,7 +80,8 @@ ALL_SCOPES = TICK_SCOPES + (
     REPLAY_DRAW, REPLAY_GATHER, REPLAY_WRITEBACK, LEARN_STEP, NET_TRUNK,
     LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
-    CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM,
+    CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM, GDN_MIX, GATTN_PROJ, GATTN_ATTN,
+    GATTN_ROPE,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 

@@ -1,5 +1,5 @@
 """The CLI (`train_agent_apex.py --architecture r2d2 --core-config <file>`)
-with either non-LSTM core, at tiny widths: the host-fed anakin loop,
+with every non-LSTM core, at tiny widths: the host-fed anakin loop,
 `train_r2d2`, the apex R2D2 driver and the fused trainer.  A file of its own:
 these are the slowest cases of the cores' tests, and the suite runs a file a
 worker."""
@@ -12,7 +12,7 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CORES = {name: os.path.join(HERE, "fixtures", name + "_core_tiny.json")
-         for name in ("kimi", "deepseek_v3")}
+         for name in ("kimi", "deepseek_v3", "qwen3_next")}
 
 
 @pytest.mark.parametrize("core", sorted(CORES))

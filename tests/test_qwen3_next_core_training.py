@@ -1,9 +1,9 @@
-"""The R2D2 agent with the DeepSeek-V3-family core (`Config.core_config`)
-through the normal paths: the core picked by the file's `model_type`, the
-learn step against the plain reference, the fused segment and the act step (the
-CLI cases are tests/test_core_cli.py's, run with either core), at
-tiny widths (the trunk's 2,304 features at 80x80 frames go through the input
-projection to the core's hidden size, which the heads read)."""
+"""The R2D2 agent with the Qwen3-Next core (`Config.core_config`) through the
+normal paths: the core picked by the file's `model_type`, the learn step
+against the plain reference, the fused segment and the act step (the CLI
+cases are tests/test_core_cli.py's, run with every core), at tiny widths (the
+trunk's 2,304 features at 80x80 frames go through the input projection to
+the core's hidden size, which the heads read)."""
 
 import json
 import os
@@ -15,7 +15,7 @@ import pytest
 
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.models.cores import make_core, state_bytes_per_lane
-from rainbow_iqn_apex_tpu.models.deepseek_v3 import DeepSeekV3Core
+from rainbow_iqn_apex_tpu.models.qwen3_next import Qwen3NextCore
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     SequenceBatch,
     build_r2d2_act_step,
@@ -24,8 +24,8 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import (
 )
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-TINY = os.path.join(HERE, "fixtures", "deepseek_v3_core_tiny.json")
-PUBLISHED = "configs/cores/kanana_2_30b_a3b.json"
+TINY = os.path.join(HERE, "fixtures", "qwen3_next_core_tiny.json")
+PUBLISHED = "configs/cores/qwen3_next_80b_a3b.json"
 
 
 def _cfg(tmp_path, **kw):
@@ -54,31 +54,24 @@ def _rows(cfg):
 def test_the_core_comes_from_the_files_model_type(tmp_path):
     cfg = _cfg(tmp_path)
     core = make_core(cfg)
-    assert isinstance(core, DeepSeekV3Core)
+    assert isinstance(core, Qwen3NextCore)
     assert core.stored_width == 0 and core.kc.hidden == 32 and core.kc.in_proj
-    assert core.kc.rope_theta == 1000.0
-    assert [m.layer_name for m in core.kc.mixers] == ["mla"] * 5
-    # five windows of 12 latents (16 + 8) and their validity, float32
-    assert state_bytes_per_lane(core) == 5 * 4 * 12 * (24 + 1)
-    published = make_core(cfg.replace(core_config=PUBLISHED)).kc
-    assert (published.hidden, published.layers, published.mla_heads,
-            published.nope, published.rope, published.v_dim,
-            published.kv_rank) == (2048, 5, 32, 128, 64, 128, 512)
-    assert (published.experts, published.experts_here, published.top_k,
-            published.expert_width, published.shared_width,
-            published.dense_width) == (128, 16, 6, 768, 1536, 6144)
-    assert (published.rope_theta, published.route_scale,
-            published.window) == (1e6, 2.448, 120)
-    assert state_bytes_per_lane(make_core(cfg.replace(
-        core_config=PUBLISHED))) == 5 * 4 * 120 * 577  # 1.38 MB a lane
+    assert [m.layer_name for m in core.kc.mixers] == ["gdn"] * 3 + ["gattn"]
+    # three states S [4, 8, 8] with tails [3, 2x16 + 32], one window of 12
+    # keys and values [2, 8] and its validity, float32
+    assert state_bytes_per_lane(core) == 4 * (
+        3 * (4 * 8 * 8 + 3 * 64) + 12 * (2 * 2 * 8 + 1))
+    published = make_core(cfg.replace(core_config=PUBLISHED))
+    assert isinstance(published, Qwen3NextCore)
+    assert state_bytes_per_lane(published) == 7_078_368  # 7.08 MB a lane
     bad = tmp_path / "other.json"
     bad.write_text(json.dumps({"model_type": "llama"}))
     with pytest.raises(ValueError, match="no core for model_type 'llama'"):
         make_core(cfg.replace(core_config=str(bad)))
 
 
-def test_the_published_cut_is_519_million_parameters(tmp_path):
-    """The byte count of benchmarks/configs/kanana-2-r2d2-1chip.json, from
+def test_the_published_cut_is_557_million_parameters(tmp_path):
+    """The byte count of benchmarks/configs/qwen3-next-r2d2-1chip.json, from
     `jax.eval_shape` of the program's own init: nothing is allocated."""
     cfg = _cfg(tmp_path, core_config=PUBLISHED, history_length=4,
                hidden_size=512, compute_dtype="bfloat16")
@@ -87,22 +80,30 @@ def test_the_published_cut_is_519_million_parameters(tmp_path):
         jax.random.PRNGKey(0))
     count = lambda tree: sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))  # noqa: E731
     core = params["core"]
-    mla = 2048 * 6144 + 2048 * 576 + 512 + 512 * 8192 + 4096 * 2048
-    assert count(core["layer_1"]["mla"]) == mla == 26_345_984
-    assert count(core["layer_1"]["ffn"]) == 3 * 2048 * 6144
-    moe = core["layer_2"]["moe"]
-    assert count(moe["experts"]) == 16 * 3 * 2048 * 768
-    assert count(moe["shared"]) == 3 * 2048 * 1536
-    assert count(moe["router"]) == 2048 * 128 + 128
+    gdn = (2048 * 12288 + 2048 * 64 + 4 * 8192 + 32 + 32 + 128 + 4096 * 2048)
+    assert count(core["layer_1"]["gdn"]) == gdn == 33_718_464
+    gattn = 2048 * 8192 + 2 * 2048 * 512 + 4096 * 2048 + 2 * 256
+    assert count(core["layer_4"]["gattn"]) == gattn == 27_263_488
+    for i in (1, 2, 3, 4):  # every feed-forward is an expert layer
+        moe = core[f"layer_{i}"]["moe"]
+        assert count(moe["experts"]) == 32 * 3 * 2048 * 512
+        assert count(moe["shared"]) == 3 * 2048 * 512
+        assert count(moe["shared_gate"]) == 2048
+        assert count(moe["router"]) == 2048 * 512 + 512
     assert count(core["in_proj"]) == 2304 * 2048
-    assert count(core) == 515_007_488
-    assert count(params) == 519_285_928  # x 20 B = 10.39 GB
+    assert count(core) == 552_596_544
+    assert count(params) == 556_874_984  # x 20 B = 11.14 GB
     # the heads read the core's hidden size
     assert params["value_hidden"]["w_mu"].shape == (2048, 512)
+    # every leaf bears a name benchmarks/weights_core.py fills
+    names = {jax.tree_util.keystr(p).rsplit("'", 2)[-2]
+             for p, _ in jax.tree_util.tree_leaves_with_path(core)}
+    assert names == {"kernel", "gate", "up", "down", "scale", "taps", "A_log",
+                     "dt_bias", "select_bias"}
 
 
 def test_learn_step_loss_and_gradient_match_the_reference(tmp_path):
-    from benchmarks.references import r2d2_kanana
+    from benchmarks.references import r2d2_qwen3_next
 
     cfg = _cfg(tmp_path, history_length=4, batch_size=2)
     with open(TINY) as f:
@@ -132,11 +133,13 @@ def test_learn_step_loss_and_gradient_match_the_reference(tmp_path):
         reward=batch["reward"], done=batch["done"], valid=batch["valid"],
         init_c=zero, init_h=zero, weight=batch["weight"])
     new, info = jax.jit(build_r2d2_learn_step(cfg, actions))(ts, seq, ks[5])
-    (loss, _), grads = jax.value_and_grad(r2d2_kanana.loss_fn, has_aux=True)(
-        ts.params, ts.target_params, batch, ks[5], hp, cc)
+    (loss, _), grads = jax.jit(jax.value_and_grad(
+        lambda p, t, b, k: r2d2_qwen3_next.loss_fn(p, t, b, k, hp, cc),
+        has_aux=True))(ts.params, ts.target_params, batch, ks[5])
     assert float(info["loss"]) == pytest.approx(float(loss), rel=1e-4)
     assert float(info["moe_tokens_dropped"]) == 0.0
-    assert 0.0 < float(info["mla_live_key_share"]) < 1.0
+    assert 0.0 < float(info["gattn_live_key_share"]) < 1.0
+    assert float(info["kda_fused_tile_share"]) == 0.0  # the CPU's plain path
     mu = [s for s in jax.tree.leaves(
         new.opt_state, is_leaf=lambda x: hasattr(x, "mu"))
         if hasattr(s, "mu")][0].mu
@@ -157,16 +160,17 @@ def test_fused_segment_trains_with_the_core(tmp_path):
     assert all(np.isfinite(r["loss"]) for r in learn)
     assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
     assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
-    assert "kda_fused_tile_share" not in learn[0]
+    assert all(r["kda_fused_tile_share"] == 0.0 for r in learn)
+    assert "mla_live_key_share" not in learn[0]
     # freeway has no terminals: the trained slice's 8 queries see the 4
     # burn-in keys and their own causal half, of 12 + 8 slots
-    assert all(r["mla_live_key_share"] == pytest.approx(
+    assert all(r["gattn_live_key_share"] == pytest.approx(
         (8 * 4 + 36) / (8 * 20)) for r in learn)
     assert learn[0]["core_state_bytes_per_lane"] == state_bytes_per_lane(
         make_core(cfg))
 
 
-def test_act_step_carries_the_windows_and_a_cut_empties_them(tmp_path):
+def test_act_step_carries_the_state_and_a_cut_empties_it(tmp_path):
     from rainbow_iqn_apex_tpu.models.cores import zero_lanes
 
     cfg = _cfg(tmp_path)
@@ -178,10 +182,12 @@ def test_act_step_carries_the_windows_and_a_cut_empties_them(tmp_path):
     _, q0, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
     _, q1, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
     assert np.abs(np.asarray(q1 - q0)).max() > 0  # the window matters
-    # the rope keys are kept un-rotated: a step's latent does not depend on
-    # when it was written
-    lat = np.asarray(state["layer_1"]["lat"])
-    np.testing.assert_allclose(lat[:, -1], lat[:, -2], rtol=1e-6, atol=1e-7)
+    # the keys are kept un-rotated; a key is a function of the residual
+    # stream, which three recurrent layers have moved between the two ticks
+    keys = np.asarray(state["layer_4"]["k"])
+    assert np.abs(keys[:, -1]).max() > 0 and np.abs(keys[:, -2]).max() > 0
+    assert not np.any(keys[:, :-2])
+    assert np.abs(np.asarray(state["layer_1"]["S"])).max() > 0
     state = zero_lanes(state, jnp.asarray([0, 1], jnp.uint8))
     _, q2, _ = act(ts.params, obs, state, jax.random.PRNGKey(3))
     np.testing.assert_allclose(np.asarray(q2[0]), np.asarray(q0[0]),
