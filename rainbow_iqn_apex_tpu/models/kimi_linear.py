@@ -18,7 +18,9 @@ Per-lane state, all float32, zero = initial (models/cores.zero_lanes):
 Sequences run the KDA recurrence chunked (`kda_chunked`: WY form, the
 in-chunk decay products taken relative to sub-block starts so that no
 exponent is positive; on a TPU the in-chunk preparation is a tile kernel,
-models/kda_tile.py, of which `_prep_plain` is the definition); one step
+models/kda_tile.py, of which `_prep_plain` is the definition; a gate one
+channel wide, Gated DeltaNet's in models/qwen3_next.py, takes the scalar form
+`_prep_scalar` instead, plain matrix products, everywhere); one step
 (`T == 1`, the actor) runs it as written.
 An episode cut inside a sequence is a segment boundary: steps interact only
 within a segment, in the chunk, the convolutions and the attention mask.
@@ -149,6 +151,19 @@ def _decay_pairs(x, y, g_cum, block: int, dtype):
     return off + diag.reshape(*lead, c, c)
 
 
+def _chunk_masks(seg, n: int, c: int):
+    """seg [B, T] cut into n chunks of c: (seg [B, N, 1, C]; m0 [B, N, 1, C],
+    1.0 where the incoming state reaches step r; same [B, N, 1, C, C], steps
+    of one segment; lower [C, C], i <= r)."""
+    b = seg.shape[0]
+    seg = seg.reshape(b, n, 1, c)
+    seg_in = jnp.concatenate(
+        [jnp.zeros((b, 1, 1), seg.dtype), seg[:, :-1, :, -1]], axis=1)
+    m0 = (seg == seg_in[..., None]).astype(jnp.float32)
+    same = seg[..., :, None] == seg[..., None, :]
+    return seg, m0, same, jnp.tril(jnp.ones((c, c), bool))
+
+
 def _prep_plain(q, k, v, g, beta, seg, c: int, block: int, dtype):
     """The in-chunk preparation, as written: what the chunk scan reads.
 
@@ -163,12 +178,7 @@ def _prep_plain(q, k, v, g, beta, seg, c: int, block: int, dtype):
     ch = lambda z: jnp.swapaxes(z.reshape(b, n, c, h, -1), 2, 3)  # noqa: E731
     q, k, v, g = ch(q), ch(k), ch(v), ch(g)
     beta = jnp.swapaxes(beta.reshape(b, n, c, h), 2, 3)  # [B, N, H, C]
-    seg = seg.reshape(b, n, 1, c)
-    seg_in = jnp.concatenate(
-        [jnp.zeros((b, 1, 1), seg.dtype), seg[:, :-1, :, -1]], axis=1)
-    m0 = (seg == seg_in[..., None]).astype(jnp.float32)  # s0 reaches step r
-    same = seg[..., :, None] == seg[..., None, :]  # [B, N, 1, C, C]
-    lower = jnp.tril(jnp.ones((c, c), bool))
+    seg, m0, same, lower = _chunk_masks(seg, n, c)
     g_cum = jnp.cumsum(g, axis=-2)
     p_kk = _decay_pairs(k, k, g_cum, block, dtype)
     p_qk = _decay_pairs(q, k, g_cum, block, dtype)
@@ -185,6 +195,50 @@ def _prep_plain(q, k, v, g, beta, seg, c: int, block: int, dtype):
         seg == seg[..., -1:]).astype(jnp.float32)[..., None]
     s_keep = jnp.exp(g_last[..., 0, :]) * m0[..., -1:]  # [B, N, H, dk]
     # the scan multiplies all but u and s_keep on `dtype` operands
+    return tuple(jnp.moveaxis(z, 1, 0).astype(dt) for z, dt in (
+        (u, jnp.float32), (wk, dtype), (qg, dtype), (a_qk, dtype),
+        (k_end, dtype), (s_keep, jnp.float32)))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(6, 7))
+def _prep_scalar(q, k, v, g, beta, seg, c: int, dtype):
+    """`_prep_plain` where the gate is one channel wide (g [B, T, H, 1]: one
+    log decay a head and step, Gated DeltaNet's).  The exponent then leaves
+    the sum over the channels: the decayed pair products are [C, dk] x
+    [dk, C] matrix products on `dtype` operands, scaled in float32 by
+    exp(G[r] - G[i]) (never positive on or below the diagonal), so no
+    sub-block reference is needed; s_keep comes back one wide and the scan
+    broadcasts it.  Saves its inputs alone for the backward, as
+    `_prep_fused` does."""
+    b, t, h, _ = q.shape
+    n = t // c
+    ch = lambda z: jnp.swapaxes(z.reshape(b, n, c, h, -1), 2, 3)  # noqa: E731
+    q, k, v = ch(q), ch(k), ch(v)
+    g, beta = ch(g)[..., 0], ch(beta)[..., 0]  # [B, N, H, C]
+    seg, m0, same, lower = _chunk_masks(seg, n, c)
+    g_cum = jnp.cumsum(g, axis=-1)
+    # a select, not a clip: where two steps' sums tie a clip at 0 would pass
+    # half the cotangent
+    pairs = jnp.exp(jnp.where(
+        lower, g_cum[..., :, None] - g_cum[..., None, :], 0.0))
+    p_kk = _mm("...rd,...id->...ri", k, k, dtype) * pairs
+    p_qk = _mm("...rd,...id->...ri", q, k, dtype) * pairs
+    a = beta[..., None] * jnp.where(same & jnp.tril(lower, -1), p_kk, 0.0)
+    a_qk = jnp.where(same & lower, p_qk, 0.0)
+    decay = jnp.exp(g_cum) * m0
+    qg = q * decay[..., None]
+    # (1 + a) [u | wk] = beta [v | k decay]: the inverse with the right-hand
+    # sides' row scalars folded into its columns, then one float32 product a
+    # side, on v and k as they came
+    eye = jnp.eye(c, dtype=a.dtype)
+    inv = jax.scipy.linalg.solve_triangular(
+        a + eye, jnp.broadcast_to(eye, a.shape), lower=True,
+        unit_diagonal=True)
+    u = jnp.matmul(inv * beta[..., None, :], v, precision=HI)
+    wk = jnp.matmul(inv * (beta * decay)[..., None, :], k, precision=HI)
+    g_last = g_cum[..., -1:]
+    k_end = k * (jnp.exp(g_last - g_cum) * (seg == seg[..., -1:]))[..., None]
+    s_keep = jnp.exp(g_last) * m0[..., -1:]  # [B, N, H, 1]
     return tuple(jnp.moveaxis(z, 1, 0).astype(dt) for z, dt in (
         (u, jnp.float32), (wk, dtype), (qg, dtype), (a_qk, dtype),
         (k_end, dtype), (s_keep, jnp.float32)))
@@ -226,12 +280,26 @@ def kda_prep_fused(dk: int, dv: int, chunk: int, block: int) -> bool:
             and kda_tile.takes(dk, dv, chunk, block))
 
 
+def kda_prep_path(gate_width: int, dk: int, dv: int, chunk: int,
+                  block: int) -> str:
+    """Which execution of the in-chunk preparation a sequence takes, by what
+    can be observed: "scalar" for a gate one channel wide, else "tile" where
+    the kernel runs, else "plain"."""
+    if gate_width == 1:
+        return "scalar"
+    return "tile" if kda_prep_fused(dk, dv, chunk, block) else "plain"
+
+
 def kda_chunked(q, k, v, g, beta, seg, s0, chunk: int, block: int, dtype):
     """The KDA recurrence over a sequence, chunk by chunk.
 
-    q, k [B, T, H, dk] (l2-normalised), v [B, T, H, dv], g [B, T, H, dk] the
-    per-step log decay (<= 0), beta [B, T, H], seg [B, T] segment ids (0 =
-    the segment `s0` belongs to), s0 [B, H, dk, dv].
+    q, k [B, T, H, dk] (l2-normalised), v [B, T, H, dv], g the per-step log
+    decay (<= 0), [B, T, H, dk] a key channel (KDA) or [B, T, H, 1] a head
+    (Gated DeltaNet), beta [B, T, H], seg [B, T] segment ids (0 = the
+    segment `s0` belongs to), s0 [B, H, dk, dv].  The gate's width picks the
+    in-chunk preparation: one wide, its scalar form (`_prep_scalar`, plain
+    matrix products, on every platform); a channel wide, `_prep_plain` or
+    on a TPU the tile kernel.
     Returns (o [B, T, H, dv] before the 1/sqrt(dk), final state)."""
     b, t, h, dk = q.shape
     c = _chunk_len(t, chunk, block)
@@ -242,9 +310,12 @@ def kda_chunked(q, k, v, g, beta, seg, s0, chunk: int, block: int, dtype):
         seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
     n = (t + pad) // c
     with jax.named_scope(device_scopes.KDA_PREP):
-        prep = (_prep_fused if kda_prep_fused(dk, v.shape[-1], c, block)
-                else _prep_plain)
-        xs = prep(q, k, v, g, beta, seg, c, block, dtype)
+        path = kda_prep_path(g.shape[-1], dk, v.shape[-1], c, block)
+        if path == "scalar":
+            xs = _prep_scalar(q, k, v, g, beta, seg, c, dtype)
+        else:
+            prep = _prep_fused if path == "tile" else _prep_plain
+            xs = prep(q, k, v, g, beta, seg, c, block, dtype)
 
     def one(s, xs):
         u_n, wk_n, qg_n, aqk_n, kend_n, keep_n = xs
@@ -313,8 +384,9 @@ class _KDA(nn.Module):
             with jax.named_scope(device_scopes.KDA_SCAN):
                 o, s = kda_chunked(q, k, v, g, beta, seg, state["S"],
                                    kc.chunk, kc.block, cd)
-            self.sow(STATS, "kda_fused_tile_share", float(kda_prep_fused(
-                dk, dk, _chunk_len(t, kc.chunk, kc.block), kc.block)))
+            self.sow(STATS, "kda_fused_tile_share", float(kda_prep_path(
+                dk, dk, dk, _chunk_len(t, kc.chunk, kc.block),
+                kc.block) == "tile"))
         o = _RMSNorm(kc.eps, name="o_norm")(o / math.sqrt(dk))
         gate = jax.nn.sigmoid(low("g_a", "g_b", d))
         y = _Linear(kc.hidden, cd, name="o_proj")(gate * o.reshape(b, t, d))

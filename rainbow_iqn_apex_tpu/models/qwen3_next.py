@@ -12,8 +12,10 @@ This module holds what is Qwen3-Next's alone: the two mixers and the reader of
 the published keys.  The stack, the expert layer and the other blocks are
 models/mla_moe.py's, the delta-rule recurrence (`kda_chunked`, `kda_step`)
 models/kimi_linear.py's: Gated DeltaNet decays a head's state by one scalar a
-step where KDA decays every key channel by its own, so the recurrence runs
-with the scalar broadcast over the channels, unedited.
+step where KDA decays every key channel by its own, so the recurrence is
+handed a gate one channel wide and `kda_chunked` prepares its chunks in the
+scalar form (`_prep_scalar`: the decayed pair products are matrix products),
+on every platform; KDA's tile kernels do not run for this core.
 
 Gated DeltaNet, of `gdn_key_heads` key heads serving `gdn_value_heads` value
 heads (value head i reads key head i // (value heads / key heads)):
@@ -70,7 +72,7 @@ from rainbow_iqn_apex_tpu.models.kimi_linear import (
     _l2_norm,
     _Taps,
     kda_chunked,
-    kda_prep_fused,
+    kda_prep_path,
     kda_step,
 )
 from rainbow_iqn_apex_tpu.models.mla_moe import (
@@ -153,8 +155,8 @@ class _GatedDeltaNet(nn.Module):
             a_log = self.param("A_log", _a_log_init, (hv,))
             dt_bias = self.param("dt_bias", _dt_bias_init, (hv,))
             beta = jax.nn.sigmoid(ba[..., :hv])
-            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
-            g = jnp.broadcast_to(g[..., None], (b, t, hv, dk))
+            g = (-jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+                 )[..., None]  # [B, T, Hv, 1]: one wide, the scan's scalar form
         if t == 1:
             with jax.named_scope(device_scopes.CORE_STEP):
                 o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
@@ -164,8 +166,10 @@ class _GatedDeltaNet(nn.Module):
             with jax.named_scope(device_scopes.KDA_SCAN):
                 o, s = kda_chunked(q, k, v, g, beta, seg, state["S"],
                                    kc.chunk, kc.block, cd)
-            self.sow(STATS, "kda_fused_tile_share", float(kda_prep_fused(
-                dk, dv, _chunk_len(t, kc.chunk, kc.block), kc.block)))
+            path = kda_prep_path(g.shape[-1], dk, dv,
+                                 _chunk_len(t, kc.chunk, kc.block), kc.block)
+            self.sow(STATS, "kda_fused_tile_share", float(path == "tile"))
+            self.sow(STATS, "kda_scalar_gate_share", float(path == "scalar"))
         with jax.named_scope(device_scopes.GDN_MIX):
             o = _RMSNorm(kc.eps, name="o_norm")(o / math.sqrt(dk))
             y = _Linear(kc.hidden, cd, name="o_proj")(
@@ -275,4 +279,5 @@ class Qwen3NextCore(StackCore):
     compute_dtype: Any = jnp.bfloat16
 
     stat_names = StackCore.moe_stat_names + (
-        "kda_fused_tile_share", "gattn_live_key_share")
+        "kda_fused_tile_share", "gattn_live_key_share",
+        "kda_scalar_gate_share")

@@ -1,8 +1,10 @@
 """The tile kernel of the KDA chunk's preparation (models/kda_tile.py) against
 the plain `kda_chunked`, which is its definition: under `interpret=True` on
 the CPU at small batch and head counts that keep d 128, chunk 40 and block 8;
-the choice between the two paths; where a device trace files each; and the
-kernels compiled at the published widths for a described v5e."""
+the scalar form a one-wide gate takes (`_prep_scalar`) against the same
+definition; the choice between the paths; where a device trace files each;
+and the kernels and the scalar form compiled at the published widths for a
+described v5e."""
 
 import functools
 import json
@@ -104,6 +106,69 @@ def test_shapes_the_kernel_does_not_take_fall_back(d, chunk, block):
     jax.tree.map(functools.partial(close, tol=1e-6), got, want)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("cuts", ["lockstep", "one", "random"])
+@pytest.mark.parametrize("steps", [40, 80])
+def test_scalar_form_gives_the_plain_preparations_six_outputs(
+        steps, cuts, dtype):
+    """`_prep_scalar` on a gate one channel wide against `_prep_plain` on
+    the same gate broadcast over the channels, leaf by leaf, compared in
+    float32: u, wk, qg, a_qk, k_end, s_keep (one wide, the value of every
+    channel), in their dtypes.  In float32 only the order of rounding
+    differs; on bfloat16 operands the decay multiplies the float32
+    accumulator where the plain path rounds decayed operands, so those two
+    leaves agree to bfloat16's last place."""
+    q, k, v, g, beta, _, seg = inputs(steps, cuts, 0.0)
+    g = g[..., :1]
+    got = kl._prep_scalar(q, k, v, g, beta, seg, CHUNK, dtype)
+    want = kl._prep_plain(q, k, v, jnp.broadcast_to(g, q.shape), beta, seg,
+                          CHUNK, BLOCK, dtype)
+    tol = 2e-5 if dtype == jnp.float32 else 2 ** -7
+    for name, a, b in zip(("u", "wk", "qg", "a_qk", "k_end", "s_keep"),
+                          got, want):
+        assert a.dtype == b.dtype, name
+        assert a.shape == (b.shape[:-1] + (1,) if name == "s_keep"
+                           else b.shape), name
+        close(jnp.broadcast_to(a.astype(jnp.float32), b.shape),
+              b.astype(jnp.float32), tol)
+
+
+def test_a_one_wide_gate_takes_the_scalar_form_on_every_platform():
+    """The gate's width alone picks the scalar form: on the CPU, on a TPU
+    where a channel-wide gate takes the kernel, and under a mesh.  The
+    Qwen3-Next core counts it (and no tile); the Kimi-Linear core, whose
+    gate is dk wide, has no such counter."""
+    from rainbow_iqn_apex_tpu.models import qwen3_next as qn
+    from rainbow_iqn_apex_tpu.parallel.mesh import traced_under
+
+    path = lambda w: kl.kda_prep_path(w, D, D, CHUNK, BLOCK)  # noqa: E731
+    assert (path(1), path(D)) == ("scalar", "plain")
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert (path(1), path(D)) == ("scalar", "tile")
+        several = Mesh(np.array(jax.devices()[:4]), ("dp",))
+        assert traced_under(several, lambda: (path(1), path(D)))() == (
+            "scalar", "plain")
+        # at shapes the kernel does not take, too
+        assert kl.kda_prep_path(1, 64, 64, 12, 4) == "scalar"
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "fixtures", "qwen3_next_core_tiny.json")) as f:
+        kc = qn.Qwen3NextConfig.from_dict(json.load(f))
+    core = qn.Qwen3NextCore(kc, jnp.float32)
+    x, resets = jnp.ones((2, 16, 24)), jnp.zeros((2, 16), bool)
+    stack = kl._Stack(kc, jnp.float32)
+    state = core.initial_state(2)
+    params = stack.init(jax.random.PRNGKey(0), x, state, resets)["params"]
+    _, sown = stack.apply({"params": params}, x, state, resets,
+                          mutable=[CORE_STATS])
+    stats = reduce_stats(sown)
+    assert float(stats["kda_scalar_gate_share"]) == 1.0
+    assert float(stats["kda_fused_tile_share"]) == 0.0
+    assert core.stat_names[-1] == "kda_scalar_gate_share"
+    assert "kda_scalar_gate_share" not in kl.KimiLinearCore.stat_names
+
+
 def test_the_path_is_chosen_by_platform_mesh_and_shape():
     """CPU: plain, and the core counts no fused tile.  TPU: the kernel,
     unless the function is traced under a mesh of several devices (the
@@ -122,6 +187,7 @@ def test_the_path_is_chosen_by_platform_mesh_and_shape():
     _, sown = stack.apply({"params": params}, x, state, resets,
                           mutable=[CORE_STATS])
     assert float(reduce_stats(sown)["kda_fused_tile_share"]) == 0.0
+    assert "kda_scalar_gate_share" not in reduce_stats(sown)
     assert "kda_fused_tile_share" in core.stat_names
 
     chosen = lambda: kl.kda_prep_fused(D, D, CHUNK, BLOCK)  # noqa: E731
@@ -137,6 +203,13 @@ def scan_in_scope(q, k, v, g, beta, s0, seg):
     with jax.named_scope(device_scopes.KDA_SCAN):
         return kl.kda_chunked(q, k, v, g, beta, seg, s0, CHUNK, BLOCK,
                               jnp.bfloat16)
+
+
+def scan_grads(*args):
+    """Gradients of every float input through `scan_in_scope`."""
+    return jax.grad(lambda *z: sum(
+        (y.astype(jnp.float32) ** 2).sum() for y in scan_in_scope(*z)),
+        argnums=(0, 1, 2, 3, 4, 5))(*args)
 
 
 def paths_of(text, opcode):
@@ -182,18 +255,51 @@ def test_kernels_compile_at_the_published_widths_and_carry_the_scope(
     args = (*(shaped(b, steps, h, D) for _ in range(4)), shaped(b, steps, h),
             shaped(b, h, D, D), shaped(b, steps, dt=jnp.int32))
 
-    def grads(*a):
-        return jax.grad(lambda *z: sum(
-            (y.astype(jnp.float32) ** 2).sum() for y in scan_in_scope(*z)),
-            argnums=(0, 1, 2, 3, 4, 5))(*a)
-
     with mock.patch.object(kl, "kda_prep_fused", lambda *a: True):
-        text = jax.jit(grads).lower(*args).compile().as_text()
+        text = jax.jit(scan_grads).lower(*args).compile().as_text()
     scopes = device_scopes.instruction_scopes(text)
     kernels = {name: path for name, path in scopes.items()
                if name.startswith("kda_tile")}
     assert {n.split(".")[0] for n in kernels} == {"kda_tile", "kda_tile_vjp"}
     assert set(kernels.values()) == {("kda_scan", "kda_prep")}, kernels
+
+
+@pytest.mark.parametrize("steps,backward", [(80, True), (40, False)],
+                         ids=["T80_grad", "T40_forward"])
+def test_scalar_form_compiles_at_the_published_shapes_with_no_tile_kernel(
+        one_chip, steps, backward):
+    """The Qwen3-Next cell's scans (B 64, H 32, d 128, chunk 40; a learn
+    step differentiates the 80-step slice and runs the 40-step burn-in
+    forward) with a one-wide gate, compiled for the chip where a channel-wide
+    gate would take the kernel: no `kda_tile` custom call, the preparation's
+    ops under kda_scan/kda_prep, and no array the size of the per-channel
+    plain path's pair products (`[.., C, C, d]`) or decayed copies
+    (`[.., nb, C, d]`): the largest is the scan's stacked states."""
+    import re
+
+    b, h = 64, 32
+    n, nb = steps // CHUNK, CHUNK // BLOCK
+    shaped = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    args = (*(shaped(b, steps, h, D) for _ in range(3)),
+            shaped(b, steps, h, 1), shaped(b, steps, h),
+            shaped(b, h, D, D), shaped(b, steps, dt=jnp.int32))
+
+    run = scan_grads if backward else scan_in_scope
+    with mock.patch.object(kl, "kda_prep_fused", lambda *a: True):
+        text = jax.jit(run).lower(*args).compile().as_text()
+    assert "kda_tile" not in text
+    scopes = device_scopes.instruction_scopes(text)
+    prep = [name for name, path in scopes.items()
+            if path[-2:] == ("kda_scan", "kda_prep")]
+    assert len(prep) > 20, len(prep)
+    results = re.findall(
+        r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(?\w+\[([\d,]*)\]", text, re.M)
+    assert len(results) > 200
+    largest = max(math.prod(int(x) for x in dims.split(",") if x)
+                  for dims in results)
+    assert largest < b * n * h * nb * CHUNK * D, largest
+    assert largest < b * n * h * CHUNK * CHUNK * D
 
 
 def test_the_sequence_rings_loop_needs_no_ring_sized_temporary(one_chip):

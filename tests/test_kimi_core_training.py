@@ -123,6 +123,7 @@ def test_fused_segment_trains_with_the_core(tmp_path):
     assert all(r["moe_expert_load_max_over_mean"] >= 1.0 for r in learn)
     # on the CPU every KDA layer's preparation took the plain path
     assert all(r["kda_fused_tile_share"] == 0.0 for r in learn)
+    assert "kda_scalar_gate_share" not in learn[0]  # its gate is dk wide
     assert learn[0]["core_state_bytes_per_lane"] == state_bytes_per_lane(
         make_core(cfg))
 

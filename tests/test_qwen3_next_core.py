@@ -126,7 +126,8 @@ def test_the_stack_is_three_delta_layers_and_one_attention_layer():
         "experts", "router", "shared", "shared_gate"]
     assert core.stat_names == (
         "moe_expert_load_max_over_mean", "moe_held_assign_share",
-        "moe_tokens_dropped", "kda_fused_tile_share", "gattn_live_key_share")
+        "moe_tokens_dropped", "kda_fused_tile_share", "gattn_live_key_share",
+        "kda_scalar_gate_share")
 
 
 def test_sequence_pass_matches_the_reference_values_and_gradients():
@@ -227,14 +228,18 @@ def test_a_cut_inside_a_chunk_equals_two_passes():
         close(whole[:, cut:], run(params, x[:, cut:], state, none[:, cut:])[0])
 
 
-@pytest.mark.parametrize("steps,chunk,block", [(20, 8, 4), (80, 40, 8)])
+@pytest.mark.parametrize("gate", ["one_wide", "broadcast"])
+@pytest.mark.parametrize("steps,chunk,block", [
+    (20, 8, 4), (80, 40, 8), (27, 8, 4)])
 def test_chunked_scan_with_a_scalar_gate_matches_the_published_step(
-        steps, chunk, block):
-    """`kda_chunked` handed one log-decay a head, broadcast over the key
-    channels, against a loop of the published Gated DeltaNet step
+        steps, chunk, block, gate):
+    """`kda_chunked` handed one log-decay a head, one channel wide (the
+    scalar form of the in-chunk preparation, what `_GatedDeltaNet` hands it)
+    or broadcast over the key channels (`_prep_plain`, the definition),
+    against a loop of the published Gated DeltaNet step
     (S <- exp(g) S; S <- S + beta k (v - S^T k)^T; o = S^T q), with resets
     inside a chunk and from a non-zero state: values, final state and the
-    gradients of every input."""
+    gradients of every input.  27 steps are padded to 32."""
     b, h, dk, dv = 2, 4, 8, 8
     ks = jax.random.split(jax.random.PRNGKey(steps), 6)
     unit = lambda z: z / jnp.linalg.norm(z, axis=-1, keepdims=True)  # noqa: E731
@@ -247,8 +252,12 @@ def test_chunked_scan_with_a_scalar_gate_matches_the_published_step(
     resets = jnp.zeros((b, steps), bool).at[0, 3].set(True).at[1, 5].set(True)
     seg = jnp.cumsum(resets.astype(jnp.int32), axis=1)
 
+    width = 1 if gate == "one_wide" else dk
+    assert kl.kda_prep_path(width, dk, dv, chunk, block) == (
+        "scalar" if gate == "one_wide" else "plain")
+
     def chunked(q, k, v, g, beta):
-        wide = jnp.broadcast_to(g[..., None], (b, steps, h, dk))
+        wide = jnp.broadcast_to(g[..., None], (b, steps, h, width))
         return kl.kda_chunked(q, k, v, wide, beta, seg, s0, chunk, block,
                               jnp.float32)
 
@@ -379,7 +388,13 @@ def test_live_key_share_of_the_learn_steps_two_passes(steps, filled, share):
             params, x[:, filled:], state, none[:, filled:])
     stats = reduce_stats(sown)
     assert float(stats["gattn_live_key_share"]) == pytest.approx(share, rel=1e-6)
-    assert ("kda_fused_tile_share" in stats) == (steps > 1)
+    # a sequence's preparation ran the scalar form and no tile kernel; a
+    # tick prepares nothing and sows neither
+    if steps > 1:
+        assert float(stats["kda_scalar_gate_share"]) == 1.0
+        assert float(stats["kda_fused_tile_share"]) == 0.0
+    else:
+        assert not {"kda_scalar_gate_share", "kda_fused_tile_share"} & set(stats)
 
 
 def test_the_published_file_reads_the_published_sizes():

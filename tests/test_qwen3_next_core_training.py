@@ -139,7 +139,9 @@ def test_learn_step_loss_and_gradient_match_the_reference(tmp_path):
     assert float(info["loss"]) == pytest.approx(float(loss), rel=1e-4)
     assert float(info["moe_tokens_dropped"]) == 0.0
     assert 0.0 < float(info["gattn_live_key_share"]) < 1.0
-    assert float(info["kda_fused_tile_share"]) == 0.0  # the CPU's plain path
+    # a one-wide gate: the scalar form, on every platform, and no tile kernel
+    assert float(info["kda_scalar_gate_share"]) == 1.0
+    assert float(info["kda_fused_tile_share"]) == 0.0
     mu = [s for s in jax.tree.leaves(
         new.opt_state, is_leaf=lambda x: hasattr(x, "mu"))
         if hasattr(s, "mu")][0].mu
@@ -161,6 +163,7 @@ def test_fused_segment_trains_with_the_core(tmp_path):
     assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
     assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
     assert all(r["kda_fused_tile_share"] == 0.0 for r in learn)
+    assert all(r["kda_scalar_gate_share"] == 1.0 for r in learn)
     assert "mla_live_key_share" not in learn[0]
     # freeway has no terminals: the trained slice's 8 queries see the 4
     # burn-in keys and their own causal half, of 12 + 8 slots
