@@ -360,21 +360,22 @@ class _KDA(nn.Module):
         h, dk, kk = kc.kda_heads, kc.kda_dim, kc.conv_kernel
         d = h * dk
         names = ("q", "k", "v")
-        z = jnp.concatenate(
-            [_Linear(d, cd, name=f"{n}_proj")(x) for n in names], axis=-1)
-        taps = jnp.concatenate(
-            [_Taps(kk, d, name=f"{n}_conv")() for n in names], axis=-1)
-        conv, tail = _causal_conv(z, taps, state["conv"], seg)
-        q, k, v = (y.reshape(b, t, h, dk)
-                   for y in jnp.split(jax.nn.silu(conv), 3, axis=-1))
-        q, k = _l2_norm(q), _l2_norm(k)
-        low = lambda a, bb, n: _Linear(n, cd, name=bb)(  # noqa: E731
-            _Linear(kc.low_rank, cd, name=a)(x))
-        a_log = self.param("A_log", _a_log_init, (h,))
-        dt_bias = self.param("dt_bias", _dt_bias_init, (d,))
-        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
-            (low("f_a", "f_b", d) + dt_bias).reshape(b, t, h, dk))
-        beta = jax.nn.sigmoid(_Linear(h, cd, name="b_proj")(x))
+        with jax.named_scope(device_scopes.KDA_MIX):
+            z = jnp.concatenate(
+                [_Linear(d, cd, name=f"{n}_proj")(x) for n in names], axis=-1)
+            taps = jnp.concatenate(
+                [_Taps(kk, d, name=f"{n}_conv")() for n in names], axis=-1)
+            conv, tail = _causal_conv(z, taps, state["conv"], seg)
+            q, k, v = (y.reshape(b, t, h, dk)
+                       for y in jnp.split(jax.nn.silu(conv), 3, axis=-1))
+            q, k = _l2_norm(q), _l2_norm(k)
+            low = lambda a, bb, n: _Linear(n, cd, name=bb)(  # noqa: E731
+                _Linear(kc.low_rank, cd, name=a)(x))
+            a_log = self.param("A_log", _a_log_init, (h,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (d,))
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                (low("f_a", "f_b", d) + dt_bias).reshape(b, t, h, dk))
+            beta = jax.nn.sigmoid(_Linear(h, cd, name="b_proj")(x))
         if t == 1:
             with jax.named_scope(device_scopes.CORE_STEP):
                 o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
@@ -387,9 +388,11 @@ class _KDA(nn.Module):
             self.sow(STATS, "kda_fused_tile_share", float(kda_prep_path(
                 dk, dk, dk, _chunk_len(t, kc.chunk, kc.block),
                 kc.block) == "tile"))
-        o = _RMSNorm(kc.eps, name="o_norm")(o / math.sqrt(dk))
-        gate = jax.nn.sigmoid(low("g_a", "g_b", d))
-        y = _Linear(kc.hidden, cd, name="o_proj")(gate * o.reshape(b, t, d))
+        with jax.named_scope(device_scopes.KDA_MIX):
+            o = _RMSNorm(kc.eps, name="o_norm")(o / math.sqrt(dk))
+            gate = jax.nn.sigmoid(low("g_a", "g_b", d))
+            y = _Linear(kc.hidden, cd, name="o_proj")(
+                gate * o.reshape(b, t, d))
         return y, {"S": s, "conv": tail}
 
 

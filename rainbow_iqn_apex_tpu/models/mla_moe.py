@@ -361,13 +361,17 @@ class _Layer(nn.Module):
     @nn.compact
     def __call__(self, x, state, seg):
         kc, cd = self.kc, self.compute_dtype
-        hn = _RMSNorm(kc.eps, name="mix_norm")(x)
+        with jax.named_scope(device_scopes.CORE_NORM):
+            hn = _RMSNorm(kc.eps, name="mix_norm")(x)
         mixer = kc.mixers[self.index - 1]
         y, state = mixer(kc, cd, name=mixer.layer_name)(hn, state, seg)
         x = x + y
-        hn = _RMSNorm(kc.eps, name="ffn_norm")(x)
+        with jax.named_scope(device_scopes.CORE_NORM):
+            hn = _RMSNorm(kc.eps, name="ffn_norm")(x)
         if self.index <= kc.first_dense:
-            x = x + _SwiGLU(kc.dense_width, cd, name="ffn")(hn)
+            with jax.named_scope(device_scopes.DENSE_FFN):
+                y = _SwiGLU(kc.dense_width, cd, name="ffn")(hn)
+            x = x + y
         else:
             x = x + _MoE(kc, cd, name="moe")(hn)
         return x, state
@@ -396,7 +400,8 @@ class _Stack(nn.Module):
             with jax.named_scope(device_scopes.CORE_LAYER):
                 x, new_state[f"layer_{i}"] = layer(
                     x, state[f"layer_{i}"], seg)
-        return _RMSNorm(kc.eps, name="final_norm")(x), new_state
+        with jax.named_scope(device_scopes.CORE_NORM):
+            return _RMSNorm(kc.eps, name="final_norm")(x), new_state
 
 
 class StackCore:

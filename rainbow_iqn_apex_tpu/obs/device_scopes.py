@@ -23,6 +23,14 @@ head of the next, the whole fusion counts under the one that named it.  A
 container (`while`, `conditional`) counts its own self time, which is the
 waits between its children, under its own scope: the waits between the ops
 of a tick land on the segment's outer ``while``, outside every tick scope.
+The same waits are counted a second time where they belong, as idle: every
+gap between two innermost ops of one program run goes to the scope path of
+the op that ends it (``idle_s_by_path``), so busy and idle time together
+account for the whole traced window by scope.  An instruction the compiler
+made (no ``op_name`` of its own: a layout copy, a slice of a loop it
+unrolled) is a class of its own beside its inherited scope
+(``instruction_origins``): it is also put down to the instruction that
+consumes its result, the one that needed the layout.
 
 jax is imported only inside ``reduce_capture`` (for the .xplane.pb reader);
 everything else is plain string and number work.
@@ -31,11 +39,14 @@ everything else is plain string and number work.
 from __future__ import annotations
 
 import bisect
+import functools
 import glob
+import heapq
+import itertools
 import os
 import re
 from collections import defaultdict
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 # ---- the fused tick (train_anakin.build_fused_segment,
 # train_anakin_r2d2.build_fused_r2d2_segment)
@@ -65,6 +76,9 @@ MOE_ROUTE = "moe_route"  # router, top-k, the sort by held expert
 MOE_EXPERTS = "moe_experts"  # gather, the grouped products, scatter-add
 MOE_SHARED = "moe_shared"  # the shared expert
 CORE_STEP = "core_step"  # the delta-rule recurrence of one step (the actor's tick)
+CORE_NORM = "core_norm"  # a block's two pre-norms, and the stack's final norm
+DENSE_FFN = "dense_ffn"  # the SwiGLU of a dense (not expert) layer
+KDA_MIX = "kda_mix"  # KDA but its scan: projections, conv, gates, o_norm, o
 # ---- the Qwen3-Next core's two mixers (models/qwen3_next.py); its scan wears
 # KDA_SCAN / KDA_PREP / CORE_STEP, its expert layers the MOE_* names
 GDN_MIX = "gdn_mix"  # Gated DeltaNet but its scan: projections, conv, gates, gated norm
@@ -81,7 +95,7 @@ ALL_SCOPES = TICK_SCOPES + (
     LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
     CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM, GDN_MIX, GATTN_PROJ, GATTN_ATTN,
-    GATTN_ROPE,
+    GATTN_ROPE, CORE_NORM, DENSE_FFN, KDA_MIX,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 
@@ -121,14 +135,59 @@ def scope_path(op_name: str) -> Tuple[str, ...]:
     return tuple(out)
 
 
-_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s+")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_SHAPE_OPCODE = re.compile(r"(.*?) ([a-z][\w\-]*)\(")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _CALLS = re.compile(
     r"\b(?:body|condition|calls|to_apply|true_computation|false_computation)"
     r"=%?([\w.\-]+)|\b(?:branch|called)_computations=\{([^}]*)\}")
 
+NO_SCOPE = "(no scope)"  # the key of an empty scope path where paths are keys
+UNRESOLVED = "(unresolved)"  # of an op whose instruction no module text holds
 
+
+class Origin(NamedTuple):
+    """Where an instruction of a compiled module comes from."""
+
+    own: bool  # it carries `op_name` metadata of its own: the program wrote it
+    opcode: str  # `copy`, `fusion`, `while`, ...
+    shape: str  # its result's shape as the text has it, layout and all
+    consumer_path: Tuple[str, ...]  # see `instruction_origins`
+
+
+@functools.lru_cache(maxsize=2)
+def _parse(hlo_text: str):
+    """One pass over a compiled module's text: ``[(instruction, computation,
+    shape, opcode, op_name or None, operands)]`` in the text's order, and
+    ``{computation: the first instruction that calls it}``.  Operands are the
+    `%names` between the opcode and the metadata, so they also hold the
+    computations an instruction calls; `instruction_origins` keeps those of
+    the instruction's own computation."""
+    rows, caller, comp = [], {}, ""
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            c = _COMPUTATION.match(line)
+            comp = c.group(1) if c else comp
+            continue
+        inst, rest = m.group(1), line[m.end():]
+        so = _SHAPE_OPCODE.match(rest)
+        shape, opcode = (so.group(1), so.group(2)) if so else ("", "")
+        op = _OP_NAME.search(rest)
+        cut = rest.find(", metadata={")
+        body = rest[so.end() if so else 0: cut if cut >= 0 else len(rest)]
+        operands = re.findall(r"%([\w.\-]+)", body) if "%" in body else \
+            re.findall(r"[A-Za-z_][\w.\-]*", body)
+        rows.append((inst, comp, shape, opcode,
+                     op.group(1) if op else None, operands))
+        for one, many in _CALLS.findall(rest):
+            for called in [one] if one else re.findall(r"[\w.\-]+", many):
+                caller.setdefault(called, inst)
+    return rows, caller
+
+
+@functools.lru_cache(maxsize=2)  # a capture's readers ask again and again
 def instruction_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
     """{instruction name: scope path} of a compiled module's text
     (``jitted.lower(...).compile().as_text()``), from each instruction's
@@ -137,25 +196,12 @@ def instruction_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
     of the instruction that calls its computation: the copies inside the
     `while` that a gather became count as that gather's, and a copy in the
     entry computation, which nothing calls, has the empty path: it is
-    known, and in no scope."""
-    named: Dict[str, Tuple[str, ...]] = {}
-    home: Dict[str, str] = {}  # instruction -> its computation
-    caller: Dict[str, str] = {}  # computation -> the instruction calling it
-    comp = ""
-    for line in hlo_text.splitlines():
-        m = _INSTRUCTION.match(line)
-        if not m:
-            c = _COMPUTATION.match(line)
-            comp = c.group(1) if c else comp
-            continue
-        inst = m.group(1)
-        home[inst] = comp
-        op = _OP_NAME.search(line)
-        if op:
-            named[inst] = scope_path(op.group(1))
-        for one, many in _CALLS.findall(line):
-            for called in [one] if one else re.findall(r"[\w.\-]+", many):
-                caller.setdefault(called, inst)
+    known, and in no scope.  The result is kept for the text's next asker:
+    read it, do not change it."""
+    rows, caller = _parse(hlo_text)
+    named = {inst: scope_path(op_name)
+             for inst, _c, _s, _o, op_name, _a in rows if op_name is not None}
+    home = {row[0]: row[1] for row in rows}
     out = dict(named)
     for inst in home:
         seen, at = set(), inst
@@ -163,6 +209,38 @@ def instruction_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
             seen.add(at)
             at = caller.get(home.get(at, ""), "")
         out[inst] = named.get(at, ())
+    return out
+
+
+@functools.lru_cache(maxsize=2)
+def instruction_origins(hlo_text: str) -> Dict[str, Origin]:
+    """{instruction name: `Origin`} of the same text, by the same parse:
+    whether the instruction carries ``op_name`` metadata of its own, its
+    opcode and result shape, and its ``consumer_path``.  For an instruction
+    with metadata that is its own scope path.  For one the compiler made it
+    is the scope path of the first instruction of the same computation that
+    reads its result, through further instructions without metadata (a copy
+    read by a bitcast read by a fusion is that fusion's): a layout copy
+    belongs to the op that needed the layout.  Where nothing with a name
+    reads it (the computation's root) it keeps its inherited path, the one
+    `instruction_scopes` gives it."""
+    rows, _caller = _parse(hlo_text)
+    inherited = instruction_scopes(hlo_text)
+    own = {row[0]: row[4] is not None for row in rows}
+    home = {row[0]: row[1] for row in rows}
+    reader: Dict[str, str] = {}  # instruction -> the first one that reads it
+    for inst, comp, _s, _o, _n, operands in rows:
+        for operand in operands:
+            if home.get(operand) == comp and operand != inst:
+                reader.setdefault(operand, inst)
+    out = {}
+    for inst, _c, shape, opcode, _n, _a in rows:
+        seen, at = set(), inst
+        while not own[at] and at not in seen and at in reader:
+            seen.add(at)
+            at = reader[at]
+        out[inst] = Origin(own[inst], opcode, shape,
+                           inherited[at if own[at] else inst])
     return out
 
 
@@ -174,7 +252,8 @@ def instruction_name(event_name: str) -> str:
 
 
 def attribute(op_seconds: Iterable[Sequence],
-              inst_scopes: Dict[str, Tuple[str, ...]]) -> dict:
+              inst_scopes: Dict[str, Tuple[str, ...]],
+              origins: Optional[Dict[str, Origin]] = None) -> dict:
     """Device self time by scope.  ``op_seconds`` is [(event name, self
     seconds)] as a trace reduction gives them.  Every entry lands in exactly
     one of three classes, which add up to the input:
@@ -185,30 +264,65 @@ def attribute(op_seconds: Iterable[Sequence],
                       is part of ``tick_learn``) and once in ``by_path``
                       under the whole path, "/"-joined (``seconds`` reads it)
       outside_tick_s  known instruction, no ``tick_*`` scope in its path
-                      (``outside`` lists them, longest first)
+                      (``outside`` lists them, longest first; those with
+                      a scope all the same, which is every op of a host-fed
+                      loop's programs, are in ``outside_by_path``)
       unresolved_s    the instruction is not in ``inst_scopes``: the
                       attribution is broken to that extent
                       (``unresolved`` lists them)
-    """
+
+    Across the three, with ``origins`` (`instruction_origins` of the same
+    text), the instructions the compiler made are summed a second time:
+    ``compiler_made_s``, ``compiler_made_by_consumer_path`` (the path of
+    the instruction that reads the result, `NO_SCOPE` for the empty one) and
+    ``compiler_made`` (instruction, seconds, opcode, shape, consumer path;
+    longest first).  Without ``origins`` the three are None."""
+    out = _attribute(
+        (inst, s, inst_scopes.get(inst), origins.get(inst) if origins else None)
+        for inst, s in ((instruction_name(n), s) for n, s in op_seconds))
+    if origins is None:
+        out.update(compiler_made_s=None, compiler_made_by_consumer_path=None,
+                   compiler_made=None)
+    return out
+
+
+def path_key(path: Optional[Tuple[str, ...]]) -> str:
+    """A scope path as a key: "/"-joined, `NO_SCOPE` for the empty path,
+    `UNRESOLVED` for None (an instruction no module text holds)."""
+    return UNRESOLVED if path is None else "/".join(path) or NO_SCOPE
+
+
+def _attribute(entries) -> dict:
+    """`attribute` over (instruction, seconds, path or None, `Origin` or
+    None) entries: `reduce_events` resolves each op in its own program's
+    text, where instruction names are unique."""
     by_scope: Dict[str, float] = defaultdict(float)
     by_path: Dict[str, float] = defaultdict(float)
     outside: Dict[str, float] = defaultdict(float)
+    outside_by_path: Dict[str, float] = defaultdict(float)
     unresolved: Dict[str, float] = defaultdict(float)
+    made: Dict[str, float] = defaultdict(float)
+    made_by: Dict[str, float] = defaultdict(float)
+    made_of: Dict[str, Origin] = {}
     total = tick_s = 0.0
-    for name, seconds in op_seconds:
+    for inst, seconds, path, origin in entries:
         seconds = float(seconds)
         total += seconds
-        inst = instruction_name(name)
-        path = inst_scopes.get(inst)
         if path is None:
             unresolved[inst] += seconds
         elif not any(s in TICK_SCOPES for s in path):
             outside[inst] += seconds
+            if path:
+                outside_by_path["/".join(path)] += seconds
         else:
             tick_s += seconds
             by_path["/".join(path)] += seconds
             for scope in set(path):
                 by_scope[scope] += seconds
+        if origin is not None and not origin.own:
+            made[inst] += seconds
+            made_by[path_key(origin.consumer_path)] += seconds
+            made_of[inst] = origin
     ranked = lambda d: sorted(d.items(), key=lambda kv: -kv[1])  # noqa: E731
     return {
         "total_s": total,
@@ -218,7 +332,14 @@ def attribute(op_seconds: Iterable[Sequence],
         "by_scope": dict(by_scope),
         "by_path": dict(by_path),
         "outside": ranked(outside),
+        "outside_by_path": dict(outside_by_path),
         "unresolved": ranked(unresolved),
+        "compiler_made_s": sum(made.values()),
+        "compiler_made_by_consumer_path": dict(made_by),
+        "compiler_made": [
+            (inst, t, made_of[inst].opcode, made_of[inst].shape,
+             path_key(made_of[inst].consumer_path))
+            for inst, t in ranked(made)],
     }
 
 
@@ -264,14 +385,15 @@ def module_name(hlo_text: str) -> str:
 
 
 def _self_times(events):
-    """[(name, start, end, self_ns, is_leaf)] of one line's (name, start,
-    end) events, which nest: an event's self time is its own without what it
-    contains."""
-    out, stack = [], []  # stack entries: [name, start, end, child_ns, kids]
+    """[(name, start, end, self_ns, is_leaf, parent)] of one line's (name,
+    start, end) events, which nest: an event's self time is its own without
+    what it contains; `parent` is the name of the innermost event round it
+    (a `while`, a `conditional`), "" at the top."""
+    out, stack = [], []  # entries: [name, start, end, child_ns, kids, parent]
 
     def close(top):
         out.append((top[0], top[1], top[2], top[2] - top[1] - top[3],
-                    top[4] == 0))
+                    top[4] == 0, top[5]))
 
     for name, s, e in sorted(events, key=lambda ev: (ev[1], -ev[2])):
         while stack and stack[-1][2] <= s:
@@ -279,7 +401,7 @@ def _self_times(events):
         if stack:
             stack[-1][3] += min(e, stack[-1][2]) - s
             stack[-1][4] += 1
-        stack.append([name, s, e, 0.0, 0])
+        stack.append([name, s, e, 0.0, 0, stack[-1][0] if stack else ""])
     while stack:
         close(stack.pop())
     return out
@@ -300,11 +422,23 @@ def reduce_events(events, module_texts: Sequence[str] = (),
     averaged over the planes; an operation's time is its own (`_self_times`).
     Operations are put down to scopes (`attribute`) through the text of the
     module they ran in: `module_texts` are the compiled programs the caller
-    knows, matched to the "XLA Modules" events by name, and an operation of
-    any other program counts as unresolved.  Each idle gap longer than 1 ms
-    is put down to the innermost host event named in `span_names`
-    (obs/trace.Tracer's spans are `TraceAnnotation`s, on the same clock)
-    that covers its middle."""
+    knows, matched to the "XLA Modules" events by name (each op is looked up
+    in its own program's text), and an operation of any other program counts
+    as unresolved.
+
+    Idle time is every gap between two innermost operations, of any length.
+    A gap between two ops of ONE program run is idle inside a dispatch and
+    goes to the scope path of the op that ends it, as that op's self time
+    does (``idle_s_by_path``; `path_key` makes the keys); any other gap (no
+    program running, or the next run's first op) is ``idle_between_
+    dispatches_s``.  The two add up to ``window_s`` - ``busy_s`` exactly.
+    ``idle_gaps`` lists the 32 longest gaps: one inside a dispatch with its
+    path, the op that ends it (``op``), the op it follows (``after``) and
+    the innermost container event round the closing op (`while.N`,
+    `conditional.N`); every gap longer than 1 ms with the
+    innermost host event named in `span_names` (obs/trace.Tracer's spans are
+    `TraceAnnotation`s, on the same clock) that covers its middle, which
+    ``idle_gap_ms_by_span`` sums."""
     span_names = set(span_names)
     ops, runs, host_spans = defaultdict(list), defaultdict(list), []
     for plane, line, name, s, d in events:
@@ -317,14 +451,16 @@ def reduce_events(events, module_texts: Sequence[str] = (),
     if not ops:
         return None
     planes = sorted(ops)
-    known = {module_name(t) for t in module_texts}
-    inst_scopes: Dict[str, Tuple[str, ...]] = {}
-    for text in module_texts:
-        inst_scopes.update(instruction_scopes(text))
+    scopes_of = {module_name(t): instruction_scopes(t) for t in module_texts}
+    origins_of = {module_name(t): instruction_origins(t) for t in module_texts}
     lo = min(s for p in planes for _n, s, _e in ops[p])
     hi = max(e for p in planes for _n, _s, e in ops[p])
-    busy_ns, gaps = 0.0, []
-    op_s: Dict[str, float] = defaultdict(float)
+    busy_ns = between_ns = 0.0
+    longest, long_gaps = [], []  # heap of the 32 longest; every gap over 1 ms
+    order = itertools.count()  # ties never compare the dicts
+    op_s: Dict[Tuple[str, str], float] = defaultdict(float)
+    idle_by_path: Dict[str, float] = defaultdict(float)
+    key_of: Dict[Tuple[str, str], str] = {}  # (program, event) -> path key
     programs: Dict[str, Dict[str, float]] = {}
     for p in planes:
         mods = sorted(runs[p], key=lambda r: r[1])
@@ -333,25 +469,54 @@ def reduce_events(events, module_texts: Sequence[str] = (),
             prog = programs.setdefault(name, {"runs": 0, "device_ms": 0.0})
             prog["runs"] += 1 / len(planes)
             prog["device_ms"] += (e - s) / 1e6 / len(planes)
-        cur = lo
-        for name, s, e, self_ns, leaf in sorted(
+        cur, last_run, last = lo, None, ""  # the last innermost op, its run
+        for name, s, e, self_ns, leaf, parent in sorted(
                 _self_times(ops[p]), key=lambda ev: ev[1]):
             i = bisect.bisect_right(starts, s) - 1
-            mod = mods[i][0] if i >= 0 and s < mods[i][2] else "no program"
-            # instruction names are unique within one module only: under the
-            # program's name an op of another program resolves to nothing
-            op_s[name if mod in known else f"{mod}:{name}"] += (
-                self_ns / 1e9 / len(planes))
+            run = i if i >= 0 and s < mods[i][2] else None
+            mod = mods[run][0] if run is not None else "no program"
+            op_s[mod, name] += self_ns / 1e9 / len(planes)
             if not leaf:
                 continue
-            if s - cur > MIN_GAP_NS:
-                gaps.append((s - cur, _covering(host_spans, 0.5 * (s + cur))))
+            ns = s - cur
+            if ns > 0:
+                inside = run is not None and run == last_run
+                if inside:
+                    if (mod, name) not in key_of:
+                        key_of[mod, name] = path_key(scopes_of.get(
+                            mod, {}).get(instruction_name(name)))
+                    idle_by_path[key_of[mod, name]] += ns / 1e9 / len(planes)
+                else:
+                    between_ns += ns
+                if ns > MIN_GAP_NS or len(longest) < 32 or ns > longest[0][0]:
+                    gap = {"ms": ns / 1e6}
+                    if inside:
+                        gap.update(path=key_of[mod, name],
+                                   op=instruction_name(name),
+                                   after=instruction_name(last),
+                                   container=instruction_name(parent))
+                    entry = (ns, next(order), 0.5 * (cur + s), gap)
+                    if ns > MIN_GAP_NS:
+                        long_gaps.append(entry)
+                    elif len(longest) < 32:
+                        heapq.heappush(longest, entry)
+                    else:
+                        heapq.heapreplace(longest, entry)
             busy_ns += max(e - max(s, cur), 0.0)
-            cur = max(cur, e)
-    attr = attribute(op_s.items(), inst_scopes)
+            if e >= cur:
+                cur, last = e, name
+            last_run = run
+        between_ns += hi - cur  # a plane that ends before the last one
+    single = len(scopes_of) == 1  # one program: its instructions' own names
+    attr = _attribute(
+        (inst if single and mod in scopes_of else f"{mod}:{inst}", t,
+         scopes_of.get(mod, {}).get(inst), origins_of.get(mod, {}).get(inst))
+        for (mod, name), t in op_s.items()
+        for inst in [instruction_name(name)])
     by_span: Dict[str, float] = defaultdict(float)
-    for ns, span in gaps:
-        by_span[span] += ns / 1e6
+    for _ns, _i, middle, gap in long_gaps:
+        gap["span"] = _covering(host_spans, middle)
+        by_span[gap["span"]] += gap["ms"]
     busy_s, window_s = busy_ns / len(planes) / 1e9, (hi - lo) / 1e9
     return {
         "chips": len(planes),
@@ -359,11 +524,16 @@ def reduce_events(events, module_texts: Sequence[str] = (),
         "busy_s": busy_s,
         "idle_share": 100.0 * (1.0 - busy_s / window_s) if window_s else 0.0,
         "programs": programs,
-        "scoped_instructions": sum(1 for path in inst_scopes.values() if path),
+        "scoped_instructions": sum(
+            1 for paths in scopes_of.values() for path in paths.values()
+            if path),
         "dispatches": max(
-            (programs[m]["runs"] for m in known if m in programs), default=0),
+            (programs[m]["runs"] for m in scopes_of if m in programs),
+            default=0),
         **attr,
-        "idle_gaps": [{"ms": ns / 1e6, "span": span}
-                      for ns, span in sorted(gaps, reverse=True)[:32]],
+        "idle_s_by_path": dict(idle_by_path),
+        "idle_between_dispatches_s": between_ns / len(planes) / 1e9,
+        "idle_gaps": [
+            gap for *_k, gap in sorted(long_gaps + longest, reverse=True)[:32]],
         "idle_gap_ms_by_span": dict(by_span),
     }

@@ -61,9 +61,18 @@ REQUIRED_KEYS: Dict[str, frozenset] = {
     # busy_s/idle_share of the device, `programs` run in the window,
     # scope_ms_per_step/path_ms_per_step (device self time a learn step by
     # the programs' scope names), outside_tick_ms_per_dispatch,
-    # unresolved_share, and idle_gaps (each gap over 1 ms with the host
-    # span that covers it).  Absent on a backend with no device plane (CPU);
-    # carries `error` alone where the capture could not be reduced
+    # unresolved_share, idle_gaps (the 32 longest: one inside a dispatch
+    # with the scope `path`, the `op` that ends it, the op it comes `after`
+    # and its `container`; each gap over 1 ms with the host `span` that
+    # covers it) and idle_gap_ms_by_span; idle_ms_by_path_per_step (idle
+    # inside a dispatch a learn step, by the scope path of the op that ends
+    # each gap) and idle_between_dispatches_s, which add up to window_s -
+    # busy_s;
+    # compiler_made_ms_per_dispatch, compiler_made_ms_by_consumer_path_per_
+    # dispatch and compiler_made (the 16 largest instructions the compiler
+    # made, with opcode, shape and the path of the op that reads them).
+    # Absent on a backend with no device plane (CPU); carries `error` alone
+    # where the capture could not be reduced
     # elasticity rows (parallel/elastic.py; docs/RESILIENCE.md "heal"):
     "host_alive": frozenset({"alive_host", "epoch"}),  # lease revival edge
     "shard_readmit": frozenset({"shard", "epoch"}),  # drop_shard reversed

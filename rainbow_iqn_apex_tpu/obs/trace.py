@@ -16,8 +16,10 @@ Also here: the jax-side gauges (compile and cache-hit counts via
 jax.monitoring, device memory via Device.memory_stats) and TraceWindow — the
 step-windowed profiler capture of --trace-dir, which reduces its own capture
 to one 'device_time' row when it closes (obs/device_scopes.py): device busy
-and idle share, milliseconds a learn step by the program's scope names, and
-the long idle gaps by the host span that covers them.
+and idle share, milliseconds a learn step by the program's scope names, idle
+inside a dispatch by the scope path of the op that ends each gap, the
+instructions the compiler made, and the long idle gaps by the host span that
+covers them.
 """
 
 from __future__ import annotations
@@ -123,8 +125,9 @@ class TraceWindow:
     When the window closes the capture is reduced (obs/device_scopes.py) and
     logged as one 'device_time' row; the .xplane.pb stays where it is.  The
     scopes of the row come from the text of the compiled programs the loop
-    registered with ``add_program``; the idle gaps are named by ``tracer``'s
-    spans.  A capture with no device plane (the CPU backend) logs no row."""
+    registered with ``add_program``; the idle gaps outside a program run are
+    named by ``tracer``'s spans, those inside one by scope path.  A capture
+    with no device plane (the CPU backend) logs no row."""
 
     def __init__(self, logdir: str, start_step: int, num_steps: int,
                  logger=None, tracer: Optional["Tracer"] = None):
@@ -225,11 +228,34 @@ class TraceWindow:
                 k: ms(v, steps) for k, v in sorted(red["by_path"].items())},
             "outside_tick_ms_per_dispatch": ms(
                 red["outside_tick_s"], red["dispatches"]),
+            # of it, what wears a scope outside every tick: all of a
+            # host-fed loop's programs, which have no tick
+            "outside_path_ms_per_step": {
+                k: ms(v, steps)
+                for k, v in sorted(red["outside_by_path"].items())},
             "unresolved_share": round(
                 100.0 * red["unresolved_s"] / red["total_s"], 4)
             if red["total_s"] else 0.0,
             "idle_gaps": red["idle_gaps"],
             "idle_gap_ms_by_span": red["idle_gap_ms_by_span"],
+            # the other half of the account: idle inside a dispatch by the
+            # scope path of the op that ends each gap, and what the compiler
+            # made, by the path of the op that reads it
+            "idle_ms_by_path_per_step": {
+                k: ms(v, steps)
+                for k, v in sorted(red["idle_s_by_path"].items())},
+            "idle_between_dispatches_s": round(
+                red["idle_between_dispatches_s"], 9),
+            "compiler_made_ms_per_dispatch": ms(
+                red["compiler_made_s"], red["dispatches"]),
+            "compiler_made_ms_by_consumer_path_per_dispatch": {
+                k: ms(v, red["dispatches"]) for k, v in sorted(
+                    red["compiler_made_by_consumer_path"].items())},
+            "compiler_made": [
+                {"instruction": inst, "ms": round(1e3 * t, 6),
+                 "opcode": opcode, "shape": shape, "consumer": consumer}
+                for inst, t, opcode, shape, consumer
+                in red["compiler_made"][:16]],
         }
 
     def close(self, step: int = 0) -> None:

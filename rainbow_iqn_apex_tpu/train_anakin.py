@@ -152,6 +152,14 @@ def train_anakin(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, Any
     returns: collections.deque = collections.deque(maxlen=100)
     device = jax.devices()[0]
 
+    # --trace-dir: the capture's 'device_time' row resolves both programs'
+    # ops to scopes, each in its own text (read when the capture closes, in
+    # steady state: `prev` is a tuple then, the program the ticks run)
+    obs_run.trace_window.add_program(lambda: act_append.lower(
+        ts.params, stack, ds, frame_d, keep_d, prev, k).compile().as_text())
+    obs_run.trace_window.add_program(lambda: fused.lower(
+        ts, ds, k, jnp.float32(priority_beta(cfg, frames))
+    ).compile().as_text())
     try:
         while frames < total_frames:
             frame_d = put_frames(obs)  # flat-byte staging (rank-3 put penalty)

@@ -539,6 +539,15 @@ def _train_anakin_r2d2_hostfed(cfg: Config,
         eval_agent.state = ts
         return evaluate_r2d2(cfg, eval_agent, seed=cfg.seed + 977)
 
+    # --trace-dir: the capture's 'device_time' row resolves both programs'
+    # ops to scopes, each in its own text (read when the capture closes, in
+    # steady state: `prev` is a tuple then, the program the ticks run)
+    obs_run.trace_window.add_program(lambda: act_append.lower(
+        ts.params, stack, ss, lstm, frame_d, keep_d, prev, k
+    ).compile().as_text())
+    obs_run.trace_window.add_program(lambda: learn.lower(
+        ts, ss, k, jnp.float32(priority_beta(cfg, frames))
+    ).compile().as_text())
     try:
         while frames < total_frames:
             frame_d = put_frames(obs)

@@ -87,7 +87,8 @@ def _last_with(rows: List[Dict[str, Any]], kind: str, key: str) -> Dict[str, Any
 
 def _device_time_lines(dt) -> List[str]:
     """The 'device_time' row as text: idle share, milliseconds a learn step
-    by scope, what ran outside the tick, and the long idle gaps by span."""
+    by scope, what ran outside the tick, the long idle gaps by span, idle
+    inside a dispatch by scope path, and what the compiler made."""
     if not dt:
         return []
     if dt.get("error"):
@@ -105,6 +106,21 @@ def _device_time_lines(dt) -> List[str]:
     for span, ms in sorted((dt.get("idle_gap_ms_by_span") or {}).items(),
                            key=lambda kv: -kv[1]):
         lines.append(f"  idle gaps over 1ms under {span}: {round(ms, 3)}ms")
+    # idle inside a dispatch, by the scope path of the op that ends each gap
+    # (a fused loop's host span is always `segment`; the path says where)
+    for path, ms in sorted((dt.get("idle_ms_by_path_per_step") or {}).items(),
+                           key=lambda kv: -kv[1])[:12]:
+        lines.append(f"  idle before {path}: {ms}ms/learn step")
+    if dt.get("idle_between_dispatches_s") is not None:
+        lines.append(
+            f"  idle between dispatches: {dt['idle_between_dispatches_s']}s")
+    if dt.get("compiler_made_ms_per_dispatch") is not None:
+        lines.append(f"  compiler-made instructions: "
+                     f"{dt['compiler_made_ms_per_dispatch']}ms/dispatch")
+    for made in (dt.get("compiler_made") or [])[:6]:
+        lines.append(
+            f"    {made['instruction']} {made['opcode']} {made['shape']}: "
+            f"{made['ms']}ms, read under {made['consumer']}")
     return lines
 
 

@@ -72,13 +72,26 @@ def test_fused_requires_divisible_lanes(tmp_path):
         train_anakin(cfg, max_frames=100)
 
 
-def test_fused_host_loop_flag(tmp_path):
+def test_fused_host_loop_flag(tmp_path, monkeypatch):
     """fused_env=False drives the same jax game through the host anakin
     loop — the two paths share the game, not the loop."""
+    from rainbow_iqn_apex_tpu.obs import TraceWindow
+    from rainbow_iqn_apex_tpu.obs import device_scopes as ds
+
+    registered = []
+    monkeypatch.setattr(TraceWindow, "add_program",
+                        lambda self, text: registered.append(text))
     cfg = _cfg(tmp_path, fused_env=False)
     summary = train_anakin(cfg, max_frames=600)
     assert summary["frames"] >= 600
     assert summary["learn_steps"] > 0
+    # both programs are registered for the 'device_time' row, and their
+    # texts name the scopes of the work inside them
+    act, learn = (text() for text in registered)
+    assert ds.module_name(act) != ds.module_name(learn)
+    assert any(ds.NET_TRUNK in p for p in ds.instruction_scopes(act).values())
+    assert any(ds.LEARN_STEP in p and ds.OPTIMIZER in p
+               for p in ds.instruction_scopes(learn).values())
 
 
 @pytest.mark.slow

@@ -106,7 +106,17 @@ def test_learn_row_reports_append_emit_tick_share(tmp_path):
         before = now
 
 
-def test_hostfed_anakin_r2d2_smoke(tmp_path):
+def _spy_on_add_program(monkeypatch):
+    """The programs a loop registers with its `TraceWindow`, armed or not."""
+    from rainbow_iqn_apex_tpu.obs import TraceWindow
+
+    registered = []
+    monkeypatch.setattr(TraceWindow, "add_program",
+                        lambda self, text: registered.append(text))
+    return registered
+
+
+def test_hostfed_anakin_r2d2_smoke(tmp_path, monkeypatch):
     """Non-jaxgame envs dispatch to the host-fed loop: env on host, sequence
     ring + LSTM + stack device-resident, lag-one appends."""
     cfg = _cfg(
@@ -118,10 +128,20 @@ def test_hostfed_anakin_r2d2_smoke(tmp_path):
         learn_start=200,
         anakin_segment_ticks=8,
     )
+    registered = _spy_on_add_program(monkeypatch)
     summary = train_anakin_r2d2(cfg, max_frames=1_200)
     assert summary["frames"] >= 1_200
     assert summary["learn_steps"] > 20
     assert np.isfinite(summary["eval_score_mean"])
+    # both programs are registered for the 'device_time' row, and their
+    # texts name the scopes of the work inside them
+    from rainbow_iqn_apex_tpu.obs import device_scopes as ds
+
+    act, learn = (text() for text in registered)
+    assert ds.module_name(act) != ds.module_name(learn)
+    assert any(ds.LSTM_SCAN in p for p in ds.instruction_scopes(act).values())
+    assert any(ds.LEARN_STEP in p and ds.OPTIMIZER in p
+               for p in ds.instruction_scopes(learn).values())
 
 
 @pytest.mark.slow

@@ -272,6 +272,188 @@ def test_reduce_events_by_hand():
         {"segment": 2.0, "no span": 19.0})
 
 
+# ---------------- the other half of the account: idle by scope path, and
+# the instructions the compiler made
+
+HLO_MADE = """HloModule jit_segment, entry_computation_layout={()->f32[]}
+
+%body (arg: (s32[], u8[9])) -> (s32[], u8[9]) {
+  %gte.1 = u8[9]{0} get-tuple-element(%arg), index=1
+  %copy.415 = u8[9]{0:T(8,128)} copy(%gte.1), backend_config={"estimated_cycles":"100"}
+  %bitcast.3 = u8[3,3]{1,0} bitcast(%copy.415)
+  %fusion.20 = f32[3]{0} fusion(%bitcast.3), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(segment)/while/body/tick_learn/cond/branch_1_fun/learn_step/core_layer/kda_mix/dot_general" stack_frame_id=3}
+  %copy.416 = f32[3]{0} copy(%fusion.20)
+  ROOT %tuple.5 = (s32[], f32[3]) tuple(%gte.0, %copy.416)
+}
+
+ENTRY %main (arg0: u8[9]) -> (f32[4]) {
+  %copy.284 = u8[9]{0:T(8,128)(4,1)} copy(%arg0)
+  %while.134 = (s32[], u8[9]{0:T(8,128)}) while(%copy.284), condition=%cond.1, body=%body, metadata={op_name="jit(segment)/while/body/tick_learn/cond"}
+  %copy.9 = f32[4]{0} copy(%gte.9)
+  ROOT %tuple.9 = (f32[4]{0}) tuple(%copy.9)
+}
+"""
+KDA_MIX_PATH = ("tick_learn", "learn_step", "core_layer", "kda_mix")
+
+
+@pytest.mark.parametrize("inst,own,opcode,shape,consumer", [
+    # in a called computation: read by a bitcast read by a named fusion
+    ("copy.415", False, "copy", "u8[9]{0:T(8,128)}", KDA_MIX_PATH),
+    ("bitcast.3", False, "bitcast", "u8[3,3]{1,0}", KDA_MIX_PATH),
+    # read only by the root, which has no name: the caller's path stays
+    ("copy.416", False, "copy", "f32[3]{0}", ("tick_learn",)),
+    # in the entry: no scope of its own, and the named `while` reads it
+    ("copy.284", False, "copy", "u8[9]{0:T(8,128)(4,1)}", ("tick_learn",)),
+    ("copy.9", False, "copy", "f32[4]{0}", ()),
+    ("fusion.20", True, "fusion", "f32[3]{0}", KDA_MIX_PATH),
+    ("while.134", True, "while", "(s32[], u8[9]{0:T(8,128)})",
+     ("tick_learn",)),
+])
+def test_instruction_origins_by_hand(inst, own, opcode, shape, consumer):
+    assert ds.instruction_origins(HLO_MADE)[inst] == ds.Origin(
+        own, opcode, shape, consumer)
+
+
+def test_origins_leave_the_scopes_as_they_were():
+    """`instruction_scopes` keeps its answer (a compiler-made instruction
+    inherits its caller's path, an entry copy has none), on the module of
+    the tests above too, whose operands carry no `%`-less surprises."""
+    scopes = ds.instruction_scopes(HLO_MADE)
+    assert scopes["copy.415"] == scopes["copy.416"] == ("tick_learn",)
+    assert scopes["copy.284"] == scopes["copy.9"] == ()
+    assert set(ds.instruction_origins(HLO)) == set(ds.instruction_scopes(HLO))
+    assert ds.instruction_origins(HLO)["copy.415"] == ds.Origin(
+        False, "copy", "u8[9]{0}", ("tick_learn", "replay_gather"))
+
+
+def test_attribute_sums_the_compiler_made_by_consumer():
+    ops = [["%copy.415 = u8[9]{0:T(8,128)} copy(u8[9]{0} %gte.1)", 0.5],
+           ["%copy.416 = f32[3]{0} copy(f32[3]{0} %fusion.20)", 0.25],
+           ["%copy.284 = u8[9]{0:T(8,128)(4,1)} copy(u8[9]{0} %arg0)", 0.125],
+           ["%copy.9 = f32[4]{0} copy(...)", 0.0625],
+           ["%fusion.20 = f32[3]{0} fusion(...)", 2.0],
+           ["%fusion.999 = f32[] fusion()", 0.03125]]
+    scopes = ds.instruction_scopes(HLO_MADE)
+    a = ds.attribute(ops, scopes, ds.instruction_origins(HLO_MADE))
+    assert a["tick_s"] + a["outside_tick_s"] + a["unresolved_s"] \
+        == a["total_s"] == sum(t for _n, t in ops)
+    assert a["compiler_made_s"] == 0.5 + 0.25 + 0.125 + 0.0625
+    assert a["compiler_made_by_consumer_path"] == {
+        "tick_learn/learn_step/core_layer/kda_mix": 0.5,
+        "tick_learn": 0.25 + 0.125, ds.NO_SCOPE: 0.0625}
+    assert a["compiler_made"][0] == (
+        "copy.415", 0.5, "copy", "u8[9]{0:T(8,128)}",
+        "tick_learn/learn_step/core_layer/kda_mix")
+    # the copy in the loop counts under `tick_learn` alone as it did: no
+    # accepted reading moves
+    assert a["by_path"]["tick_learn"] == 0.5 + 0.25
+    plain = ds.attribute(ops, scopes)
+    assert plain["compiler_made_s"] is None and plain["compiler_made"] is None
+    assert {k: v for k, v in plain.items() if not k.startswith("compiler")} \
+        == {k: v for k, v in a.items() if not k.startswith("compiler")}
+
+
+def _nested_capture():
+    """One run of `jit_segment`: a 10 ms entry copy, then the scan's `while`
+    (10-40 ms) holding a 4 ms act op and a nested `while` (15-30 ms) with a
+    compiler-made copy, an LSTM fusion and, 500 ns after it, a draw fusion;
+    3 ms after the run ends another program's op.  Idle: 14-16 ms (ended by
+    the copy), 18-20 (by the LSTM fusion), 500 ns (by the draw), and
+    29.0005-43 ms with no program's next op to end it."""
+    return [
+        (DEV, ds.MODULES_LINE, "jit_segment(123)", 0.0, 40 * MS),
+        (DEV, ds.OPS_LINE, "%copy.284 = u8[9]{0} copy(...)", 0.0, 10 * MS),
+        (DEV, ds.OPS_LINE, "%while.1 = () while(...)", 10 * MS, 30 * MS),
+        (DEV, ds.OPS_LINE, "%gather.2 = f32[4]{0} gather(...)", 10 * MS, 4 * MS),
+        (DEV, ds.OPS_LINE, "%while.134 = (s32[]) while(...)", 15 * MS, 15 * MS),
+        (DEV, ds.OPS_LINE, "%copy.415 = u8[9]{0} copy(...)", 16 * MS, 2 * MS),
+        (DEV, ds.OPS_LINE, "%fusion.403 = f32[4]{0} fusion(...)", 20 * MS, 8 * MS),
+        (DEV, ds.OPS_LINE, "%fusion.7 = f32[4]{0} fusion(...)", 28 * MS + 500, 1 * MS),
+        (DEV, ds.MODULES_LINE, "jit_other(7)", 43 * MS, 1 * MS),
+        (DEV, ds.OPS_LINE, "%fusion.403 = f32[] fusion()", 43 * MS, 1 * MS),
+        (HOST, "python3", "segment", 0.0, 30 * MS),
+    ]
+
+
+def test_idle_by_path_and_between_dispatches_close_the_window():
+    r = ds.reduce_events(_nested_capture(), [HLO_RUN], {"segment"})
+    assert r["busy_s"] == pytest.approx(0.026)  # 10 + 4 + 2 + 8 + 1 + 1
+    gather = "tick_learn/replay_gather"  # the copy's inherited path
+    assert r["idle_s_by_path"] == pytest.approx({
+        gather: 0.002, "tick_learn/learn_step/lstm_scan": 0.002,
+        "tick_learn/replay_draw": 500e-9})
+    assert r["idle_between_dispatches_s"] == pytest.approx(0.014 - 500e-9)
+    # the identity: every nanosecond of the window is busy or idle somewhere
+    assert sum(r["idle_s_by_path"].values()) + r["idle_between_dispatches_s"] \
+        == pytest.approx(r["window_s"] - r["busy_s"], rel=1e-12)
+    assert r["tick_s"] + r["outside_tick_s"] + r["unresolved_s"] \
+        == pytest.approx(r["total_s"], rel=1e-12)
+    gaps = r["idle_gaps"]
+    assert gaps[0] == {"ms": pytest.approx(13.9995), "span": "no span"}
+    assert sorted((g["op"], g["after"], g["container"], g["path"], g["span"])
+                  for g in gaps[1:3]) == [
+        ("copy.415", "gather.2", "while.134", gather, "segment"),
+        ("fusion.403", "copy.415", "while.134",
+         "tick_learn/learn_step/lstm_scan", "segment")]
+    # a gap under 1 ms is listed too, without a host span
+    assert gaps[3] == {"ms": pytest.approx(0.0005), "op": "fusion.7",
+                       "after": "fusion.403", "container": "while.134",
+                       "path": "tick_learn/replay_draw"}
+    assert r["idle_gap_ms_by_span"] == pytest.approx(
+        {"segment": 4.0, "no span": 13.9995})
+    # the copy inside the nested loop is the compiler's, and `while.134`
+    # reads nothing of it: its caller's path stands in for the consumer
+    assert r["compiler_made_s"] == pytest.approx(0.012)
+    assert r["compiler_made_by_consumer_path"] == pytest.approx(
+        {ds.NO_SCOPE: 0.010, gather: 0.002})
+
+
+def test_idle_without_a_registered_program_is_unresolved_not_lost():
+    """The host-fed loops' capture before they registered their programs:
+    the gaps inside a run land on `UNRESOLVED`, the identity still holds."""
+    r = ds.reduce_events(_nested_capture(), [], {"segment"})
+    assert set(r["idle_s_by_path"]) == {ds.UNRESOLVED}
+    assert sum(r["idle_s_by_path"].values()) + r["idle_between_dispatches_s"] \
+        == pytest.approx(r["window_s"] - r["busy_s"], rel=1e-12)
+    assert r["unresolved_s"] == pytest.approx(r["total_s"])
+
+
+def test_two_programs_resolve_each_in_its_own_text():
+    """The host-fed loops run two programs, and instruction names are unique
+    within one module only: `fusion.3` of the act program is its own."""
+    act = "\n".join([
+        "HloModule jit_act_append, entry_computation_layout={()->f32[]}",
+        "ENTRY %main () -> f32[] {",
+        '  %fusion.3 = f32[] fusion(%a), metadata={op_name="jit(act_append)/net_trunk/conv"}',
+        "}"])
+    learn = "\n".join([
+        "HloModule jit_learn, entry_computation_layout={()->f32[]}",
+        "ENTRY %main () -> f32[] {",
+        '  %fusion.3 = f32[] fusion(%a), metadata={op_name="jit(learn)/learn_step/optimizer/mul"}',
+        "  %copy.4 = f32[] copy(%fusion.3)",
+        "}"])
+    events = [
+        (DEV, ds.MODULES_LINE, "jit_act_append(1)", 0.0, 2 * MS),
+        (DEV, ds.OPS_LINE, "%fusion.3 = f32[] fusion(...)", 0.0, 2 * MS),
+        (DEV, ds.MODULES_LINE, "jit_learn(2)", 5 * MS, 9 * MS),
+        (DEV, ds.OPS_LINE, "%fusion.3 = f32[] fusion(...)", 5 * MS, 4 * MS),
+        (DEV, ds.OPS_LINE, "%copy.4 = f32[] copy(...)", 10 * MS, 4 * MS),
+        (HOST, "python3", "act_append", 0.0, 3 * MS),
+        (HOST, "python3", "learn_step", 3 * MS, 12 * MS),
+    ]
+    r = ds.reduce_events(events, [act, learn], {"act_append", "learn_step"})
+    # a host-fed loop's programs have no tick: their scopes are outside it
+    assert r["by_path"] == {} and r["outside_by_path"] == pytest.approx({
+        "net_trunk": 0.002, "learn_step/optimizer": 0.004})
+    assert r["unresolved_s"] == 0.0 and r["dispatches"] == 1
+    assert r["outside"][-1] == ("jit_act_append:fusion.3", pytest.approx(0.002))
+    assert r["outside_tick_s"] == pytest.approx(r["total_s"])
+    assert r["idle_s_by_path"] == pytest.approx({ds.NO_SCOPE: 0.001})
+    assert r["idle_between_dispatches_s"] == pytest.approx(0.003)
+    assert r["idle_gap_ms_by_span"] == pytest.approx({"learn_step": 3.0})
+    assert r["scoped_instructions"] == 2
+
+
 def test_reduce_events_without_a_device_plane_is_none():
     assert ds.reduce_events(
         [(HOST, "python3", "segment", 0.0, 1.0)], [HLO], {"segment"}) is None
@@ -319,6 +501,125 @@ def test_device_time_row_is_valid(tmp_path, monkeypatch):
     assert "device_time: 2 learn steps, 1.0 dispatches" in text
     assert "scope lstm_scan: 4.0ms/learn step" in text
     assert "idle gaps over 1ms under no span: 19.0ms" in text
+
+
+def test_device_time_row_carries_the_idle_and_compiler_made_keys(
+        tmp_path, monkeypatch):
+    """`TraceWindow.device_time` on the nested capture: idle by path a learn
+    step and between dispatches add up to the row's window less its busy
+    time; the compiler's instructions are listed with opcode and shape; the
+    row is strict JSON and the report prints the new lines."""
+    import json
+    import sys
+
+    from rainbow_iqn_apex_tpu.obs import MetricRegistry, Tracer, TraceWindow
+    from rainbow_iqn_apex_tpu.obs.schema import validate_row
+    from rainbow_iqn_apex_tpu.utils.logging import MetricsLogger
+
+    sys.path.insert(0, str(
+        __import__("pathlib").Path(__file__).resolve().parents[1] / "scripts"))
+    from obs_report import _device_time_lines
+
+    monkeypatch.setattr(ds, "load_capture", lambda logdir: _nested_capture())
+    tracer = Tracer(MetricRegistry(), None, "learner")
+    tw = TraceWindow(str(tmp_path / "trace"), 1, 2, tracer=tracer)
+    tw.add_program(lambda: HLO_RUN)
+    with tracer.span("segment"):
+        pass
+    row = tw.device_time(2)
+    gather = "tick_learn/replay_gather"
+    assert row["idle_ms_by_path_per_step"] == pytest.approx({
+        gather: 1.0, "tick_learn/learn_step/lstm_scan": 1.0,
+        "tick_learn/replay_draw": 0.00025}, abs=1e-6)
+    assert row["idle_between_dispatches_s"] == pytest.approx(0.0139995)
+    assert 2e-3 * sum(row["idle_ms_by_path_per_step"].values()) \
+        + row["idle_between_dispatches_s"] == pytest.approx(
+            row["window_s"] - row["busy_s"], abs=1e-8)
+    assert row["compiler_made_ms_per_dispatch"] == pytest.approx(12.0)
+    assert row["compiler_made_ms_by_consumer_path_per_dispatch"] == \
+        pytest.approx({ds.NO_SCOPE: 10.0, gather: 2.0})
+    assert row["compiler_made"][0] == {
+        "instruction": "copy.284", "ms": 10.0, "opcode": "copy",
+        "shape": "u8[6554,120,80,80]{1,3,2,0}", "consumer": ds.NO_SCOPE}
+    assert row["idle_gaps"][1]["container"] == "while.134"
+    m = MetricsLogger(str(tmp_path / "m.jsonl"), "r", echo=False)
+    m.log("device_time", step=3, **row)
+    m.close()
+    (logged,) = [json.loads(line) for line in open(tmp_path / "m.jsonl")]
+    assert validate_row(logged, require_known_kind=True) == []
+    text = "\n".join(_device_time_lines(logged))
+    assert f"idle before {gather}: 1.0ms/learn step" in text
+    assert "idle between dispatches: 0.0139995s" in text
+    assert "compiler-made instructions: 12.0ms/dispatch" in text
+    assert "copy.284 copy u8[6554,120,80,80]{1,3,2,0}: 10.0ms" in text
+
+
+def _core_learn_step_names(fixture: str):
+    """The `op_name`s of the R2D2 learn step lowered (not compiled) with the
+    tiny core of `tests/fixtures/<fixture>`."""
+    import os
+
+    import jax.numpy as jnp
+
+    from rainbow_iqn_apex_tpu.ops.r2d2 import (
+        SequenceBatch,
+        build_r2d2_learn_step,
+        init_r2d2_state,
+    )
+
+    cfg = Config(
+        env_id="jaxgame:freeway", architecture="r2d2", role="anakin",
+        core_config=os.path.join(os.path.dirname(__file__), "fixtures", fixture),
+        compute_dtype="float32", history_length=4, hidden_size=32,
+        r2d2_burn_in=4, r2d2_seq_len=8, r2d2_overlap=4, batch_size=2,
+        multi_step=2, gamma=0.9, learner_devices=1, seed=3)
+    b, length, actions = 2, 12, 3
+    key = jax.random.PRNGKey(0)
+    ts = jax.eval_shape(
+        lambda k: init_r2d2_state(cfg, actions, k, (80, 80)), key)
+    shaped = jax.ShapeDtypeStruct
+    seq = SequenceBatch(
+        obs=shaped((b, length, 80, 80, 1), jnp.uint8),
+        action=shaped((b, length), jnp.int32),
+        reward=shaped((b, length), jnp.float32),
+        done=shaped((b, length), bool), valid=shaped((b, length), bool),
+        init_c=shaped((b, 0), jnp.float32), init_h=shaped((b, 0), jnp.float32),
+        weight=shaped((b,), jnp.float32))
+    lowered = jax.jit(build_r2d2_learn_step(cfg, actions)).lower(ts, seq, key)
+    return set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+
+
+@pytest.fixture(scope="module")
+def core_paths():
+    return {fixture: {ds.scope_path(n) for n in _core_learn_step_names(fixture)}
+            for fixture in ("kimi_core_tiny.json", "deepseek_v3_core_tiny.json",
+                            "qwen3_next_core_tiny.json")}
+
+
+@pytest.mark.parametrize("fixture,scope", [
+    ("kimi_core_tiny.json", ds.CORE_NORM),
+    ("kimi_core_tiny.json", ds.DENSE_FFN),
+    ("kimi_core_tiny.json", ds.KDA_MIX),
+    ("deepseek_v3_core_tiny.json", ds.CORE_NORM),
+    ("deepseek_v3_core_tiny.json", ds.DENSE_FFN),
+    ("qwen3_next_core_tiny.json", ds.CORE_NORM),
+])
+def test_the_cores_name_their_norms_dense_ffn_and_kda_mixer(
+        core_paths, fixture, scope):
+    """The three names of PR 37 are constants of `ALL_SCOPES` and occur in
+    the lowered learn step under `learn_step`, forward and backward; inside a
+    layer they nest in `core_layer` (the stack's final norm stands after the
+    last layer, outside it), and `kda_mix` is KDA but its scan."""
+    assert scope in ds.ALL_SCOPES
+    holding = [p for p in core_paths[fixture] if scope in p]
+    # (a function the lowering outlines names its ops from its own root)
+    assert any(ds.LEARN_STEP in p and ds.CORE_LAYER in p for p in holding)
+    if scope == ds.CORE_NORM:  # final_norm
+        assert any(ds.LEARN_STEP in p and ds.CORE_LAYER not in p
+                   for p in holding)
+    if scope == ds.KDA_MIX:
+        assert not any(ds.KDA_SCAN in p for p in holding)
+        assert any(ds.KDA_SCAN in p for p in core_paths[fixture])
 
 
 @pytest.mark.parametrize("with_program", [False, True])
