@@ -13,7 +13,18 @@ A core sits between the conv trunk and the dueling heads:
 `resets[b, t]` zeroes lane b's state BEFORE step t.  A core is a plain
 (hashable) object; called inside `R2D2Net.__call__` it builds its flax
 modules in the net's scope, so the LSTM's parameters stay `lstm/cell/...`
-leaf for leaf.
+leaf for leaf: the leaves of flax's `LSTMCell`, which checkpoints, the ring's
+stored states, the benchmark's reference and its seeded weights read by name.
+
+The LSTM is one formula for every T (the actor's tick is T = 1).  The input
+side of the gates, `x @ [W_ii | W_if | W_ig | W_io]`, does not depend on the
+carry, so it is one `[T, B, F] x [F, 4m]` product before the loop (scope
+`lstm_input`); the loop keeps the reset, `h @ W_h + b`, the activations and
+the state.  Autodiff of that form stacks the gates' cotangents `[T, B, 4m]` in
+the backward loop and takes the input kernels' gradient and `dx` as one
+product each after it.  In the loop they were T products of B rows each, at
+half the rate or less, and an `f32[F, 4m]` accumulator read and written every
+turn (PERF.md, PR 39).  All of it wears the scope `lstm_scan`.
 
 Four cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
 (c, h) of every sequence start), and three over the blocks of
@@ -36,6 +47,7 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from flax.linen.recurrent import DenseParams
 
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
@@ -43,26 +55,59 @@ LSTMState = Tuple[jnp.ndarray, jnp.ndarray]  # (c, h), each [B, lstm_size]
 CORE_STATS = "core_stats"  # flax collection a core sows its counters in
 
 
-class _ResettableLSTMStep(nn.Module):
-    """One LSTM step with an optional pre-step state reset (episode cut)."""
+_GATES = "ifgo"  # flax's order; the column blocks of the joined kernels
+
+
+class _JoinedLSTMCell(nn.Module):
+    """The leaves of flax's `LSTMCell`, made as `OptimizedLSTMCell` makes
+    them (`cell/{ii,if,ig,io}/kernel [F, m]` lecun-normal,
+    `cell/{hi,hf,hg,ho}/{kernel [m, m] orthogonal, bias [m]}`) and handed out
+    joined: `W_x [F, 4m]`, `W_h [m, 4m]`, `b [4m]`."""
 
     features: int
 
     @nn.compact
-    def __call__(self, carry: LSTMState, xs):
-        x_t, reset_t = xs  # [B, F], [B] bool
-        c, h = carry
-        keep = (1.0 - reset_t.astype(jnp.float32))[:, None]
-        c, h = c * keep, h * keep
-        (c, h), out = nn.OptimizedLSTMCell(features=self.features, name="cell")(
-            (c, h), x_t
-        )
-        return (c, h), out
+    def __call__(self, x, h):
+        w_x, _ = zip(*(DenseParams(
+            self.features, use_bias=False, name="i" + g)(x) for g in _GATES))
+        w_h, b = zip(*(DenseParams(
+            self.features, kernel_init=nn.initializers.orthogonal(),
+            name="h" + g)(h) for g in _GATES))
+        return (jnp.concatenate(w_x, axis=-1), jnp.concatenate(w_h, axis=-1),
+                jnp.concatenate(b, axis=-1))
+
+
+class _LSTM(nn.Module):
+    """The LSTM over a whole sequence, time-major (the module's docstring
+    says why the input product stands before the loop)."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, x, state: LSTMState, resets):
+        # x [T, B, F] float32, resets [T, B] bool (zero the state BEFORE t)
+        w_x, w_h, b = _JoinedLSTMCell(self.features, name="cell")(x, state[1])
+        with jax.named_scope(device_scopes.LSTM_INPUT):
+            # contracts F alone: a batch axis split over a mesh stays split
+            z_x = jnp.einsum("tbf,fg->tbg", x, w_x)  # [T, B, 4m]
+
+        def step(carry, xs):
+            z_x_t, reset_t = xs  # [B, 4m], [B] bool
+            c, h = carry
+            keep = (1.0 - reset_t.astype(jnp.float32))[:, None]
+            c, h = c * keep, h * keep
+            i, f, g, o = jnp.split((jnp.dot(h, w_h) + b) + z_x_t, 4, axis=-1)
+            c = nn.sigmoid(f) * c + nn.sigmoid(i) * jnp.tanh(g)
+            h = nn.sigmoid(o) * jnp.tanh(c)
+            return (c, h), h
+
+        return jax.lax.scan(step, state, (z_x, resets))
 
 
 @dataclasses.dataclass(frozen=True)
 class LSTMCore:
-    """`lax.scan` over an `OptimizedLSTMCell` step; the state is (c, h)."""
+    """`_LSTM` under the name `lstm`: the input product over all T steps, then
+    a `lax.scan` over the hidden side of the gates; the state is (c, h)."""
 
     features: int = 512
 
@@ -85,16 +130,9 @@ class LSTMCore:
         return (init_c, init_h)
 
     def __call__(self, x, state: LSTMState, resets):
-        xs = (jnp.moveaxis(x, 1, 0), jnp.moveaxis(resets, 1, 0))  # [T, B, .]
-        scan = nn.scan(
-            _ResettableLSTMStep,
-            variable_broadcast="params",
-            split_rngs={"params": False},
-            in_axes=0,
-            out_axes=0,
-        )
+        x, resets = jnp.moveaxis(x, 1, 0), jnp.moveaxis(resets, 1, 0)  # [T, B, .]
         with jax.named_scope(device_scopes.LSTM_SCAN):
-            state, outs = scan(features=self.features, name="lstm")(state, xs)
+            state, outs = _LSTM(self.features, name="lstm")(x, state, resets)
         return jnp.moveaxis(outs, 0, 1), state
 
 

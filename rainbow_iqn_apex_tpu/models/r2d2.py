@@ -9,9 +9,10 @@ dueling Q head rather than IQN quantiles; noisy layers keep the Rainbow
 exploration story.
 
 TPU-first notes:
-- Time unrolling is a `lax.scan` over an `OptimizedLSTMCell` step inside one
-  jit: [B, T, H, W, C] -> conv trunk applied as one [B*T] batch (one conv a
-  layer over all steps), then the scan carries only the small LSTM state.
+- Time unrolling is inside one jit: [B, T, H, W, C] -> conv trunk applied as
+  one [B*T] batch (one conv a layer over all steps), the LSTM's input product
+  over all steps at once, then a `lax.scan` that carries only the small LSTM
+  state through the hidden side of the gates (`cores.LSTMCore`).
 - A learn step hands the trunk the ring's single frames [B, T, H, W, 1] with
   the history-1 frames before them (`frames_before`), and the first conv
   reads the history from those (`layers.StemConv`): stacking them per pixel

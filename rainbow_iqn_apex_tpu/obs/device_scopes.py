@@ -62,7 +62,8 @@ REPLAY_WRITEBACK = "replay_writeback"  # the priority scatter
 LEARN_STEP = "learn_step"  # forward, loss, backward, optimizer, target copy
 NET_TRUNK = "net_trunk"  # conv trunk
 NET_STEM = "net_stem"  # inside it: the first conv with its input side
-LSTM_SCAN = "lstm_scan"  # the lax.scan over the LSTM cell (R2D2)
+LSTM_SCAN = "lstm_scan"  # the LSTM core (R2D2): the input product, the lax.scan
+LSTM_INPUT = "lstm_input"  # inside it: x @ W_x over all T steps, outside the loop
 # ---- the Kimi-Linear and DeepSeek-V3 cores (models/mla_moe.py,
 # models/kimi_linear.py)
 CORE_EMBED = "core_embed"  # the input projection in the embedding's place
@@ -95,7 +96,7 @@ ALL_SCOPES = TICK_SCOPES + (
     LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
     CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM, GDN_MIX, GATTN_PROJ, GATTN_ATTN,
-    GATTN_ROPE, CORE_NORM, DENSE_FFN, KDA_MIX,
+    GATTN_ROPE, CORE_NORM, DENSE_FFN, KDA_MIX, LSTM_INPUT,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 

@@ -17,7 +17,7 @@ from rainbow_iqn_apex_tpu.obs import device_scopes as ds
 R2D2_SCOPES = (
     ds.TICK_ACT, ds.TICK_ENV, ds.TICK_APPEND, ds.TICK_LEARN, ds.REPLAY_DRAW,
     ds.REPLAY_GATHER, ds.REPLAY_WRITEBACK, ds.LEARN_STEP, ds.NET_TRUNK,
-    ds.LSTM_SCAN, ds.OPTIMIZER,
+    ds.LSTM_SCAN, ds.OPTIMIZER, ds.LSTM_INPUT,
 )
 IQN_SCOPES = (
     ds.TICK_ACT, ds.TICK_ENV, ds.TICK_APPEND, ds.TICK_LEARN, ds.REPLAY_DRAW,
@@ -113,6 +113,40 @@ def test_backward_pass_resolves_to_its_scope(texts, program, scope):
     path = ds.scope_path(ops[0])
     assert path[0] == ds.TICK_LEARN and ds.LEARN_STEP in path
     assert path.index(ds.LEARN_STEP) < path.index(scope)
+
+
+@pytest.mark.parametrize("side,shapes", [
+    # the learn step's four scans: online and target, burn-in 2 and slice 6,
+    # batch 8, lstm 16 (4m = 64), 2,304 features
+    ("jvp(", {"f32[16,64]", "f32[48,64]"}),  # z_x = x W_x, before each loop
+    ("transpose(jvp(", {"f32[2304,64]", "f32[48,2304]"}),  # x^T dz, dx
+])
+def test_the_lstms_input_products_stand_outside_its_loop_under_lstm_input(
+        texts, side, shapes):
+    """PR 39 took the LSTM's input products out of the time loop: forward
+    and backward they resolve to `tick_learn/learn_step/lstm_scan/lstm_input`
+    (so `lstm_scan_device_ms` still reads them), none is in a `while` body,
+    and no `dot` the loops keep has a 2,304-wide operand."""
+    rows, _caller = ds._parse(texts["r2d2"])
+    shape_of = {inst: shape.split("{")[0] for inst, _c, shape, *_ in rows}
+    # (result shape, every shape the dot touches, op_name) of the core's dots
+    dots = [(shape_of[inst], [shape_of[inst]] + [
+                shape_of.get(a, "") for a in operands], op_name)
+            for inst, _c, _s, opcode, op_name, operands in rows
+            if opcode == "dot" and op_name
+            and ds.LSTM_SCAN in ds.scope_path(op_name)]
+    products = {result: op_name for result, _touched, op_name in dots
+                if ds.LSTM_INPUT in ds.scope_path(op_name)
+                and "/learn_step/" + side in op_name}
+    assert set(products) == shapes
+    for op_name in products.values():
+        assert ds.scope_path(op_name) == (
+            ds.TICK_LEARN, ds.LEARN_STEP, ds.LSTM_SCAN, ds.LSTM_INPUT)
+        assert "while" not in op_name.split(ds.LSTM_SCAN)[1]
+    in_loop = [touched for _r, touched, op_name in dots
+               if "while" in op_name.split(ds.LSTM_SCAN)[-1]]
+    assert in_loop and not any("2304" in s for t in in_loop for s in t)
+    assert all("f32[16,64]" in t for t in in_loop)  # they read W_h [m, 4m]
 
 
 @pytest.mark.parametrize("op_name,want", [
