@@ -1,18 +1,29 @@
 """The blocks the non-LSTM cores of `R2D2Net` run (interface: models/cores.py):
-a pre-norm residual stack in which every layer names its mixer, and whose
-feed-forward is a dense SwiGLU in the leading layers and sparse experts
-beside shared ones in the rest.  The MLA mixer (latent attention over a
-rolling window of latents) lives here.
+a residual stack in which every layer names its mixer, and whose
+feed-forward is a dense SwiGLU in the leading layers (in all of them for a
+dense family) and sparse experts beside shared ones in the rest.  A block
+norms each sub-layer's input (pre-norm) and, where the family says so
+(`out_norms`), its output too before the residual add (four norms a block).
+The stack is run `passes` times over the SAME layer modules, its final norm
+after every pass feeding the next: each layer's leaves stand in the parameter
+tree once, a leaf's gradient is the sum over its uses, and the per-lane state
+has one entry a (pass, layer), because every use of a layer sees keys of its
+own.  The MLA mixer (latent attention over a rolling window of latents) lives
+here, and what the attention mixers share: the window's mask and the two
+published forms of the rotation.
 
-Three cores are built from them: `models/kimi_linear.py` (three KDA mixers in
+Four cores are built from them: `models/kimi_linear.py` (three KDA mixers in
 four, defined there, and an un-rotated MLA in the fourth),
 `models/deepseek_v3.py` (every mixer MLA with decoupled rotary keys, an input
-projection in the embedding's place) and `models/qwen3_next.py` (three Gated
+projection in the embedding's place), `models/qwen3_next.py` (three Gated
 DeltaNet mixers in four and a gated softmax attention in the fourth, both
-defined there; softmax routing and a gated shared expert).  Each reads its
-own published keys into one `CoreConfig`; which mixer a layer runs, whether
-the rope dimensions are rotated, how the router scores and whether the input
-is projected are read off it.
+defined there; softmax routing and a gated shared expert) and
+`models/ouro.py` (plain multi-head attention defined there, every
+feed-forward dense, four norms a block, the stack run `total_ut_steps`
+times).  Each reads its own published keys into one `CoreConfig`; which mixer
+a layer runs, whether the rope dimensions are rotated, how the router scores,
+whether the input is projected, how often the stack is run and whether a
+block norms its outputs are read off it.
 
 A mixer is a flax module `Mixer(kc, compute_dtype)` called as
 `(x [B, T, hidden], state, seg [B, T]) -> (y, state)` that says under which
@@ -68,20 +79,25 @@ EXPERT_ROWS = (0.5, 2.0)
 class CoreConfig:
     """What the stack is built from; a family's reader fills it from its own
     published keys (`KimiLinearConfig`, `DeepSeekV3Config`,
-    `Qwen3NextConfig`).  A mixer's sizes are read by that mixer alone, so a
-    family leaves the others' at their zeros."""
+    `Qwen3NextConfig`, `OuroConfig`).  A mixer's sizes are read by that mixer
+    alone and the expert layer's by `_MoE` alone, so a family leaves the
+    others' at their zeros (a dense family has no expert layer:
+    `first_dense` is all its layers)."""
 
     hidden: int
     mixers: Tuple[Any, ...]  # the mixer module of each layer, in order
     eps: float
-    experts: int
-    top_k: int
-    expert_width: int
-    shared_width: int
-    experts_here: int
-    first_expert: int = 0
+    passes: int = 1  # how many times the stack is run over the same weights
+    out_norms: bool = False  # a block norms its sub-layers' outputs too
     first_dense: int = 0  # the leading layers whose feed-forward is dense
     dense_width: int = 0
+    # the expert layer (`_MoE`)
+    experts: int = 0
+    top_k: int = 0
+    expert_width: int = 0
+    shared_width: int = 0
+    experts_here: int = 0
+    first_expert: int = 0
     route: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax"
     route_scale: float = 1.0
     shared_gate: bool = False  # the shared expert weighed by a sigmoid gate
@@ -107,7 +123,8 @@ class CoreConfig:
     gdn_value_heads: int = 0
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
-    # gated softmax attention (models/qwen3_next.py)
+    # softmax attention over a K/V window (models/qwen3_next.py gated and
+    # partly rotated, models/ouro.py plain and fully rotated)
     attn_heads: int = 0
     attn_kv_heads: int = 0
     attn_head_dim: int = 0
@@ -180,6 +197,43 @@ def rotate_pairs(u, pos, theta: float):
     a, b = pairs[..., 0], pairs[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
                      axis=-1).reshape(u.shape)
+
+
+def rotate_halves(u, pos, theta: float):
+    """u [B, S, ..., d] turned by pos[s]: (u_i, u_{i + d/2}) by the angle
+    pos[s] x theta^(-2i/d), the published `rotate_half` form."""
+    d = u.shape[-1]
+    cos, sin = rope_cos_sin(u, pos, theta)
+    a, b = u[..., : d // 2], u[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def kv_window_zero_state(kc: CoreConfig, batch: int):
+    """The empty window of an attention mixer that keeps keys and values:
+    [B, W, Hkv, d] each, and their validity [B, W]."""
+    kv = (batch, kc.window, kc.attn_kv_heads, kc.attn_head_dim)
+    return {"k": jnp.zeros(kv, jnp.float32),
+            "v": jnp.zeros(kv, jnp.float32),
+            "valid": jnp.zeros((batch, kc.window), jnp.float32)}
+
+
+def window_mask(valid, seg, w: int):
+    """What a query of the new steps may attend to in `[window; new]`
+    (`_MLA`'s rule, whose ops stay where its cells' metrics read them).
+
+    valid [B, W] the window's validity, seg [B, T] the new steps' segment
+    ids (the window's slots belong to segment 0).  Returns the mask
+    [B, T, W+T] (causal, at most the last `w` slots the step itself
+    included, valid, of the step's own segment) and the validity [B, W+T] of
+    the slots once the steps are through: a slot of a segment that has ended
+    is void."""
+    b, t = seg.shape
+    seg_all = jnp.concatenate([jnp.zeros((b, w), seg.dtype), seg], axis=1)
+    valid = jnp.concatenate([valid, jnp.ones((b, t), jnp.float32)], axis=1)
+    pos_q, pos_k = w + jnp.arange(t)[:, None], jnp.arange(w + t)[None]
+    mask = ((pos_k <= pos_q) & (pos_k > pos_q - w))[None] & (
+        valid[:, None, :] > 0) & (seg_all[:, None, :] == seg[:, :, None])
+    return mask, valid * (seg_all == seg[:, -1:])
 
 
 class _MLA(nn.Module):
@@ -353,6 +407,13 @@ class _MoE(nn.Module):
 
 
 # ----------------------------------------------------------------- stack
+def state_key(kc: CoreConfig, r: int, i: int) -> str:
+    """Where the per-lane state keeps layer i's r-th use (both 1-based): by
+    the layer where the stack is run once, by pass and layer where it is run
+    several times."""
+    return f"layer_{i}" if kc.passes == 1 else f"pass_{r}_layer_{i}"
+
+
 class _Layer(nn.Module):
     kc: CoreConfig
     index: int  # 1-based, as linear_attn_config counts
@@ -361,20 +422,22 @@ class _Layer(nn.Module):
     @nn.compact
     def __call__(self, x, state, seg):
         kc, cd = self.kc, self.compute_dtype
-        with jax.named_scope(device_scopes.CORE_NORM):
-            hn = _RMSNorm(kc.eps, name="mix_norm")(x)
+
+        def norm(name, u):
+            with jax.named_scope(device_scopes.CORE_NORM):
+                return _RMSNorm(kc.eps, name=name)(u)
+
         mixer = kc.mixers[self.index - 1]
-        y, state = mixer(kc, cd, name=mixer.layer_name)(hn, state, seg)
-        x = x + y
-        with jax.named_scope(device_scopes.CORE_NORM):
-            hn = _RMSNorm(kc.eps, name="ffn_norm")(x)
+        y, state = mixer(kc, cd, name=mixer.layer_name)(
+            norm("mix_norm", x), state, seg)
+        x = x + (norm("mix_out_norm", y) if kc.out_norms else y)
+        hn = norm("ffn_norm", x)
         if self.index <= kc.first_dense:
             with jax.named_scope(device_scopes.DENSE_FFN):
                 y = _SwiGLU(kc.dense_width, cd, name="ffn")(hn)
-            x = x + y
         else:
-            x = x + _MoE(kc, cd, name="moe")(hn)
-        return x, state
+            y = _MoE(kc, cd, name="moe")(hn)
+        return x + (norm("ffn_out_norm", y) if kc.out_norms else y), state
 
 
 class _Stack(nn.Module):
@@ -393,30 +456,48 @@ class _Stack(nn.Module):
                 f"it {x.shape[-1]} features: this configuration has no "
                 f"projection between them (80x80 frames give 2,304)")
         seg = jnp.cumsum(resets.astype(jnp.int32), axis=1)
+        # every layer module is built once and called once a pass: a second
+        # call of a flax module reads the leaves the first one made
+        layers = [nn.remat(_Layer)(kc, i, self.compute_dtype,
+                                   name=f"layer_{i}")
+                  for i in range(1, kc.layers + 1)]
+        final_norm = _RMSNorm(kc.eps, name="final_norm")
         new_state = {}
-        for i in range(1, kc.layers + 1):
-            layer = nn.remat(_Layer)(kc, i, self.compute_dtype,
-                                     name=f"layer_{i}")
-            with jax.named_scope(device_scopes.CORE_LAYER):
-                x, new_state[f"layer_{i}"] = layer(
-                    x, state[f"layer_{i}"], seg)
-        with jax.named_scope(device_scopes.CORE_NORM):
-            return _RMSNorm(kc.eps, name="final_norm")(x), new_state
+
+        def one_pass(x, r):
+            for i, layer in enumerate(layers, 1):
+                key = state_key(kc, r, i)
+                with jax.named_scope(device_scopes.CORE_LAYER):
+                    x, new_state[key] = layer(x, state[key], seg)
+            with jax.named_scope(device_scopes.CORE_NORM):
+                return final_norm(x)
+
+        if kc.passes == 1:  # no pass to tell from another: the paths stay
+            return one_pass(x, 1), new_state
+        for r in range(1, kc.passes + 1):
+            with jax.named_scope(device_scopes.LOOP_PASS):
+                x = one_pass(x, r)
+        self.sow(STATS, "loop_passes", float(kc.passes))  # the weights' uses
+        return x, new_state
 
 
 class StackCore:
     """The core interface (models/cores.py) over `_Stack`: zero start state,
     nothing stored in the ring.  A family's core (`KimiLinearCore`,
-    `DeepSeekV3Core`, `Qwen3NextCore`) is a frozen dataclass of `kc` and
-    `compute_dtype` that names the counters it reports, `stat_names`: each is
-    an output of the compiled segment, so a core lists what its cell reads."""
+    `DeepSeekV3Core`, `Qwen3NextCore`, `OuroCore`) is a frozen dataclass of
+    `kc` and `compute_dtype` that names the counters it reports,
+    `stat_names`: each is an output of the compiled segment, so a core lists
+    what its cell reads (`moe_stat_names` where it has expert layers)."""
 
     stored_width = 0  # zero start state: the ring stores no state
     moe_stat_names = ("moe_expert_load_max_over_mean", "moe_held_assign_share",
                       "moe_tokens_dropped")
 
     def initial_state(self, batch: int):
-        return {f"layer_{i}": mixer.zero_state(self.kc, batch)
+        """One entry a (pass, layer): every use of a layer has a state of its
+        own, each leaf led by the lane axis."""
+        return {state_key(self.kc, r, i): mixer.zero_state(self.kc, batch)
+                for r in range(1, self.kc.passes + 1)
                 for i, mixer in enumerate(self.kc.mixers, 1)}
 
     def to_stored(self, state):

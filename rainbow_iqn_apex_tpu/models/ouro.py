@@ -1,0 +1,135 @@
+"""Ouro (a looped, weight-shared dense transformer) as the recurrent core of
+`R2D2Net` (interface: models/cores.py).
+
+Layers as published for Ouro-2.6B (configs/cores/): the layers held here are
+run `total_ut_steps` times over the SAME parameters, the final norm after
+every pass feeding the next; a block norms the input AND the output of each
+sub-layer,
+  x <- x + N2(Attn(N1(x))),   x <- x + N4(SwiGLU(N3(x))),
+its mixer plain multi-head attention and every feed-forward a dense SwiGLU.
+The trunk's features are not the model's hidden size and no width is cut, so
+an input projection stands where a language model has its embedding.  The
+exit gate (a linear and a sigmoid on each pass's output, beside the LM head
+whose per-pass loss it weighs) is left out with the tokens: every step runs
+every pass, as the published `early_exit_threshold` 1 has it.
+
+This module holds what is Ouro's alone: the mixer and the reader of the
+published keys.  The stack with its passes, the block with its four norms and
+the SwiGLU are models/mla_moe.py's, as are the window's mask and the
+rotation.
+
+Attention, of `attn_heads` query heads over `attn_kv_heads` key/value heads
+(query head i reads head i // (heads / kv heads); the published model has as
+many of one as of the other):
+  q, k, v = x W_q, x W_k, x W_v, no bias, no norm, no gate; every dimension of
+  every q and k head turned by position (`rotate_half` over the head's two
+  halves); o_i = softmax(q_i K^T / sqrt(d) + mask) V; y = [o_i]_i W_o.
+
+Per-lane state, float32, zero = initial (models/cores.zero_lanes), one a
+(pass, layer), as the published model keeps a key/value cache for each: a
+pass's keys are projections of that pass's input.  The window's keys,
+UN-rotated, and values [B, W, Hkv, d] each, and their validity [B, W].  The
+rotation is applied at use, by the slot: the key in slot s of `[window; new]`
+by s, the query of new step t by W + t (models/mla_moe.py says why that is
+the published rotation by absolute position).  An episode cut inside a
+sequence is a segment boundary.  One step (`T == 1`, the actor) is one row of
+scores a (pass, layer).
+
+The plain reference is tests/reference_ouro_core.py.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from rainbow_iqn_apex_tpu.models.cores import CORE_STATS as STATS
+from rainbow_iqn_apex_tpu.models.mla_moe import (
+    NEG,
+    CoreConfig,
+    StackCore,
+    kv_window_zero_state,
+    _Linear,
+    _mm,
+    rotate_halves,
+    window_mask,
+)
+from rainbow_iqn_apex_tpu.obs import device_scopes
+
+
+class _MHA(nn.Module):
+    kc: CoreConfig
+    compute_dtype: Any
+
+    layer_name = "mha"
+
+    zero_state = staticmethod(kv_window_zero_state)
+
+    @nn.compact
+    def __call__(self, x, state, seg):
+        kc, cd = self.kc, self.compute_dtype
+        b, t, _ = x.shape
+        h, g, d, w = kc.attn_heads, kc.attn_kv_heads, kc.attn_head_dim, kc.window
+        with jax.named_scope(device_scopes.MHA_PROJ):
+            q = _Linear(h * d, cd, name="q_proj")(x).reshape(
+                b, t, g, h // g, d)
+            k = _Linear(g * d, cd, name="k_proj")(x).reshape(b, t, g, d)
+            v = _Linear(g * d, cd, name="v_proj")(x).reshape(b, t, g, d)
+        k = jnp.concatenate([state["k"], k], axis=1)  # [B, W+T, G, d]
+        v = jnp.concatenate([state["v"], v], axis=1)
+        with jax.named_scope(device_scopes.MHA_ATTN):
+            with jax.named_scope(device_scopes.MHA_ROPE):
+                q = rotate_halves(q, w + jnp.arange(t), kc.rope_theta)
+                k_at = rotate_halves(k, jnp.arange(w + t), kc.rope_theta)
+            scores = _mm("btgrd,bsgd->bgrts", q, k_at, cd)
+            mask, valid = window_mask(state["valid"], seg, w)
+            scores = jnp.where(
+                mask[:, None, None], scores / math.sqrt(d), NEG)
+            o = _mm("bgrts,bsgd->btgrd", jax.nn.softmax(scores, axis=-1), v, cd)
+        with jax.named_scope(device_scopes.MHA_PROJ):
+            y = _Linear(kc.hidden, cd, name="o_proj")(o.reshape(b, t, h * d))
+        self.sow(STATS, "attn_live_key_share",
+                 jnp.mean(mask, dtype=jnp.float32))
+        return y, {"k": k[:, t:], "v": v[:, t:], "valid": valid[:, t:]}
+
+
+class OuroConfig(CoreConfig):
+    """`CoreConfig` read from an `ouro` configuration file."""
+
+    @classmethod
+    def from_dict(cls, cc: Dict[str, Any]) -> "OuroConfig":
+        assumed = cc.get("assumed", {})
+        layers = cc["layers_here"]
+        if cc.get("rope_scaling") or cc.get("use_sliding_window"):
+            raise ValueError("a scaled rotation and a sliding window are "
+                             "not written")
+        if set(cc.get("layer_types", ())[:layers]) - {"full_attention"}:
+            raise ValueError("a layer that is not full attention is not "
+                             "written")
+        if cc.get("early_exit_threshold", 1) < 1:
+            raise ValueError("an exit before the last pass is not written: "
+                             "every step runs every pass")
+        return cls(
+            hidden=cc["hidden_size"], mixers=(_MHA,) * layers,
+            eps=cc["rms_norm_eps"], passes=cc["total_ut_steps"],
+            out_norms=True, first_dense=layers,
+            dense_width=cc["intermediate_size"],
+            attn_heads=cc["num_attention_heads"],
+            attn_kv_heads=cc["num_key_value_heads"],
+            attn_head_dim=cc["head_dim"],
+            window=assumed.get("attn_window", 120),
+            rope_theta=float(cc["rope_theta"]), in_proj=True,
+        )
+
+
+@dataclasses.dataclass(frozen=True)
+class OuroCore(StackCore):
+    kc: CoreConfig
+    compute_dtype: Any = jnp.bfloat16
+
+    stat_names = ("attn_live_key_share", "loop_passes")

@@ -79,41 +79,14 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (
     NEG,
     CoreConfig,
     StackCore,
+    kv_window_zero_state,
     _Linear,
     _mm,
     _RMSNorm,
-    rope_cos_sin,
+    rotate_halves,
+    window_mask,
 )
 from rainbow_iqn_apex_tpu.obs import device_scopes
-
-
-def rotate_halves(u, pos, theta: float):
-    """u [B, S, ..., d] turned by pos[s]: (u_i, u_{i + d/2}) by the angle
-    pos[s] x theta^(-2i/d), the published `rotate_half` form."""
-    d = u.shape[-1]
-    cos, sin = rope_cos_sin(u, pos, theta)
-    a, b = u[..., : d // 2], u[..., d // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-
-
-def window_mask(valid, seg, w: int):
-    """What a query of the new steps may attend to in `[window; new]`
-    (`mla_moe._MLA`'s rule, whose ops stay where its cells' metrics read
-    them).
-
-    valid [B, W] the window's validity, seg [B, T] the new steps' segment
-    ids (the window's slots belong to segment 0).  Returns the mask
-    [B, T, W+T] (causal, at most the last `w` slots the step itself
-    included, valid, of the step's own segment) and the validity [B, W+T] of
-    the slots once the steps are through: a slot of a segment that has ended
-    is void."""
-    b, t = seg.shape
-    seg_all = jnp.concatenate([jnp.zeros((b, w), seg.dtype), seg], axis=1)
-    valid = jnp.concatenate([valid, jnp.ones((b, t), jnp.float32)], axis=1)
-    pos_q, pos_k = w + jnp.arange(t)[:, None], jnp.arange(w + t)[None]
-    mask = ((pos_k <= pos_q) & (pos_k > pos_q - w))[None] & (
-        valid[:, None, :] > 0) & (seg_all[:, None, :] == seg[:, :, None])
-    return mask, valid * (seg_all == seg[:, -1:])
 
 
 class _GatedDeltaNet(nn.Module):
@@ -183,12 +156,7 @@ class _GatedAttention(nn.Module):
 
     layer_name = "gattn"
 
-    @staticmethod
-    def zero_state(kc: CoreConfig, batch: int):
-        kv = (batch, kc.window, kc.attn_kv_heads, kc.attn_head_dim)
-        return {"k": jnp.zeros(kv, jnp.float32),
-                "v": jnp.zeros(kv, jnp.float32),
-                "valid": jnp.zeros((batch, kc.window), jnp.float32)}
+    zero_state = staticmethod(kv_window_zero_state)
 
     @nn.compact
     def __call__(self, x, state, seg):

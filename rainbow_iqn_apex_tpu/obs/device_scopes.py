@@ -67,7 +67,8 @@ LSTM_INPUT = "lstm_input"  # inside it: x @ W_x over all T steps, outside the lo
 # ---- the Kimi-Linear and DeepSeek-V3 cores (models/mla_moe.py,
 # models/kimi_linear.py)
 CORE_EMBED = "core_embed"  # the input projection in the embedding's place
-CORE_LAYER = "core_layer"  # one pre-norm block: mixer + feed-forward
+CORE_LAYER = "core_layer"  # one block: mixer + feed-forward, each behind its
+# pre-norm and, in a family that has them, before a norm of its output
 KDA_SCAN = "kda_scan"  # the chunked delta-rule recurrence of a sequence
 KDA_PREP = "kda_prep"  # inside it: the in-chunk preparation (WY factors)
 MLA_PROJ = "mla_proj"  # q, kv_a with kv_norm, kv_b over [window; new], o
@@ -77,7 +78,8 @@ MOE_ROUTE = "moe_route"  # router, top-k, the sort by held expert
 MOE_EXPERTS = "moe_experts"  # gather, the grouped products, scatter-add
 MOE_SHARED = "moe_shared"  # the shared expert
 CORE_STEP = "core_step"  # the delta-rule recurrence of one step (the actor's tick)
-CORE_NORM = "core_norm"  # a block's two pre-norms, and the stack's final norm
+CORE_NORM = "core_norm"  # a block's norms (two, or four with the output
+# norms), and the stack's final norm after every pass
 DENSE_FFN = "dense_ffn"  # the SwiGLU of a dense (not expert) layer
 KDA_MIX = "kda_mix"  # KDA but its scan: projections, conv, gates, o_norm, o
 # ---- the Qwen3-Next core's two mixers (models/qwen3_next.py); its scan wears
@@ -86,6 +88,12 @@ GDN_MIX = "gdn_mix"  # Gated DeltaNet but its scan: projections, conv, gates, ga
 GATTN_PROJ = "gattn_proj"  # q with its gate, k, v, their norms, o
 GATTN_ATTN = "gattn_attn"  # scores, mask, softmax, values over the K/V window, the gate
 GATTN_ROPE = "gattn_rope"  # inside it: the rotary dimensions turned by their slot
+# ---- a stack run several times over shared weights, and the Ouro core's
+# mixer (models/mla_moe.py::_Stack, models/ouro.py)
+LOOP_PASS = "loop_pass"  # one pass of a stack run several times: its layers, its final norm
+MHA_PROJ = "mha_proj"  # plain multi-head attention: q, k, v, o
+MHA_ATTN = "mha_attn"  # scores, mask, softmax, values over the K/V window
+MHA_ROPE = "mha_rope"  # inside it: every q and k head turned whole by its slot
 IQN_HEAD = "iqn_head"  # tau embedding + the tau-folded heads (IQN)
 OPTIMIZER = "optimizer"  # tx.update, apply_updates, the target copy
 GRAD_ALLREDUCE = "grad_allreduce"  # psum/pmax/pmean of the sharded builders
@@ -96,7 +104,8 @@ ALL_SCOPES = TICK_SCOPES + (
     LSTM_SCAN, IQN_HEAD, OPTIMIZER, GRAD_ALLREDUCE, CORE_LAYER, KDA_SCAN,
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
     CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM, GDN_MIX, GATTN_PROJ, GATTN_ATTN,
-    GATTN_ROPE, CORE_NORM, DENSE_FFN, KDA_MIX, LSTM_INPUT,
+    GATTN_ROPE, CORE_NORM, DENSE_FFN, KDA_MIX, LSTM_INPUT, LOOP_PASS,
+    MHA_PROJ, MHA_ATTN, MHA_ROPE,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 

@@ -12,7 +12,7 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CORES = {name: os.path.join(HERE, "fixtures", name + "_core_tiny.json")
-         for name in ("kimi", "deepseek_v3", "qwen3_next")}
+         for name in ("kimi", "deepseek_v3", "qwen3_next", "ouro")}
 
 
 @pytest.mark.parametrize("core", sorted(CORES))
@@ -69,4 +69,10 @@ def test_cli_runs_the_fused_trainer_with_core_config(tmp_path, core):
     rows = [json.loads(line) for line in open(
         tmp_path / "results" / "cli" / "metrics.jsonl")]
     learn = [r for r in rows if r["kind"] == "learn"]
-    assert learn and all(r["moe_tokens_dropped"] == 0.0 for r in learn)
+    assert learn
+    if core == "ouro":  # no expert layer: no such counter in its rows
+        assert all("moe_tokens_dropped" not in r and r["loop_passes"] == 3.0
+                   for r in learn)
+    else:
+        assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
+    assert all("core_state_bytes_per_lane" in r for r in learn)
