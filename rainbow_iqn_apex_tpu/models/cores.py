@@ -8,7 +8,11 @@ A core sits between the conv trunk and the dueling heads:
     core.stored_width                        width of EACH of the ring's two
                                              stored-state columns
     core.to_stored(state) -> (c, h)          what the ring keeps of a state
-    core.from_stored(c, h) -> state          a sequence's start state
+    core.from_stored(c, h) -> state          a sequence's start state: the
+                                             tree of `initial_state`, where a
+                                             leaf that only grows with the
+                                             steps (an attention window's
+                                             slots, axis 1) may hold fewer
 
 `resets[b, t]` zeroes lane b's state BEFORE step t.  A core is a plain
 (hashable) object; called inside `R2D2Net.__call__` it builds its flax
@@ -28,7 +32,8 @@ turn (PERF.md, PR 39).  All of it wears the scope `lstm_scan`.
 
 Five cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
 (c, h) of every sequence start), and four over the blocks of
-models/mla_moe.py (zero start state: the ring's state columns have width 0):
+models/mla_moe.py (zero start state: the ring's state columns have width 0,
+and a sequence's attention windows start with no slots):
 `models/kimi_linear.KimiLinearCore`, `models/deepseek_v3.DeepSeekV3Core`,
 `models/qwen3_next.Qwen3NextCore` and `models/ouro.OuroCore`, where F' is the
 model's hidden size and not F.  `Config.core_config` names the file of one,

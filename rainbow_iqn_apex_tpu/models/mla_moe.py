@@ -32,15 +32,27 @@ the start (`zero_state(kc, batch)`: float32, zero = initial, every leaf led
 by the lane axis, so models/cores.zero_lanes resets a lane).
 
 MLA's per-lane state, float32, zero = initial (models/cores.zero_lanes): the
-window's latents `lat` [B, W, rank + rope] with the rope key UN-rotated, and
-their validity `valid` [B, W].  Where the configuration rotates
+window's latents `lat` [B, L, rank + rope] with the rope key UN-rotated, and
+their validity `valid` [B, L].  Where the configuration rotates
 (`rope_theta` > 0) the rotation is applied at use, by the slot: the key in
-slot s of `[window; new]` by s, the query of new step t by W + t.  A score
+slot s of `[window; new]` by s, the query of new step t by L + t.  A score
 depends on the difference of the two positions alone, so this is the
 published rotation by absolute position exactly, with no counter in the
 state and no angle over (W + T) x 1 rad however long a lane runs without a
 cut.  An episode cut inside a sequence is a segment boundary: steps interact
 only within a segment.
+
+An attention window (MLA's here, the K/V windows of models/qwen3_next.py and
+models/ouro.py) is as long as its state's shape says, L slots from 0 to
+W = `window`: a mixer reads L off `state["valid"]`, attends over the L + T
+slots of `[window; new]` and hands on the last min(L + T, W).  `window` is the
+span of the mask (a query sees at most the last W slots, itself included) and
+the length a window grows to.  A lane that acts holds W slots from the start
+(`initial_state`: the tick's `[:, 1:]` over 121); a sequence the learner
+unrolls starts from none (`from_stored`), so its window grows 0 -> burn-in ->
+min(burn-in + T, W), no slot is projected, rotated or scored that no step
+wrote, and a slot's position is the step's position in the sequence.  A slot
+whose `valid` is 0 weighs exactly 0, so leaving it out is the same result.
 
 The expert layer is told which experts it holds (`experts_here` from
 `first_expert`): it routes over all of them, sorts the assignments that fell
@@ -102,7 +114,7 @@ class CoreConfig:
     route_scale: float = 1.0
     shared_gate: bool = False  # the shared expert weighed by a sigmoid gate
     in_proj: bool = False  # a projection of the trunk's features to `hidden`
-    window: int = 0  # slots of an attention mixer's rolling window
+    window: int = 0  # an attention mask's span, and the slots a window grows to
     rope_theta: float = 0.0  # 0: the rope dimensions are not rotated (NoPE)
     # MLA (`_MLA`, here)
     mla_heads: int = 0
@@ -217,20 +229,27 @@ def kv_window_zero_state(kc: CoreConfig, batch: int):
             "valid": jnp.zeros((batch, kc.window), jnp.float32)}
 
 
+def window_keep(n: int, t: int, w: int) -> int:
+    """The first slot of `[window; new]` (n + t slots) that is handed on: the
+    last min(n + t, w) stay.  A full window (n = w) drops t."""
+    return max(n + t - w, 0)
+
+
 def window_mask(valid, seg, w: int):
     """What a query of the new steps may attend to in `[window; new]`
     (`_MLA`'s rule, whose ops stay where its cells' metrics read them).
 
-    valid [B, W] the window's validity, seg [B, T] the new steps' segment
-    ids (the window's slots belong to segment 0).  Returns the mask
-    [B, T, W+T] (causal, at most the last `w` slots the step itself
-    included, valid, of the step's own segment) and the validity [B, W+T] of
-    the slots once the steps are through: a slot of a segment that has ended
-    is void."""
+    valid [B, L] the window's validity (L slots, 0 to `w`: the state's shape
+    says how many), seg [B, T] the new steps' segment ids (the window's slots
+    belong to segment 0).  Returns the mask [B, T, L+T] (causal, at most the
+    last `w` slots the step itself included, valid, of the step's own
+    segment) and the validity [B, L+T] of the slots once the steps are
+    through: a slot of a segment that has ended is void."""
     b, t = seg.shape
-    seg_all = jnp.concatenate([jnp.zeros((b, w), seg.dtype), seg], axis=1)
+    n = valid.shape[1]
+    seg_all = jnp.concatenate([jnp.zeros((b, n), seg.dtype), seg], axis=1)
     valid = jnp.concatenate([valid, jnp.ones((b, t), jnp.float32)], axis=1)
-    pos_q, pos_k = w + jnp.arange(t)[:, None], jnp.arange(w + t)[None]
+    pos_q, pos_k = n + jnp.arange(t)[:, None], jnp.arange(n + t)[None]
     mask = ((pos_k <= pos_q) & (pos_k > pos_q - w))[None] & (
         valid[:, None, :] > 0) & (seg_all[:, None, :] == seg[:, :, None])
     return mask, valid * (seg_all == seg[:, -1:])
@@ -253,6 +272,7 @@ class _MLA(nn.Module):
         kc, cd = self.kc, self.compute_dtype
         b, t, _ = x.shape
         h, w, rank = kc.mla_heads, kc.window, kc.kv_rank
+        n = state["valid"].shape[1]  # the window's slots, 0 to w
         with jax.named_scope(device_scopes.MLA_PROJ):
             q = _Linear(h * (kc.nope + kc.rope), cd, name="q_proj")(x)
             q = q.reshape(b, t, h, kc.nope + kc.rope)
@@ -260,13 +280,13 @@ class _MLA(nn.Module):
             lat = jnp.concatenate(
                 [_RMSNorm(kc.eps, name="kv_norm")(kva[..., :rank]),
                  kva[..., rank:]], axis=-1)
-        lat = jnp.concatenate([state["lat"], lat], axis=1)  # [B, W+T, .]
-        seg_all = jnp.concatenate([jnp.zeros((b, w), seg.dtype), seg], axis=1)
+        lat = jnp.concatenate([state["lat"], lat], axis=1)  # [B, L+T, .]
+        seg_all = jnp.concatenate([jnp.zeros((b, n), seg.dtype), seg], axis=1)
         valid = jnp.concatenate(
             [state["valid"], jnp.ones((b, t), jnp.float32)], axis=1)
         with jax.named_scope(device_scopes.MLA_PROJ):
             kv = _Linear(h * (kc.nope + kc.v_dim), cd, name="kv_b")(
-                lat[..., :rank]).reshape(b, w + t, h, kc.nope + kc.v_dim)
+                lat[..., :rank]).reshape(b, n + t, h, kc.nope + kc.v_dim)
 
         def rope(u, pos):
             if not kc.rope_theta:
@@ -278,9 +298,9 @@ class _MLA(nn.Module):
             scores = (_mm("bthd,bshd->bhts", q[..., : kc.nope],
                           kv[..., : kc.nope], cd)
                       + _mm("bthr,bsr->bhts",
-                            rope(q[..., kc.nope:], w + jnp.arange(t)),
-                            rope(lat[..., rank:], jnp.arange(w + t)), cd))
-            pos_q, pos_k = w + jnp.arange(t)[:, None], jnp.arange(w + t)[None]
+                            rope(q[..., kc.nope:], n + jnp.arange(t)),
+                            rope(lat[..., rank:], jnp.arange(n + t)), cd))
+            pos_q, pos_k = n + jnp.arange(t)[:, None], jnp.arange(n + t)[None]
             mask = ((pos_k <= pos_q) & (pos_k > pos_q - w))[None] & (
                 valid[:, None, :] > 0) & (seg_all[:, None, :] == seg[:, :, None])
             scores = jnp.where(
@@ -292,7 +312,8 @@ class _MLA(nn.Module):
                 o.reshape(b, t, h * kc.v_dim))
         self.sow(STATS, "mla_live_key_share", jnp.mean(mask, dtype=jnp.float32))
         valid = valid * (seg_all == seg[:, -1:])
-        return y, {"lat": lat[:, t:], "valid": valid[:, t:]}
+        keep = window_keep(n, t, w)
+        return y, {"lat": lat[:, keep:], "valid": valid[:, keep:]}
 
 
 # ---------------------------------------------------------- expert layer
@@ -493,19 +514,27 @@ class StackCore:
     moe_stat_names = ("moe_expert_load_max_over_mean", "moe_held_assign_share",
                       "moe_tokens_dropped")
 
+    def _zero_state(self, kc: CoreConfig, batch: int):
+        return {state_key(kc, r, i): mixer.zero_state(kc, batch)
+                for r in range(1, kc.passes + 1)
+                for i, mixer in enumerate(kc.mixers, 1)}
+
     def initial_state(self, batch: int):
         """One entry a (pass, layer): every use of a layer has a state of its
-        own, each leaf led by the lane axis."""
-        return {state_key(self.kc, r, i): mixer.zero_state(self.kc, batch)
-                for r in range(1, self.kc.passes + 1)
-                for i, mixer in enumerate(self.kc.mixers, 1)}
+        own, each leaf led by the lane axis.  The attention windows hold
+        `window` slots, none valid: what a lane that acts starts from."""
+        return self._zero_state(self.kc, batch)
 
     def to_stored(self, state):
         b = jax.tree.leaves(state)[0].shape[0]
         return (jnp.zeros((b, 0), jnp.float32), jnp.zeros((b, 0), jnp.float32))
 
     def from_stored(self, init_c, init_h):
-        return self.initial_state(init_c.shape[0])
+        """A sequence's start state: `initial_state` with every attention
+        window at zero slots (the mixers grow it, the module's docstring says
+        how), so an unroll never works on slots no step of it wrote."""
+        return self._zero_state(
+            dataclasses.replace(self.kc, window=0), init_c.shape[0])
 
     def __call__(self, x, state, resets):
         return _Stack(self.kc, self.compute_dtype, name="core")(

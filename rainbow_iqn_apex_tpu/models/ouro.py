@@ -28,10 +28,13 @@ many of one as of the other):
 Per-lane state, float32, zero = initial (models/cores.zero_lanes), one a
 (pass, layer), as the published model keeps a key/value cache for each: a
 pass's keys are projections of that pass's input.  The window's keys,
-UN-rotated, and values [B, W, Hkv, d] each, and their validity [B, W].  The
-rotation is applied at use, by the slot: the key in slot s of `[window; new]`
-by s, the query of new step t by W + t (models/mla_moe.py says why that is
-the published rotation by absolute position).  An episode cut inside a
+UN-rotated, and values [B, L, Hkv, d] each, and their validity [B, L]: the
+state's shape says how many slots a window holds, `window` for a lane that
+acts and, for a sequence the learner unrolls, none at its start and then the
+steps written, up to `window` (models/mla_moe.py).  The rotation is applied
+at use, by the slot: the key in slot s of `[window; new]` by s, the query of
+new step t by L + t (models/mla_moe.py says why that is the published
+rotation by absolute position).  An episode cut inside a
 sequence is a segment boundary.  One step (`T == 1`, the actor) is one row of
 scores a (pass, layer).
 
@@ -57,6 +60,7 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (
     _Linear,
     _mm,
     rotate_halves,
+    window_keep,
     window_mask,
 )
 from rainbow_iqn_apex_tpu.obs import device_scopes
@@ -80,12 +84,13 @@ class _MHA(nn.Module):
                 b, t, g, h // g, d)
             k = _Linear(g * d, cd, name="k_proj")(x).reshape(b, t, g, d)
             v = _Linear(g * d, cd, name="v_proj")(x).reshape(b, t, g, d)
-        k = jnp.concatenate([state["k"], k], axis=1)  # [B, W+T, G, d]
+        n = state["valid"].shape[1]  # the window's slots, 0 to w
+        k = jnp.concatenate([state["k"], k], axis=1)  # [B, L+T, G, d]
         v = jnp.concatenate([state["v"], v], axis=1)
         with jax.named_scope(device_scopes.MHA_ATTN):
             with jax.named_scope(device_scopes.MHA_ROPE):
-                q = rotate_halves(q, w + jnp.arange(t), kc.rope_theta)
-                k_at = rotate_halves(k, jnp.arange(w + t), kc.rope_theta)
+                q = rotate_halves(q, n + jnp.arange(t), kc.rope_theta)
+                k_at = rotate_halves(k, jnp.arange(n + t), kc.rope_theta)
             scores = _mm("btgrd,bsgd->bgrts", q, k_at, cd)
             mask, valid = window_mask(state["valid"], seg, w)
             scores = jnp.where(
@@ -95,7 +100,9 @@ class _MHA(nn.Module):
             y = _Linear(kc.hidden, cd, name="o_proj")(o.reshape(b, t, h * d))
         self.sow(STATS, "attn_live_key_share",
                  jnp.mean(mask, dtype=jnp.float32))
-        return y, {"k": k[:, t:], "v": v[:, t:], "valid": valid[:, t:]}
+        keep = window_keep(n, t, w)
+        return y, {"k": k[:, keep:], "v": v[:, keep:],
+                   "valid": valid[:, keep:]}
 
 
 class OuroConfig(CoreConfig):

@@ -39,9 +39,11 @@ Per-lane state, all float32, zero = initial (models/cores.zero_lanes):
   Gated DeltaNet  S [B, Hv, d_k, d_v] and the convolution's tail
                   [B, K-1, 2 Hk d_k + Hv d_v]
   attention       the window's keys (after their norm, UN-rotated) and values
-                  [B, W, Hkv, d] each, and their validity [B, W]
+                  [B, L, Hkv, d] each, and their validity [B, L]: L slots,
+                  `window` for a lane that acts, 0 at a sequence's start
+                  and growing by its steps (models/mla_moe.py)
 The rotation is applied at use, by the slot: the key in slot s of
-`[window; new]` by s, the query of new step t by W + t (models/mla_moe.py says
+`[window; new]` by s, the query of new step t by L + t (models/mla_moe.py says
 why that is the published rotation by absolute position).  An episode cut
 inside a sequence is a segment boundary: steps interact only within a
 segment, in the chunk, the convolution and the attention mask.  One step
@@ -84,6 +86,7 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (
     _mm,
     _RMSNorm,
     rotate_halves,
+    window_keep,
     window_mask,
 )
 from rainbow_iqn_apex_tpu.obs import device_scopes
@@ -172,7 +175,8 @@ class _GatedAttention(nn.Module):
             k = _RMSNorm(kc.eps, name="k_norm")(
                 _Linear(g * d, cd, name="k_proj")(x).reshape(b, t, g, d))
             v = _Linear(g * d, cd, name="v_proj")(x).reshape(b, t, g, d)
-        k = jnp.concatenate([state["k"], k], axis=1)  # [B, W+T, G, d]
+        n = state["valid"].shape[1]  # the window's slots, 0 to w
+        k = jnp.concatenate([state["k"], k], axis=1)  # [B, L+T, G, d]
         v = jnp.concatenate([state["v"], v], axis=1)
 
         def rope(u, pos):
@@ -182,8 +186,8 @@ class _GatedAttention(nn.Module):
                      u[..., rot:]], axis=-1)
 
         with jax.named_scope(device_scopes.GATTN_ATTN):
-            scores = _mm("btgrd,bsgd->bgrts", rope(q, w + jnp.arange(t)),
-                         rope(k, jnp.arange(w + t)), cd)
+            scores = _mm("btgrd,bsgd->bgrts", rope(q, n + jnp.arange(t)),
+                         rope(k, jnp.arange(n + t)), cd)
             mask, valid = window_mask(state["valid"], seg, w)
             scores = jnp.where(
                 mask[:, None, None], scores / math.sqrt(d), NEG)
@@ -193,7 +197,9 @@ class _GatedAttention(nn.Module):
             y = _Linear(kc.hidden, cd, name="o_proj")(o.reshape(b, t, h * d))
         self.sow(STATS, "gattn_live_key_share",
                  jnp.mean(mask, dtype=jnp.float32))
-        return y, {"k": k[:, t:], "v": v[:, t:], "valid": valid[:, t:]}
+        keep = window_keep(n, t, w)
+        return y, {"k": k[:, keep:], "v": v[:, keep:],
+                   "valid": valid[:, keep:]}
 
 
 class Qwen3NextConfig(CoreConfig):

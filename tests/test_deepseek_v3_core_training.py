@@ -159,9 +159,9 @@ def test_fused_segment_trains_with_the_core(tmp_path):
     assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
     assert "kda_fused_tile_share" not in learn[0]
     # freeway has no terminals: the trained slice's 8 queries see the 4
-    # burn-in keys and their own causal half, of 12 + 8 slots
+    # burn-in keys and their own causal half, of 4 + 8 slots
     assert all(r["mla_live_key_share"] == pytest.approx(
-        (8 * 4 + 36) / (8 * 20)) for r in learn)
+        (8 * 4 + 36) / (8 * 12)) for r in learn)
     assert learn[0]["core_state_bytes_per_lane"] == state_bytes_per_lane(
         make_core(cfg))
 

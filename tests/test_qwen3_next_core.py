@@ -368,18 +368,21 @@ def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
     close(y, ref.moe_ffn(p, cc, x, (0, 32), ref.plain_dot))
 
 
-@pytest.mark.parametrize("steps,filled,share", [
-    (40, 0, 820 / (40 * 160)),  # the burn-in from the empty state
-    (80, 40, (80 * 40 + 3240) / (80 * 200)),  # the trained slice after it
-    (1, 120, 120 / 121),  # a warmed actor's tick
+@pytest.mark.parametrize("steps,filled,lane,share", [
+    (40, 0, False, 820 / (40 * 40)),  # the burn-in from a sequence's start
+    (80, 40, False, (80 * 40 + 3240) / (80 * 120)),  # the trained slice after
+    (1, 120, True, 120 / 121),  # a warmed actor's tick over its full window
 ])
-def test_live_key_share_of_the_learn_steps_two_passes(steps, filled, share):
+def test_live_key_share_of_the_learn_steps_two_passes(
+        steps, filled, lane, share):
     """`gattn_live_key_share`: the share of score columns the mask leaves, at
     the published window and sequence lengths (tiny widths)."""
     cc = tiny_cc(window=120, **SHORT)
     core, stack, params, _, _, state = make(cc, batch=1, steps=2, reset_at=())
     x = jax.random.normal(jax.random.PRNGKey(1), (1, filled + steps, FEATURES))
     none = jnp.zeros((1, filled + steps), bool)
+    if not lane:  # the learner's passes: from a sequence's zero-slot start
+        state = core.from_stored(jnp.zeros((1, 0)), jnp.zeros((1, 0)))
     if filled:
         _, state = jitted(cc, stack)[0](
             params, x[:, :filled], state, none[:, :filled])

@@ -166,9 +166,9 @@ def test_fused_segment_trains_with_the_core(tmp_path):
     assert all(r["kda_scalar_gate_share"] == 1.0 for r in learn)
     assert "mla_live_key_share" not in learn[0]
     # freeway has no terminals: the trained slice's 8 queries see the 4
-    # burn-in keys and their own causal half, of 12 + 8 slots
+    # burn-in keys and their own causal half, of 4 + 8 slots
     assert all(r["gattn_live_key_share"] == pytest.approx(
-        (8 * 4 + 36) / (8 * 20)) for r in learn)
+        (8 * 4 + 36) / (8 * 12)) for r in learn)
     assert learn[0]["core_state_bytes_per_lane"] == state_bytes_per_lane(
         make_core(cfg))
 
