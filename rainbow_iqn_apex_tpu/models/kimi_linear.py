@@ -47,12 +47,14 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (  # noqa: F401  (the blocks' ol
     HI,
     CoreConfig,
     StackCore,
+    _causal_conv,
     _Linear,
     _MLA,
     _mm,
     _MoE,
     _RMSNorm,
     _Stack,
+    _Taps,
 )
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
@@ -87,34 +89,6 @@ class KimiLinearConfig(CoreConfig):
             experts_here=cc["experts_here"],
             first_expert=cc.get("first_expert_here", 0),
         )
-
-
-class _Taps(nn.Module):
-    kernel: int
-    channels: int
-
-    @nn.compact
-    def __call__(self):
-        bound = 1.0 / math.sqrt(self.kernel)
-        return self.param(
-            "taps", lambda k, s: jax.random.uniform(k, s, jnp.float32,
-                                                    -bound, bound),
-            (self.kernel, self.channels))
-
-
-def _causal_conv(z, taps, tail, seg):
-    """Causal depthwise convolution of z [B, T, C] after the last K-1 steps
-    `tail` [B, K-1, C] of the lane's past, over the steps of a step's own
-    segment: out_t = sum_j taps[j] z_{t-j}.  Returns (out, the new tail)."""
-    b, t, _ = z.shape
-    kk = taps.shape[0]
-    zin = jnp.concatenate([tail, z], axis=1)  # [B, K-1+T, C]
-    sin = jnp.concatenate([jnp.zeros((b, kk - 1), seg.dtype), seg], axis=1)
-    conv = sum(
-        taps[j] * zin[:, kk - 1 - j: kk - 1 - j + t]
-        * (sin[:, kk - 1 - j: kk - 1 - j + t] == seg)[..., None]
-        for j in range(kk))
-    return conv, zin[:, t:] * (sin[:, t:] == seg[:, -1:])[..., None]
 
 
 def _a_log_init(key, shape):

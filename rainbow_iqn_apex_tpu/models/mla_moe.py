@@ -1,7 +1,8 @@
 """The blocks the non-LSTM cores of `R2D2Net` run (interface: models/cores.py):
 a residual stack in which every layer names its mixer, and whose
 feed-forward is a dense SwiGLU in the leading layers (in all of them for a
-dense family) and sparse experts beside shared ones in the rest.  A block
+dense family) and sparse experts in the rest, beside a shared expert where
+the family has one.  A block
 norms each sub-layer's input (pre-norm) and, where the family says so
 (`out_norms`), its output too before the residual add (four norms a block).
 The stack is run `passes` times over the SAME layer modules, its final norm
@@ -9,27 +10,37 @@ after every pass feeding the next: each layer's leaves stand in the parameter
 tree once, a leaf's gradient is the sum over its uses, and the per-lane state
 has one entry a (pass, layer), because every use of a layer sees keys of its
 own.  The MLA mixer (latent attention over a rolling window of latents) lives
-here, and what the attention mixers share: the window's mask and the two
-published forms of the rotation.
+here, and what the mixers share: the attention window's mask, the two
+published forms of the rotation, and the short causal convolution with its
+taps (`_causal_conv`, `_Taps`: the two delta-rule mixers' and the gated short
+convolution's).
 
-Four cores are built from them: `models/kimi_linear.py` (three KDA mixers in
+Five cores are built from them: `models/kimi_linear.py` (three KDA mixers in
 four, defined there, and an un-rotated MLA in the fourth),
 `models/deepseek_v3.py` (every mixer MLA with decoupled rotary keys, an input
 projection in the embedding's place), `models/qwen3_next.py` (three Gated
 DeltaNet mixers in four and a gated softmax attention in the fourth, both
-defined there; softmax routing and a gated shared expert) and
+defined there; softmax routing and a gated shared expert),
 `models/ouro.py` (plain multi-head attention defined there, every
 feed-forward dense, four norms a block, the stack run `total_ut_steps`
-times).  Each reads its own published keys into one `CoreConfig`; which mixer
-a layer runs, whether the rope dimensions are rotated, how the router scores,
-whether the input is projected, how often the stack is run and whether a
-block norms its outputs are read off it.
+times) and `models/lfm2.py` (a gated short convolution, defined there, in
+three layers of four and `models/ouro.py`'s attention with q/k norms in the
+fourth; sigmoid routing and NO shared expert).  Each reads its own published
+keys into one `CoreConfig`; which mixer a layer runs, whether the rope
+dimensions are rotated, how the router scores, whether the input is
+projected, how often the stack is run and whether a block norms its outputs
+are read off it.
 
 A mixer is a flax module `Mixer(kc, compute_dtype)` called as
 `(x [B, T, hidden], state, seg [B, T]) -> (y, state)` that says under which
 name it stands in its layer (`layer_name`) and what its per-lane state is at
 the start (`zero_state(kc, batch)`: float32, zero = initial, every leaf led
-by the lane axis, so models/cores.zero_lanes resets a lane).
+by the lane axis, so models/cores.zero_lanes resets a lane).  Six mixers,
+five kinds of state: a delta-rule matrix with its convolution's tail (KDA,
+Gated DeltaNet), a window of latents (MLA), a window of keys and values
+(gated attention, plain attention) and, the smallest, the tail alone of a
+gated short convolution: the last `conv_kernel` - 1 steps of its gated input,
+[B, K-1, hidden] (models/lfm2.py).
 
 MLA's per-lane state, float32, zero = initial (models/cores.zero_lanes): the
 window's latents `lat` [B, L, rank + rope] with the rope key UN-rotated, and
@@ -59,12 +70,15 @@ The expert layer is told which experts it holds (`experts_here` from
 on its own by expert and runs one grouped (ragged) product per projection.
 The scores are sigmoids or a softmax over all the experts (`route`), the
 chosen ones' weights their scores over their sum, times `route_scale`; the
-shared expert is added as it is or weighed by a sigmoid gate (`shared_gate`).
+shared expert is added as it is or weighed by a sigmoid gate (`shared_gate`),
+and a family without one says `shared_width` 0: the layer then holds no
+`shared` leaf and runs no `moe_shared` op, and its output is the held
+experts' part alone.
 No capacity: the row buffer is chosen, by the count, among sizes of which the
 largest holds every assignment, so no token is ever dropped.  What absent
 experts would add is left out (the chip's share of an expert-parallel layer;
-tests/test_kimi_linear_core.py and tests/test_deepseek_v3_core.py add the
-shares up to the uncut layer).
+tests/test_kimi_linear_core.py, tests/test_deepseek_v3_core.py and
+tests/test_lfm2_core.py add the shares up to the uncut layer).
 """
 
 from __future__ import annotations
@@ -91,10 +105,10 @@ EXPERT_ROWS = (0.5, 2.0)
 class CoreConfig:
     """What the stack is built from; a family's reader fills it from its own
     published keys (`KimiLinearConfig`, `DeepSeekV3Config`,
-    `Qwen3NextConfig`, `OuroConfig`).  A mixer's sizes are read by that mixer
-    alone and the expert layer's by `_MoE` alone, so a family leaves the
-    others' at their zeros (a dense family has no expert layer:
-    `first_dense` is all its layers)."""
+    `Qwen3NextConfig`, `OuroConfig`, `Lfm2Config`).  A mixer's sizes are
+    read by that mixer alone and the expert layer's by `_MoE` alone, so a
+    family leaves the others' at their zeros (a dense family has no expert
+    layer: `first_dense` is all its layers)."""
 
     hidden: int
     mixers: Tuple[Any, ...]  # the mixer module of each layer, in order
@@ -107,7 +121,7 @@ class CoreConfig:
     experts: int = 0
     top_k: int = 0
     expert_width: int = 0
-    shared_width: int = 0
+    shared_width: int = 0  # 0: the expert layer has no shared expert
     experts_here: int = 0
     first_expert: int = 0
     route: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax"
@@ -122,9 +136,10 @@ class CoreConfig:
     rope: int = 0
     v_dim: int = 0
     kv_rank: int = 0
-    # the delta-rule mixers: the convolution and the chunked scan of both,
-    # then KDA's sizes (models/kimi_linear.py) and Gated DeltaNet's
-    # (models/qwen3_next.py)
+    # the short convolution's taps (the delta-rule mixers', and the gated
+    # short convolution's of models/lfm2.py, whose channels are `hidden`);
+    # the chunked scan of the delta-rule mixers, then KDA's sizes
+    # (models/kimi_linear.py) and Gated DeltaNet's (models/qwen3_next.py)
     conv_kernel: int = 0
     chunk: int = 0
     block: int = 0
@@ -141,6 +156,7 @@ class CoreConfig:
     attn_kv_heads: int = 0
     attn_head_dim: int = 0
     attn_rotary_dim: int = 0
+    attn_qk_norm: bool = False  # plain attention norms q and k a head
 
     @property
     def layers(self) -> int:
@@ -186,6 +202,34 @@ class _SwiGLU(nn.Module):
         lin = lambda n, name: _Linear(n, self.compute_dtype, name=name)  # noqa: E731
         h = jax.nn.silu(lin(self.width, "gate")(x)) * lin(self.width, "up")(x)
         return lin(x.shape[-1], "down")(h)
+
+
+class _Taps(nn.Module):
+    kernel: int
+    channels: int
+
+    @nn.compact
+    def __call__(self):
+        bound = 1.0 / math.sqrt(self.kernel)
+        return self.param(
+            "taps", lambda k, s: jax.random.uniform(k, s, jnp.float32,
+                                                    -bound, bound),
+            (self.kernel, self.channels))
+
+
+def _causal_conv(z, taps, tail, seg):
+    """Causal depthwise convolution of z [B, T, C] after the last K-1 steps
+    `tail` [B, K-1, C] of the lane's past, over the steps of a step's own
+    segment: out_t = sum_j taps[j] z_{t-j}.  Returns (out, the new tail)."""
+    b, t, _ = z.shape
+    kk = taps.shape[0]
+    zin = jnp.concatenate([tail, z], axis=1)  # [B, K-1+T, C]
+    sin = jnp.concatenate([jnp.zeros((b, kk - 1), seg.dtype), seg], axis=1)
+    conv = sum(
+        taps[j] * zin[:, kk - 1 - j: kk - 1 - j + t]
+        * (sin[:, kk - 1 - j: kk - 1 - j + t] == seg)[..., None]
+        for j in range(kk))
+    return conv, zin[:, t:] * (sin[:, t:] == seg[:, -1:])[..., None]
 
 
 # ------------------------------------------------------------------- MLA
@@ -411,12 +455,13 @@ class _MoE(nn.Module):
             pick = sum((n_held > r).astype(jnp.int32) for r in sizes[:-1])
             y = jax.lax.switch(pick, [with_rows(r) for r in sizes], weights,
                                x, order, w_sorted, group_sizes, n_held)
-        with jax.named_scope(device_scopes.MOE_SHARED):
-            shared = _SwiGLU(kc.shared_width, cd, name="shared")(x)
-            if kc.shared_gate:
-                shared = shared * jax.nn.sigmoid(
-                    _Linear(1, cd, name="shared_gate")(x))
-            y = y + shared
+        if kc.shared_width:  # 0: a family without a shared expert
+            with jax.named_scope(device_scopes.MOE_SHARED):
+                shared = _SwiGLU(kc.shared_width, cd, name="shared")(x)
+                if kc.shared_gate:
+                    shared = shared * jax.nn.sigmoid(
+                        _Linear(1, cd, name="shared_gate")(x))
+                y = y + shared
         load = jnp.bincount(idx.reshape(-1), length=kc.experts)
         rows_taken = jnp.asarray(sizes, jnp.int32)[pick]
         self.sow(STATS, "moe_held_assign_share", n_held / (n * k))
@@ -424,6 +469,9 @@ class _MoE(nn.Module):
                  load.max() / (n * k / kc.experts))
         self.sow(STATS, "moe_tokens_dropped",
                  (n_held - jnp.minimum(n_held, rows_taken)).astype(jnp.float32))
+        # the share of the taken buffer's rows that hold an assignment: the
+        # rest of the gather, the products' rows and the scatter is masked
+        self.sow(STATS, "moe_row_fill_share", n_held / rows_taken)
         return y.reshape(*lead, f)
 
 
@@ -505,8 +553,8 @@ class _Stack(nn.Module):
 class StackCore:
     """The core interface (models/cores.py) over `_Stack`: zero start state,
     nothing stored in the ring.  A family's core (`KimiLinearCore`,
-    `DeepSeekV3Core`, `Qwen3NextCore`, `OuroCore`) is a frozen dataclass of
-    `kc` and `compute_dtype` that names the counters it reports,
+    `DeepSeekV3Core`, `Qwen3NextCore`, `OuroCore`, `Lfm2Core`) is a frozen
+    dataclass of `kc` and `compute_dtype` that names the counters it reports,
     `stat_names`: each is an output of the compiled segment, so a core lists
     what its cell reads (`moe_stat_names` where it has expert layers)."""
 

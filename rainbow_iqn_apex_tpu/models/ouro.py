@@ -24,6 +24,10 @@ many of one as of the other):
   q, k, v = x W_q, x W_k, x W_v, no bias, no norm, no gate; every dimension of
   every q and k head turned by position (`rotate_half` over the head's two
   halves); o_i = softmax(q_i K^T / sqrt(d) + mask) V; y = [o_i]_i W_o.
+A family whose attention is this one with an RMSNorm a head on q and k before
+the rotation says `attn_qk_norm` (models/lfm2.py: 32 query heads over 8
+key/value heads); the window then keeps the keys after their norm.  Ouro's
+tree has no such leaf.
 
 Per-lane state, float32, zero = initial (models/cores.zero_lanes), one a
 (pass, layer), as the published model keeps a key/value cache for each: a
@@ -59,6 +63,7 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (
     kv_window_zero_state,
     _Linear,
     _mm,
+    _RMSNorm,
     rotate_halves,
     window_keep,
     window_mask,
@@ -84,6 +89,9 @@ class _MHA(nn.Module):
                 b, t, g, h // g, d)
             k = _Linear(g * d, cd, name="k_proj")(x).reshape(b, t, g, d)
             v = _Linear(g * d, cd, name="v_proj")(x).reshape(b, t, g, d)
+            if kc.attn_qk_norm:  # over the head's d, one scale for all heads
+                q = _RMSNorm(kc.eps, name="q_norm")(q)
+                k = _RMSNorm(kc.eps, name="k_norm")(k)
         n = state["valid"].shape[1]  # the window's slots, 0 to w
         k = jnp.concatenate([state["k"], k], axis=1)  # [B, L+T, G, d]
         v = jnp.concatenate([state["v"], v], axis=1)

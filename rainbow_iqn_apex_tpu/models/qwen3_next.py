@@ -68,11 +68,9 @@ from flax import linen as nn
 from rainbow_iqn_apex_tpu.models.cores import CORE_STATS as STATS
 from rainbow_iqn_apex_tpu.models.kimi_linear import (
     _a_log_init,
-    _causal_conv,
     _chunk_len,
     _dt_bias_init,
     _l2_norm,
-    _Taps,
     kda_chunked,
     kda_prep_path,
     kda_step,
@@ -82,9 +80,11 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (
     CoreConfig,
     StackCore,
     kv_window_zero_state,
+    _causal_conv,
     _Linear,
     _mm,
     _RMSNorm,
+    _Taps,
     rotate_halves,
     window_keep,
     window_mask,

@@ -30,7 +30,12 @@ account for the whole traced window by scope.  An instruction the compiler
 made (no ``op_name`` of its own: a layout copy, a slice of a loop it
 unrolled) is a class of its own beside its inherited scope
 (``instruction_origins``): it is also put down to the instruction that
-consumes its result, the one that needed the layout.
+consumes its result, the one that needed the layout.  Where the compiler
+writes a label of its own over the program's name (the TPU compiler lowers
+`jax.lax.ragged_dot` to custom calls whose ``op_name`` is `ragged-dot-none`:
+a name without a `/`, which no name stack of the program is), the
+instruction counts under the scope path of what feeds it: the grouped
+products of an expert layer, forwards and backwards, are `moe_experts`' time.
 
 jax is imported only inside ``reduce_capture`` (for the .xplane.pb reader);
 everything else is plain string and number work.
@@ -91,9 +96,15 @@ GATTN_ROPE = "gattn_rope"  # inside it: the rotary dimensions turned by their sl
 # ---- a stack run several times over shared weights, and the Ouro core's
 # mixer (models/mla_moe.py::_Stack, models/ouro.py)
 LOOP_PASS = "loop_pass"  # one pass of a stack run several times: its layers, its final norm
-MHA_PROJ = "mha_proj"  # plain multi-head attention: q, k, v, o
+MHA_PROJ = "mha_proj"  # plain multi-head attention: q, k, v, o (and the q/k
+# norms of a family that has them)
 MHA_ATTN = "mha_attn"  # scores, mask, softmax, values over the K/V window
 MHA_ROPE = "mha_rope"  # inside it: every q and k head turned whole by its slot
+# ---- the LFM2 core's gated short convolution (models/lfm2.py); its attention
+# layer wears the MHA_* names (the q/k norms under MHA_PROJ), its expert
+# layers MOE_ROUTE and MOE_EXPERTS (there is no shared expert)
+SCONV_MIX = "sconv_mix"  # the input product, the two gates, the 3-tap
+# convolution, the output product
 IQN_HEAD = "iqn_head"  # tau embedding + the tau-folded heads (IQN)
 OPTIMIZER = "optimizer"  # tx.update, apply_updates, the target copy
 GRAD_ALLREDUCE = "grad_allreduce"  # psum/pmax/pmean of the sharded builders
@@ -105,7 +116,7 @@ ALL_SCOPES = TICK_SCOPES + (
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
     CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM, GDN_MIX, GATTN_PROJ, GATTN_ATTN,
     GATTN_ROPE, CORE_NORM, DENSE_FFN, KDA_MIX, LSTM_INPUT, LOOP_PASS,
-    MHA_PROJ, MHA_ATTN, MHA_ROPE,
+    MHA_PROJ, MHA_ATTN, MHA_ROPE, SCONV_MIX,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 
@@ -197,6 +208,54 @@ def _parse(hlo_text: str):
     return rows, caller
 
 
+def _first_readers(rows) -> Dict[str, str]:
+    """{instruction: the first instruction of its own computation that reads
+    its result}, in the text's order."""
+    home = {row[0]: row[1] for row in rows}
+    reader: Dict[str, str] = {}
+    for inst, comp, _s, _o, _n, operands in rows:
+        for operand in operands:
+            if home.get(operand) == comp and operand != inst:
+                reader.setdefault(operand, inst)
+    return reader
+
+
+def _labelled_paths(rows, named) -> Dict[str, Tuple[str, ...]]:
+    """{instruction: scope path} of the instructions that bear the compiler's
+    own label where a name of the program's stood (`instruction_scopes` says
+    which and why): the path of the instructions that make its operands,
+    each looked for through instructions without a name of the program's,
+    and of several the innermost (the longest path: a grouped product's
+    group sizes come from the learn step at large, its rows from the expert
+    layer; of equals the last operand's, sizes standing before rows and
+    kernels); where none has a name, of the first instruction that reads its
+    result, through further ones without a name.  An instruction neither
+    finds is left out."""
+    home = {row[0]: row[1] for row in rows}
+    feeds = {inst: [o for o in operands if home.get(o) == comp and o != inst]
+             for inst, comp, _s, _o, _n, operands in rows}
+    reader = _first_readers(rows)
+
+    def follow(at, step):
+        seen = set()
+        while at is not None and at not in named and at not in seen:
+            seen.add(at)
+            at = step(at)
+        return named.get(at)
+
+    out = {}
+    for inst, _c, _s, _o, op_name, _a in rows:
+        if op_name is None or inst in named:
+            continue
+        paths = [follow(o, lambda at: (feeds[at] or [None])[0])
+                 for o in feeds[inst]]
+        paths = [p for p in paths if p is not None] or [
+            follow(reader.get(inst), reader.get)]
+        if paths[0] is not None:
+            out[inst] = max(reversed(paths), key=len)
+    return out
+
+
 @functools.lru_cache(maxsize=2)  # a capture's readers ask again and again
 def instruction_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
     """{instruction name: scope path} of a compiled module's text
@@ -206,14 +265,24 @@ def instruction_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
     of the instruction that calls its computation: the copies inside the
     `while` that a gather became count as that gather's, and a copy in the
     entry computation, which nothing calls, has the empty path: it is
-    known, and in no scope.  The result is kept for the text's next asker:
+    known, and in no scope.  An instruction that bears the compiler's own
+    label where the program's name stood (an ``op_name`` without a `/`,
+    which no name stack of the program is: `ragged-dot-none` on the custom
+    calls a `ragged_dot` becomes, forwards and backwards) takes the path of
+    what feeds it (`_labelled_paths`), and where that finds nothing the path
+    of its caller, as above.  The result is kept for the text's next asker:
     read it, do not change it."""
     rows, caller = _parse(hlo_text)
     named = {inst: scope_path(op_name)
-             for inst, _c, _s, _o, op_name, _a in rows if op_name is not None}
+             for inst, _c, _s, _o, op_name, _a in rows
+             if op_name is not None and "/" in op_name}
     home = {row[0]: row[1] for row in rows}
     out = dict(named)
+    if len(named) < sum(row[4] is not None for row in rows):
+        out.update(_labelled_paths(rows, named))
     for inst in home:
+        if inst in out:
+            continue
         seen, at = set(), inst
         while at not in named and at not in seen:
             seen.add(at)
@@ -237,12 +306,7 @@ def instruction_origins(hlo_text: str) -> Dict[str, Origin]:
     rows, _caller = _parse(hlo_text)
     inherited = instruction_scopes(hlo_text)
     own = {row[0]: row[4] is not None for row in rows}
-    home = {row[0]: row[1] for row in rows}
-    reader: Dict[str, str] = {}  # instruction -> the first one that reads it
-    for inst, comp, _s, _o, _n, operands in rows:
-        for operand in operands:
-            if home.get(operand) == comp and operand != inst:
-                reader.setdefault(operand, inst)
+    reader = _first_readers(rows)
     out = {}
     for inst, _c, shape, opcode, _n, _a in rows:
         seen, at = set(), inst

@@ -1,8 +1,10 @@
 """The CLI (`train_agent_apex.py --architecture r2d2 --core-config <file>`)
 with every non-LSTM core, at tiny widths: the host-fed anakin loop,
-`train_r2d2`, the apex R2D2 driver and the fused trainer.  A file of its own:
-these are the slowest cases of the cores' tests, and the suite runs a file a
-worker."""
+`train_r2d2` and the apex R2D2 driver (the fused trainer's cases are
+tests/test_core_cli_fused.py's, which reads `CORES` from here).  Files of
+their own: these are the slowest cases of the cores' tests, the suite runs a
+file a worker, and with five cores one file of both was its longest worker by
+itself."""
 
 import json
 import os
@@ -12,7 +14,7 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CORES = {name: os.path.join(HERE, "fixtures", name + "_core_tiny.json")
-         for name in ("kimi", "deepseek_v3", "qwen3_next", "ouro")}
+         for name in ("kimi", "deepseek_v3", "qwen3_next", "ouro", "lfm2")}
 
 
 @pytest.mark.parametrize("core", sorted(CORES))
@@ -44,35 +46,3 @@ def test_host_fed_roles_train_with_the_core(tmp_path, role, learners, core):
     losses = [r["loss"] for r in rows
               if r["kind"] == "learn" and r["loss"] is not None]
     assert losses and all(np.isfinite(x) for x in losses)
-
-
-@pytest.mark.parametrize("core", sorted(CORES))
-def test_cli_runs_the_fused_trainer_with_core_config(tmp_path, core):
-    import train_agent_apex
-
-    rc = train_agent_apex.main([
-        "--role", "anakin", "--architecture", "r2d2",
-        "--env-id", "jaxgame:freeway", "--core-config", CORES[core],
-        "--compute-dtype", "float32", "--history-length", "2",
-        "--hidden-size", "32", "--r2d2-burn-in", "4", "--r2d2-seq-len", "8",
-        "--r2d2-overlap", "4", "--batch-size", "4", "--multi-step", "2",
-        "--memory-capacity", "480", "--learn-start", "96",
-        "--frames-per-learn", "2", "--num-envs-per-actor", "4",
-        "--anakin-segment-ticks", "8", "--learner-devices", "1",
-        "--eval-episodes", "1", "--eval-interval", "0",
-        "--checkpoint-interval", "0", "--metrics-interval", "1",
-        "--t-max", "320", "--run-id", "cli",
-        "--results-dir", str(tmp_path / "results"),
-        "--checkpoint-dir", str(tmp_path / "ckpt"),
-    ])
-    assert rc == 0
-    rows = [json.loads(line) for line in open(
-        tmp_path / "results" / "cli" / "metrics.jsonl")]
-    learn = [r for r in rows if r["kind"] == "learn"]
-    assert learn
-    if core == "ouro":  # no expert layer: no such counter in its rows
-        assert all("moe_tokens_dropped" not in r and r["loop_passes"] == 3.0
-                   for r in learn)
-    else:
-        assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
-    assert all("core_state_bytes_per_lane" in r for r in learn)

@@ -2,7 +2,7 @@
 a sequence the learner unrolls starts from zero slots (`from_stored`) and its
 windows grow by the steps written, up to the configuration's `window`; a lane
 that acts holds `window` slots from the start (`initial_state`) and its tick
-is the program it was.  One case a family, over the tiny cores of the four
+is the program it was.  One case a family, over the tiny cores of the five
 families' own test files; and the learn step's jaxpr, which holds no score
 array over slots that no step of the sequence wrote."""
 
@@ -23,15 +23,17 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import (
 
 import test_deepseek_v3_core
 import test_kimi_linear_core
+import test_lfm2_core
 import test_ouro_core
 import test_qwen3_next_core
-from test_deepseek_v3_core import close, grads_close  # the four files' one
+from test_deepseek_v3_core import close, grads_close  # the five files' one
 
 # family -> its test file: `TINY`, `tiny_cc`, `make` and the plain reference
 FAMILIES = {"deepseek_v3": test_deepseek_v3_core,
             "kimi_linear": test_kimi_linear_core,
             "qwen3_next": test_qwen3_next_core,
-            "ouro": test_ouro_core}
+            "ouro": test_ouro_core,
+            "lfm2_moe": test_lfm2_core}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 
 

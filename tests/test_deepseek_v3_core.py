@@ -306,6 +306,7 @@ def test_the_kimi_cores_parameter_paths_and_outputs_are_unchanged():
         pinned["tiny_grad_abs_sums"], rel=1e-4, abs=1e-6)
     stats = {k: float(v) for k, v in reduce_stats(sown).items()}
     assert stats.pop("mla_live_key_share") > 0  # sown by `_MLA`, not listed
+    assert 0 < stats.pop("moe_row_fill_share") <= 1  # by `_MoE`, not listed
     assert stats == pytest.approx(pinned["tiny_stats"])
 
 

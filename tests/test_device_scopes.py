@@ -360,6 +360,70 @@ def test_origins_leave_the_scopes_as_they_were():
         False, "copy", "u8[9]{0}", ("tick_learn", "replay_gather"))
 
 
+_LEARN = "jit(segment)/while/body/tick_learn/cond/branch_1_fun/learn_step/"
+HLO_LABELLED = f"""HloModule jit_segment, entry_computation_layout={{()->f32[]}}
+
+%branch.clone (arg: (s32[8], bf16[64,32], bf16[8,32,16])) -> (f32[64,16]) {{
+  %gte.sizes = s32[8]{{0}} get-tuple-element(%arg), index=0, metadata={{op_name="{_LEARN}closed_call"}}
+  %ragged-dot-metadata.1 = (s32[9]{{0}}, s32[12]{{0}}) custom-call(%gte.sizes), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-metadata"}}
+  %gte.m = s32[9]{{0}} get-tuple-element(%ragged-dot-metadata.1), index=0
+  %fusion.rows = bf16[64,32]{{1,0}} fusion(%arg), kind=kLoop, metadata={{op_name="{_LEARN}transpose(jvp(core_layer))/moe_experts/cond/branch_0_fun/convert_element_type"}}
+  %copy.k = bf16[8,32,16]{{2,1,0}} copy(%fusion.rows)
+  %ragged-dot-none.7 = f32[64,16]{{1,0}} custom-call(%gte.m, %fusion.rows, %copy.k), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  %fusion.norm = f32[] fusion(%ragged-dot-none.7), kind=kInput, metadata={{op_name="{_LEARN}optimizer/reduce_sum"}}
+  %ragged-dot-none.8 = f32[64,16]{{1,0}} custom-call(%arg), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  %bitcast.8 = f32[1024]{{0}} bitcast(%ragged-dot-none.8)
+  %fusion.after = f32[] fusion(%bitcast.8), kind=kInput, metadata={{op_name="{_LEARN}core_layer/moe_experts/cond/branch_0_fun/mul"}}
+  %ragged-dot-none.9 = f32[64,16]{{1,0}} custom-call(%arg), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  ROOT %tuple.5 = (f32[64,16]) tuple(%ragged-dot-none.9)
+}}
+
+ENTRY %main (arg0: u8[9]) -> (f32[4]) {{
+  %conditional.74 = (f32[64,16]) conditional(%i, %t), branch_computations={{%branch.clone}}
+  %conditional.95 = (f32[4]) conditional(%p, %t), branch_computations={{%learn}}, metadata={{op_name="jit(segment)/while/body/tick_learn/cond"}}
+  ROOT %tuple.9 = (f32[4]{{0}}) tuple(%conditional.95)
+}}
+"""
+MOE_BACK = ("tick_learn", "learn_step", "core_layer", "moe_experts")
+
+
+@pytest.mark.parametrize("inst,want", [
+    # the gradient of a grouped product in a branch the compiler cloned
+    # without a name: fed by the learn step's sizes (through its own
+    # metadata call), by rows and, through a copy, kernels of `moe_experts`:
+    # the innermost; that the optimizer's norm reads it first says nothing
+    ("ragged-dot-none.7", MOE_BACK),
+    # nothing with a name feeds it: the first that reads it, through a bitcast
+    ("ragged-dot-none.8", MOE_BACK),
+    # neither: its caller's path, which here is nobody's
+    ("ragged-dot-none.9", ()),
+    ("ragged-dot-metadata.1", ("tick_learn", "learn_step")),
+    ("fusion.norm", ("tick_learn", "learn_step", "optimizer")),
+    ("copy.k", ()),  # no metadata at all: the caller's, as ever
+])
+def test_an_instruction_under_the_compilers_label_takes_its_feeders_path(
+        inst, want):
+    """The TPU compiler lowers `jax.lax.ragged_dot` to custom calls whose
+    `op_name` is its own (`ragged-dot-none`: no `/`, so no name stack of the
+    program): the grouped products of the expert layers stood outside every
+    tick scope (PR 43: 19% of `lfm2-r2d2-fused`'s dispatch)."""
+    assert ds.instruction_scopes(HLO_LABELLED)[inst] == want
+    origin = ds.instruction_origins(HLO_LABELLED)[inst]
+    # it bears metadata, so it is no instruction the compiler made
+    assert origin.own == (inst != "copy.k")
+    if origin.own:
+        assert origin.consumer_path == want
+
+
+def test_a_labelled_product_counts_in_its_layer_and_not_outside():
+    ops = [["%ragged-dot-none.7 = f32[64,16]{1,0} custom-call(...)", 0.5],
+           ["%ragged-dot-none.9 = f32[64,16]{1,0} custom-call(...)", 0.25]]
+    a = ds.attribute(ops, ds.instruction_scopes(HLO_LABELLED),
+                     ds.instruction_origins(HLO_LABELLED))
+    assert ds.seconds(a, "learn_step", "moe_experts") == 0.5
+    assert a["outside_tick_s"] == 0.25 and a["compiler_made_s"] == 0.0
+
+
 def test_attribute_sums_the_compiler_made_by_consumer():
     ops = [["%copy.415 = u8[9]{0:T(8,128)} copy(u8[9]{0} %gte.1)", 0.5],
            ["%copy.416 = f32[3]{0} copy(f32[3]{0} %fusion.20)", 0.25],
