@@ -118,6 +118,7 @@ class LSTMCore:
     features: int = 512
 
     stat_names = ()  # counters the core sows (CORE_STATS), by name
+    act_stat_names = ()  # those a fused tick's act step reports beside them
 
     @property
     def stored_width(self) -> int:

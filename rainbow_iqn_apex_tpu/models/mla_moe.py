@@ -75,7 +75,14 @@ and a family without one says `shared_width` 0: the layer then holds no
 `shared` leaf and runs no `moe_shared` op, and its output is the held
 experts' part alone.
 No capacity: the row buffer is chosen, by the count, among sizes of which the
-largest holds every assignment, so no token is ever dropped.  What absent
+largest holds every assignment, so no token is ever dropped.  Where the
+layer has few tokens (`FEW_ROWS`: the actor's 16 lanes, an eval rollout, a
+tiny sequence pass) it sorts nothing: it walks the held experts, skips every
+one no token chose, and runs one that has rows on all the tokens from that
+expert's own kernels, each token weighed by its routing weight for it (0.0
+where it did not choose it); the same products on the same operands, summed
+in another order, and what the walk costs goes by the experts touched
+(`moe_act_touched_expert_share`), not by the experts held.  What absent
 experts would add is left out (the chip's share of an expert-parallel layer;
 tests/test_kimi_linear_core.py, tests/test_deepseek_v3_core.py and
 tests/test_lfm2_core.py add the shares up to the uncut layer).
@@ -99,6 +106,9 @@ NEG = -1e30
 # row buffers of the grouped product, as multiples of the token count; the
 # last is top_k, which holds every assignment
 EXPERT_ROWS = (0.5, 2.0)
+# up to so many rows (tokens x the choices that can be held) the expert layer
+# sorts nothing and walks the held experts its tokens chose
+FEW_ROWS = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -407,6 +417,30 @@ def _grouped_swiglu(weights, xs, group_sizes, dtype):
     return rd(jax.nn.silu(rd(xs, gate)) * rd(xs, up), down)
 
 
+def _walked_swiglu(weights, x, coef, group_sizes, dtype):
+    """The held experts' part for a few tokens x [n, f]: a walk over the held
+    experts that skips every one no token chose.  One that has rows slices
+    its own three kernels out of the stacks INSIDE the branch taken, so the
+    slice alone is read and cast (the TPU compiler folds slice and cast into
+    the product's operand), and runs the SwiGLU on all n tokens, each weighed
+    by `coef[e]` [n]: its routing weight for that expert, 0.0 for a token that
+    did not choose it, which adds exactly 0.  Cost goes by the experts
+    touched, not by the experts held.  The walk is unrolled with static
+    slices: from a loop over the slots the compiler hoists the cast of the
+    WHOLE stacks (PERF.md, PR 44)."""
+    xc = x.astype(dtype)
+    y = jnp.zeros_like(x)
+    for e in range(coef.shape[0]):
+        def run(y, e=e):
+            gate, up, down = (w[e] for w in weights)
+            h = jax.nn.silu(_mm("ni,io->no", xc, gate, dtype)) * _mm(
+                "ni,io->no", xc, up, dtype)
+            return y + _mm("ni,io->no", h, down, dtype) * coef[e][:, None]
+
+        y = jax.lax.cond(group_sizes[e] > 0, run, lambda y: y, y)
+    return y
+
+
 class _MoE(nn.Module):
     kc: CoreConfig
     compute_dtype: Any
@@ -417,6 +451,8 @@ class _MoE(nn.Module):
         lead, f = x.shape[:-1], x.shape[-1]
         x = x.reshape(-1, f)
         n, k, held_n = x.shape[0], kc.top_k, kc.experts_here
+        most = n * min(k, held_n)  # rows that hold every assignment
+        few = most <= FEW_ROWS  # a few tokens (the actor): no rows are sorted
         with jax.named_scope(device_scopes.MOE_ROUTE):
             s, bias = _Router(kc.experts, kc.route, name="router")(x)
             _, idx = jax.lax.top_k(s + bias, k)
@@ -424,11 +460,19 @@ class _MoE(nn.Module):
             w = sel / sel.sum(axis=-1, keepdims=True) * kc.route_scale
             local = idx - kc.first_expert
             held = (local >= 0) & (local < held_n)
-            key = jnp.where(held, local, held_n).reshape(-1)
-            order = jnp.argsort(key, stable=True)  # held first, by expert
-            group_sizes = jnp.bincount(key, length=held_n + 1)[:held_n]
-            n_held = group_sizes.sum()
-            w_sorted = (w * held).reshape(-1)[order]
+            if few:
+                # [held_n, n, k]: which of a token's choices fell on expert e
+                # (at most one: top_k's choices are distinct)
+                chose = local[None] == jnp.arange(held_n)[:, None, None]
+                coef = jnp.sum(jnp.where(chose, w[None], 0.0), axis=-1)
+                group_sizes = jnp.sum(chose, axis=(1, 2))
+                n_held = group_sizes.sum()
+            else:
+                key = jnp.where(held, local, held_n).reshape(-1)
+                order = jnp.argsort(key, stable=True)  # held first, by expert
+                group_sizes = jnp.bincount(key, length=held_n + 1)[:held_n]
+                n_held = group_sizes.sum()
+                w_sorted = (w * held).reshape(-1)[order]
         weights = _ExpertWeights(held_n, kc.expert_width, f,
                                  name="experts")()
         cd = self.compute_dtype
@@ -447,14 +491,16 @@ class _MoE(nn.Module):
                 return jnp.zeros_like(x).at[tok].add(ys)
             return run
 
-        most = n * min(k, held_n)  # rows that hold every assignment
-        sizes = [most]
-        if most > 1024:  # a few tokens (the actor): one buffer is enough
-            sizes = sorted({min(most, int(n * r)) for r in EXPERT_ROWS} | {most})
         with jax.named_scope(device_scopes.MOE_EXPERTS):
-            pick = sum((n_held > r).astype(jnp.int32) for r in sizes[:-1])
-            y = jax.lax.switch(pick, [with_rows(r) for r in sizes], weights,
-                               x, order, w_sorted, group_sizes, n_held)
+            if few:
+                y = _walked_swiglu(weights, x, coef, group_sizes, cd)
+            else:
+                sizes = sorted(
+                    {min(most, int(n * r)) for r in EXPERT_ROWS} | {most})
+                pick = sum((n_held > r).astype(jnp.int32) for r in sizes[:-1])
+                y = jax.lax.switch(pick, [with_rows(r) for r in sizes],
+                                   weights, x, order, w_sorted, group_sizes,
+                                   n_held)
         if kc.shared_width:  # 0: a family without a shared expert
             with jax.named_scope(device_scopes.MOE_SHARED):
                 shared = _SwiGLU(kc.shared_width, cd, name="shared")(x)
@@ -463,7 +509,12 @@ class _MoE(nn.Module):
                         _Linear(1, cd, name="shared_gate")(x))
                 y = y + shared
         load = jnp.bincount(idx.reshape(-1), length=kc.experts)
-        rows_taken = jnp.asarray(sizes, jnp.int32)[pick]
+        if few:  # nothing is buffered: the rows that hold every assignment
+            rows_taken = most
+            self.sow(STATS, "moe_act_touched_expert_share",
+                     jnp.mean(group_sizes > 0, dtype=jnp.float32))
+        else:
+            rows_taken = jnp.asarray(sizes, jnp.int32)[pick]
         self.sow(STATS, "moe_held_assign_share", n_held / (n * k))
         self.sow(STATS, "moe_expert_load_max_over_mean",
                  load.max() / (n * k / kc.experts))
@@ -561,6 +612,15 @@ class StackCore:
     stored_width = 0  # zero start state: the ring stores no state
     moe_stat_names = ("moe_expert_load_max_over_mean", "moe_held_assign_share",
                       "moe_tokens_dropped")
+
+    @property
+    def act_stat_names(self):
+        """What a fused tick's act step reports of its own, after the learn
+        steps' `stat_names` in the segment's outputs: the share of the held
+        experts the lanes' tokens touched, where the stack has expert layers."""
+        if self.kc.first_dense < self.kc.layers:
+            return ("moe_act_touched_expert_share",)
+        return ()
 
     def _zero_state(self, kc: CoreConfig, batch: int):
         return {state_key(kc, r, i): mixer.zero_state(kc, batch)

@@ -349,22 +349,26 @@ def as_actor_input(obs, history: int):
 
 
 def build_r2d2_act_step(
-    cfg: Config, num_actions: int, use_noise: bool = True
+    cfg: Config, num_actions: int, use_noise: bool = True,
+    with_stats: bool = False,
 ) -> Callable:
     """Recurrent acting: (params, obs [B,H,W,C] u8, state, key) ->
-    (action [B], q [B,A], new_state).  C must match the training channels
-    (cfg.history_length when frame-stacking; the host FrameStacker supplies
-    it on the actor side)."""
+    (action [B], q [B,A], new_state), and with `with_stats` a fourth entry,
+    the counters the core sowed over the step ({name: scalar}).  C must match
+    the training channels (cfg.history_length when frame-stacking; the host
+    FrameStacker supplies it on the actor side)."""
     net = make_r2d2_network(cfg, num_actions, use_noise=use_noise)
 
     def act_step(params, obs, state, key):
-        q, new_state = net.apply(
+        (q, new_state), sown = net.apply(
             {"params": params},
             obs[:, None],  # [B, 1, H, W, C]
             state,
             rngs={"noise": key},
+            mutable=[CORE_STATS] if with_stats else [],
         )
         q = q[:, 0]
-        return jnp.argmax(q, axis=-1).astype(jnp.int32), q, new_state
+        out = (jnp.argmax(q, axis=-1).astype(jnp.int32), q, new_state)
+        return out + (reduce_stats(sown),) if with_stats else out
 
     return act_step

@@ -104,7 +104,9 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
     learn.  carry = (ts, ss, env_states, ep_returns, stack, frame, keep,
     core_state, frames); outs = per-tick (ep_return [L], loss/q_mean/
     grad_norm [learns_per_tick], then one entry per counter of the core's
-    `stat_names`; NaN when cold or off-cadence).
+    `stat_names`; NaN when cold or off-cadence; after them one scalar a tick
+    per counter of the core's `act_stat_names`, what the tick's own act step
+    sowed, NaN where it did not).
 
     `append_fn` defaults to replay.append; the sharded path passes the
     shard_map'd build_sharded_seq_append so each device's lanes emit into
@@ -114,7 +116,8 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
     lanes = cfg.num_envs_per_actor
     period, lpt = _learn_cadence(cfg)
     _, _, _, learn_start_seqs = _seq_geometry(cfg)
-    act_fn = build_r2d2_act_step(cfg, game.num_actions, use_noise=True)
+    act_fn = build_r2d2_act_step(cfg, game.num_actions, use_noise=True,
+                                 with_stats=True)
     env_step = batched_reset_step(game)
     append = append_fn or replay.append
     bw = cfg.priority_weight
@@ -128,7 +131,8 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
         pre_c, pre_h = core.to_stored(state)
         with jax.named_scope(device_scopes.TICK_ACT):
             stack = shift_stack(stack, frame, keep)
-            actions, _q, state = act_fn(ts.params, stack, state, ka)
+            actions, _q, state, act_stats = act_fn(
+                ts.params, stack, state, ka)
         with jax.named_scope(device_scopes.TICK_ENV):
             env_s, ep, nframe, reward, term, trunc, out_ret = env_step(
                 env_s, ep, actions, ks
@@ -171,8 +175,10 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
 
         cut_keep = (~(term | trunc)).astype(jnp.uint8)
         state = zero_lanes(state, cut_keep)  # zero-reset on episode cut
+        acted = tuple(act_stats.get(n, jnp.float32(jnp.nan))
+                      for n in core.act_stat_names)
         return (ts, ss, env_s, ep, stack, nframe, cut_keep, state,
-                frames), (out_ret, *infos)
+                frames), (out_ret, *infos, *acted)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def segment(carry, key):
@@ -429,8 +435,10 @@ def train_anakin_r2d2(cfg: Config,
                     core_state_bytes_per_lane=state_bytes,
                     stem_from_frames_share=stem_from_frames_share(
                         cfg, (h, w), n_dev),
-                    **{n: nanmean(v)
-                       for n, v in zip(core.stat_names, counters)},
+                    # the learn steps' counters, then the ticks' own (the
+                    # mean over this dispatch's ticks)
+                    **{n: nanmean(v) for n, v in zip(
+                        (*core.stat_names, *core.act_stat_names), counters)},
                 )
                 obs_run.periodic(learn_steps, frames)
             if crossed(cfg.eval_interval, prev_steps, learn_steps):

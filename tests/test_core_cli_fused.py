@@ -10,8 +10,8 @@ import pytest
 from test_core_cli import CORES
 
 
-@pytest.mark.parametrize("core", sorted(CORES))
-def test_cli_runs_the_fused_trainer_with_core_config(tmp_path, core):
+def run_fused_cli(tmp_path, core):
+    """A fused run of ten 8-tick dispatches of 4 lanes; its `learn` rows."""
     import train_agent_apex
 
     rc = train_agent_apex.main([
@@ -32,11 +32,20 @@ def test_cli_runs_the_fused_trainer_with_core_config(tmp_path, core):
     assert rc == 0
     rows = [json.loads(line) for line in open(
         tmp_path / "results" / "cli" / "metrics.jsonl")]
-    learn = [r for r in rows if r["kind"] == "learn"]
+    return [r for r in rows if r["kind"] == "learn"]
+
+
+@pytest.mark.parametrize("core", sorted(CORES))
+def test_cli_runs_the_fused_trainer_with_core_config(tmp_path, core):
+    learn = run_fused_cli(tmp_path, core)
     assert learn
     if core == "ouro":  # no expert layer: no such counter in its rows
         assert all("moe_tokens_dropped" not in r and r["loop_passes"] == 3.0
+                   and "moe_act_touched_expert_share" not in r
                    for r in learn)
     else:
         assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
+        # the ticks' own counter, the mean over a dispatch's ticks
+        assert all(0.0 <= r["moe_act_touched_expert_share"] <= 1.0
+                   for r in learn)
     assert all("core_state_bytes_per_lane" in r for r in learn)

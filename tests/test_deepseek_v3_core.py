@@ -307,6 +307,8 @@ def test_the_kimi_cores_parameter_paths_and_outputs_are_unchanged():
     stats = {k: float(v) for k, v in reduce_stats(sown).items()}
     assert stats.pop("mla_live_key_share") > 0  # sown by `_MLA`, not listed
     assert 0 < stats.pop("moe_row_fill_share") <= 1  # by `_MoE`, not listed
+    # by `_MoE` where it walks the held experts (a few tokens), not listed
+    assert 0 < stats.pop("moe_act_touched_expert_share") <= 1
     assert stats == pytest.approx(pinned["tiny_stats"])
 
 
