@@ -5,6 +5,9 @@ A core sits between the conv trunk and the dueling heads:
     core(x [B, T, F] float32, state, resets [B, T] bool) -> (y [B, T, F'], state)
     core.initial_state(batch) -> state      (all zeros; zeroing a lane's
                                              leaves resets that lane)
+    core.reset_lanes(state, keep [B]) -> state   the lanes where keep is 0
+                                             back at the start, by the
+                                             core's cheapest means
     core.stored_width                        width of EACH of the ring's two
                                              stored-state columns
     core.to_stored(state) -> (c, h)          what the ring keeps of a state
@@ -136,6 +139,9 @@ class LSTMCore:
     def from_stored(self, init_c, init_h) -> LSTMState:
         return (init_c, init_h)
 
+    def reset_lanes(self, state: LSTMState, keep) -> LSTMState:
+        return zero_lanes(state, keep)
+
     def __call__(self, x, state: LSTMState, resets):
         x, resets = jnp.moveaxis(x, 1, 0), jnp.moveaxis(resets, 1, 0)  # [T, B, .]
         with jax.named_scope(device_scopes.LSTM_SCAN):
@@ -145,7 +151,11 @@ class LSTMCore:
 
 def zero_lanes(state: Any, keep: jnp.ndarray) -> Any:
     """`state` with the lanes where `keep` [B] is 0 back at the initial
-    (zero) state; every leaf leads with the lane axis."""
+    (zero) state; every leaf leads with the lane axis.  A reset for every
+    core, and the LSTM's; the trainers call `core.reset_lanes`, which for the
+    cores of models/mla_moe.py leaves an attention window's keys and values
+    where they are and resets the lane by the slots' validity and the ring's
+    head ([B, W] and [B], not a multiply over the window)."""
     kf = keep.astype(jnp.float32)
     return jax.tree.map(
         lambda s: s * kf.reshape((-1,) + (1,) * (s.ndim - 1)), state)

@@ -7,9 +7,9 @@ configs/cores/kanana_2_30b_a3b.json is layers 1 to 5 of Kanana-2-30B-A3B.
 
 The blocks are models/mla_moe.py's, shared with the Kimi-Linear core; this
 module is the reader of the family's published keys.  The agent's only
-memory across ticks is each layer's window of latents (un-rotated rope keys,
-rotated at use by their slot: models/mla_moe.py says why that is the
-published rotation).  The trunk's features are not the model's hidden size
+memory across ticks is each layer's window of latents (a ring a tick writes
+one slot of; un-rotated rope keys, rotated at use by their slot's age:
+models/mla_moe.py says why that is the published rotation).  The trunk's features are not the model's hidden size
 and no width is cut, so an input projection stands where a language model has
 its embedding.  `n_group` = `topk_group` = 1 is the only grouping read: the
 group limit is then vacuous.

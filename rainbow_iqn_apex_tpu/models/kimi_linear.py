@@ -13,8 +13,10 @@ other blocks are models/mla_moe.py's, which the DeepSeek-V3 core
 
 Per-lane state, all float32, zero = initial (models/cores.zero_lanes):
   KDA   S [B, H, d_k, d_v] and the convolutions' tails [B, K-1, 3*H*d_k]
-  MLA   the window's latents [B, L, rank + rope] and their validity [B, L]
-        (L slots: `window` for a lane, 0 at a sequence's start and growing)
+  MLA   the window's latents [B, L, rank + rope], their validity [B, L] and
+        the ring's head [B] (L slots: `window` for a lane, a ring written one
+        slot a tick and reset by its validity; 0 at a sequence's start and
+        growing: models/mla_moe.py)
 
 Sequences run the KDA recurrence chunked (`kda_chunked`: WY form, the
 in-chunk decay products taken relative to sub-block starts so that no

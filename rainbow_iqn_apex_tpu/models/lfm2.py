@@ -33,13 +33,16 @@ cross-correlation over a left-padded input, so its tap K-1 meets z_t: here
 `taps` [K, channel] stands in the order of the lag j, that kernel reversed
 and transposed, a permutation of a seeded leaf.
 
-Per-lane state, all float32, zero = initial (models/cores.zero_lanes):
-  short convolution  the tail: the last K-1 steps of z, [B, K-1, hidden]
+Per-lane state, all float32, zero = initial:
+  short convolution  the tail: the last K-1 steps of z, [B, K-1, hidden];
+                     reset by models/cores.zero_lanes
   attention          the window's keys (after their norm, UN-rotated) and
-                     values [B, L, Hkv, d] each, and their validity [B, L]:
-                     L slots, `window` for a lane that acts, 0 at a
-                     sequence's start and growing by its steps
-                     (models/mla_moe.py)
+                     values [B, L, Hkv, d] each, their validity [B, L] and
+                     the ring's head [B].  `window` slots are a RING (a lane
+                     that acts): a tick writes one slot in place and the lane
+                     is reset by its slots' validity and head; fewer are a
+                     sequence's window, 0 at its start and growing by its
+                     steps (models/mla_moe.py; models/ouro.py's `_MHA`)
 An episode cut inside a sequence is a segment boundary: steps interact only
 within a segment, in the convolution (a step reads no z from before the cut)
 and in the attention mask.  One step (`T == 1`, the actor) is one K-tap sum a

@@ -9,11 +9,11 @@ The stack is run `passes` times over the SAME layer modules, its final norm
 after every pass feeding the next: each layer's leaves stand in the parameter
 tree once, a leaf's gradient is the sum over its uses, and the per-lane state
 has one entry a (pass, layer), because every use of a layer sees keys of its
-own.  The MLA mixer (latent attention over a rolling window of latents) lives
-here, and what the mixers share: the attention window's mask, the two
-published forms of the rotation, and the short causal convolution with its
-taps (`_causal_conv`, `_Taps`: the two delta-rule mixers' and the gated short
-convolution's).
+own.  The MLA mixer (latent attention over a window of latents) lives here,
+and what the mixers share: the attention window (a ring for a lane that acts:
+its opening, its mask and its reset), the two published forms of the rotation,
+and the short causal convolution with its taps (`_causal_conv`, `_Taps`: the
+two delta-rule mixers' and the gated short convolution's).
 
 Five cores are built from them: `models/kimi_linear.py` (three KDA mixers in
 four, defined there, and an un-rotated MLA in the fourth),
@@ -33,37 +33,59 @@ are read off it.
 
 A mixer is a flax module `Mixer(kc, compute_dtype)` called as
 `(x [B, T, hidden], state, seg [B, T]) -> (y, state)` that says under which
-name it stands in its layer (`layer_name`) and what its per-lane state is at
-the start (`zero_state(kc, batch)`: float32, zero = initial, every leaf led
-by the lane axis, so models/cores.zero_lanes resets a lane).  Six mixers,
-five kinds of state: a delta-rule matrix with its convolution's tail (KDA,
-Gated DeltaNet), a window of latents (MLA), a window of keys and values
-(gated attention, plain attention) and, the smallest, the tail alone of a
-gated short convolution: the last `conv_kernel` - 1 steps of its gated input,
-[B, K-1, hidden] (models/lfm2.py).
+name it stands in its layer (`layer_name`), what its per-lane state is at the
+start (`zero_state(kc, batch)`: float32, zero = initial, every leaf led by the
+lane axis, so models/cores.zero_lanes resets a lane) and, where zeroing every
+leaf is more than a reset needs, how a lane is reset (`reset_state(state,
+keep)`; `StackCore.reset_lanes` maps the mixers' resets over the (pass,
+layer) states).  Six mixers, five kinds of state: a delta-rule matrix with its
+convolution's tail (KDA, Gated DeltaNet), a window of latents (MLA), a window
+of keys and values (gated attention, plain attention) and, the smallest, the
+tail alone of a gated short convolution: the last `conv_kernel` - 1 steps of
+its gated input, [B, K-1, hidden] (models/lfm2.py).
 
-MLA's per-lane state, float32, zero = initial (models/cores.zero_lanes): the
-window's latents `lat` [B, L, rank + rope] with the rope key UN-rotated, and
-their validity `valid` [B, L].  Where the configuration rotates
-(`rope_theta` > 0) the rotation is applied at use, by the slot: the key in
-slot s of `[window; new]` by s, the query of new step t by L + t.  A score
-depends on the difference of the two positions alone, so this is the
-published rotation by absolute position exactly, with no counter in the
+MLA's per-lane state, float32, zero = initial: the window's latents `lat`
+[B, L, rank + rope] with the rope key UN-rotated, their validity `valid`
+[B, L] and the ring's `head` [B].  Where the configuration rotates
+(`rope_theta` > 0) the rotation is applied at use, by a slot's position among
+the slots attended over (`window_open`'s `pos_k`, the new steps' `pos_q`).  A
+score depends on the difference of the two positions alone, so this is the
+published rotation by absolute position exactly, with no step counter in the
 state and no angle over (W + T) x 1 rad however long a lane runs without a
 cut.  An episode cut inside a sequence is a segment boundary: steps interact
 only within a segment.
 
 An attention window (MLA's here, the K/V windows of models/qwen3_next.py and
-models/ouro.py) is as long as its state's shape says, L slots from 0 to
-W = `window`: a mixer reads L off `state["valid"]`, attends over the L + T
-slots of `[window; new]` and hands on the last min(L + T, W).  `window` is the
-span of the mask (a query sees at most the last W slots, itself included) and
-the length a window grows to.  A lane that acts holds W slots from the start
-(`initial_state`: the tick's `[:, 1:]` over 121); a sequence the learner
-unrolls starts from none (`from_stored`), so its window grows 0 -> burn-in ->
-min(burn-in + T, W), no slot is projected, rotated or scored that no step
-wrote, and a slot's position is the step's position in the sequence.  A slot
-whose `valid` is 0 weighs exactly 0, so leaving it out is the same result.
+models/ouro.py; all three go through `window_open` and `window_mask`) is as
+long as its state's SHAPE says, L slots from 0 to W = `window`, and the shape
+alone says which of two things it is.  `window` is the span of the mask (a
+query sees at most the last W slots, itself included) and the length a window
+grows to.
+  Fewer than W slots: a sequence the learner unrolls.  It starts from none
+  (`from_stored`), stands in position order with head 0 and grows by
+  `[window; new]`, 0 -> burn-in -> min(burn-in + T, W): no slot is projected,
+  rotated or scored that no step wrote, and a slot's position is the step's
+  position in the sequence.
+  W slots: a RING, what a lane that acts holds from the start
+  (`initial_state`).  Slot s holds the step of age-ordered position
+  (s - head) mod W: the slot at `head` is the oldest and the next to be
+  written.  A tick (T = 1) writes its step over that slot FIRST, in place,
+  and attends over the ring's W slots: the slot it overwrote is the one of
+  `[window; new]` that the mask shut out, so the scores are [.., 1, W], the
+  same numbers, and a tick touches one slot of the window where a roll
+  rewrote all of them.  A call of T > 1 steps on a ring (an eval rollout)
+  attends over `[ring; new]`, the ring's slots at their ages and the new
+  steps at W + i, and then writes its last min(T, W) steps, step i to slot
+  (head + i) mod W.  Either way `head` advances by T mod W and the ring holds
+  the last W slots of `[window; new]`.  A window that has grown to W slots
+  (position order, head 0) is a ring already.
+A slot whose `valid` is 0 weighs exactly 0 (its score is `NEG`, whose softmax
+weight is 0.0 in float32, and what a slot holds is finite), so leaving it out
+is the same result, and so a lane is reset by its slots' validity and its
+head alone (`window_reset`: [B, W] and [B], what the slots held stays in them,
+stale); `zero_lanes` on a ring stays a correct reset, everything zero being
+the initial state.  A call on a ring sows `attn_act_window_written_share`,
+the slots written over the slots held: 1 / W on a tick.
 
 The expert layer is told which experts it holds (`experts_here` from
 `first_expert`): it routes over all of them, sorts the assignments that fell
@@ -92,13 +114,14 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from rainbow_iqn_apex_tpu.models.cores import CORE_STATS as STATS
+from rainbow_iqn_apex_tpu.models.cores import zero_lanes
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
 HI = jax.lax.Precision.HIGHEST
@@ -244,18 +267,20 @@ def _causal_conv(z, taps, tail, seg):
 
 # ------------------------------------------------------------------- MLA
 def rope_cos_sin(u, pos, theta: float):
-    """(cos, sin) of the angles pos[s] x theta^(-2i/d), i < d/2, shaped to
-    broadcast against u [B, S, ..., d/2]."""
+    """(cos, sin) of the angles pos x theta^(-2i/d), i < d/2, shaped to
+    broadcast against u [B, S, ..., d/2]; pos [S], or [B, S] where the lanes'
+    slots stand at different positions (a ring)."""
     d = u.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = pos.astype(jnp.float32)[:, None] * freq  # [S, d/2]
-    angle = angle.reshape((1, pos.shape[0]) + (1,) * (u.ndim - 3) + (d // 2,))
+    pos = jnp.atleast_2d(pos).astype(jnp.float32)
+    angle = (pos[..., None] * freq).reshape(  # [B or 1, S, d/2]
+        pos.shape + (1,) * (u.ndim - 3) + (d // 2,))
     return jnp.cos(angle), jnp.sin(angle)
 
 
 def rotate_pairs(u, pos, theta: float):
     """u [B, S, ..., d] with its adjacent pairs (u_2i, u_2i+1) turned by
-    pos[s] x theta^(-2i/d): the published `rope_interleave` rotation (which
+    pos x theta^(-2i/d): the published `rope_interleave` rotation (which
     permutes to halves and applies `rotate_half`: the same scores)."""
     d = u.shape[-1]
     cos, sin = rope_cos_sin(u, pos, theta)
@@ -266,21 +291,39 @@ def rotate_pairs(u, pos, theta: float):
 
 
 def rotate_halves(u, pos, theta: float):
-    """u [B, S, ..., d] turned by pos[s]: (u_i, u_{i + d/2}) by the angle
-    pos[s] x theta^(-2i/d), the published `rotate_half` form."""
+    """u [B, S, ..., d] turned by pos: (u_i, u_{i + d/2}) by the angle
+    pos x theta^(-2i/d), the published `rotate_half` form."""
     d = u.shape[-1]
     cos, sin = rope_cos_sin(u, pos, theta)
     a, b = u[..., : d // 2], u[..., d // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
+# ------------------------------------------------------ attention windows
+def window_zero_state(batch: int, slots: int, **payload):
+    """The empty window of an attention mixer: `payload` names what a slot
+    keeps and its shape ([B, slots, *shape], float32), beside the slots'
+    validity [B, slots] and the ring's head [B]."""
+    return {**{name: jnp.zeros((batch, slots) + tuple(shape), jnp.float32)
+               for name, shape in payload.items()},
+            "valid": jnp.zeros((batch, slots), jnp.float32),
+            "head": jnp.zeros((batch,), jnp.float32)}
+
+
 def kv_window_zero_state(kc: CoreConfig, batch: int):
     """The empty window of an attention mixer that keeps keys and values:
-    [B, W, Hkv, d] each, and their validity [B, W]."""
-    kv = (batch, kc.window, kc.attn_kv_heads, kc.attn_head_dim)
-    return {"k": jnp.zeros(kv, jnp.float32),
-            "v": jnp.zeros(kv, jnp.float32),
-            "valid": jnp.zeros((batch, kc.window), jnp.float32)}
+    [B, W, Hkv, d] each."""
+    kv = (kc.attn_kv_heads, kc.attn_head_dim)
+    return window_zero_state(batch, kc.window, k=kv, v=kv)
+
+
+def window_reset(state, keep):
+    """A window with the lanes where `keep` [B] is 0 back at the start: by the
+    slots' validity and the head alone.  What the slots held stays where it
+    is and weighs exactly 0 (the module's docstring)."""
+    kf = keep.astype(jnp.float32)
+    return {**state, "valid": state["valid"] * kf[:, None],
+            "head": state["head"] * kf}
 
 
 def window_keep(n: int, t: int, w: int) -> int:
@@ -289,24 +332,85 @@ def window_keep(n: int, t: int, w: int) -> int:
     return max(n + t - w, 0)
 
 
-def window_mask(valid, seg, w: int):
-    """What a query of the new steps may attend to in `[window; new]`
-    (`_MLA`'s rule, whose ops stay where its cells' metrics read them).
+def ring_positions(head, w: int):
+    """[B, W]: the age-ordered position of every slot of a ring whose oldest
+    slot is `head` [B]."""
+    return (jnp.arange(w)[None] - head.astype(jnp.int32)[:, None]) % w
 
-    valid [B, L] the window's validity (L slots, 0 to `w`: the state's shape
-    says how many), seg [B, T] the new steps' segment ids (the window's slots
-    belong to segment 0).  Returns the mask [B, T, L+T] (causal, at most the
-    last `w` slots the step itself included, valid, of the step's own
-    segment) and the validity [B, L+T] of the slots once the steps are
-    through: a slot of a segment that has ended is void."""
-    b, t = seg.shape
-    n = valid.shape[1]
+
+class Window(NamedTuple):
+    """What `window_open` hands a mixer: the slots its new steps attend over
+    and the state it hands on."""
+
+    held: Dict[str, Any]  # name -> [B, S, ...]
+    pos_q: Any  # [T]: the new steps' positions
+    pos_k: Any  # [S], or [B, S] on a ring: the held slots' positions
+    valid: Any  # [B, S]
+    seg: Any  # [B, S] (or [B, 1]): the held slots' segment ids, a window's 0
+    state: Dict[str, Any]
+
+
+def window_open(state, new, seg, w: int) -> Window:
+    """The new steps `new` (name -> [B, T, ...]) of segment ids `seg` [B, T]
+    taken into a window of span `w`; the state's SHAPE says how.
+
+    Fewer than `w` slots (a sequence's window, in position order, head 0):
+    the steps attend over `[window; new]` and its last min(L + T, w) slots
+    are handed on.  `w` slots (a ring): one step is written FIRST, over the
+    oldest slot, which its query may not see anyway, and attends over the
+    ring's `w` slots; several attend over `[ring; new]` and the last
+    min(T, w) are written then, step i to slot (head + i) mod w: the last `w`
+    slots of `[window; new]` in age order, in place."""
+    valid, head = state["valid"], state["head"]
+    b, n = valid.shape
+    t = seg.shape[1]
+    last = seg[:, -1:]
+    if n == w:  # a ring: the last m steps go to the slots from head + t - m on
+        m = min(t, w)
+        lanes = jnp.arange(b)[:, None]
+        slots = (head.astype(jnp.int32)[:, None] + jnp.arange(t - m, t)) % w
+        put = lambda buf, x: buf.at[lanes, slots].set(  # noqa: E731
+            x[:, -m:], unique_indices=True)
+        head = (head + t) % w
+    if n == w and t == 1:
+        held = {name: put(state[name], x) for name, x in new.items()}
+        valid = put(valid * (last == 0), jnp.ones((b, 1), jnp.float32))
+        return Window(held, jnp.full((1,), w - 1), ring_positions(head, w),
+                      valid, seg, {**held, "valid": valid, "head": head})
+    held = {name: jnp.concatenate([state[name], x], axis=1)
+            for name, x in new.items()}
     seg_all = jnp.concatenate([jnp.zeros((b, n), seg.dtype), seg], axis=1)
-    valid = jnp.concatenate([valid, jnp.ones((b, t), jnp.float32)], axis=1)
-    pos_q, pos_k = n + jnp.arange(t)[:, None], jnp.arange(n + t)[None]
-    mask = ((pos_k <= pos_q) & (pos_k > pos_q - w))[None] & (
-        valid[:, None, :] > 0) & (seg_all[:, None, :] == seg[:, :, None])
-    return mask, valid * (seg_all == seg[:, -1:])
+    valid_all = jnp.concatenate([valid, jnp.ones((b, t), jnp.float32)], axis=1)
+    pos_q = n + jnp.arange(t)
+    after = valid_all * (seg_all == last)  # an ended segment's slots are void
+    if n < w:
+        keep = window_keep(n, t, w)
+        return Window(held, pos_q, jnp.arange(n + t), valid_all, seg_all,
+                      {**{name: x[:, keep:] for name, x in held.items()},
+                       "valid": after[:, keep:], "head": head})
+    pos_k = jnp.concatenate(
+        [ring_positions(state["head"], w), jnp.broadcast_to(pos_q, (b, t))],
+        axis=1)
+    return Window(held, pos_q, pos_k, valid_all, seg_all,
+                  {**{name: put(state[name], x) for name, x in new.items()},
+                   "valid": put(after[:, :n], after), "head": head})
+
+
+def window_mask(win: Window, seg, w: int):
+    """[B, T, S]: what a query of the new steps `seg` [B, T] may attend to
+    among the slots `win` holds: causal, at most the last `w` slots the step
+    itself included, valid, of the step's own segment."""
+    pos_q = win.pos_q[:, None]
+    pos_k = jnp.atleast_2d(win.pos_k)[:, None, :]  # [B or 1, 1, S]
+    return (pos_k <= pos_q) & (pos_k > pos_q - w) & (
+        win.valid[:, None, :] > 0) & (win.seg[:, None, :] == seg[:, :, None])
+
+
+def sow_written_share(module, state, t: int, w: int):
+    """`attn_act_window_written_share` of a call on a ring (none on a window
+    that still grows): the slots it writes over the slots it holds."""
+    if state["valid"].shape[1] == w:
+        module.sow(STATS, "attn_act_window_written_share", min(t, w) / w)
 
 
 class _MLA(nn.Module):
@@ -315,18 +419,18 @@ class _MLA(nn.Module):
 
     layer_name = "mla"
 
+    reset_state = staticmethod(window_reset)
+
     @staticmethod
     def zero_state(kc: CoreConfig, batch: int):
-        return {"lat": jnp.zeros((batch, kc.window, kc.kv_rank + kc.rope),
-                                 jnp.float32),
-                "valid": jnp.zeros((batch, kc.window), jnp.float32)}
+        return window_zero_state(batch, kc.window,
+                                 lat=(kc.kv_rank + kc.rope,))
 
     @nn.compact
     def __call__(self, x, state, seg):
         kc, cd = self.kc, self.compute_dtype
         b, t, _ = x.shape
         h, w, rank = kc.mla_heads, kc.window, kc.kv_rank
-        n = state["valid"].shape[1]  # the window's slots, 0 to w
         with jax.named_scope(device_scopes.MLA_PROJ):
             q = _Linear(h * (kc.nope + kc.rope), cd, name="q_proj")(x)
             q = q.reshape(b, t, h, kc.nope + kc.rope)
@@ -334,13 +438,12 @@ class _MLA(nn.Module):
             lat = jnp.concatenate(
                 [_RMSNorm(kc.eps, name="kv_norm")(kva[..., :rank]),
                  kva[..., rank:]], axis=-1)
-        lat = jnp.concatenate([state["lat"], lat], axis=1)  # [B, L+T, .]
-        seg_all = jnp.concatenate([jnp.zeros((b, n), seg.dtype), seg], axis=1)
-        valid = jnp.concatenate(
-            [state["valid"], jnp.ones((b, t), jnp.float32)], axis=1)
+        win = window_open(state, {"lat": lat}, seg, w)
+        lat = win.held["lat"]  # [B, S, rank + rope]
         with jax.named_scope(device_scopes.MLA_PROJ):
             kv = _Linear(h * (kc.nope + kc.v_dim), cd, name="kv_b")(
-                lat[..., :rank]).reshape(b, n + t, h, kc.nope + kc.v_dim)
+                lat[..., :rank]).reshape(
+                    b, lat.shape[1], h, kc.nope + kc.v_dim)
 
         def rope(u, pos):
             if not kc.rope_theta:
@@ -352,11 +455,9 @@ class _MLA(nn.Module):
             scores = (_mm("bthd,bshd->bhts", q[..., : kc.nope],
                           kv[..., : kc.nope], cd)
                       + _mm("bthr,bsr->bhts",
-                            rope(q[..., kc.nope:], n + jnp.arange(t)),
-                            rope(lat[..., rank:], jnp.arange(n + t)), cd))
-            pos_q, pos_k = n + jnp.arange(t)[:, None], jnp.arange(n + t)[None]
-            mask = ((pos_k <= pos_q) & (pos_k > pos_q - w))[None] & (
-                valid[:, None, :] > 0) & (seg_all[:, None, :] == seg[:, :, None])
+                            rope(q[..., kc.nope:], win.pos_q),
+                            rope(lat[..., rank:], win.pos_k), cd))
+            mask = window_mask(win, seg, w)
             scores = jnp.where(
                 mask[:, None], scores / math.sqrt(kc.nope + kc.rope), NEG)
             o = _mm("bhts,bshd->bthd", jax.nn.softmax(scores, axis=-1),
@@ -365,9 +466,8 @@ class _MLA(nn.Module):
             y = _Linear(kc.hidden, cd, name="o_proj")(
                 o.reshape(b, t, h * kc.v_dim))
         self.sow(STATS, "mla_live_key_share", jnp.mean(mask, dtype=jnp.float32))
-        valid = valid * (seg_all == seg[:, -1:])
-        keep = window_keep(n, t, w)
-        return y, {"lat": lat[:, keep:], "valid": valid[:, keep:]}
+        sow_written_share(self, state, t, w)
+        return y, win.state
 
 
 # ---------------------------------------------------------- expert layer
@@ -601,6 +701,18 @@ class _Stack(nn.Module):
         return x, new_state
 
 
+def _uses(kc: CoreConfig):
+    """(state key, mixer) of every use of a layer, pass by pass."""
+    return [(state_key(kc, r, i), mixer) for r in range(1, kc.passes + 1)
+            for i, mixer in enumerate(kc.mixers, 1)]
+
+
+def _reset_of(mixer):
+    """How a mixer's state is reset: its `reset_state(state, keep)` beside its
+    `zero_state`, else the multiply by `keep` of every leaf."""
+    return getattr(mixer, "reset_state", zero_lanes)
+
+
 class StackCore:
     """The core interface (models/cores.py) over `_Stack`: zero start state,
     nothing stored in the ring.  A family's core (`KimiLinearCore`,
@@ -617,15 +729,25 @@ class StackCore:
     def act_stat_names(self):
         """What a fused tick's act step reports of its own, after the learn
         steps' `stat_names` in the segment's outputs: the share of the held
-        experts the lanes' tokens touched, where the stack has expert layers."""
+        experts the lanes' tokens touched, where the stack has expert layers,
+        and the share of its windows' slots a tick writes, where it has
+        attention windows."""
+        names = ()
         if self.kc.first_dense < self.kc.layers:
-            return ("moe_act_touched_expert_share",)
-        return ()
+            names += ("moe_act_touched_expert_share",)
+        if any(_reset_of(m) is window_reset for m in self.kc.mixers):
+            names += ("attn_act_window_written_share",)
+        return names
+
+    def reset_lanes(self, state, keep):
+        """`state` with the lanes where `keep` [B] is 0 back at the start,
+        each (pass, layer) state as its mixer says (`reset_state`): an
+        attention window by its validity, any other by `zero_lanes`."""
+        return {key: _reset_of(mixer)(state[key], keep)
+                for key, mixer in _uses(self.kc)}
 
     def _zero_state(self, kc: CoreConfig, batch: int):
-        return {state_key(kc, r, i): mixer.zero_state(kc, batch)
-                for r in range(1, kc.passes + 1)
-                for i, mixer in enumerate(kc.mixers, 1)}
+        return {key: mixer.zero_state(kc, batch) for key, mixer in _uses(kc)}
 
     def initial_state(self, batch: int):
         """One entry a (pass, layer): every use of a layer has a state of its

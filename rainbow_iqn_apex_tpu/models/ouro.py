@@ -29,18 +29,21 @@ the rotation says `attn_qk_norm` (models/lfm2.py: 32 query heads over 8
 key/value heads); the window then keeps the keys after their norm.  Ouro's
 tree has no such leaf.
 
-Per-lane state, float32, zero = initial (models/cores.zero_lanes), one a
-(pass, layer), as the published model keeps a key/value cache for each: a
-pass's keys are projections of that pass's input.  The window's keys,
-UN-rotated, and values [B, L, Hkv, d] each, and their validity [B, L]: the
-state's shape says how many slots a window holds, `window` for a lane that
-acts and, for a sequence the learner unrolls, none at its start and then the
-steps written, up to `window` (models/mla_moe.py).  The rotation is applied
-at use, by the slot: the key in slot s of `[window; new]` by s, the query of
-new step t by L + t (models/mla_moe.py says why that is the published
-rotation by absolute position).  An episode cut inside a
-sequence is a segment boundary.  One step (`T == 1`, the actor) is one row of
-scores a (pass, layer).
+Per-lane state, float32, zero = initial, one a (pass, layer), as the
+published model keeps a key/value cache for each: a pass's keys are
+projections of that pass's input.  The window's keys, UN-rotated, and values
+[B, L, Hkv, d] each, their validity [B, L] and the ring's head [B].  The
+state's shape says what a window is (models/mla_moe.py): `window` slots are a
+RING, what a lane that acts holds; a tick writes its one step over the oldest
+slot in place and attends over the ring, one row of `window` scores a (pass,
+layer), and a lane is reset by its slots' validity and its head
+(`window_reset` through `StackCore.reset_lanes`), what the slots held left
+where it is.  Fewer slots are a sequence the learner unrolls: none at its
+start, then the steps written, in position order, up to `window`.  The
+rotation is applied at use, by a slot's position among the slots attended
+over, (slot - head) mod `window` on a ring (models/mla_moe.py says why that
+is the published rotation by absolute position).  An episode cut inside a
+sequence is a segment boundary.
 
 The plain reference is tests/reference_ouro_core.py.
 """
@@ -65,8 +68,10 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (
     _mm,
     _RMSNorm,
     rotate_halves,
-    window_keep,
+    sow_written_share,
     window_mask,
+    window_open,
+    window_reset,
 )
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
@@ -78,6 +83,7 @@ class _MHA(nn.Module):
     layer_name = "mha"
 
     zero_state = staticmethod(kv_window_zero_state)
+    reset_state = staticmethod(window_reset)
 
     @nn.compact
     def __call__(self, x, state, seg):
@@ -92,15 +98,14 @@ class _MHA(nn.Module):
             if kc.attn_qk_norm:  # over the head's d, one scale for all heads
                 q = _RMSNorm(kc.eps, name="q_norm")(q)
                 k = _RMSNorm(kc.eps, name="k_norm")(k)
-        n = state["valid"].shape[1]  # the window's slots, 0 to w
-        k = jnp.concatenate([state["k"], k], axis=1)  # [B, L+T, G, d]
-        v = jnp.concatenate([state["v"], v], axis=1)
+        win = window_open(state, {"k": k, "v": v}, seg, w)
+        k, v = win.held["k"], win.held["v"]  # [B, S, G, d]
         with jax.named_scope(device_scopes.MHA_ATTN):
             with jax.named_scope(device_scopes.MHA_ROPE):
-                q = rotate_halves(q, n + jnp.arange(t), kc.rope_theta)
-                k_at = rotate_halves(k, jnp.arange(n + t), kc.rope_theta)
+                q = rotate_halves(q, win.pos_q, kc.rope_theta)
+                k_at = rotate_halves(k, win.pos_k, kc.rope_theta)
             scores = _mm("btgrd,bsgd->bgrts", q, k_at, cd)
-            mask, valid = window_mask(state["valid"], seg, w)
+            mask = window_mask(win, seg, w)
             scores = jnp.where(
                 mask[:, None, None], scores / math.sqrt(d), NEG)
             o = _mm("bgrts,bsgd->btgrd", jax.nn.softmax(scores, axis=-1), v, cd)
@@ -108,9 +113,8 @@ class _MHA(nn.Module):
             y = _Linear(kc.hidden, cd, name="o_proj")(o.reshape(b, t, h * d))
         self.sow(STATS, "attn_live_key_share",
                  jnp.mean(mask, dtype=jnp.float32))
-        keep = window_keep(n, t, w)
-        return y, {"k": k[:, keep:], "v": v[:, keep:],
-                   "valid": valid[:, keep:]}
+        sow_written_share(self, state, t, w)
+        return y, win.state
 
 
 class OuroConfig(CoreConfig):

@@ -35,19 +35,22 @@ heads (query head i reads head i // (heads / kv heads)):
   by position (`rotate_half` over the halves of those dimensions);
   o_i = softmax(q_i K^T / sqrt(d) + mask) V; y = [o_i sigmoid(gate_i)]_i W_o.
 
-Per-lane state, all float32, zero = initial (models/cores.zero_lanes):
+Per-lane state, all float32, zero = initial:
   Gated DeltaNet  S [B, Hv, d_k, d_v] and the convolution's tail
-                  [B, K-1, 2 Hk d_k + Hv d_v]
+                  [B, K-1, 2 Hk d_k + Hv d_v]; reset by models/cores.zero_lanes
   attention       the window's keys (after their norm, UN-rotated) and values
-                  [B, L, Hkv, d] each, and their validity [B, L]: L slots,
-                  `window` for a lane that acts, 0 at a sequence's start
-                  and growing by its steps (models/mla_moe.py)
-The rotation is applied at use, by the slot: the key in slot s of
-`[window; new]` by s, the query of new step t by L + t (models/mla_moe.py says
-why that is the published rotation by absolute position).  An episode cut
-inside a sequence is a segment boundary: steps interact only within a
-segment, in the chunk, the convolution and the attention mask.  One step
-(`T == 1`, the actor) runs the recurrence as written and one row of scores.
+                  [B, L, Hkv, d] each, their validity [B, L] and the ring's
+                  head [B].  `window` slots are a RING (a lane that acts): a
+                  tick writes one slot in place and the lane is reset by its
+                  slots' validity and head (`window_reset`); fewer are a
+                  sequence's window, 0 at its start and growing by its steps
+                  (models/mla_moe.py says which is which: the state's shape)
+The rotation is applied at use, by a slot's position among the slots attended
+over, (slot - head) mod `window` on a ring (models/mla_moe.py says why that is
+the published rotation by absolute position).  An episode cut inside a
+sequence is a segment boundary: steps interact only within a segment, in the
+chunk, the convolution and the attention mask.  One step (`T == 1`, the
+actor) runs the recurrence as written and one row of `window` scores.
 
 The published RMSNorm is zero-centred (`1 + w`, w = 0 at the start); its
 `1 + w` is stored here as `scale` (1 at the start): the same function.
@@ -86,8 +89,10 @@ from rainbow_iqn_apex_tpu.models.mla_moe import (
     _RMSNorm,
     _Taps,
     rotate_halves,
-    window_keep,
+    sow_written_share,
     window_mask,
+    window_open,
+    window_reset,
 )
 from rainbow_iqn_apex_tpu.obs import device_scopes
 
@@ -160,6 +165,7 @@ class _GatedAttention(nn.Module):
     layer_name = "gattn"
 
     zero_state = staticmethod(kv_window_zero_state)
+    reset_state = staticmethod(window_reset)
 
     @nn.compact
     def __call__(self, x, state, seg):
@@ -175,9 +181,8 @@ class _GatedAttention(nn.Module):
             k = _RMSNorm(kc.eps, name="k_norm")(
                 _Linear(g * d, cd, name="k_proj")(x).reshape(b, t, g, d))
             v = _Linear(g * d, cd, name="v_proj")(x).reshape(b, t, g, d)
-        n = state["valid"].shape[1]  # the window's slots, 0 to w
-        k = jnp.concatenate([state["k"], k], axis=1)  # [B, L+T, G, d]
-        v = jnp.concatenate([state["v"], v], axis=1)
+        win = window_open(state, {"k": k, "v": v}, seg, w)
+        k, v = win.held["k"], win.held["v"]  # [B, S, G, d]
 
         def rope(u, pos):
             with jax.named_scope(device_scopes.GATTN_ROPE):
@@ -186,9 +191,9 @@ class _GatedAttention(nn.Module):
                      u[..., rot:]], axis=-1)
 
         with jax.named_scope(device_scopes.GATTN_ATTN):
-            scores = _mm("btgrd,bsgd->bgrts", rope(q, n + jnp.arange(t)),
-                         rope(k, jnp.arange(n + t)), cd)
-            mask, valid = window_mask(state["valid"], seg, w)
+            scores = _mm("btgrd,bsgd->bgrts", rope(q, win.pos_q),
+                         rope(k, win.pos_k), cd)
+            mask = window_mask(win, seg, w)
             scores = jnp.where(
                 mask[:, None, None], scores / math.sqrt(d), NEG)
             o = _mm("bgrts,bsgd->btgrd", jax.nn.softmax(scores, axis=-1), v, cd)
@@ -197,9 +202,8 @@ class _GatedAttention(nn.Module):
             y = _Linear(kc.hidden, cd, name="o_proj")(o.reshape(b, t, h * d))
         self.sow(STATS, "gattn_live_key_share",
                  jnp.mean(mask, dtype=jnp.float32))
-        keep = window_keep(n, t, w)
-        return y, {"k": k[:, keep:], "v": v[:, keep:],
-                   "valid": valid[:, keep:]}
+        sow_written_share(self, state, t, w)
+        return y, win.state
 
 
 class Qwen3NextConfig(CoreConfig):

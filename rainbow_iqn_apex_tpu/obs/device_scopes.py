@@ -76,7 +76,7 @@ CORE_LAYER = "core_layer"  # one block: mixer + feed-forward, each behind its
 # pre-norm and, in a family that has them, before a norm of its output
 KDA_SCAN = "kda_scan"  # the chunked delta-rule recurrence of a sequence
 KDA_PREP = "kda_prep"  # inside it: the in-chunk preparation (WY factors)
-MLA_PROJ = "mla_proj"  # q, kv_a with kv_norm, kv_b over [window; new], o
+MLA_PROJ = "mla_proj"  # q, kv_a with kv_norm, kv_b over the slots attended, o
 MLA_ATTN = "mla_attn"  # scores, mask, softmax, values over the latent window
 MLA_ROPE = "mla_rope"  # inside it: the rope dimensions turned by their slot
 MOE_ROUTE = "moe_route"  # router, top-k, the sort by held expert

@@ -27,7 +27,7 @@ import numpy as np
 from rainbow_iqn_apex_tpu.agents.agent import FrameStacker
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.envs import make_vector_env
-from rainbow_iqn_apex_tpu.models.cores import make_core, zero_lanes
+from rainbow_iqn_apex_tpu.models.cores import make_core
 from rainbow_iqn_apex_tpu.obs import RunObs
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     R2D2TrainState,
@@ -155,7 +155,7 @@ class R2D2ApexDriver(QuantPublishMixin):
         self.actor_stack = None  # created lazily at the first act_frames
         # device-side episode-cut mask for the carried state
         self._mask_state = jax.jit(
-            zero_lanes,
+            core.reset_lanes,
             in_shardings=(state_sh, lane_sh),
             out_shardings=state_sh,
         )

@@ -42,7 +42,6 @@ from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.models.cores import (
     make_core,
     state_bytes_per_lane,
-    zero_lanes,
 )
 from rainbow_iqn_apex_tpu.obs import RunObs, device_scopes
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
@@ -174,7 +173,7 @@ def build_fused_r2d2_segment(cfg: Config, game, replay: DeviceSequenceReplay,
                 warm & due, do_learn, no_learn, (ts, ss))
 
         cut_keep = (~(term | trunc)).astype(jnp.uint8)
-        state = zero_lanes(state, cut_keep)  # zero-reset on episode cut
+        state = core.reset_lanes(state, cut_keep)  # episode cut
         acted = tuple(act_stats.get(n, jnp.float32(jnp.nan))
                       for n in core.act_stat_names)
         return (ts, ss, env_s, ep, stack, nframe, cut_keep, state,
@@ -501,7 +500,7 @@ def _train_anakin_r2d2_hostfed(cfg: Config,
         if prev is not None:
             ss = replay.append(ss, *prev)
         stack = shift_stack(stack, frame, keep)
-        lstm = zero_lanes(lstm, keep)
+        lstm = core.reset_lanes(lstm, keep)
         pre = core.to_stored(lstm)
         a, _q, lstm = act_fn(params, stack, lstm, key)
         return a, stack, ss, lstm, pre

@@ -19,7 +19,7 @@ import numpy as np
 from rainbow_iqn_apex_tpu.agents.agent import FrameStacker
 from rainbow_iqn_apex_tpu.config import Config
 from rainbow_iqn_apex_tpu.envs import make_env, make_vector_env
-from rainbow_iqn_apex_tpu.models.cores import make_core, zero_lanes
+from rainbow_iqn_apex_tpu.models.cores import make_core
 from rainbow_iqn_apex_tpu.obs import RunObs
 from rainbow_iqn_apex_tpu.ops.r2d2 import (
     as_actor_input,
@@ -87,9 +87,10 @@ class R2D2Agent:
         return int(self.state.step)
 
 
-def _mask_reset(lstm_state, terminals: np.ndarray):
-    """Zero the (c, h) rows of lanes whose episode just ended."""
-    return zero_lanes(
+def _mask_reset(core, lstm_state, terminals: np.ndarray):
+    """The core's state with the lanes whose episode just ended back at the
+    start."""
+    return core.reset_lanes(
         lstm_state, jnp.asarray(1.0 - np.asarray(terminals, np.float32)))
 
 
@@ -185,7 +186,7 @@ def train_r2d2(cfg: Config, max_frames: Optional[int] = None) -> Dict[str, Any]:
             memory.append_batch(
                 obs, actions, rewards, terminals, state_c, state_h, truncations=truncs
             )
-            lstm_state = _mask_reset(lstm_state, cuts)
+            lstm_state = _mask_reset(agent.core, lstm_state, cuts)
             stacker.reset_lanes(cuts)
             obs = new_obs
             frames += lanes

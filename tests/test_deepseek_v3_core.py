@@ -18,6 +18,7 @@ from rainbow_iqn_apex_tpu.models import mla_moe
 from rainbow_iqn_apex_tpu.models.cores import CORE_STATS, reduce_stats
 
 import reference_deepseek_v3_core as ref
+from ring_windows import aged
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = os.path.join(HERE, "fixtures", "deepseek_v3_core_tiny.json")
@@ -135,7 +136,8 @@ def test_act_ticks_over_a_window_that_rolls_twice_match_absolute_positions():
     close(ticks, ref.core_forward(params, cc, x, resets, window=window))
     seq, seq_state = stack.apply({"params": params}, x, state, resets)
     close(ticks, seq)
-    for a, c in zip(jax.tree.leaves(st), jax.tree.leaves(seq_state)):
+    for a, c in zip(jax.tree.leaves(aged(st)),
+                    jax.tree.leaves(aged(seq_state))):
         close(a, c)
     # the window matters here: the unwindowed pass differs
     assert float(jnp.abs(
@@ -234,7 +236,7 @@ def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
 @pytest.mark.parametrize("steps,filled,lane,share", [
     (40, 0, False, 820 / (40 * 40)),  # the burn-in from a sequence's start
     (80, 40, False, (80 * 40 + 3240) / (80 * 120)),  # the trained slice after
-    (1, 120, True, 120 / 121),  # a warmed actor's tick over its full window
+    (1, 120, True, 1.0),  # a warmed actor's tick: written first, its ring whole
 ])
 def test_live_key_share_of_the_learn_steps_two_passes(
         steps, filled, lane, share):
@@ -309,6 +311,8 @@ def test_the_kimi_cores_parameter_paths_and_outputs_are_unchanged():
     assert 0 < stats.pop("moe_row_fill_share") <= 1  # by `_MoE`, not listed
     # by `_MoE` where it walks the held experts (a few tokens), not listed
     assert 0 < stats.pop("moe_act_touched_expert_share") <= 1
+    # by `_MLA` on a ring (PR 45): 20 steps from `initial_state` fill 20 of 32
+    assert float(stats.pop("attn_act_window_written_share")) == 20 / 32
     assert stats == pytest.approx(pinned["tiny_stats"])
 
 

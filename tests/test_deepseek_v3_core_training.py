@@ -23,6 +23,8 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import (
     init_r2d2_state,
 )
 
+from ring_windows import aged
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = os.path.join(HERE, "fixtures", "deepseek_v3_core_tiny.json")
 PUBLISHED = "configs/cores/kanana_2_30b_a3b.json"
@@ -58,8 +60,9 @@ def test_the_core_comes_from_the_files_model_type(tmp_path):
     assert core.stored_width == 0 and core.kc.hidden == 32 and core.kc.in_proj
     assert core.kc.rope_theta == 1000.0
     assert [m.layer_name for m in core.kc.mixers] == ["mla"] * 5
-    # five windows of 12 latents (16 + 8) and their validity, float32
-    assert state_bytes_per_lane(core) == 5 * 4 * 12 * (24 + 1)
+    # five windows of 12 latents (16 + 8), their validity and the ring's
+    # head, float32
+    assert state_bytes_per_lane(core) == 5 * 4 * (12 * (24 + 1) + 1)
     published = make_core(cfg.replace(core_config=PUBLISHED)).kc
     assert (published.hidden, published.layers, published.mla_heads,
             published.nope, published.rope, published.v_dim,
@@ -70,7 +73,7 @@ def test_the_core_comes_from_the_files_model_type(tmp_path):
     assert (published.rope_theta, published.route_scale,
             published.window) == (1e6, 2.448, 120)
     assert state_bytes_per_lane(make_core(cfg.replace(
-        core_config=PUBLISHED))) == 5 * 4 * 120 * 577  # 1.38 MB a lane
+        core_config=PUBLISHED))) == 5 * 4 * (120 * 577 + 1)  # 1.38 MB a lane
     bad = tmp_path / "other.json"
     bad.write_text(json.dumps({"model_type": "llama"}))
     with pytest.raises(ValueError, match="no core for model_type 'llama'"):
@@ -166,11 +169,15 @@ def test_fused_segment_trains_with_the_core(tmp_path):
         make_core(cfg))
 
 
-def test_act_step_carries_the_windows_and_a_cut_empties_them(tmp_path):
+@pytest.mark.parametrize("how", ["zero_lanes", "reset_lanes"])
+def test_act_step_carries_the_windows_and_a_cut_empties_them(tmp_path, how):
     from rainbow_iqn_apex_tpu.models.cores import zero_lanes
 
     cfg = _cfg(tmp_path)
     core = make_core(cfg)
+    # the multiply of every leaf, and the core's own reset (a window by its
+    # validity, what it held left in its slots): the same lane afterwards
+    cut = zero_lanes if how == "zero_lanes" else core.reset_lanes
     ts = init_r2d2_state(cfg, 3, jax.random.PRNGKey(1), (80, 80))
     act = jax.jit(build_r2d2_act_step(cfg, 3, use_noise=False))
     obs = jax.random.bits(jax.random.PRNGKey(2), (2, 80, 80, 2), jnp.uint8)
@@ -180,9 +187,9 @@ def test_act_step_carries_the_windows_and_a_cut_empties_them(tmp_path):
     assert np.abs(np.asarray(q1 - q0)).max() > 0  # the window matters
     # the rope keys are kept un-rotated: a step's latent does not depend on
     # when it was written
-    lat = np.asarray(state["layer_1"]["lat"])
+    lat = np.asarray(aged(state)["layer_1"]["lat"])
     np.testing.assert_allclose(lat[:, -1], lat[:, -2], rtol=1e-6, atol=1e-7)
-    state = zero_lanes(state, jnp.asarray([0, 1], jnp.uint8))
+    state = cut(state, jnp.asarray([0, 1], jnp.uint8))
     _, q2, _ = act(ts.params, obs, state, jax.random.PRNGKey(3))
     np.testing.assert_allclose(np.asarray(q2[0]), np.asarray(q0[0]),
                                rtol=1e-5, atol=1e-6)

@@ -58,11 +58,12 @@ def test_the_core_comes_from_the_config(tmp_path):
     assert core.stored_width == 0 and core.kc.hidden == 2304
     assert make_core(cfg.replace(core_config="")) == LSTMCore(cfg.lstm_size)
     # 4 KDA layers of S [2, 8, 8] + tails [3, 48], one MLA window [12, 20+1]
-    assert state_bytes_per_lane(core) == 4 * (4 * (128 + 144)) + 4 * 12 * 21
+    # and the ring's head
+    assert state_bytes_per_lane(core) == 4 * (4 * (128 + 144)) + 4 * (12 * 21 + 1)
     published = make_core(cfg.replace(
         core_config="configs/cores/kimi_linear_48b_a3b.json"))
     kda = 32 * 128 * 128 + 3 * 3 * 4096
-    assert state_bytes_per_lane(published) == 4 * (4 * kda + 120 * 577)
+    assert state_bytes_per_lane(published) == 4 * (4 * kda + 120 * 577 + 1)
 
 
 def test_learn_step_loss_and_gradient_match_the_reference(tmp_path):
@@ -128,11 +129,15 @@ def test_fused_segment_trains_with_the_core(tmp_path):
         make_core(cfg))
 
 
-def test_act_step_carries_the_state_and_a_cut_resets_it(tmp_path):
+@pytest.mark.parametrize("how", ["zero_lanes", "reset_lanes"])
+def test_act_step_carries_the_state_and_a_cut_resets_it(tmp_path, how):
     from rainbow_iqn_apex_tpu.models.cores import zero_lanes
 
     cfg = _cfg(tmp_path)
     core = make_core(cfg)
+    # the multiply of every leaf, and the core's own reset (a window by its
+    # validity, what it held left in its slots): the same lane afterwards
+    cut = zero_lanes if how == "zero_lanes" else core.reset_lanes
     ts = init_r2d2_state(cfg, 3, jax.random.PRNGKey(1), (80, 80))
     act = jax.jit(build_r2d2_act_step(cfg, 3, use_noise=False))
     obs = jax.random.bits(jax.random.PRNGKey(2), (2, 80, 80, 2), jnp.uint8)
@@ -140,7 +145,7 @@ def test_act_step_carries_the_state_and_a_cut_resets_it(tmp_path):
     _, q0, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
     _, q1, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
     assert np.abs(np.asarray(q1 - q0)).max() > 0  # the state matters
-    state = zero_lanes(state, jnp.asarray([0, 1], jnp.uint8))
+    state = cut(state, jnp.asarray([0, 1], jnp.uint8))
     _, q2, _ = act(ts.params, obs, state, jax.random.PRNGKey(3))
     np.testing.assert_allclose(np.asarray(q2[0]), np.asarray(q0[0]),
                                rtol=1e-5, atol=1e-6)

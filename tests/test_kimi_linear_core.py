@@ -15,6 +15,7 @@ from rainbow_iqn_apex_tpu.models import kimi_linear as kl
 from rainbow_iqn_apex_tpu.models.cores import CORE_STATS, reduce_stats
 
 import reference_kimi_linear_core as ref
+from ring_windows import aged
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = os.path.join(HERE, "fixtures", "kimi_core_tiny.json")
@@ -175,7 +176,8 @@ def test_act_ticks_one_by_one_match_the_sequence_pass():
         y, st = step(st, x[:, t:t + 1], resets[:, t:t + 1])
         ys.append(y)
     close(jnp.concatenate(ys, axis=1), seq)
-    for a, c in zip(jax.tree.leaves(st), jax.tree.leaves(seq_state)):
+    for a, c in zip(jax.tree.leaves(aged(st)),
+                    jax.tree.leaves(aged(seq_state))):
         close(a, c)
 
 

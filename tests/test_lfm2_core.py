@@ -26,6 +26,7 @@ from rainbow_iqn_apex_tpu.models.cores import (
 from rainbow_iqn_apex_tpu.obs import device_scopes as ds
 
 import reference_lfm2_core as ref
+from ring_windows import aged
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -136,7 +137,7 @@ def test_the_stack_is_the_cuts_five_layers_in_their_order():
                     "down": (2, 16, 32)}}
     # the state: one K/V window and four 2-step tails, every leaf led by lanes
     assert sorted(state) == [f"layer_{i}" for i in range(1, 6)]
-    assert sorted(state["layer_2"]) == ["k", "v", "valid"]
+    assert sorted(state["layer_2"]) == ["head", "k", "v", "valid"]
     assert state["layer_2"]["k"].shape == (3, 32, 2, 8)
     for i in (1, 3, 4, 5):
         assert jax.tree.map(jnp.shape, state[f"layer_{i}"]) == {
@@ -215,7 +216,8 @@ def test_act_ticks_match_the_sequence_pass_and_absolute_positions(
     close(ticks, plain(params, x, resets, window=window if rolled else None))
     seq, seq_state = run(params, x, state, resets)
     close(ticks, seq)
-    for a, c in zip(jax.tree.leaves(st), jax.tree.leaves(seq_state)):
+    for a, c in zip(jax.tree.leaves(aged(st)),
+                    jax.tree.leaves(aged(seq_state))):
         close(a, c)
     if rolled:  # the window matters there: another window's pass differs
         assert float(jnp.abs(ticks - plain(
@@ -438,7 +440,7 @@ def test_eight_key_value_heads_under_thirty_two_against_the_reference():
     _, new = mixer.apply({"params": p}, x, state, seg)
     k = ref.rms_norm(ref.plain_dot(x, p["k_proj"]["kernel"]).reshape(
         2, 10, 8, 4), p["k_norm"]["scale"], cc["norm_eps"])
-    close(new["k"][:, -10:], k)
+    close(aged({"mha": new})["mha"]["k"][:, -10:], k)
 
 
 def test_zero_lanes_returns_a_lane_to_the_initial_state():
@@ -492,7 +494,7 @@ def test_row_fill_share_by_the_buffer_the_switch_took(
 @pytest.mark.parametrize("steps,filled,lane,share", [
     (40, 0, False, 820 / (40 * 40)),  # the burn-in from a sequence's start
     (80, 40, False, (80 * 40 + 3240) / (80 * 120)),  # the trained slice after
-    (1, 120, True, 120 / 121),  # a warmed actor's tick over its full window
+    (1, 120, True, 1.0),  # a warmed actor's tick: written first, its ring whole
 ])
 def test_live_key_share_and_the_expert_counters_of_the_learn_steps_passes(
         steps, filled, lane, share):
@@ -541,9 +543,9 @@ def test_the_published_file_reads_the_published_sizes():
             kc.experts_here, kc.first_expert, kc.route, kc.route_scale) == (
                 32, 4, 1792, 0, 8, 0, "sigmoid", 1)
     # four 2-step tails of 2,048 and one window of 120 keys and values
-    # [8, 64] with its validity, float32: 0.56 MB a lane
+    # [8, 64] with its validity and the ring's head, float32: 0.56 MB a lane
     assert state_bytes_per_lane(lfm2.Lfm2Core(kc)) == 4 * (
-        4 * 2 * 2048 + 2 * 120 * 8 * 64 + 120) == 557_536
+        4 * 2 * 2048 + 2 * 120 * 8 * 64 + 120 + 1) == 557_540
     # the whole published model from its first layer: two dense layers lead
     whole = lfm2.Lfm2Config.from_dict(
         {**cc, "first_layer_here": 0, "layers_here": 24})

@@ -24,6 +24,8 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import (
     init_r2d2_state,
 )
 
+from ring_windows import aged
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = os.path.join(HERE, "fixtures", "lfm2_core_tiny.json")
 PUBLISHED = "configs/cores/lfm2_8b_a1b.json"
@@ -60,11 +62,12 @@ def test_the_core_comes_from_the_files_model_type(tmp_path):
     assert [m.layer_name for m in core.kc.mixers] == [
         "sconv", "mha", "sconv", "sconv", "sconv"]
     # four 2-step tails of 32, one window of 12 keys and values [2, 8] and
-    # its validity, float32
-    assert state_bytes_per_lane(core) == 4 * (4 * 2 * 32 + 12 * (2 * 2 * 8 + 1))
+    # its validity and the ring's head, float32
+    assert state_bytes_per_lane(core) == 4 * (
+        4 * 2 * 32 + 12 * (2 * 2 * 8 + 1) + 1)
     published = make_core(cfg.replace(core_config=PUBLISHED))
     assert isinstance(published, Lfm2Core)
-    assert state_bytes_per_lane(published) == 557_536  # 0.56 MB a lane
+    assert state_bytes_per_lane(published) == 557_540  # 0.56 MB a lane
 
 
 def test_the_published_cut_is_483_million_parameters(tmp_path):
@@ -179,11 +182,15 @@ def test_fused_segment_trains_with_the_core(tmp_path):
         make_core(cfg))
 
 
-def test_act_step_carries_the_state_and_a_cut_empties_it(tmp_path):
+@pytest.mark.parametrize("how", ["zero_lanes", "reset_lanes"])
+def test_act_step_carries_the_state_and_a_cut_empties_it(tmp_path, how):
     from rainbow_iqn_apex_tpu.models.cores import zero_lanes
 
     cfg = _cfg(tmp_path)
     core = make_core(cfg)
+    # the multiply of every leaf, and the core's own reset (a window by its
+    # validity, what it held left in its slots): the same lane afterwards
+    cut = zero_lanes if how == "zero_lanes" else core.reset_lanes
     ts = init_r2d2_state(cfg, 3, jax.random.PRNGKey(1), (80, 80))
     act = jax.jit(build_r2d2_act_step(cfg, 3, use_noise=False))
     obs = jax.random.bits(jax.random.PRNGKey(2), (2, 80, 80, 2), jnp.uint8)
@@ -191,16 +198,16 @@ def test_act_step_carries_the_state_and_a_cut_empties_it(tmp_path):
     _, q0, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
     _, q1, state = act(ts.params, obs, state, jax.random.PRNGKey(3))
     assert np.abs(np.asarray(q1 - q0)).max() > 0  # the memory matters
-    # the window holds the two steps' keys in its last two slots, and every
+    # the window holds the two steps' keys in its two newest slots, and every
     # convolution's tail the two steps' gated inputs
-    keys = np.asarray(state["layer_2"]["k"])
+    keys = np.asarray(aged(state)["layer_2"]["k"])
     assert np.abs(keys[:, -1]).max() > 0 and np.abs(keys[:, -2]).max() > 0
     assert not np.any(keys[:, :-2])
     for i in (1, 3, 4, 5):
         tail = np.asarray(state[f"layer_{i}"]["conv"])
         assert tail.shape == (2, 2, 32)
         assert np.abs(tail[:, 0]).max() > 0 and np.abs(tail[:, 1]).max() > 0
-    state = zero_lanes(state, jnp.asarray([0, 1], jnp.uint8))
+    state = cut(state, jnp.asarray([0, 1], jnp.uint8))
     _, q2, _ = act(ts.params, obs, state, jax.random.PRNGKey(3))
     np.testing.assert_allclose(np.asarray(q2[0]), np.asarray(q0[0]),
                                rtol=1e-5, atol=1e-6)

@@ -4,6 +4,7 @@ into one grouped product): against the plain references' `moe_ffn` at act
 shapes, what it traces to at the published LFM2 sizes, and the counter it
 sows, `moe_act_touched_expert_share`, up to the fused trainer's rows."""
 
+import dataclasses
 import json
 import os
 
@@ -228,7 +229,41 @@ def test_the_fused_trainers_rows_carry_the_ticks_touched_expert_share(
         assert row["moe_act_touched_expert_share"] * 8 * 8 == pytest.approx(
             round(row["moe_act_touched_expert_share"] * 8 * 8))
     core = cores._load("tests/fixtures/lfm2_core_tiny.json", "float32")
-    assert core.act_stat_names == ("moe_act_touched_expert_share",)
+    assert core.act_stat_names == ("moe_act_touched_expert_share",
+                                   "attn_act_window_written_share")
     assert "moe_act_touched_expert_share" not in core.stat_names
     ouro = cores._load("tests/fixtures/ouro_core_tiny.json", "float32")
-    assert ouro.act_stat_names == () and LSTMCore().act_stat_names == ()
+    assert ouro.act_stat_names == ("attn_act_window_written_share",)
+    assert LSTMCore().act_stat_names == ()
+
+
+def test_the_ticks_written_window_share_is_one_slot_of_the_ring(tmp_path):
+    """`attn_act_window_written_share` beside it: a tick writes one of its
+    ring's 12 slots in every attention layer of the tiny Ouro core, so 1 / 12
+    in every row (1.0 would say a window was rewritten whole); a core without
+    attention windows, the LSTM or a stack of delta-rule mixers alone, names
+    no such counter and its ticks sow none."""
+    from rainbow_iqn_apex_tpu.models.cores import LSTMCore
+
+    learn = run_fused_cli(tmp_path, "ouro")
+    assert len(learn) >= 2
+    assert all(row["attn_act_window_written_share"] == pytest.approx(1 / 12)
+               for row in learn)
+    assert "attn_act_window_written_share" not in LSTMCore().act_stat_names
+    qwen3 = cores._load("tests/fixtures/qwen3_next_core_tiny.json", "float32")
+    kc = qwen3.kc
+    delta = dataclasses.replace(qwen3, kc=dataclasses.replace(
+        kc, mixers=tuple(m for m in kc.mixers if m.layer_name == "gdn")))
+    assert delta.act_stat_names == ("moe_act_touched_expert_share",)
+    x = jnp.zeros((2, 1, kc.hidden))
+    stack = mla_moe._Stack(delta.kc, jnp.float32)
+    state, none = delta.initial_state(2), jnp.zeros((2, 1), bool)
+    params = stack.init(jax.random.PRNGKey(0), x, state, none)["params"]
+    sown = stack.apply({"params": params}, x, state, none,
+                       mutable=[CORE_STATS])[1]
+    assert "attn_act_window_written_share" not in reduce_stats(sown)
+    # and its reset is the multiply of every leaf
+    warm, keep = jax.tree.map(jnp.ones_like, state), jnp.asarray([1, 0])
+    for a, b in zip(jax.tree.leaves(delta.reset_lanes(warm, keep)),
+                    jax.tree.leaves(cores.zero_lanes(warm, keep))):
+        np.testing.assert_array_equal(a, b)

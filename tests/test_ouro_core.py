@@ -25,6 +25,7 @@ from rainbow_iqn_apex_tpu.models.cores import (
 )
 
 import reference_ouro_core as ref
+from ring_windows import aged
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -114,7 +115,7 @@ def test_the_stack_is_two_dense_attention_layers_run_three_times():
     # the state has one entry a (pass, layer), every leaf led by the lanes
     assert sorted(state) == [f"pass_{r}_layer_{i}"
                              for r in (1, 2, 3) for i in (1, 2)]
-    assert sorted(state["pass_2_layer_1"]) == ["k", "v", "valid"]
+    assert sorted(state["pass_2_layer_1"]) == ["head", "k", "v", "valid"]
     assert state["pass_3_layer_2"]["k"].shape == (3, 32, 4, 8)
     assert all(leaf.shape[0] == 3 for leaf in jax.tree.leaves(state))
     assert core.stat_names == ("attn_live_key_share", "loop_passes")
@@ -224,7 +225,8 @@ def test_act_ticks_match_the_sequence_pass_and_absolute_positions(
     close(ticks, plain(params, x, resets, window=window if rolled else None))
     seq, seq_state = run(params, x, state, resets)
     close(ticks, seq)
-    for a, c in zip(jax.tree.leaves(st), jax.tree.leaves(seq_state)):
+    for a, c in zip(jax.tree.leaves(aged(st)),
+                    jax.tree.leaves(aged(seq_state))):
         close(a, c)
     if rolled:  # the window matters there: another window's pass differs
         assert float(jnp.abs(ticks - plain(
@@ -290,7 +292,7 @@ def test_whole_head_rotation_against_the_published_form(shift):
 @pytest.mark.parametrize("steps,filled,lane,share", [
     (40, 0, False, 820 / (40 * 40)),  # the burn-in from a sequence's start
     (80, 40, False, (80 * 40 + 3240) / (80 * 120)),  # the trained slice after
-    (1, 120, True, 120 / 121),  # a warmed actor's tick over its full window
+    (1, 120, True, 1.0),  # a warmed actor's tick: written first, its ring whole
 ])
 def test_live_key_share_and_loop_passes_of_the_learn_steps_two_passes(
         steps, filled, lane, share):
@@ -326,10 +328,10 @@ def test_the_published_file_reads_the_published_sizes():
             kc.eps, kc.in_proj) == (2048, 4, True, 4, 5632, 1e-6, True)
     assert (kc.attn_heads, kc.attn_kv_heads, kc.attn_head_dim, kc.window,
             kc.rope_theta) == (16, 16, 128, 120, 1e6)
-    # 16 (pass, layer) windows of 120 keys and values [16, 128] and their
-    # validity, float32: 31.5 MB a lane
+    # 16 (pass, layer) windows of 120 keys and values [16, 128], their
+    # validity and the ring's head, float32: 31.5 MB a lane
     assert state_bytes_per_lane(ouro.OuroCore(kc)) == 16 * (
-        2 * 120 * 16 * 128 + 120) * 4 == 31_464_960
+        2 * 120 * 16 * 128 + 120 + 1) * 4 == 31_465_024
     for key, bad in (("use_sliding_window", True),
                      ("rope_scaling", {"type": "yarn"}),
                      ("early_exit_threshold", 0.5),

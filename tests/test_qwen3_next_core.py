@@ -27,6 +27,7 @@ from rainbow_iqn_apex_tpu.models.cores import (
 )
 
 import reference_qwen3_next_core as ref
+from ring_windows import aged
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -121,7 +122,7 @@ def test_the_stack_is_three_delta_layers_and_one_attention_layer():
     assert sorted(params["layer_4"]) == ["ffn_norm", "gattn", "mix_norm", "moe"]
     assert sorted(state["layer_3"]) == ["S", "conv"]
     assert state["layer_3"]["conv"].shape == (3, 3, 2 * 16 + 32)
-    assert sorted(state["layer_4"]) == ["k", "v", "valid"]
+    assert sorted(state["layer_4"]) == ["head", "k", "v", "valid"]
     assert sorted(params["layer_2"]["moe"]) == [
         "experts", "router", "shared", "shared_gate"]
     assert core.stat_names == (
@@ -203,7 +204,8 @@ def test_act_ticks_match_the_sequence_pass_and_absolute_positions(
     close(ticks, want)
     seq, seq_state = run(params, x, state, resets)
     close(ticks, seq)
-    for a, c in zip(jax.tree.leaves(st), jax.tree.leaves(seq_state)):
+    for a, c in zip(jax.tree.leaves(aged(st)),
+                    jax.tree.leaves(aged(seq_state))):
         close(a, c)
     if rolled:  # the window matters there: another window's pass differs
         assert float(jnp.abs(ticks - plain(
@@ -371,7 +373,7 @@ def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
 @pytest.mark.parametrize("steps,filled,lane,share", [
     (40, 0, False, 820 / (40 * 40)),  # the burn-in from a sequence's start
     (80, 40, False, (80 * 40 + 3240) / (80 * 120)),  # the trained slice after
-    (1, 120, True, 120 / 121),  # a warmed actor's tick over its full window
+    (1, 120, True, 1.0),  # a warmed actor's tick: written first, its ring whole
 ])
 def test_live_key_share_of_the_learn_steps_two_passes(
         steps, filled, lane, share):
@@ -415,9 +417,10 @@ def test_the_published_file_reads_the_published_sizes():
     assert (kc.experts, kc.experts_here, kc.first_expert, kc.top_k,
             kc.expert_width, kc.shared_width, kc.eps) == (
         512, 32, 0, 10, 512, 512, 1e-6)
-    # 3 x (S 32x128x128 + a tail of 3 x 8,192) + 2 x 120x2x256 + 120, float32
+    # 3 x (S 32x128x128 + a tail of 3 x 8,192) + 2 x 120x2x256 + 120 and
+    # the ring's head, float32
     assert state_bytes_per_lane(qn.Qwen3NextCore(kc)) == (
-        3 * 2_195_456 + 492_000) == 7_078_368
+        3 * 2_195_456 + 492_004) == 7_078_372
     for key, bad in (("mlp_only_layers", [2]), ("decoder_sparse_step", 2),
                      ("use_sliding_window", True), ("norm_topk_prob", False),
                      ("rope_scaling", {"type": "yarn"})):

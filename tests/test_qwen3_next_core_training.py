@@ -23,6 +23,8 @@ from rainbow_iqn_apex_tpu.ops.r2d2 import (
     init_r2d2_state,
 )
 
+from ring_windows import aged
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = os.path.join(HERE, "fixtures", "qwen3_next_core_tiny.json")
 PUBLISHED = "configs/cores/qwen3_next_80b_a3b.json"
@@ -58,12 +60,12 @@ def test_the_core_comes_from_the_files_model_type(tmp_path):
     assert core.stored_width == 0 and core.kc.hidden == 32 and core.kc.in_proj
     assert [m.layer_name for m in core.kc.mixers] == ["gdn"] * 3 + ["gattn"]
     # three states S [4, 8, 8] with tails [3, 2x16 + 32], one window of 12
-    # keys and values [2, 8] and its validity, float32
+    # keys and values [2, 8], its validity and the ring's head, float32
     assert state_bytes_per_lane(core) == 4 * (
-        3 * (4 * 8 * 8 + 3 * 64) + 12 * (2 * 2 * 8 + 1))
+        3 * (4 * 8 * 8 + 3 * 64) + 12 * (2 * 2 * 8 + 1) + 1)
     published = make_core(cfg.replace(core_config=PUBLISHED))
     assert isinstance(published, Qwen3NextCore)
-    assert state_bytes_per_lane(published) == 7_078_368  # 7.08 MB a lane
+    assert state_bytes_per_lane(published) == 7_078_372  # 7.08 MB a lane
     bad = tmp_path / "other.json"
     bad.write_text(json.dumps({"model_type": "llama"}))
     with pytest.raises(ValueError, match="no core for model_type 'llama'"):
@@ -173,11 +175,15 @@ def test_fused_segment_trains_with_the_core(tmp_path):
         make_core(cfg))
 
 
-def test_act_step_carries_the_state_and_a_cut_empties_it(tmp_path):
+@pytest.mark.parametrize("how", ["zero_lanes", "reset_lanes"])
+def test_act_step_carries_the_state_and_a_cut_empties_it(tmp_path, how):
     from rainbow_iqn_apex_tpu.models.cores import zero_lanes
 
     cfg = _cfg(tmp_path)
     core = make_core(cfg)
+    # the multiply of every leaf, and the core's own reset (a window by its
+    # validity, what it held left in its slots): the same lane afterwards
+    cut = zero_lanes if how == "zero_lanes" else core.reset_lanes
     ts = init_r2d2_state(cfg, 3, jax.random.PRNGKey(1), (80, 80))
     act = jax.jit(build_r2d2_act_step(cfg, 3, use_noise=False))
     obs = jax.random.bits(jax.random.PRNGKey(2), (2, 80, 80, 2), jnp.uint8)
@@ -187,11 +193,11 @@ def test_act_step_carries_the_state_and_a_cut_empties_it(tmp_path):
     assert np.abs(np.asarray(q1 - q0)).max() > 0  # the window matters
     # the keys are kept un-rotated; a key is a function of the residual
     # stream, which three recurrent layers have moved between the two ticks
-    keys = np.asarray(state["layer_4"]["k"])
+    keys = np.asarray(aged(state)["layer_4"]["k"])
     assert np.abs(keys[:, -1]).max() > 0 and np.abs(keys[:, -2]).max() > 0
     assert not np.any(keys[:, :-2])
     assert np.abs(np.asarray(state["layer_1"]["S"])).max() > 0
-    state = zero_lanes(state, jnp.asarray([0, 1], jnp.uint8))
+    state = cut(state, jnp.asarray([0, 1], jnp.uint8))
     _, q2, _ = act(ts.params, obs, state, jax.random.PRNGKey(3))
     np.testing.assert_allclose(np.asarray(q2[0]), np.asarray(q0[0]),
                                rtol=1e-5, atol=1e-6)
