@@ -1,119 +1,30 @@
 """The DeepSeek-V3-family core (models/deepseek_v3.py over models/mla_moe.py)
 against its plain float32 reference (tests/reference_deepseek_v3_core.py), at
-tiny widths, float32 compute, seeded weights; and the Kimi-Linear core, which
-runs the same blocks, held to what it computed before they moved."""
+tiny widths, float32 compute, seeded weights: what is this family's own (the
+cases every family shares are tests/test_core_reference.py's); and the
+Kimi-Linear core, which runs the same blocks, held to what it computed before
+they moved."""
 
-import dataclasses
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from rainbow_iqn_apex_tpu.models import deepseek_v3 as ds3
-from rainbow_iqn_apex_tpu.models import kimi_linear as kl
 from rainbow_iqn_apex_tpu.models import mla_moe
-from rainbow_iqn_apex_tpu.models.cores import CORE_STATS, reduce_stats
+from rainbow_iqn_apex_tpu.models.cores import reduce_stats
 
+import core_families as cf
 import reference_deepseek_v3_core as ref
+from core_families import close
 from ring_windows import aged
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-TINY = os.path.join(HERE, "fixtures", "deepseek_v3_core_tiny.json")
-FEATURES = 24  # what the trunk would feed; the input projection takes any
-
-
-def tiny_cc(window=32, **over):
-    """The reference attends over the whole sequence, so the window is as
-    long as the sequences compared with it unless a test says otherwise."""
-    with open(TINY) as f:
-        cc = json.load(f)
-    cc["assumed"]["mla_window"] = window
-    cc.update(over)
-    return cc
-
-
-def make(cc, batch=3, steps=20, seed=0, reset_at=((0, 5), (1, 9), (1, 10))):
-    """(core, stack, params, x, resets, zero state) with every leaf random,
-    the norms' scales and the router's selection bias included."""
-    core = ds3.DeepSeekV3Core(ds3.DeepSeekV3Config.from_dict(cc), jnp.float32)
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    x = jax.random.normal(k1, (batch, steps, FEATURES))
-    resets = np.zeros((batch, steps), bool)
-    for b, t in reset_at:
-        if b < batch and t < steps:
-            resets[b, t] = True
-    resets = jnp.asarray(resets)
-    state = core.initial_state(batch)
-    stack = mla_moe._Stack(core.kc, jnp.float32)
-    params = stack.init(k2, x, state, resets)["params"]
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(k3, len(leaves))
-    leaves = [p + 0.1 * jax.random.normal(k, p.shape) if p.ndim == 1 else p
-              for p, k in zip(leaves, keys)]
-    return core, stack, jax.tree.unflatten(tree, leaves), x, resets, state
-
-
-def close(a, b, tol=2e-4):
-    a, b = np.asarray(a), np.asarray(b)
-    scale = max(float(np.abs(b).max()), 1e-6)
-    assert float(np.abs(a - b).max()) <= tol * scale, (
-        float(np.abs(a - b).max()), scale)
-
-
-def grads_close(g1, g2, tol=2e-3):
-    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(g1),
-                            jax.tree.leaves(g2)):
-        if "select_bias" in jax.tree_util.keystr(path):
-            # the bias enters the choice alone: no gradient on either side
-            assert not np.any(np.asarray(a)) and not np.any(np.asarray(c))
-            continue
-        close(a, c, tol)
-
-
-def test_sequence_pass_matches_the_reference_values_and_gradients():
-    cc = tiny_cc()
-    core, stack, params, x, resets, state = make(cc)
-    w = jax.random.normal(jax.random.PRNGKey(4), (*x.shape[:2], core.kc.hidden))
-
-    def prog(p):
-        return stack.apply({"params": p}, x, state, resets)[0]
-
-    def plain(p):
-        return ref.core_forward(p, cc, x, resets)
-
-    assert prog(params).shape == (*x.shape[:2], cc["hidden_size"])
-    close(prog(params), plain(params))
-    grads_close(jax.grad(lambda p: jnp.sum(prog(p) * w))(params),
-                jax.grad(lambda p: jnp.sum(plain(p) * w))(params))
-
-
-def test_burn_in_then_trained_slice_match_one_full_pass():
-    """The learn step's two passes (burn-in, its final state stop-gradiented,
-    then the trained slice from it) against the reference's one pass with its
-    stop-gradient boundary: values, and the gradient of the trained slice.
-    The slice's keys sit in the window at slots that are not their absolute
-    positions; the scores are the same."""
-    cc = tiny_cc()
-    burn, steps = 6, 14
-    core, stack, params, x, resets, state = make(
-        cc, steps=steps, reset_at=((0, 2), (1, 9)))
-    w = jax.random.normal(
-        jax.random.PRNGKey(5), (x.shape[0], steps - burn, core.kc.hidden))
-
-    def prog(p):
-        _, st = stack.apply({"params": p}, x[:, :burn], state, resets[:, :burn])
-        st = jax.lax.stop_gradient(st)
-        return stack.apply({"params": p}, x[:, burn:], st, resets[:, burn:])[0]
-
-    def plain(p):
-        return ref.core_forward(p, cc, x, resets, burn=burn)[:, burn:]
-
-    close(prog(params), plain(params))
-    grads_close(jax.grad(lambda p: jnp.sum(prog(p) * w))(params),
-                jax.grad(lambda p: jnp.sum(plain(p) * w))(params))
+FAMILY = "deepseek_v3"
+FEATURES = cf.FAMILIES[FAMILY].features
+tiny_cc = functools.partial(cf.tiny_cc, FAMILY)
+make = functools.partial(cf.make, FAMILY)
+jitted = functools.partial(cf.jitted, FAMILY)
 
 
 def test_act_ticks_over_a_window_that_rolls_twice_match_absolute_positions():
@@ -127,21 +38,14 @@ def test_act_ticks_over_a_window_that_rolls_twice_match_absolute_positions():
     cc = tiny_cc(window=window)
     core, stack, params, x, resets, state = make(
         cc, batch=2, steps=steps, reset_at=((0, 7), (1, 19), (1, 20)))
-    step = jax.jit(lambda st, xt, rt: stack.apply({"params": params}, xt, st, rt))
-    st, ys = state, []
-    for t in range(steps):
-        y, st = step(st, x[:, t:t + 1], resets[:, t:t + 1])
-        ys.append(y)
-    ticks = jnp.concatenate(ys, axis=1)
-    close(ticks, ref.core_forward(params, cc, x, resets, window=window))
-    seq, seq_state = stack.apply({"params": params}, x, state, resets)
+    run, plain = jitted(cc)
+    ticks, st = cf.ticks_from(run, params, x, resets, state)
+    close(ticks, plain(params, x, resets, window=window))
+    seq, seq_state = run(params, x, state, resets)
     close(ticks, seq)
-    for a, c in zip(jax.tree.leaves(aged(st)),
-                    jax.tree.leaves(aged(seq_state))):
-        close(a, c)
+    cf.states_close(st, seq_state, aged)
     # the window matters here: the unwindowed pass differs
-    assert float(jnp.abs(
-        ticks - ref.core_forward(params, cc, x, resets)).max()) > 1e-3
+    assert float(jnp.abs(ticks - plain(params, x, resets)).max()) > 1e-3
 
 
 @pytest.mark.parametrize("cut", [1, 6, 13, 19])
@@ -152,10 +56,10 @@ def test_a_cut_inside_a_sequence_starts_the_memory_anew(cut):
     cc = tiny_cc()
     core, stack, params, x, _, state = make(cc, batch=2, reset_at=())
     resets = jnp.zeros(x.shape[:2], bool).at[:, cut].set(True)
-    whole = stack.apply({"params": params}, x, state, resets)[0]
-    close(whole, ref.core_forward(params, cc, x, resets))
-    fresh = stack.apply({"params": params}, x[:, cut:], state,
-                        jnp.zeros_like(resets[:, cut:]))[0]
+    run, plain = jitted(cc)
+    whole = run(params, x, state, resets)[0]
+    close(whole, plain(params, x, resets))
+    fresh = run(params, x[:, cut:], state, jnp.zeros_like(resets[:, cut:]))[0]
     close(whole[:, cut:], fresh)
 
 
@@ -196,25 +100,12 @@ def test_the_eight_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     cfg = ds3.DeepSeekV3Config.from_dict({**cc, "experts_here": 128})
     assert (cfg.experts, cfg.top_k) == (128, 6)
     assert cfg.shared_width == 2 * cc["moe_intermediate_size"]
-    p = mla_moe._MoE(cfg, jnp.float32).init(jax.random.PRNGKey(1), x)["params"]
+    p, _ = cf.expert_layer(cfg, x)
     p["router"]["select_bias"] = 0.05 * jax.random.normal(
         jax.random.PRNGKey(2), (128,))
     whole = ref.moe_ffn(p, cc, x, (0, 128), ref.plain_dot)
     shared = ref.swiglu(p["shared"], x, ref.plain_dot)
-    total, held = shared, 0.0
-    for first in range(0, 128, 16):
-        share_cfg = dataclasses.replace(cfg, experts_here=16, first_expert=first)
-        share_p = {**p, "experts": {n: w[first:first + 16]
-                                    for n, w in p["experts"].items()}}
-        y, sown = mla_moe._MoE(share_cfg, jnp.float32).apply(
-            {"params": share_p}, x, mutable=[CORE_STATS])
-        close(y, ref.moe_ffn(share_p, cc, x, (first, 16), ref.plain_dot))
-        total = total + (y - shared)
-        stats = reduce_stats(sown)
-        assert float(stats["moe_tokens_dropped"]) == 0.0
-        held += float(stats["moe_held_assign_share"])
-    close(total, whole)
-    assert abs(held - 1.0) < 1e-6  # every assignment fell on one share
+    cf.shares_add_up(cfg, cc, ref, p, x, 16, whole, shared)
 
 
 def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
@@ -224,10 +115,8 @@ def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
     cc = tiny_cc(n_routed_experts=16, num_experts_per_tok=6)
     cfg = ds3.DeepSeekV3Config.from_dict({**cc, "experts_here": 16})
     x = jax.random.normal(jax.random.PRNGKey(0), (600, cc["hidden_size"]))
-    moe = mla_moe._MoE(cfg, jnp.float32)
-    p = moe.init(jax.random.PRNGKey(1), x)["params"]
-    y, sown = moe.apply({"params": p}, x, mutable=[CORE_STATS])
-    stats = reduce_stats(sown)
+    p, run = cf.expert_layer(cfg, x)
+    y, stats = run(p, x)
     assert float(stats["moe_tokens_dropped"]) == 0.0
     assert float(stats["moe_held_assign_share"]) == 1.0
     close(y, ref.moe_ffn(p, cc, x, (0, 16), ref.plain_dot))
@@ -249,76 +138,9 @@ def test_live_key_share_of_the_learn_steps_two_passes(
     if not lane:  # the learner's passes: from a sequence's zero-slot start
         state = core.from_stored(jnp.zeros((1, 0)), jnp.zeros((1, 0)))
     if filled:
-        _, state = stack.apply(
-            {"params": params}, x[:, :filled], state, none[:, :filled])
-    _, sown = stack.apply({"params": params}, x[:, filled:], state,
-                          none[:, filled:], mutable=[CORE_STATS])
+        _, state = jitted(cc)[0](
+            params, x[:, :filled], state, none[:, :filled])
+    _, sown = cf.jitted_sown(FAMILY, cc)(
+        params, x[:, filled:], state, none[:, filled:])
     got = float(reduce_stats(sown)["mla_live_key_share"])
     assert got == pytest.approx(share, rel=1e-6)
-
-
-def test_the_kimi_cores_parameter_paths_and_outputs_are_unchanged():
-    """The blocks moved to models/mla_moe.py and `_MLA` learned to rotate:
-    the Kimi-Linear core's parameter tree (which benchmarks/weights_core.py
-    walks by name) and state are leaf for leaf what they were at the
-    published sizes, and its outputs, final state, gradient and counters on
-    a fixed seed are what the tree before the move computed."""
-    import test_kimi_linear_core as t
-
-    with open(os.path.join(HERE, "fixtures", "kimi_core_pinned.json")) as f:
-        pinned = json.load(f)
-    with open(os.path.join(os.path.dirname(HERE), "configs", "cores",
-                           "kimi_linear_48b_a3b.json")) as f:
-        published = json.load(f)
-    core = kl.KimiLinearCore(
-        kl.KimiLinearConfig.from_dict(published), jnp.bfloat16)
-    assert core.kc.rope_theta == 0.0 and not core.kc.in_proj
-    state = jax.eval_shape(lambda: core.initial_state(2))
-    shapes = jax.eval_shape(
-        lambda k, x, s, r: kl._Stack(core.kc, jnp.bfloat16).init(
-            k, x, s, r)["params"],
-        jax.random.PRNGKey(0), jax.ShapeDtypeStruct((2, 3, 2304), jnp.float32),
-        state, jax.ShapeDtypeStruct((2, 3), jnp.bool_))
-    by_path = lambda tree: {  # noqa: E731
-        jax.tree_util.keystr(p): list(v.shape)
-        for p, v in jax.tree_util.tree_leaves_with_path(tree)}
-    assert by_path(shapes) == pinned["published_param_shapes"]
-    assert by_path(state) == pinned["published_state_shapes"]
-    assert core.stat_names == (
-        "moe_expert_load_max_over_mean", "moe_held_assign_share",
-        "moe_tokens_dropped", "kda_fused_tile_share")
-
-    _, stack, params, x, resets, state = t.make(t.tiny_cc())
-    (y, new_state), sown = stack.apply(
-        {"params": params}, x, state, resets, mutable=[CORE_STATS])
-    np.testing.assert_allclose(
-        np.asarray(y)[:, ::4, ::8], np.asarray(pinned["tiny_output"]),
-        rtol=1e-5, atol=1e-6)
-    assert float(jnp.abs(y).sum()) == pytest.approx(
-        pinned["tiny_output_abs_sum"], rel=1e-5)
-    sums = lambda tree: {  # noqa: E731
-        jax.tree_util.keystr(p): float(jnp.abs(v).sum())
-        for p, v in jax.tree_util.tree_leaves_with_path(tree)}
-    assert sums(new_state) == pytest.approx(
-        pinned["tiny_state_abs_sums"], rel=1e-5)
-    w = jax.random.normal(jax.random.PRNGKey(4), x.shape)
-    grads = jax.grad(lambda p: jnp.sum(
-        stack.apply({"params": p}, x, state, resets)[0] * w))(params)
-    assert sums(grads) == pytest.approx(
-        pinned["tiny_grad_abs_sums"], rel=1e-4, abs=1e-6)
-    stats = {k: float(v) for k, v in reduce_stats(sown).items()}
-    assert stats.pop("mla_live_key_share") > 0  # sown by `_MLA`, not listed
-    assert 0 < stats.pop("moe_row_fill_share") <= 1  # by `_MoE`, not listed
-    # by `_MoE` where it walks the held experts (a few tokens), not listed
-    assert 0 < stats.pop("moe_act_touched_expert_share") <= 1
-    # by `_MLA` on a ring (PR 45): 20 steps from `initial_state` fill 20 of 32
-    assert float(stats.pop("attn_act_window_written_share")) == 20 / 32
-    assert stats == pytest.approx(pinned["tiny_stats"])
-
-
-def test_the_two_copies_of_the_reference_are_the_same_text():
-    root = os.path.dirname(HERE)
-    with open(os.path.join(HERE, "reference_deepseek_v3_core.py")) as a, open(
-            os.path.join(root, "benchmarks", "references",
-                         "deepseek_v3_core.py")) as b:
-        assert a.read() == b.read()

@@ -348,10 +348,20 @@ def test_device_sampling_off_and_depth0_reproduce_host_path(tmp_path):
     (loss/q_mean bitwise equal at fixed seeds)."""
     from rainbow_iqn_apex_tpu.parallel.apex import train_apex
 
+    # prefetch_depth=0 on both sides, for tests/test_league.py's reason: the
+    # host path's prefetch thread (utils/prefetch.py) samples ahead of the
+    # main thread's appends and priority write-backs, so what a batch sees
+    # goes by thread timing (this case failed in most runs on a loaded box:
+    # a learn row that differed at step 200 and agreed before).  Staleness by
+    # the pipeline's depth is the documented semantics; WHICH depth a batch
+    # saw is not reproducible with the thread.  writeback_depth=2 stays: that
+    # ring retires on the main thread at a fixed depth.
     s_off = train_apex(
-        _apex_cfg(tmp_path, "off", device_sampling=False), max_frames=600)
+        _apex_cfg(tmp_path, "off", device_sampling=False, prefetch_depth=0),
+        max_frames=600)
     s_d0 = train_apex(
-        _apex_cfg(tmp_path, "d0", device_sampling=True, sample_ahead_depth=0),
+        _apex_cfg(tmp_path, "d0", device_sampling=True, sample_ahead_depth=0,
+                  prefetch_depth=0),
         max_frames=600)
     assert s_off["learn_steps"] == s_d0["learn_steps"] > 0
     rows_off = _learn_rows(_apex_cfg(tmp_path, "off"))

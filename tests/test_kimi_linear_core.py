@@ -1,8 +1,9 @@
 """The Kimi-Linear core (models/kimi_linear.py) against its plain float32
 reference (tests/reference_kimi_linear_core.py), at tiny widths, float32
-compute, seeded weights."""
+compute, seeded weights: what is this family's own (the cases every family
+shares are tests/test_core_reference.py's)."""
 
-import dataclasses
+import functools
 import json
 import os
 
@@ -12,52 +13,17 @@ import numpy as np
 import pytest
 
 from rainbow_iqn_apex_tpu.models import kimi_linear as kl
-from rainbow_iqn_apex_tpu.models.cores import CORE_STATS, reduce_stats
+from rainbow_iqn_apex_tpu.models.cores import reduce_stats
 
+import core_families as cf
 import reference_kimi_linear_core as ref
+from core_families import close
 from ring_windows import aged
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-TINY = os.path.join(HERE, "fixtures", "kimi_core_tiny.json")
-
-
-def tiny_cc(window=32, **over):
-    """The reference attends over the whole sequence, so the window is as
-    long as the sequences compared with it unless a test says otherwise."""
-    with open(TINY) as f:
-        cc = json.load(f)
-    cc["hidden_size"] = 32  # no trunk in front of the core here
-    cc["assumed"]["mla_window"] = window
-    cc.update(over)
-    return cc
-
-
-def make(cc, batch=3, steps=20, seed=0, reset_at=((0, 5), (1, 9), (1, 10))):
-    """(core, params, x, resets, zero state) with every leaf random, the
-    router's selection bias and the decay's parameters included."""
-    core = kl.KimiLinearCore(kl.KimiLinearConfig.from_dict(cc), jnp.float32)
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    x = jax.random.normal(k1, (batch, steps, cc["hidden_size"]))
-    resets = np.zeros((batch, steps), bool)
-    for b, t in reset_at:
-        if b < batch and t < steps:
-            resets[b, t] = True
-    resets = jnp.asarray(resets)
-    state = core.initial_state(batch)
-    stack = kl._Stack(core.kc, jnp.float32)
-    params = stack.init(k2, x, state, resets)["params"]
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(k3, len(leaves))
-    leaves = [p + 0.1 * jax.random.normal(k, p.shape) if p.ndim == 1 else p
-              for p, k in zip(leaves, keys)]
-    return core, stack, jax.tree.unflatten(tree, leaves), x, resets, state
-
-
-def close(a, b, tol=2e-4):
-    a, b = np.asarray(a), np.asarray(b)
-    scale = max(float(np.abs(b).max()), 1e-6)
-    assert float(np.abs(a - b).max()) <= tol * scale, (
-        float(np.abs(a - b).max()), scale)
+FAMILY = "kimi_linear"
+tiny_cc = functools.partial(cf.tiny_cc, FAMILY)
+make = functools.partial(cf.make, FAMILY)
+jitted = functools.partial(cf.jitted, FAMILY)
 
 
 def step_recurrence(q, k, v, g, beta, resets, s0):
@@ -98,38 +64,16 @@ def test_chunked_kda_matches_the_step_recurrence(steps, chunk, block, strong):
         return step_recurrence(q, k, v, g, beta, resets, s0)
 
     args = (q, k, v, g, beta, s0)
-    (o1, s1), (o2, s2) = chunked(*args), plain(*args)
+    (o1, s1), (o2, s2) = jax.jit(chunked)(*args), jax.jit(plain)(*args)
     close(o1, o2)
     close(s1, s2)
     w = jax.random.normal(jax.random.PRNGKey(9), o1.shape)
     loss = lambda f: lambda *a: (  # noqa: E731
         jnp.sum(f(*a)[0] * w) + jnp.sum(f(*a)[1] ** 2))
-    g1 = jax.grad(loss(chunked), argnums=range(6))(*args)
-    g2 = jax.grad(loss(plain), argnums=range(6))(*args)
+    g1 = jax.jit(jax.grad(loss(chunked), argnums=range(6)))(*args)
+    g2 = jax.jit(jax.grad(loss(plain), argnums=range(6)))(*args)
     for a, c in zip(g1, g2):
         close(a, c, 1e-3)
-
-
-def test_sequence_pass_matches_the_reference_values_and_gradients():
-    cc = tiny_cc()
-    core, stack, params, x, resets, state = make(cc)
-    w = jax.random.normal(jax.random.PRNGKey(4), x.shape)
-
-    def prog(p):
-        return stack.apply({"params": p}, x, state, resets)[0]
-
-    def plain(p):
-        return ref.core_forward(p, cc, x, resets)
-
-    close(prog(params), plain(params))
-    g1 = jax.grad(lambda p: jnp.sum(prog(p) * w))(params)
-    g2 = jax.grad(lambda p: jnp.sum(plain(p) * w))(params)
-    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(g1),
-                            jax.tree.leaves(g2)):
-        if "select_bias" in jax.tree_util.keystr(path):
-            assert not np.any(np.asarray(a)) and not np.any(np.asarray(c))
-            continue
-        close(a, c, 2e-3)
 
 
 def test_burn_in_then_steps_match_one_full_pass():
@@ -142,23 +86,27 @@ def test_burn_in_then_steps_match_one_full_pass():
     core, stack, params, x, resets, state = make(
         cc, steps=steps, reset_at=((0, 2), (1, 9)))
     w = jax.random.normal(jax.random.PRNGKey(5), x.shape)[:, burn:]
+    run, plain = jitted(cc)  # the step: compiled once, called eight times
 
     def prog(p):
-        _, st = stack.apply({"params": p}, x[:, :burn], state, resets[:, :burn])
+        _, st = run(p, x[:, :burn], state, resets[:, :burn])
         st = jax.lax.stop_gradient(st)
         ys = []
         for t in range(burn, steps):
-            y, st = stack.apply({"params": p}, x[:, t:t + 1], st,
-                                resets[:, t:t + 1])
+            y, st = run(p, x[:, t:t + 1], st, resets[:, t:t + 1])
             ys.append(y)
-        return jnp.concatenate(ys, axis=1)
+        y = jnp.concatenate(ys, axis=1)
+        return jnp.sum(y * w), y
 
-    def plain(p):
-        return ref.core_forward(p, cc, x, resets, burn=burn)[:, burn:]
+    def want(p):
+        y = plain(p, x, resets, burn=burn)[:, burn:]
+        return jnp.sum(y * w), y
 
-    close(prog(params), plain(params))
-    g1 = jax.grad(lambda p: jnp.sum(prog(p) * w))(params)
-    g2 = jax.grad(lambda p: jnp.sum(plain(p) * w))(params)
+    # (not one program of eight inlined steps: the step's derivative compiles
+    # once too, and is called eight times)
+    (_, y), g1 = jax.value_and_grad(prog, has_aux=True)(params)
+    (_, y_ref), g2 = jax.jit(jax.value_and_grad(want, has_aux=True))(params)
+    close(y, y_ref)
     for a, c in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         close(a, c, 2e-3)
 
@@ -169,16 +117,11 @@ def test_act_ticks_one_by_one_match_the_sequence_pass():
     cc = tiny_cc(window=12)
     core, stack, params, x, resets, state = make(
         cc, batch=2, steps=120, reset_at=((0, 30), (1, 77), (1, 78)))
-    seq, seq_state = stack.apply({"params": params}, x, state, resets)
-    step = jax.jit(lambda st, xt, rt: stack.apply({"params": params}, xt, st, rt))
-    st, ys = state, []
-    for t in range(120):
-        y, st = step(st, x[:, t:t + 1], resets[:, t:t + 1])
-        ys.append(y)
-    close(jnp.concatenate(ys, axis=1), seq)
-    for a, c in zip(jax.tree.leaves(aged(st)),
-                    jax.tree.leaves(aged(seq_state))):
-        close(a, c)
+    run, _ = jitted(cc)
+    seq, seq_state = run(params, x, state, resets)
+    ticks, st = cf.ticks_from(run, params, x, resets, state)
+    close(ticks, seq)
+    cf.states_close(st, seq_state, aged)
 
 
 def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
@@ -189,25 +132,12 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     _, _, params, x, _, _ = make(cc)
     x = x.reshape(-1, x.shape[-1])
     cfg = kl.KimiLinearConfig.from_dict({**cc, "experts_here": 16})
-    p = kl._MoE(cfg, jnp.float32).init(jax.random.PRNGKey(1), x)["params"]
+    p, _ = cf.expert_layer(cfg, x)
     p["router"]["select_bias"] = 0.05 * jax.random.normal(
         jax.random.PRNGKey(2), (16,))
     whole = ref.moe_ffn(p, cc, x, (0, 16), ref.plain_dot)
     shared = ref.swiglu(p["shared"], x, ref.plain_dot)
-    total, held = shared, 0.0
-    for first in (0, 4, 8, 12):
-        share_cfg = dataclasses.replace(cfg, experts_here=4, first_expert=first)
-        share_p = {**p, "experts": {n: w[first:first + 4]
-                                    for n, w in p["experts"].items()}}
-        y, sown = kl._MoE(share_cfg, jnp.float32).apply(
-            {"params": share_p}, x, mutable=[CORE_STATS])
-        close(y, ref.moe_ffn(share_p, cc, x, (first, 4), ref.plain_dot))
-        total = total + (y - shared)
-        stats = reduce_stats(sown)
-        assert float(stats["moe_tokens_dropped"]) == 0.0
-        held += float(stats["moe_held_assign_share"])
-    close(total, whole)
-    assert abs(held - 1.0) < 1e-6  # every assignment fell on one share
+    cf.shares_add_up(cfg, cc, ref, p, x, 4, whole, shared)
 
 
 def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
@@ -216,18 +146,58 @@ def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
     cc = tiny_cc(num_experts=4, num_experts_per_token=4)
     cfg = kl.KimiLinearConfig.from_dict({**cc, "experts_here": 4})
     x = jax.random.normal(jax.random.PRNGKey(0), (600, 32))
-    moe = kl._MoE(cfg, jnp.float32)
-    p = moe.init(jax.random.PRNGKey(1), x)["params"]
-    y, sown = moe.apply({"params": p}, x, mutable=[CORE_STATS])
-    stats = reduce_stats(sown)
+    p, run = cf.expert_layer(cfg, x)
+    y, stats = run(p, x)
     assert float(stats["moe_tokens_dropped"]) == 0.0
     assert float(stats["moe_held_assign_share"]) == 1.0
     close(y, ref.moe_ffn(p, cc, x, (0, 4), ref.plain_dot))
 
 
-def test_the_two_copies_of_the_reference_are_the_same_text():
-    root = os.path.dirname(HERE)
-    with open(os.path.join(HERE, "reference_kimi_linear_core.py")) as a, open(
-            os.path.join(root, "benchmarks", "references",
-                         "kimi_linear_core.py")) as b:
-        assert a.read() == b.read()
+def test_the_kimi_cores_parameter_paths_and_outputs_are_unchanged():
+    """The blocks moved to models/mla_moe.py and `_MLA` learned to rotate:
+    the Kimi-Linear core's parameter tree (which benchmarks/weights_core.py
+    walks by name) and state are leaf for leaf what they were at the
+    published sizes, and its outputs, final state, gradient and counters on
+    a fixed seed are what the tree before the move computed."""
+    with open(os.path.join(cf.HERE, "fixtures", "kimi_core_pinned.json")) as f:
+        pinned = json.load(f)
+    with open(cf.FAMILIES[FAMILY].published_path) as f:
+        published = json.load(f)
+    core = kl.KimiLinearCore(
+        kl.KimiLinearConfig.from_dict(published), jnp.bfloat16)
+    assert core.kc.rope_theta == 0.0 and not core.kc.in_proj
+    shapes, state, _ = cf.stack_shapes(
+        core.kc, cf.TRUNK_FEATURES, jnp.bfloat16)
+    assert cf.shapes_by_path(shapes) == pinned["published_param_shapes"]
+    assert cf.shapes_by_path(state) == pinned["published_state_shapes"]
+    assert core.stat_names == (
+        "moe_expert_load_max_over_mean", "moe_held_assign_share",
+        "moe_tokens_dropped", "kda_fused_tile_share")
+
+    cc = tiny_cc()
+    _, stack, params, x, resets, state = make(cc)
+    (y, new_state), sown = cf.jitted_sown(FAMILY, cc)(params, x, state, resets)
+    np.testing.assert_allclose(
+        np.asarray(y)[:, ::4, ::8], np.asarray(pinned["tiny_output"]),
+        rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(y).sum()) == pytest.approx(
+        pinned["tiny_output_abs_sum"], rel=1e-5)
+    sums = lambda tree: {  # noqa: E731
+        jax.tree_util.keystr(p): float(jnp.abs(v).sum())
+        for p, v in jax.tree_util.tree_leaves_with_path(tree)}
+    assert sums(new_state) == pytest.approx(
+        pinned["tiny_state_abs_sums"], rel=1e-5)
+    w = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+    run, _ = jitted(cc)
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(
+        run(p, x, state, resets)[0] * w)))(params)
+    assert sums(grads) == pytest.approx(
+        pinned["tiny_grad_abs_sums"], rel=1e-4, abs=1e-6)
+    stats = {k: float(v) for k, v in reduce_stats(sown).items()}
+    assert stats.pop("mla_live_key_share") > 0  # sown by `_MLA`, not listed
+    assert 0 < stats.pop("moe_row_fill_share") <= 1  # by `_MoE`, not listed
+    # by `_MoE` where it walks the held experts (a few tokens), not listed
+    assert 0 < stats.pop("moe_act_touched_expert_share") <= 1
+    # by `_MLA` on a ring (PR 45): 20 steps from `initial_state` fill 20 of 32
+    assert float(stats.pop("attn_act_window_written_share")) == 20 / 32
+    assert stats == pytest.approx(pinned["tiny_stats"])

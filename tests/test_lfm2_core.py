@@ -2,14 +2,16 @@
 attention) against its plain float32 reference (tests/reference_lfm2_core.py),
 at tiny widths (the cut's five layers in its order, 4 query heads over 2
 key/value heads of 8, 8 experts of which 2 are held and 2 a token), float32
-compute, seeded weights; an expert layer without a shared expert, whose shares
-add up to the uncut layer; and the accepted cores, whose trees the two new
-switches (`shared_width` 0, `attn_qk_norm`) leave as they were."""
+compute, seeded weights: what is this family's own (the cases every family
+shares are tests/test_core_reference.py's); an expert layer without a shared
+expert, whose shares add up to the uncut layer; and the accepted cores, whose
+trees the two new switches (`shared_width` 0, `attn_qk_norm`) leave as they
+were."""
 
 import dataclasses
 import functools
 import json
-import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,90 +23,19 @@ from rainbow_iqn_apex_tpu.models.cores import (
     CORE_STATS,
     reduce_stats,
     state_bytes_per_lane,
-    zero_lanes,
 )
 from rainbow_iqn_apex_tpu.obs import device_scopes as ds
 
+import core_families as cf
 import reference_lfm2_core as ref
+from core_families import close, grads_close
 from ring_windows import aged
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-TINY = os.path.join(HERE, "fixtures", "lfm2_core_tiny.json")
-FEATURES = 24  # what the trunk would feed; the input projection takes any
-
-
-def tiny_cc(window=32, **over):
-    """The reference attends over the whole sequence, so the window is as
-    long as the sequences compared with it unless a test says otherwise."""
-    with open(TINY) as f:
-        cc = json.load(f)
-    cc["assumed"]["attn_window"] = window
-    cc.update(over)
-    return cc
-
-
-@functools.lru_cache(maxsize=None)
-def _built(cc_json, seed):
-    """(core, stack, params) of a configuration, every leaf random (the
-    norms' scales and the routers' selection bias too): one compiled init
-    serves every test of it."""
-    cc = json.loads(cc_json)
-    core = lfm2.Lfm2Core(lfm2.Lfm2Config.from_dict(cc), jnp.float32)
-    k2, k3 = jax.random.split(jax.random.PRNGKey(seed))
-    stack = mla_moe._Stack(core.kc, jnp.float32)
-    params = jax.jit(stack.init)(
-        k2, jnp.zeros((1, 2, FEATURES)), core.initial_state(1),
-        jnp.zeros((1, 2), bool))["params"]
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(k3, len(leaves))
-    leaves = [p + 0.1 * jax.random.normal(k, p.shape) if p.ndim == 1 else p
-              for p, k in zip(leaves, keys)]
-    return core, stack, jax.tree.unflatten(tree, leaves)
-
-
-def make(cc, batch=3, steps=20, seed=0, reset_at=((0, 5), (1, 9), (1, 10))):
-    core, stack, params = _built(json.dumps(cc, sort_keys=True), seed)
-    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (batch, steps, FEATURES))
-    resets = np.zeros((batch, steps), bool)
-    for b, t in reset_at:
-        if b < batch and t < steps:
-            resets[b, t] = True
-    return core, stack, params, x, jnp.asarray(resets), core.initial_state(batch)
-
-
-def jitted(cc, stack):
-    """(program, reference) as compiled functions of (params, x, state,
-    resets) and (params, x, resets, burn=, window=)."""
-    run = jax.jit(lambda p, x, st, r: stack.apply({"params": p}, x, st, r))
-    plain = jax.jit(
-        lambda p, x, r, burn=0, window=None: ref.core_forward(
-            p, cc, x, r, burn=burn, window=window),
-        static_argnames=("burn", "window"))
-    return run, plain
-
-
-def close(a, b, tol=2e-4):
-    """Float32 on both sides, sums in another order (keys in window slots
-    against keys in sequence order, the experts' rows sorted against a dense
-    mask, five blocks deep): 2e-4 of the largest value is some thousand
-    roundings of room."""
-    a, b = np.asarray(a), np.asarray(b)
-    scale = max(float(np.abs(b).max()), 1e-6)
-    assert float(np.abs(a - b).max()) <= tol * scale, (
-        float(np.abs(a - b).max()), scale)
-
-
-def grads_close(g1, g2, tol=2e-3):
-    """A gradient sums over every step and token: ten times the values'."""
-    assert jax.tree.structure(g1) == jax.tree.structure(g2)
-    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(g1),
-                            jax.tree.leaves(g2)):
-        if "select_bias" in jax.tree_util.keystr(path):
-            # the bias enters the choice alone: no gradient on either side
-            assert not np.any(np.asarray(a)) and not np.any(np.asarray(c))
-            continue
-        close(a, c, tol)
+FAMILY = "lfm2_moe"
+FEATURES = cf.FAMILIES[FAMILY].features
+tiny_cc = functools.partial(cf.tiny_cc, FAMILY)
+make = functools.partial(cf.make, FAMILY)
+jitted = functools.partial(cf.jitted, FAMILY)
 
 
 def test_the_stack_is_the_cuts_five_layers_in_their_order():
@@ -145,99 +76,6 @@ def test_the_stack_is_the_cuts_five_layers_in_their_order():
     assert core.stat_names == (
         "moe_expert_load_max_over_mean", "moe_held_assign_share",
         "moe_tokens_dropped", "attn_live_key_share", "moe_row_fill_share")
-
-
-def test_sequence_pass_matches_the_reference_values_and_gradients():
-    cc = tiny_cc()
-    core, stack, params, x, resets, state = make(cc)
-    w = jax.random.normal(jax.random.PRNGKey(4), (*x.shape[:2], core.kc.hidden))
-    run, plain = jitted(cc, stack)
-    prog = jax.jit(jax.value_and_grad(
-        lambda p: jnp.sum(run(p, x, state, resets)[0] * w)))
-    want = jax.jit(jax.value_and_grad(
-        lambda p: jnp.sum(plain(p, x, resets) * w)))
-    y = run(params, x, state, resets)[0]
-    assert y.shape == (*x.shape[:2], cc["hidden_size"])
-    close(y, plain(params, x, resets))
-    grads_close(prog(params)[1], want(params)[1])
-
-
-def test_burn_in_then_trained_slice_match_one_full_pass():
-    """The learn step's two passes (burn-in, its final state stop-gradiented,
-    then the trained slice from it) against the reference's one pass with its
-    stop-gradient boundary: values, and the gradient of the trained slice.
-    The slice's first two steps read the burn-in's last `z` from the tails,
-    its queries the burn-in's keys from the window."""
-    cc = tiny_cc()
-    burn, steps = 6, 14
-    core, stack, params, x, resets, state = make(
-        cc, steps=steps, reset_at=((0, 2), (1, 7)))
-    w = jax.random.normal(
-        jax.random.PRNGKey(5), (x.shape[0], steps - burn, core.kc.hidden))
-    run, plain = jitted(cc, stack)
-
-    def prog(p):
-        _, st = run(p, x[:, :burn], state, resets[:, :burn])
-        st = jax.lax.stop_gradient(st)
-        y = run(p, x[:, burn:], st, resets[:, burn:])[0]
-        return jnp.sum(y * w), y
-
-    def want(p):
-        y = plain(p, x, resets, burn=burn)[:, burn:]
-        return jnp.sum(y * w), y
-
-    (_, y), grads = jax.jit(jax.value_and_grad(prog, has_aux=True))(params)
-    (_, y_ref), grads_ref = jax.jit(jax.value_and_grad(want, has_aux=True))(
-        params)
-    close(y, y_ref)
-    grads_close(grads, grads_ref)
-
-
-@pytest.mark.parametrize("window,steps", [(12, 30), (40, 40)])
-def test_act_ticks_match_the_sequence_pass_and_absolute_positions(
-        window, steps):
-    """Ticks of one step each from the empty state (one row of scores, one
-    3-tap sum a convolution layer, the tails carried tick to tick) against
-    the program's own pass over the sequence and against the reference's
-    absolute positions 0..T-1.  T = 2.5 W: the window rolls over twice, every
-    key is rotated by the slot it sits in when it is used.  T = W: from the
-    empty window that is full causal attention exactly, so the reference is
-    not told of a window."""
-    cc = tiny_cc(window=window)
-    core, stack, params, x, resets, state = make(
-        cc, batch=2, steps=steps, reset_at=((0, 7), (1, 19), (1, 20)))
-    run, plain = jitted(cc, stack)
-    st, ys = state, []
-    for t in range(steps):
-        y, st = run(params, x[:, t:t + 1], st, resets[:, t:t + 1])
-        ys.append(y)
-    ticks = jnp.concatenate(ys, axis=1)
-    rolled = steps > window
-    close(ticks, plain(params, x, resets, window=window if rolled else None))
-    seq, seq_state = run(params, x, state, resets)
-    close(ticks, seq)
-    for a, c in zip(jax.tree.leaves(aged(st)),
-                    jax.tree.leaves(aged(seq_state))):
-        close(a, c)
-    if rolled:  # the window matters there: another window's pass differs
-        assert float(jnp.abs(ticks - plain(
-            params, x, resets, window=2 * window)).max()) > 1e-3
-
-
-def test_a_cut_inside_a_sequence_equals_two_passes():
-    """After a reset before step `cut` the outputs are those of two
-    sequences, one that ends there and one that starts there: nothing of the
-    window and nothing of a convolution's tail crosses it."""
-    cc = tiny_cc()
-    core, stack, params, x, _, state = make(cc, batch=2, reset_at=())
-    run, plain = jitted(cc, stack)
-    none = jnp.zeros(x.shape[:2], bool)
-    for cut in (1, 10, 19):  # one step from either end, and the middle
-        resets = none.at[:, cut].set(True)
-        whole = run(params, x, state, resets)[0]
-        close(whole, plain(params, x, resets))
-        close(whole[:, :cut], run(params, x[:, :cut], state, none[:, :cut])[0])
-        close(whole[:, cut:], run(params, x[:, cut:], state, none[:, cut:])[0])
 
 
 def test_the_convolution_reads_no_gated_input_from_before_a_cut():
@@ -295,61 +133,28 @@ def test_the_four_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     x = jax.random.normal(jax.random.PRNGKey(0), (60, cc["hidden_size"]))
     cfg = lfm2.Lfm2Config.from_dict({**cc, "experts_here": 8})
     assert (cfg.experts, cfg.top_k, cfg.shared_width) == (8, 2, 0)
-    p = mla_moe._MoE(cfg, jnp.float32).init(jax.random.PRNGKey(1), x)["params"]
+    p, _ = cf.expert_layer(cfg, x)
     assert sorted(p) == ["experts", "router"]
     p["router"]["select_bias"] = 0.05 * jax.random.normal(
         jax.random.PRNGKey(2), (8,))
     whole = ref.moe_ffn(p, cc, x, (0, 8), ref.plain_dot)
-    total, held = 0.0, 0.0
-    for first in range(0, 8, 2):
-        share_cfg = dataclasses.replace(cfg, experts_here=2, first_expert=first)
-        share_p = {**p, "experts": {n: w[first:first + 2]
-                                    for n, w in p["experts"].items()}}
-        y, sown = mla_moe._MoE(share_cfg, jnp.float32).apply(
-            {"params": share_p}, x, mutable=[CORE_STATS])
-        close(y, ref.moe_ffn(share_p, cc, x, (first, 2), ref.plain_dot))
-        total = total + y
-        stats = reduce_stats(sown)
-        assert float(stats["moe_tokens_dropped"]) == 0.0
-        held += float(stats["moe_held_assign_share"])
-    close(total, whole)
-    assert abs(held - 1.0) < 1e-6  # every assignment fell on one share
-
-
-def _stack_shapes(kc, width):
-    core = mla_moe.StackCore()
-    state = jax.eval_shape(lambda: core._zero_state(kc, 2))
-    return jax.eval_shape(
-        lambda k, x, s, r: mla_moe._Stack(kc, jnp.float32).init(
-            k, x, s, r)["params"],
-        jax.random.PRNGKey(0), jax.ShapeDtypeStruct((2, 3, width), jnp.float32),
-        state, jax.ShapeDtypeStruct((2, 3), jnp.bool_)), state
-
-
-def _lowered_text(kc, width):
-    shapes, state = _stack_shapes(kc, width)
-    return jax.jit(lambda p, x, st, r: mla_moe._Stack(kc, jnp.float32).apply(
-        {"params": p}, x, st, r)).lower(
-            shapes, jax.ShapeDtypeStruct((2, 3, width), jnp.float32), state,
-            jax.ShapeDtypeStruct((2, 3), jnp.bool_)).as_text(debug_info=True)
+    cf.shares_add_up(cfg, cc, ref, p, x, 2, whole, 0.0)
 
 
 def test_no_shared_expert_is_no_leaf_and_no_op():
     """`shared_width` 0: no `shared` leaf in any expert layer and no op under
     `moe_shared` in the lowered module; the mixer's work wears `sconv_mix`."""
     kc = lfm2.Lfm2Config.from_dict(tiny_cc())
-    shapes, _ = _stack_shapes(kc, FEATURES)
+    shapes, _, _ = cf.stack_shapes(kc, FEATURES)
     paths = [jax.tree_util.keystr(p)
              for p, _ in jax.tree_util.tree_leaves_with_path(shapes)]
     assert not [p for p in paths if "shared" in p]
-    text = _lowered_text(kc, FEATURES)
+    text = cf.lowered_text(kc, FEATURES)
     # (this test's own name is in the text's locations: hence the "/")
     assert f"{ds.MOE_SHARED}/" not in text
     for scope in (ds.MOE_ROUTE, ds.MOE_EXPERTS, ds.SCONV_MIX, ds.MHA_PROJ,
                   ds.MHA_ATTN, ds.MHA_ROPE, ds.DENSE_FFN, ds.CORE_EMBED):
         assert f"{scope}/" in text, scope
-    import re
-
     # inside `core_layer`, all of the mixer: its two products, the gates
     # and the taps' sums
     inside = set(re.findall(
@@ -358,37 +163,30 @@ def test_no_shared_expert_is_no_leaf_and_no_op():
     assert ds.SCONV_MIX in ds.ALL_SCOPES
 
 
-@pytest.mark.parametrize("family,fixture,gate", [
-    ("kimi_linear", "kimi_core_tiny.json", False),
-    ("deepseek_v3", "deepseek_v3_core_tiny.json", False),
-    ("qwen3_next", "qwen3_next_core_tiny.json", True),
-])
-def test_the_accepted_expert_cores_keep_their_shared_expert(
-        family, fixture, gate):
+@pytest.mark.parametrize("family,gate", [
+    ("kimi_linear", False), ("deepseek_v3", False), ("qwen3_next", True)])
+def test_the_accepted_expert_cores_keep_their_shared_expert(family, gate):
     """The three accepted readers give `shared_width` a width: every expert
     layer keeps its `shared` leaves (and Qwen3-Next its gate) and the module
     its `moe_shared` ops; none of them lists the new counter (a listed
     counter is an output of the compiled segment)."""
-    from test_ouro_core import _family_core
-
-    cc, core = _family_core(family, fixture)
+    _, core, width = cf.tiny_core(family)
     assert core.kc.shared_width > 0 and not core.kc.attn_qk_norm
-    width = cc["hidden_size"] if core.kc.in_proj else 2304
-    shapes, _ = _stack_shapes(core.kc, width)
+    shapes, _, _ = cf.stack_shapes(core.kc, width)
     moes = [v["moe"] for v in shapes.values() if "moe" in v]
     assert moes and all(sorted(m) == sorted(
         ["experts", "router", "shared"] + ["shared_gate"] * gate)
         for m in moes)
     assert "moe_row_fill_share" not in core.stat_names
     if family == "deepseek_v3":  # one lowering is enough: `_MoE` is one class
-        assert f"{ds.MOE_SHARED}/" in _lowered_text(core.kc, width)
+        assert f"{ds.MOE_SHARED}/" in cf.lowered_text(core.kc, width)
 
 
 def test_attention_without_the_norms_is_ouros_tree_leaf_for_leaf():
     """`attn_qk_norm` is `CoreConfig`'s default False in Ouro's reader: its
     published tree has the four projections and nothing else, and `_MHA` told
     to norm differs from it by `q_norm` and `k_norm` alone."""
-    with open(os.path.join(ROOT, "configs", "cores", "ouro_2_6b.json")) as f:
+    with open(cf.FAMILIES["ouro"].published_path) as f:
         kc = ouro.OuroConfig.from_dict(json.load(f))
     assert not kc.attn_qk_norm
     x = jax.ShapeDtypeStruct((1, 2, kc.hidden), jnp.float32)
@@ -443,22 +241,6 @@ def test_eight_key_value_heads_under_thirty_two_against_the_reference():
     close(aged({"mha": new})["mha"]["k"][:, -10:], k)
 
 
-def test_zero_lanes_returns_a_lane_to_the_initial_state():
-    cc = tiny_cc(window=12)
-    core, stack, params, x, _, state = make(cc, batch=2, reset_at=())
-    run, _ = jitted(cc, stack)
-    none = jnp.zeros(x.shape[:2], bool)
-    y0, warm = run(params, x, state, none)
-    assert all(float(jnp.abs(leaf[1]).max()) > 0
-               for leaf in jax.tree.leaves(warm))
-    cut = zero_lanes(warm, jnp.asarray([1, 0], jnp.uint8))
-    for a, z in zip(jax.tree.leaves(cut), jax.tree.leaves(state)):
-        np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(z[1]))
-    y1 = run(params, x, cut, none)[0]
-    close(y1[1], y0[1], 1e-6)  # lane 1 starts over
-    assert float(jnp.abs(y1[0] - y0[0]).max()) > 1e-3  # lane 0 remembers
-
-
 @pytest.mark.parametrize("experts,chosen_held,rows", [
     (16, 0, 300),  # few held assignments: the small buffer (0.5 n) is taken
     (8, 1, 1200),  # one held expert a token: over 0.5 n, so the largest
@@ -474,13 +256,11 @@ def test_row_fill_share_by_the_buffer_the_switch_took(
     cfg = lfm2.Lfm2Config.from_dict(cc)
     n, k = 600, cfg.top_k
     x = jax.random.normal(jax.random.PRNGKey(0), (n, cfg.hidden))
-    moe = mla_moe._MoE(cfg, jnp.float32)
-    p = moe.init(jax.random.PRNGKey(1), x)["params"]
+    p, run = cf.expert_layer(cfg, x)
     if chosen_held:
         p["router"]["select_bias"] = jnp.zeros((experts,)).at[
             jnp.asarray([1, 5])].set(2.0)
-    y, sown = moe.apply({"params": p}, x, mutable=[CORE_STATS])
-    stats = reduce_stats(sown)
+    y, stats = run(p, x)
     n_held = float(stats["moe_held_assign_share"]) * n * k
     assert (n_held > 300) == (rows == 1200)
     assert float(stats["moe_row_fill_share"]) == pytest.approx(n_held / rows)
@@ -510,11 +290,10 @@ def test_live_key_share_and_the_expert_counters_of_the_learn_steps_passes(
         assert state["layer_2"]["k"].shape == (1, 0, 2, 8)
         assert state["layer_1"]["conv"].shape == (1, 2, 32)
     if filled:
-        _, state = jitted(cc, stack)[0](
+        _, state = jitted(cc)[0](
             params, x[:, :filled], state, none[:, :filled])
-    _, sown = jax.jit(lambda p, x, st, r: stack.apply(
-        {"params": p}, x, st, r, mutable=[CORE_STATS]))(
-            params, x[:, filled:], state, none[:, filled:])
+    _, sown = cf.jitted_sown(FAMILY, cc)(
+        params, x[:, filled:], state, none[:, filled:])
     assert sorted(n for n in sown[CORE_STATS] if "moe" in sown[CORE_STATS][n]
                   ) == ["layer_2", "layer_3", "layer_4", "layer_5"]
     stats = reduce_stats(sown)
@@ -526,7 +305,7 @@ def test_live_key_share_and_the_expert_counters_of_the_learn_steps_passes(
 
 
 def test_the_published_file_reads_the_published_sizes():
-    with open(os.path.join(ROOT, "configs", "cores", "lfm2_8b_a1b.json")) as f:
+    with open(cf.FAMILIES[FAMILY].published_path) as f:
         cc = json.load(f)
     assert len(cc["layer_types"]) == cc["num_hidden_layers"] == 24
     assert [i for i, k in enumerate(cc["layer_types"])
@@ -559,35 +338,3 @@ def test_the_published_file_reads_the_published_sizes():
                      ("layers_here", 30)):
         with pytest.raises(ValueError, match="not written"):
             lfm2.Lfm2Config.from_dict({**cc, key: bad})
-
-
-def test_a_core_file_imports_its_own_family_alone():
-    """`cores._load` reads `model_type` first: a process that runs the LFM2
-    core imports its attention's home (models/ouro.py) and neither the
-    delta-rule scan nor its kernels (a child process, so that this one's
-    imports do not count)."""
-    import subprocess
-    import sys
-
-    code = (
-        "import sys\n"
-        "from rainbow_iqn_apex_tpu.config import Config\n"
-        "from rainbow_iqn_apex_tpu.models.cores import make_core\n"
-        "make_core(Config(architecture='r2d2', core_config="
-        "'configs/cores/lfm2_8b_a1b.json'))\n"
-        "print(sorted(m.rsplit('.', 1)[1] for m in sys.modules if m.startswith("
-        "'rainbow_iqn_apex_tpu.models.') and m.rsplit('.', 1)[1] in "
-        "('kimi_linear', 'kda_tile', 'deepseek_v3', 'qwen3_next', 'ouro', "
-        "'lfm2')))\n")
-    out = subprocess.run(
-        [sys.executable, "-c", code], cwd=ROOT, capture_output=True, text=True,
-        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert out.stdout.strip().splitlines()[-1] == "['lfm2', 'ouro']"
-
-
-def test_the_two_copies_of_the_reference_are_the_same_text():
-    with open(os.path.join(HERE, "reference_lfm2_core.py")) as a, open(
-            os.path.join(ROOT, "benchmarks", "references",
-                         "lfm2_core.py")) as b:
-        assert a.read() == b.read()

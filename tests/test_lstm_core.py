@@ -10,6 +10,8 @@ too, so the product cannot slip back into the loop unnoticed.
 """
 
 import dataclasses
+import json
+import os
 from typing import Any
 
 import jax
@@ -162,9 +164,12 @@ def _init(net, key=0, frame=(44, 44), B=2, T=3):
 
 def test_r2d2net_keeps_the_parameter_tree_leaf_for_leaf():
     """Path, shape and float32 of every leaf at the published widths and
-    84x84 frames: the list `tests/test_trunk_stem.py` pins, which the
+    84x84 frames: the list `tests/test_trunk_stem.py` pins too, which the
     reference, `benchmarks/weights.py` and the checkpoints read by name."""
-    from test_trunk_stem import PARENT_TREE
+    with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                           "r2d2_parent_tree.json")) as f:
+        parent_tree = [(path, tuple(shape))
+                       for path, shape in json.load(f)["leaves"]]
 
     net = R2D2Net(num_actions=6)
     obs = jax.ShapeDtypeStruct((1, 2, 84, 84, 4), jnp.uint8)
@@ -173,7 +178,7 @@ def test_r2d2net_keeps_the_parameter_tree_leaf_for_leaf():
                             "noise": jax.random.PRNGKey(1)},
                            o, net.initial_state(1)), obs)["params"]
     got = _leaves(shapes)
-    assert sorted((n, v.shape) for n, v in got.items()) == PARENT_TREE
+    assert sorted((n, v.shape) for n, v in got.items()) == parent_tree
     assert all(v.dtype == jnp.float32 for v in got.values())
     assert sorted(n for n in got if n.startswith("lstm/")) == CELL_LEAVES
 

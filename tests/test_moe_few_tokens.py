@@ -16,14 +16,11 @@ import pytest
 from rainbow_iqn_apex_tpu.models import cores, lfm2, mla_moe, qwen3_next
 from rainbow_iqn_apex_tpu.models.cores import CORE_STATS, reduce_stats
 
+import core_families as cf
 import reference_lfm2_core as ref_lfm2
 import reference_qwen3_next_core as ref_qwen3
-from test_core_cli_fused import run_fused_cli
-from test_core_window_length import equations
-from test_lfm2_core import close, grads_close, tiny_cc as lfm2_cc
-from test_qwen3_next_core import tiny_cc as qwen3_cc
+from core_families import close, equations, grads_close, run_fused_cli
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 LANES = 16  # tokens of a tick in every fused cell
 HELD = 4
 
@@ -31,9 +28,9 @@ HELD = 4
 # no shared expert, a softmax router beside a gated shared expert; 4 of the
 # experts held, so a routing can touch none, one, two or all of them
 FAMILIES = {
-    "lfm2": (lfm2_cc(num_experts=8, experts_here=HELD),
+    "lfm2": (cf.tiny_cc("lfm2_moe", num_experts=8, experts_here=HELD),
              lfm2.Lfm2Config, ref_lfm2),
-    "qwen3_next": (qwen3_cc(num_experts=16, experts_here=HELD),
+    "qwen3_next": (cf.tiny_cc("qwen3_next", num_experts=16, experts_here=HELD),
                    qwen3_next.Qwen3NextConfig, ref_qwen3),
 }
 
@@ -66,7 +63,7 @@ def built(family, dtype, routing, n=LANES):
     kc = reader.from_dict(cc)
     moe = mla_moe._MoE(kc, dtype)
     x = jax.random.normal(jax.random.PRNGKey(3), (n, kc.hidden))
-    p = moe.init(jax.random.PRNGKey(4), x)["params"]
+    p = jax.jit(moe.init)(jax.random.PRNGKey(4), x)["params"]
     bias, share = routing_bias(routing, kc.experts, kc.top_k)
     p["router"]["select_bias"] = jnp.asarray(bias)
     return cc, kc, moe, ref, p, x, share
@@ -134,8 +131,8 @@ def test_few_token_branch_matches_the_reference_values_and_gradients(
     wgt = jax.random.normal(jax.random.PRNGKey(5), y.shape)
     g = jax.jit(jax.grad(lambda p, x: jnp.sum(
         moe.apply({"params": p}, x) * wgt), argnums=(0, 1)))(p, x)
-    g_ref = jax.grad(lambda p, x: jnp.sum(plain(p, x) * wgt),
-                     argnums=(0, 1))(p, x)
+    g_ref = jax.jit(jax.grad(lambda p, x: jnp.sum(plain(p, x) * wgt),
+                             argnums=(0, 1)))(p, x)
     grads_close(g, g_ref, gtol)  # kernels, router, shared expert and input
     if touched < HELD:  # an expert no token chose takes no gradient
         idle = sorted(set(range(HELD)) - set(local[local < HELD].tolist()))
@@ -192,7 +189,7 @@ def test_the_many_token_path_traces_the_primitives_it_did():
     before the few-token branch changed (PR 43's)."""
     _, eqs = _published_lfm2_jaxpr(7680)
     with open(os.path.join(
-            HERE, "fixtures", "moe_many_token_primitives.json")) as f:
+            cf.HERE, "fixtures", "moe_many_token_primitives.json")) as f:
         recorded = json.load(f)
     assert [eq.primitive.name for eq in eqs] == recorded["primitives"]
     assert recorded["primitives"].count("ragged_dot_general") == 9
@@ -204,12 +201,11 @@ def test_the_many_token_path_traces_the_primitives_it_did():
 def test_touched_expert_share_is_sown_on_the_few_token_path_only(
         routing, share):
     _, kc, moe, _, p, x, _ = built("lfm2", jnp.float32, routing)
-    _, sown = moe.apply({"params": p}, x, mutable=[CORE_STATS])
-    assert float(reduce_stats(sown)["moe_act_touched_expert_share"]) == share
+    sown = jax.jit(lambda p, x: reduce_stats(moe.apply(
+        {"params": p}, x, mutable=[CORE_STATS])[1]))
+    assert float(sown(p, x)["moe_act_touched_expert_share"]) == share
     many = mla_moe.FEW_ROWS // min(kc.top_k, HELD) + 1
-    _, sown = moe.apply({"params": p}, jnp.tile(x, (many // LANES + 1, 1)),
-                        mutable=[CORE_STATS])
-    stats = reduce_stats(sown)
+    stats = sown(p, jnp.tile(x, (many // LANES + 1, 1)))
     assert "moe_act_touched_expert_share" not in stats
     assert "moe_row_fill_share" in stats
 
@@ -258,9 +254,10 @@ def test_the_ticks_written_window_share_is_one_slot_of_the_ring(tmp_path):
     x = jnp.zeros((2, 1, kc.hidden))
     stack = mla_moe._Stack(delta.kc, jnp.float32)
     state, none = delta.initial_state(2), jnp.zeros((2, 1), bool)
-    params = stack.init(jax.random.PRNGKey(0), x, state, none)["params"]
-    sown = stack.apply({"params": params}, x, state, none,
-                       mutable=[CORE_STATS])[1]
+    params = jax.jit(stack.init)(
+        jax.random.PRNGKey(0), x, state, none)["params"]
+    sown = jax.jit(lambda p: stack.apply(
+        {"params": p}, x, state, none, mutable=[CORE_STATS])[1])(params)
     assert "attn_act_window_written_share" not in reduce_stats(sown)
     # and its reset is the multiply of every leaf
     warm, keep = jax.tree.map(jnp.ones_like, state), jnp.asarray([1, 0])

@@ -2,11 +2,11 @@
 delta-rule recurrence of models/kimi_linear.py) against its plain float32
 reference (tests/reference_qwen3_next_core.py), at tiny widths (value heads
 twice the key heads, two query heads a key/value head, half of each head
-rotated), float32 compute, seeded weights; and the two other cores, which run
-the same stack, held to the parameter trees they had before a layer named its
-mixer."""
+rotated), float32 compute, seeded weights: what is this family's own (the
+cases every family shares are tests/test_core_reference.py's); and the two
+other cores, which run the same stack, held to the parameter trees they had
+before a layer named its mixer."""
 
-import dataclasses
 import functools
 import json
 import os
@@ -17,100 +17,23 @@ import numpy as np
 import pytest
 
 from rainbow_iqn_apex_tpu.models import kimi_linear as kl
-from rainbow_iqn_apex_tpu.models import mla_moe
 from rainbow_iqn_apex_tpu.models import qwen3_next as qn
 from rainbow_iqn_apex_tpu.models.cores import (
-    CORE_STATS,
-    FAMILIES,
     reduce_stats,
     state_bytes_per_lane,
 )
 
+import core_families as cf
 import reference_qwen3_next_core as ref
-from ring_windows import aged
+from core_families import close
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-TINY = os.path.join(HERE, "fixtures", "qwen3_next_core_tiny.json")
-FEATURES = 24  # what the trunk would feed; the input projection takes any
-
-
-def tiny_cc(window=32, **over):
-    """The reference attends over the whole sequence, so the window is as
-    long as the sequences compared with it unless a test says otherwise."""
-    with open(TINY) as f:
-        cc = json.load(f)
-    cc["assumed"]["attn_window"] = window
-    cc.update(over)
-    return cc
-
-
-@functools.lru_cache(maxsize=None)
-def _built(cc_json, seed):
-    """(core, stack, params) of a configuration: the parameters do not go by
-    the batch or the sequence length, so one compiled init serves every test
-    of that configuration."""
-    cc = json.loads(cc_json)
-    core = qn.Qwen3NextCore(qn.Qwen3NextConfig.from_dict(cc), jnp.float32)
-    k2, k3 = jax.random.split(jax.random.PRNGKey(seed))
-    stack = mla_moe._Stack(core.kc, jnp.float32)
-    params = jax.jit(stack.init)(
-        k2, jnp.zeros((1, 2, FEATURES)), core.initial_state(1),
-        jnp.zeros((1, 2), bool))["params"]
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(k3, len(leaves))
-    leaves = [p + 0.1 * jax.random.normal(k, p.shape) if p.ndim == 1 else p
-              for p, k in zip(leaves, keys)]
-    return core, stack, jax.tree.unflatten(tree, leaves)
-
-
-def make(cc, batch=3, steps=20, seed=0, reset_at=((0, 5), (1, 9), (1, 10))):
-    """(core, stack, params, x, resets, zero state) with every leaf random:
-    the norms' scales, the decay's parameters and the router's selection bias
-    included."""
-    core, stack, params = _built(json.dumps(cc, sort_keys=True), seed)
-    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (batch, steps, FEATURES))
-    resets = np.zeros((batch, steps), bool)
-    for b, t in reset_at:
-        if b < batch and t < steps:
-            resets[b, t] = True
-    return core, stack, params, x, jnp.asarray(resets), core.initial_state(batch)
-
-
-def jitted(cc, stack):
-    """(program, reference) as compiled functions of (params, x, state,
-    resets) and (params, x, resets, burn=, window=): XLA:CPU compiles a tiny
-    stack in seconds where op-by-op dispatch takes three times that."""
-    run = jax.jit(lambda p, x, st, r: stack.apply({"params": p}, x, st, r))
-    plain = jax.jit(
-        lambda p, x, r, burn=0, window=None: ref.core_forward(
-            p, cc, x, r, burn=burn, window=window),
-        static_argnames=("burn", "window"))
-    return run, plain
-
+FAMILY = "qwen3_next"
+FEATURES = cf.FAMILIES[FAMILY].features
+tiny_cc = functools.partial(cf.tiny_cc, FAMILY)
+make = functools.partial(cf.make, FAMILY)
+jitted = functools.partial(cf.jitted, FAMILY)
 
 SHORT = dict(full_attention_interval=2, layers_here=2)  # one layer of each kind
-
-
-def close(a, b, tol=2e-4):
-    """Float32 on both sides, sums in another order (the chunked scan against
-    the step-by-step one, a grouped product against masked ones): 2e-4 of the
-    largest value is some thousand roundings of room."""
-    a, b = np.asarray(a), np.asarray(b)
-    scale = max(float(np.abs(b).max()), 1e-6)
-    assert float(np.abs(a - b).max()) <= tol * scale, (
-        float(np.abs(a - b).max()), scale)
-
-
-def grads_close(g1, g2, tol=2e-3):
-    """A gradient sums over every step and token: ten times the values'."""
-    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(g1),
-                            jax.tree.leaves(g2)):
-        if "select_bias" in jax.tree_util.keystr(path):
-            # the bias enters the choice alone: no gradient on either side
-            assert not np.any(np.asarray(a)) and not np.any(np.asarray(c))
-            continue
-        close(a, c, tol)
 
 
 def test_the_stack_is_three_delta_layers_and_one_attention_layer():
@@ -129,105 +52,6 @@ def test_the_stack_is_three_delta_layers_and_one_attention_layer():
         "moe_expert_load_max_over_mean", "moe_held_assign_share",
         "moe_tokens_dropped", "kda_fused_tile_share", "gattn_live_key_share",
         "kda_scalar_gate_share")
-
-
-def test_sequence_pass_matches_the_reference_values_and_gradients():
-    cc = tiny_cc()
-    core, stack, params, x, resets, state = make(cc)
-    w = jax.random.normal(jax.random.PRNGKey(4), (*x.shape[:2], core.kc.hidden))
-
-    run, plain = jitted(cc, stack)
-    prog = jax.jit(jax.value_and_grad(
-        lambda p: jnp.sum(run(p, x, state, resets)[0] * w)))
-    want = jax.jit(jax.value_and_grad(
-        lambda p: jnp.sum(plain(p, x, resets) * w)))
-    y = run(params, x, state, resets)[0]
-    assert y.shape == (*x.shape[:2], cc["hidden_size"])
-    close(y, plain(params, x, resets))
-    grads_close(prog(params)[1], want(params)[1])
-
-
-def test_burn_in_then_trained_slice_match_one_full_pass():
-    """The learn step's two passes (burn-in, its final state stop-gradiented,
-    then the trained slice from it) against the reference's one pass with its
-    stop-gradient boundary: values, and the gradient of the trained slice.
-    The boundary falls inside a chunk of the scan, and the slice's keys sit
-    in the window at slots that are not their absolute positions."""
-    cc = tiny_cc()
-    burn, steps = 6, 14
-    core, stack, params, x, resets, state = make(
-        cc, steps=steps, reset_at=((0, 2), (1, 9)))
-    w = jax.random.normal(
-        jax.random.PRNGKey(5), (x.shape[0], steps - burn, core.kc.hidden))
-    run, plain = jitted(cc, stack)
-
-    def prog(p):
-        _, st = run(p, x[:, :burn], state, resets[:, :burn])
-        st = jax.lax.stop_gradient(st)
-        y = run(p, x[:, burn:], st, resets[:, burn:])[0]
-        return jnp.sum(y * w), y
-
-    def want(p):
-        y = plain(p, x, resets, burn=burn)[:, burn:]
-        return jnp.sum(y * w), y
-
-    (_, y), grads = jax.jit(jax.value_and_grad(prog, has_aux=True))(params)
-    (_, y_ref), grads_ref = jax.jit(jax.value_and_grad(want, has_aux=True))(
-        params)
-    close(y, y_ref)
-    grads_close(grads, grads_ref)
-
-
-@pytest.mark.parametrize("window,steps,over", [
-    (12, 30, {}), (120, 120, SHORT)])
-def test_act_ticks_match_the_sequence_pass_and_absolute_positions(
-        window, steps, over):
-    """Ticks of one step each from the empty state (`kda_step` and one row of
-    scores) against the program's own pass over the sequence and against the
-    reference's absolute positions 0..T-1.  T = 2.5 W: the window rolls over
-    twice, every key is rotated by the slot it sits in when it is used, a
-    slot that changes with every tick.  T = W = 120, the cell's sequence
-    length: from the empty window that is full causal attention exactly, so
-    the reference is not told of a window (that case at one layer of each
-    kind: 120 steps compile long)."""
-    cc = tiny_cc(window=window, **over)
-    core, stack, params, x, resets, state = make(
-        cc, batch=2, steps=steps, reset_at=((0, 7), (1, 19), (1, 20)))
-    run, plain = jitted(cc, stack)
-    st, ys = state, []
-    for t in range(steps):
-        y, st = run(params, x[:, t:t + 1], st, resets[:, t:t + 1])
-        ys.append(y)
-    ticks = jnp.concatenate(ys, axis=1)
-    rolled = steps > window
-    want = plain(params, x, resets, window=window if rolled else None)
-    close(ticks, want)
-    seq, seq_state = run(params, x, state, resets)
-    close(ticks, seq)
-    for a, c in zip(jax.tree.leaves(aged(st)),
-                    jax.tree.leaves(aged(seq_state))):
-        close(a, c)
-    if rolled:  # the window matters there: another window's pass differs
-        assert float(jnp.abs(ticks - plain(
-            params, x, resets, window=2 * window)).max()) > 1e-3
-
-
-def test_a_cut_inside_a_chunk_equals_two_passes():
-    """After a reset before step `cut` (chunks are 8 steps: every cut here
-    falls inside one; the first and the last one step from the sequence's
-    ends, where a pass is the actor's single step) the outputs are those of
-    two sequences, one that ends there and one that starts there: nothing of
-    the state, the convolution's tail or the window crosses it."""
-    cc = tiny_cc()
-    core, stack, params, x, _, state = make(cc, batch=2, reset_at=())
-    run, plain = jitted(cc, stack)
-    none = jnp.zeros(x.shape[:2], bool)
-    for cut in (1, 6, 10, 14, 19):  # five cuts, three pairs of lengths
-        resets = none.at[:, cut].set(True)
-        whole = run(params, x, state, resets)[0]
-        close(whole, plain(params, x, resets))
-        close(whole[:, :cut], run(params, x[:, :cut], state, none[:, :cut])[0])
-        close(whole[:, cut:], run(params, x[:, cut:], state, none[:, cut:])[0])
 
 
 @pytest.mark.parametrize("gate", ["one_wide", "broadcast"])
@@ -331,27 +155,14 @@ def test_the_sixteen_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     cfg = qn.Qwen3NextConfig.from_dict({**cc, "experts_here": 512})
     assert (cfg.experts, cfg.top_k, cfg.route, cfg.shared_gate,
             cfg.route_scale) == (512, 10, "softmax", True, 1.0)
-    p = mla_moe._MoE(cfg, jnp.float32).init(jax.random.PRNGKey(1), x)["params"]
+    p, _ = cf.expert_layer(cfg, x)
     p["router"]["select_bias"] = 0.002 * jax.random.normal(
         jax.random.PRNGKey(2), (512,))
     whole = ref.moe_ffn(p, cc, x, (0, 512), ref.plain_dot)
     shared = ref.moe_ffn(p, cc, x, (0, 0), ref.plain_dot)  # no expert held
     close(shared, jax.nn.sigmoid(x @ p["shared_gate"]["kernel"])
           * ref.swiglu(p["shared"], x, ref.plain_dot))
-    total, held = shared, 0.0
-    for first in range(0, 512, 32):
-        share_cfg = dataclasses.replace(cfg, experts_here=32, first_expert=first)
-        share_p = {**p, "experts": {n: w[first:first + 32]
-                                    for n, w in p["experts"].items()}}
-        y, sown = mla_moe._MoE(share_cfg, jnp.float32).apply(
-            {"params": share_p}, x, mutable=[CORE_STATS])
-        close(y, ref.moe_ffn(share_p, cc, x, (first, 32), ref.plain_dot))
-        total = total + (y - shared)
-        stats = reduce_stats(sown)
-        assert float(stats["moe_tokens_dropped"]) == 0.0
-        held += float(stats["moe_held_assign_share"])
-    close(total, whole)
-    assert abs(held - 1.0) < 1e-6  # every assignment fell on one share
+    cf.shares_add_up(cfg, cc, ref, p, x, 32, whole, shared)
 
 
 def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
@@ -361,10 +172,8 @@ def test_no_token_is_dropped_when_every_token_picks_the_held_experts():
     cc = tiny_cc(num_experts=32, num_experts_per_tok=10)
     cfg = qn.Qwen3NextConfig.from_dict({**cc, "experts_here": 32})
     x = jax.random.normal(jax.random.PRNGKey(0), (300, cc["hidden_size"]))
-    moe = mla_moe._MoE(cfg, jnp.float32)
-    p = moe.init(jax.random.PRNGKey(1), x)["params"]
-    y, sown = moe.apply({"params": p}, x, mutable=[CORE_STATS])
-    stats = reduce_stats(sown)
+    p, run = cf.expert_layer(cfg, x)
+    y, stats = run(p, x)
     assert float(stats["moe_tokens_dropped"]) == 0.0
     assert float(stats["moe_held_assign_share"]) == 1.0
     close(y, ref.moe_ffn(p, cc, x, (0, 32), ref.plain_dot))
@@ -386,11 +195,10 @@ def test_live_key_share_of_the_learn_steps_two_passes(
     if not lane:  # the learner's passes: from a sequence's zero-slot start
         state = core.from_stored(jnp.zeros((1, 0)), jnp.zeros((1, 0)))
     if filled:
-        _, state = jitted(cc, stack)[0](
+        _, state = jitted(cc)[0](
             params, x[:, :filled], state, none[:, :filled])
-    _, sown = jax.jit(lambda p, x, st, r: stack.apply(
-        {"params": p}, x, st, r, mutable=[CORE_STATS]))(
-            params, x[:, filled:], state, none[:, filled:])
+    _, sown = cf.jitted_sown(FAMILY, cc)(
+        params, x[:, filled:], state, none[:, filled:])
     stats = reduce_stats(sown)
     assert float(stats["gattn_live_key_share"]) == pytest.approx(share, rel=1e-6)
     # a sequence's preparation ran the scalar form and no tile kernel; a
@@ -403,8 +211,7 @@ def test_live_key_share_of_the_learn_steps_two_passes(
 
 
 def test_the_published_file_reads_the_published_sizes():
-    with open(os.path.join(ROOT, "configs", "cores",
-                           "qwen3_next_80b_a3b.json")) as f:
+    with open(cf.FAMILIES[FAMILY].published_path) as f:
         cc = json.load(f)
     kc = qn.Qwen3NextConfig.from_dict(cc)
     assert [m.layer_name for m in kc.mixers] == ["gdn", "gdn", "gdn", "gattn"]
@@ -439,55 +246,14 @@ def test_the_other_cores_trees_are_leaf_for_leaf_what_they_were(
     benchmarks/weights_core.py walks by name) and per-lane states at the
     published sizes are, path for path and shape for shape, what the tree
     before that built (recorded from it)."""
-    import importlib
-
-    with open(os.path.join(HERE, "fixtures", pinned)) as f:
+    with open(os.path.join(cf.HERE, "fixtures", pinned)) as f:
         want = json.load(f)
-    with open(os.path.join(ROOT, "configs", "cores", published)) as f:
-        cc = json.load(f)
-    module, reader, core_cls = FAMILIES[family]
-    mod = importlib.import_module("rainbow_iqn_apex_tpu.models." + module)
-    core = getattr(mod, core_cls)(getattr(mod, reader).from_dict(cc),
-                                  jnp.bfloat16)
-    state = jax.eval_shape(lambda: core.initial_state(2))
-    shapes = jax.eval_shape(
-        lambda k, x, s, r: mla_moe._Stack(core.kc, jnp.bfloat16).init(
-            k, x, s, r)["params"],
-        jax.random.PRNGKey(0), jax.ShapeDtypeStruct((2, 3, 2304), jnp.float32),
-        state, jax.ShapeDtypeStruct((2, 3), jnp.bool_))
-    by_path = lambda tree: {  # noqa: E731
-        jax.tree_util.keystr(p): list(v.shape)
-        for p, v in jax.tree_util.tree_leaves_with_path(tree)}
-    assert by_path(shapes) == want["published_param_shapes"]
-    assert by_path(state) == want["published_state_shapes"]
+    fam = cf.FAMILIES[family]
+    assert fam.published == published
+    with open(fam.published_path) as f:
+        core = fam.core(json.load(f), jnp.bfloat16)
+    shapes, state, _ = cf.stack_shapes(
+        core.kc, cf.TRUNK_FEATURES, jnp.bfloat16)
+    assert cf.shapes_by_path(shapes) == want["published_param_shapes"]
+    assert cf.shapes_by_path(state) == want["published_state_shapes"]
     assert (core.kc.route, core.kc.shared_gate) == ("sigmoid", False)
-
-
-def test_a_core_file_imports_its_own_family_alone():
-    """`cores._load` reads `model_type` first: a process that runs one core
-    pays for one family's module (a child process, so that this one's
-    imports do not count)."""
-    import subprocess
-    import sys
-
-    code = (
-        "import sys\n"
-        "from rainbow_iqn_apex_tpu.config import Config\n"
-        "from rainbow_iqn_apex_tpu.models.cores import make_core\n"
-        "make_core(Config(architecture='r2d2', core_config="
-        "'configs/cores/kanana_2_30b_a3b.json'))\n"
-        "print(sorted(m.rsplit('.', 1)[1] for m in sys.modules if m.startswith("
-        "'rainbow_iqn_apex_tpu.models.') and m.rsplit('.', 1)[1] in "
-        "('kimi_linear', 'kda_tile', 'deepseek_v3', 'qwen3_next')))\n")
-    out = subprocess.run(
-        [sys.executable, "-c", code], cwd=ROOT, capture_output=True, text=True,
-        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert out.stdout.strip().splitlines()[-1] == "['deepseek_v3']"
-
-
-def test_the_two_copies_of_the_reference_are_the_same_text():
-    with open(os.path.join(HERE, "reference_qwen3_next_core.py")) as a, open(
-            os.path.join(ROOT, "benchmarks", "references",
-                         "qwen3_next_core.py")) as b:
-        assert a.read() == b.read()

@@ -4,6 +4,9 @@ same parameters.  One kernel, two readings: outputs, gradients, the learn
 step's semantics at the sequence start and at the burn-in boundary, the act
 path beside the sequence pass, and the parameter tree itself."""
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -210,43 +213,12 @@ def test_act_path_and_sequence_pass_read_one_kernel():
 
 
 # `init_r2d2_state(Config(), 6, key, (84, 84))` at the parent of the PR that
-# brought the stem: every leaf, by path and shape
-PARENT_TREE = [
-    ("ConvTrunk_0/Conv_0/bias", (32,)),
-    ("ConvTrunk_0/Conv_0/kernel", (8, 8, 4, 32)),
-    ("ConvTrunk_0/Conv_1/bias", (64,)),
-    ("ConvTrunk_0/Conv_1/kernel", (4, 4, 32, 64)),
-    ("ConvTrunk_0/Conv_2/bias", (64,)),
-    ("ConvTrunk_0/Conv_2/kernel", (3, 3, 64, 64)),
-    ("advantage_hidden/b_mu", (512,)),
-    ("advantage_hidden/b_sigma", (512,)),
-    ("advantage_hidden/w_mu", (512, 512)),
-    ("advantage_hidden/w_sigma", (512, 512)),
-    ("advantage_out/b_mu", (6,)),
-    ("advantage_out/b_sigma", (6,)),
-    ("advantage_out/w_mu", (512, 6)),
-    ("advantage_out/w_sigma", (512, 6)),
-    ("lstm/cell/hf/bias", (512,)),
-    ("lstm/cell/hf/kernel", (512, 512)),
-    ("lstm/cell/hg/bias", (512,)),
-    ("lstm/cell/hg/kernel", (512, 512)),
-    ("lstm/cell/hi/bias", (512,)),
-    ("lstm/cell/hi/kernel", (512, 512)),
-    ("lstm/cell/ho/bias", (512,)),
-    ("lstm/cell/ho/kernel", (512, 512)),
-    ("lstm/cell/if/kernel", (3136, 512)),
-    ("lstm/cell/ig/kernel", (3136, 512)),
-    ("lstm/cell/ii/kernel", (3136, 512)),
-    ("lstm/cell/io/kernel", (3136, 512)),
-    ("value_hidden/b_mu", (512,)),
-    ("value_hidden/b_sigma", (512,)),
-    ("value_hidden/w_mu", (512, 512)),
-    ("value_hidden/w_sigma", (512, 512)),
-    ("value_out/b_mu", (1,)),
-    ("value_out/b_sigma", (1,)),
-    ("value_out/w_mu", (512, 1)),
-    ("value_out/w_sigma", (512, 1)),
-]
+# brought the stem: every leaf, by path and shape (tests/test_lstm_core.py
+# holds `R2D2Net` to the same file)
+with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                       "r2d2_parent_tree.json")) as _f:
+    PARENT_TREE = [(path, tuple(shape))
+                   for path, shape in json.load(_f)["leaves"]]
 
 
 def test_parameter_tree_is_the_parents_leaf_for_leaf():
