@@ -287,6 +287,9 @@ class Config:
     # the act->append->learn graph (zero per-tick host traffic); turn off to
     # drive jax games through the host loop instead
     anakin_segment_ticks: int = 64  # env ticks per fused-graph dispatch
+    device_game_tick_cap: int = 0  # the fused trainers' game: truncate an
+    # episode at so many ticks where the game has a time limit of its own
+    # (jaxgame:freeway, 500); 0 = the game's own
     pipelined_actor: bool = False  # overlap device inference with env stepping
     # (one-tick action lag: the action executed at tick t was computed from
     # the observation at t-1 — Podracer/SEED-style; replay stores the action

@@ -938,7 +938,21 @@ def build_rollout(game: "DeviceGame", action_fn, episodes: int,
     return run
 
 
-def make_device_game(name: str) -> DeviceGame:
+def make_device_game(name: str, tick_cap: int = 0) -> DeviceGame:
+    """The game of a `jaxgame:<name>` id.  `tick_cap` > 0 truncates an episode
+    at so many ticks where the game ends its episodes by a time limit of its
+    own (freeway's `cap`, 500 otherwise); 0 leaves the game as it is."""
+    game = _named_game(name)
+    if tick_cap:
+        if not hasattr(game, "cap"):
+            raise ValueError(
+                f"game '{name}' has no time limit of its own to set: "
+                f"device_game_tick_cap is for freeway")
+        game.cap = int(tick_cap)
+    return game
+
+
+def _named_game(name: str) -> DeviceGame:
     if "@" in name:
         base, variant = name.split("@", 1)
         cls = VARIANT_GAMES.get(base)

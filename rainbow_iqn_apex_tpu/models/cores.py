@@ -33,13 +33,14 @@ product each after it.  In the loop they were T products of B rows each, at
 half the rate or less, and an `f32[F, 4m]` accumulator read and written every
 turn (PERF.md, PR 39).  All of it wears the scope `lstm_scan`.
 
-Six cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
-(c, h) of every sequence start), and five over the blocks of
+Seven cores: `LSTMCore` (the R2D2 paper's, stored-state replay: the ring keeps
+(c, h) of every sequence start), and six over the blocks of
 models/mla_moe.py (zero start state: the ring's state columns have width 0,
 and a sequence's attention windows start with no slots):
 `models/kimi_linear.KimiLinearCore`, `models/deepseek_v3.DeepSeekV3Core`,
-`models/qwen3_next.Qwen3NextCore`, `models/ouro.OuroCore` and
-`models/lfm2.Lfm2Core`, where F' is the model's hidden size and not F.
+`models/qwen3_next.Qwen3NextCore`, `models/ouro.OuroCore`,
+`models/lfm2.Lfm2Core` and `models/laguna.LagunaCore`, where F' is the
+model's hidden size and not F.
 `Config.core_config` names the file of one, which says which by its
 `model_type`; none is the LSTM.
 """
@@ -192,6 +193,7 @@ FAMILIES = {
     "qwen3_next": ("qwen3_next", "Qwen3NextConfig", "Qwen3NextCore"),
     "ouro": ("ouro", "OuroConfig", "OuroCore"),
     "lfm2_moe": ("lfm2", "Lfm2Config", "Lfm2Core"),
+    "laguna": ("laguna", "LagunaConfig", "LagunaCore"),
 }
 
 

@@ -15,7 +15,7 @@ its opening, its mask and its reset), the two published forms of the rotation,
 and the short causal convolution with its taps (`_causal_conv`, `_Taps`: the
 two delta-rule mixers' and the gated short convolution's).
 
-Five cores are built from them: `models/kimi_linear.py` (three KDA mixers in
+Six cores are built from them: `models/kimi_linear.py` (three KDA mixers in
 four, defined there, and an un-rotated MLA in the fourth),
 `models/deepseek_v3.py` (every mixer MLA with decoupled rotary keys, an input
 projection in the embedding's place), `models/qwen3_next.py` (three Gated
@@ -23,9 +23,13 @@ DeltaNet mixers in four and a gated softmax attention in the fourth, both
 defined there; softmax routing and a gated shared expert),
 `models/ouro.py` (plain multi-head attention defined there, every
 feed-forward dense, four norms a block, the stack run `total_ut_steps`
-times) and `models/lfm2.py` (a gated short convolution, defined there, in
+times), `models/lfm2.py` (a gated short convolution, defined there, in
 three layers of four and `models/ouro.py`'s attention with q/k norms in the
-fourth; sigmoid routing and NO shared expert).  Each reads its own published
+fourth; sigmoid routing and NO shared expert) and `models/laguna.py`
+(sliding-window and full attention layers side by side, defined there, each
+mixer with a span, heads and a rotation of its own, which is why the
+rotation by a table of frequencies and the attention by blocks of queries
+live here).  Each reads its own published
 keys into one `CoreConfig`; which mixer a layer runs, whether the rope
 dimensions are rotated, how the router scores, whether the input is
 projected, how often the stack is run and whether a block norms its outputs
@@ -138,7 +142,7 @@ FEW_ROWS = 1024
 class CoreConfig:
     """What the stack is built from; a family's reader fills it from its own
     published keys (`KimiLinearConfig`, `DeepSeekV3Config`,
-    `Qwen3NextConfig`, `OuroConfig`, `Lfm2Config`).  A mixer's sizes are
+    `Qwen3NextConfig`, `OuroConfig`, `Lfm2Config`, `LagunaConfig`).  A mixer's sizes are
     read by that mixer alone and the expert layer's by `_MoE` alone, so a
     family leaves the others' at their zeros (a dense family has no expert
     layer: `first_dense` is all its layers)."""
@@ -266,16 +270,22 @@ def _causal_conv(z, taps, tail, seg):
 
 
 # ------------------------------------------------------------------- MLA
+def _cos_sin(u, pos, freq):
+    """(cos, sin) of the angles pos x freq [n], shaped to broadcast against
+    u [B, S, ..., n]; pos [S], or [B, S] where the lanes' slots stand at
+    different positions (a ring)."""
+    pos = jnp.atleast_2d(pos).astype(jnp.float32)
+    angle = (pos[..., None] * freq).reshape(  # [B or 1, S, n]
+        pos.shape + (1,) * (u.ndim - 3) + freq.shape)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
 def rope_cos_sin(u, pos, theta: float):
     """(cos, sin) of the angles pos x theta^(-2i/d), i < d/2, shaped to
-    broadcast against u [B, S, ..., d/2]; pos [S], or [B, S] where the lanes'
-    slots stand at different positions (a ring)."""
+    broadcast against u [B, S, ..., d/2]."""
     d = u.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    pos = jnp.atleast_2d(pos).astype(jnp.float32)
-    angle = (pos[..., None] * freq).reshape(  # [B or 1, S, d/2]
-        pos.shape + (1,) * (u.ndim - 3) + (d // 2,))
-    return jnp.cos(angle), jnp.sin(angle)
+    return _cos_sin(
+        u, pos, theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))
 
 
 def rotate_pairs(u, pos, theta: float):
@@ -297,6 +307,34 @@ def rotate_halves(u, pos, theta: float):
     cos, sin = rope_cos_sin(u, pos, theta)
     a, b = u[..., : d // 2], u[..., d // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotation:
+    """A rotation as a table: pair i of a head turns by pos x `freq[i]`, and
+    cos and sin are multiplied by `factor` (a scaled rotation's attention
+    factor; 1.0 for a plain one).  The table's 2 x len(freq) leading
+    dimensions of a head are turned, the rest passed through.  A score of two
+    heads so turned depends on the difference of their positions alone,
+    whatever the table, so the rotation at use by a slot's position (the
+    module's docstring) holds for it as for `theta^(-2i/d)`."""
+
+    freq: Tuple[float, ...]
+    factor: float = 1.0
+
+
+def rotate_table(u, pos, rot: Rotation):
+    """u [B, S, ..., d] with its first n = 2 len(rot.freq) dimensions turned
+    by pos in the `rotate_half` form over those n, (u_i, u_{i + n/2}) by
+    pos x freq[i], and times `rot.factor`; dimensions from n on as they
+    are."""
+    n = 2 * len(rot.freq)
+    cos, sin = _cos_sin(u, pos, jnp.asarray(rot.freq, jnp.float32))
+    cos, sin = cos * rot.factor, sin * rot.factor
+    a, b = u[..., : n // 2], u[..., n // 2: n]
+    rest = [u[..., n:]] if n < u.shape[-1] else []
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, *rest], axis=-1)
 
 
 # ------------------------------------------------------ attention windows
@@ -411,6 +449,71 @@ def sow_written_share(module, state, t: int, w: int):
     that still grows): the slots it writes over the slots it holds."""
     if state["valid"].shape[1] == w:
         module.sow(STATS, "attn_act_window_written_share", min(t, w) / w)
+
+
+# queries a block of `attend_by_blocks`: the lanes' width, and at a span of
+# 512 a block's band is 639 of the 1,023 slots held
+ATTN_BLOCK = 128
+
+
+def attend(q, k, v, mask, dtype):
+    """Softmax attention of grouped queries q [B, T, G, R, d] (R query heads
+    a key/value head) over k, v [B, S, G, d] under mask [B, T, S]: o [B, T,
+    G, R, d].  A masked score is `NEG`, whose weight is exactly 0."""
+    scores = _mm("btgrd,bsgd->bgrts", q, k, dtype)
+    scores = jnp.where(
+        mask[:, None, None], scores / math.sqrt(q.shape[-1]), NEG)
+    return _mm("bgrts,bsgd->btgrd", jax.nn.softmax(scores, axis=-1), v, dtype)
+
+
+def attend_by_blocks(q, k, v, valid, seg_k, seg_q, span: int, dtype,
+                     block: int = 0):
+    """`attend` of T new steps over S = n + T slots IN POSITION ORDER (slot j
+    at position j, the query of new step i at n + i; k, v, valid [B, S] and
+    seg_k [B, S] the slots', seg_q [B, T] the steps'), a block of `block`
+    queries at a time, each over the slots of its own band alone: a query at
+    position p sees the slots (p - span, p], so the block of queries
+    [p0, p1) scores the slots [max(p0 - span + 1, 0), p1) and no others, and
+    a span as long as the slots held cuts nothing but what lies after the
+    block: at a span of half the slots a block of 128 scores 62% of the
+    columns the dense form would.  A block's scores [B, G, R, block, band]
+    are made again on the way back (`jax.checkpoint` a block), so neither
+    pass keeps a score array of the whole sequence ([8, 64, 512, 1024]
+    float32 is 1.07 GB on the learn path, and the dense form's backward
+    holds several).  Returns (o [B, T, G, R, d], the mask's live entries, a
+    float32 count, the columns computed, an int; `block` 0 is `ATTN_BLOCK`).
+    A key outside a block's band is masked in the dense form, weighs exactly
+    0 there, and is left out here: the same numbers summed over fewer
+    zeros."""
+    t, s = q.shape[1], k.shape[1]
+    n, block = s - t, block or ATTN_BLOCK
+    one_block = jax.checkpoint(
+        lambda qb, kb, vb, mb: attend(qb, kb, vb, mb, dtype))
+    outs, live, computed = [], 0.0, 0
+    for i0 in range(0, t, block):
+        i1 = min(i0 + block, t)
+        lo, hi = max(n + i0 - span + 1, 0), n + i1
+        pos_q = (n + jnp.arange(i0, i1))[:, None]
+        pos_k = jnp.arange(lo, hi)[None]
+        mask = ((pos_k <= pos_q) & (pos_k > pos_q - span))[None] & (
+            valid[:, None, lo:hi] > 0) & (
+                seg_k[:, None, lo:hi] == seg_q[:, i0:i1, None])
+        outs.append(one_block(q[:, i0:i1], k[:, lo:hi], v[:, lo:hi], mask))
+        live = live + jnp.sum(mask, dtype=jnp.float32)
+        computed += (i1 - i0) * (hi - lo)
+    return jnp.concatenate(outs, axis=1), live, computed
+
+
+def ring_in_age_order(win: Window, head, w: int):
+    """What several steps on a ring attend over, `[ring; new]` (`window_open`
+    of T > 1 steps on `w` slots whose oldest was `head` [B]), with the ring's
+    slots turned into age order: (held, valid), every slot j then at position
+    j as in a sequence's window.  One gather of `w` slots a leaf."""
+    order = (head.astype(jnp.int32)[:, None] + jnp.arange(w)) % w
+    lanes = jnp.arange(order.shape[0])[:, None]
+    turn = lambda x: jnp.concatenate(  # noqa: E731
+        [x[:, :w][lanes, order], x[:, w:]], axis=1)
+    return {name: turn(x) for name, x in win.held.items()}, turn(win.valid)
 
 
 class _MLA(nn.Module):
@@ -716,8 +819,9 @@ def _reset_of(mixer):
 class StackCore:
     """The core interface (models/cores.py) over `_Stack`: zero start state,
     nothing stored in the ring.  A family's core (`KimiLinearCore`,
-    `DeepSeekV3Core`, `Qwen3NextCore`, `OuroCore`, `Lfm2Core`) is a frozen
-    dataclass of `kc` and `compute_dtype` that names the counters it reports,
+    `DeepSeekV3Core`, `Qwen3NextCore`, `OuroCore`, `Lfm2Core`, `LagunaCore`)
+    is a frozen dataclass of `kc` and `compute_dtype` that names the counters
+    it reports,
     `stat_names`: each is an output of the compiled segment, so a core lists
     what its cell reads (`moe_stat_names` where it has expert layers)."""
 

@@ -105,6 +105,11 @@ MHA_ROPE = "mha_rope"  # inside it: every q and k head turned whole by its slot
 # layers MOE_ROUTE and MOE_EXPERTS (there is no shared expert)
 SCONV_MIX = "sconv_mix"  # the input product, the two gates, the 3-tap
 # convolution, the output product
+# ---- the Laguna core's two kinds of attention layer (models/laguna.py):
+# each wears its kind round the MHA_* names (the gate's projection under
+# MHA_PROJ, its product under MHA_ATTN), its expert layers the MOE_* names
+ATTN_SLIDING = "attn_sliding"  # a sliding-window layer's mixer, whole
+ATTN_FULL = "attn_full"  # a full-attention layer's mixer, whole
 IQN_HEAD = "iqn_head"  # tau embedding + the tau-folded heads (IQN)
 OPTIMIZER = "optimizer"  # tx.update, apply_updates, the target copy
 GRAD_ALLREDUCE = "grad_allreduce"  # psum/pmax/pmean of the sharded builders
@@ -116,7 +121,7 @@ ALL_SCOPES = TICK_SCOPES + (
     KDA_PREP, MLA_ATTN, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, CORE_STEP,
     CORE_EMBED, MLA_PROJ, MLA_ROPE, NET_STEM, GDN_MIX, GATTN_PROJ, GATTN_ATTN,
     GATTN_ROPE, CORE_NORM, DENSE_FFN, KDA_MIX, LSTM_INPUT, LOOP_PASS,
-    MHA_PROJ, MHA_ATTN, MHA_ROPE, SCONV_MIX,
+    MHA_PROJ, MHA_ATTN, MHA_ROPE, SCONV_MIX, ATTN_SLIDING, ATTN_FULL,
 )
 _KNOWN = frozenset(ALL_SCOPES)
 

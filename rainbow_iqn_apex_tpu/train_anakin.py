@@ -399,7 +399,8 @@ def train_anakin_fused(cfg: Config, max_frames: Optional[int] = None) -> Dict[st
             f"({cfg.frames_per_learn}) — the learn cadence is in-graph"
         )
     T = cfg.anakin_segment_ticks
-    game = make_device_game(cfg.env_id.split(":", 1)[1])
+    game = make_device_game(
+        cfg.env_id.split(":", 1)[1], cfg.device_game_tick_cap)
     h, w = game.frame_shape
     if cfg.memory_capacity % lanes:
         raise ValueError(

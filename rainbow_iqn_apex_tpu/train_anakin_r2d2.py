@@ -279,7 +279,7 @@ def train_anakin_r2d2(cfg: Config,
     lanes = cfg.num_envs_per_actor
     T = cfg.anakin_segment_ticks
     game_name = cfg.env_id.split(":", 1)[1]
-    game = make_device_game(game_name)
+    game = make_device_game(game_name, cfg.device_game_tick_cap)
     h, w = game.frame_shape
     seq_total, stride, capacity, _ = _seq_geometry(cfg)
     _learn_cadence(cfg)  # validate divisibility before building anything

@@ -34,7 +34,10 @@ class Family:
     short: str  # tests/fixtures/<short>_core_tiny.json, the CLI cases' id
     reference: str  # the plain reference's module, in tests/
     loss_reference: str  # benchmarks/references/<this>.py holds its `loss_fn`
-    window_key: str  # the attention window's length, in the file's `assumed`
+    # where the file keeps the attention windows' lengths: keys of its
+    # `assumed`, or of the file itself after a dot; a family whose layers
+    # have spans of their own names each, and the shared cases set them alike
+    window_keys: tuple
     published: str  # its file under configs/cores/
     # the width fed to the stack: its input projection takes any; None for
     # the one family without (the trunk feeds its hidden size)
@@ -78,18 +81,21 @@ class Family:
 
 FAMILIES = {f.name: f for f in (
     Family("kimi_linear", "kimi", "reference_kimi_linear_core", "r2d2_kimi",
-           "mla_window", "kimi_linear_48b_a3b.json", features=None,
+           ("mla_window",), "kimi_linear_48b_a3b.json", features=None,
            three_way_keys=True, imports=("kda_tile", "kimi_linear")),
     Family("deepseek_v3", "deepseek_v3", "reference_deepseek_v3_core",
-           "r2d2_kanana", "mla_window", "kanana_2_30b_a3b.json",
+           "r2d2_kanana", ("mla_window",), "kanana_2_30b_a3b.json",
            three_way_keys=True, imports=("deepseek_v3",)),
     Family("qwen3_next", "qwen3_next", "reference_qwen3_next_core",
-           "r2d2_qwen3_next", "attn_window", "qwen3_next_80b_a3b.json",
+           "r2d2_qwen3_next", ("attn_window",), "qwen3_next_80b_a3b.json",
            imports=("kda_tile", "kimi_linear", "qwen3_next")),
-    Family("ouro", "ouro", "reference_ouro_core", "r2d2_ouro", "attn_window",
-           "ouro_2_6b.json", imports=("ouro",)),
+    Family("ouro", "ouro", "reference_ouro_core", "r2d2_ouro",
+           ("attn_window",), "ouro_2_6b.json", imports=("ouro",)),
     Family("lfm2_moe", "lfm2", "reference_lfm2_core", "r2d2_lfm2",
-           "attn_window", "lfm2_8b_a1b.json", imports=("lfm2", "ouro")),
+           ("attn_window",), "lfm2_8b_a1b.json", imports=("lfm2", "ouro")),
+    Family("laguna", "laguna", "reference_laguna_core", "r2d2_laguna",
+           ("attn_window", ".sliding_window"), "laguna_xs_2.json",
+           imports=("laguna",)),
 )}
 # the CLI cases name a core by its fixture
 CORES = {f.short: f.tiny for f in FAMILIES.values()}
@@ -105,9 +111,16 @@ def tiny_cc(family, window=32, **over):
         cc = json.load(f)
     if fam.features is None:
         cc["hidden_size"] = 32  # no trunk in front of the core here
-    cc["assumed"][fam.window_key] = window
+    set_windows(fam, cc, window)
     cc.update(over)
     return cc
+
+
+def set_windows(fam, cc, window):
+    """Every attention window of `cc` at `window` slots."""
+    for key in fam.window_keys:
+        where = cc if key.startswith(".") else cc["assumed"]
+        where[key.lstrip(".")] = window
 
 
 def stack_width(family, cc):
@@ -150,8 +163,8 @@ def built(family, cc, seed=0):
     caller's own, the arrays every caller's."""
     core = FAMILIES[family].core(cc)
     # (the init wants some window; the parameters are the same under any)
-    any_window = {**cc, "assumed": {
-        **cc["assumed"], FAMILIES[family].window_key: 12}}
+    any_window = {**cc, "assumed": dict(cc["assumed"])}
+    set_windows(FAMILIES[family], any_window, 12)
     params = _params(family, _as_key(any_window), seed)
     return (core, mla_moe._Stack(core.kc, jnp.float32),
             jax.tree.map(lambda a: a, params))

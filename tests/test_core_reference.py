@@ -164,7 +164,7 @@ def test_a_core_file_imports_its_own_family_alone(family):
         "print(sorted(m.rsplit('.', 1)[1] for m in sys.modules if m.startswith("
         "'rainbow_iqn_apex_tpu.models.') and m.rsplit('.', 1)[1] in "
         "('kimi_linear', 'kda_tile', 'deepseek_v3', 'qwen3_next', 'ouro', "
-        "'lfm2')))\n")
+        "'lfm2', 'laguna')))\n")
     out = subprocess.run(
         [sys.executable, "-c", code], cwd=cf.ROOT, capture_output=True,
         text=True, timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu"})

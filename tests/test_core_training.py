@@ -1,13 +1,15 @@
 """The R2D2 agent with every core family's tiny core (`Config.core_config`,
 tests/core_families.py's table) through the normal paths: the core picked by
-the file's `model_type`, the parameter count of the published cut, the fused
-segment and the act step, at tiny widths (the trunk's 2,304 features at 80x80
+the file's `model_type`, the parameter count of the published cut and the act
+step, at tiny widths (the trunk's 2,304 features at 80x80
 frames go through the input projection to the core's hidden size, which the
 heads read; they ARE the Kimi-Linear core's hidden size, as in its published
-configuration).  A case's body is written once; what a family's core, cut,
-rows and state look like stands in a function a family.  The learn step
-against the reference: tests/test_core_training_learn.py; the CLI:
-tests/test_core_cli_*.py."""
+configuration).  A case's body is written once; what a family's core, cut
+and state look like stands in a function a family.  The fused segment:
+tests/test_core_training_fused.py (a file of its own: a fused run a family is
+the longest case here, and no file may hold more than 400 s of test time);
+the learn step against the reference: tests/test_core_training_learn.py; the
+CLI: tests/test_core_cli_*.py."""
 
 import json
 
@@ -54,14 +56,6 @@ class KimiLinear:
         assert state_bytes_per_lane(published) == 4 * (
             4 * kda + 120 * 577 + 1)
 
-    def rows(learn):
-        assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
-        assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
-        assert all(r["moe_expert_load_max_over_mean"] >= 1.0 for r in learn)
-        # on the CPU every KDA layer's preparation took the plain path
-        assert all(r["kda_fused_tile_share"] == 0.0 for r in learn)
-        assert "kda_scalar_gate_share" not in learn[0]  # its gate is dk wide
-
     def state(state):
         pass
 
@@ -96,12 +90,6 @@ class DeepSeekV3:
         assert count(moe["router"]) == 2048 * 128 + 128
         assert count(core) == 515_007_488
         assert count(params) == 519_285_928  # x 20 B = 10.39 GB
-
-    def rows(learn):
-        assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
-        assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
-        assert "kda_fused_tile_share" not in learn[0]
-        return "mla_live_key_share"
 
     def state(state):
         # the rope keys are kept un-rotated: a step's latent does not depend
@@ -143,14 +131,6 @@ class Qwen3Next:
             "kernel", "gate", "up", "down", "scale", "taps", "A_log",
             "dt_bias", "select_bias"}
 
-    def rows(learn):
-        assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
-        assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
-        assert all(r["kda_fused_tile_share"] == 0.0 for r in learn)
-        assert all(r["kda_scalar_gate_share"] == 1.0 for r in learn)
-        assert "mla_live_key_share" not in learn[0]
-        return "gattn_live_key_share"
-
     def state(state):
         # the keys are kept un-rotated; a key is a function of the residual
         # stream, which three recurrent layers have moved between the two
@@ -188,13 +168,6 @@ class Ouro:
         assert count(params) == 214_552_744  # x 20 B = 4.29 GB
         # every leaf bears a name benchmarks/weights_core.py fills
         assert leaf_names(core) == {"kernel", "scale"}
-
-    def rows(learn):
-        # a core with no expert layer: the rows and the segment's outputs
-        # carry no `moe_*` counter, and say how often the weights were used
-        assert not [n for n in learn[0] if n.startswith("moe_")]
-        assert all(r["loop_passes"] == 3.0 for r in learn)
-        return "attn_live_key_share"  # in all six uses
 
     def state(state):
         # every (pass, layer) window holds the two steps' keys, un-rotated,
@@ -247,14 +220,6 @@ class Lfm2:
         assert leaf_names(core) == {"kernel", "scale", "taps", "gate", "up",
                                     "down", "select_bias"}
 
-    def rows(learn):
-        # the rows carry what the core lists and nothing else
-        assert all(r["moe_tokens_dropped"] == 0.0 for r in learn)
-        assert all(0.0 <= r["moe_row_fill_share"] <= 1.0 for r in learn)
-        assert all(0.0 <= r["moe_held_assign_share"] <= 1.0 for r in learn)
-        assert "loop_passes" not in learn[0]
-        return "attn_live_key_share"
-
     def state(state):
         # the window holds the two steps' keys in its two newest slots, and
         # every convolution's tail the two steps' gated inputs
@@ -268,12 +233,84 @@ class Lfm2:
             assert np.abs(tail[:, 1]).max() > 0
 
 
+# ------------------------------------------------------------------- Laguna
+class Laguna:
+    def core(cfg, core, published):
+        assert core.kc.hidden == 32 and core.kc.in_proj
+        assert [m.layer_name for m in core.kc.mixers] == ["gqa"] * 3
+        geoms = [m.geom for m in core.kc.mixers]
+        assert [(g.kind, g.heads, g.span) for g in geoms] == [
+            ("full", 6, 0), ("sliding", 8, 8), ("sliding", 8, 8)]
+        # two readings of one file build the same classes
+        assert core.kc.mixers[1] is core.kc.mixers[2]
+        # a ring of 16 slots (the memory) and two of 8 (the sliding span) of
+        # keys and values [2, 16], their validity and the rings' heads
+        assert state_bytes_per_lane(core) == 4 * (
+            16 * (2 * 2 * 16 + 1) + 1 + 2 * (8 * (2 * 2 * 16 + 1) + 1))
+        kc = published.kc
+        assert [(m.geom.kind, m.geom.heads, m.span(kc)) for m in kc.mixers] == [
+            ("full", 48, 1024), ("sliding", 64, 512), ("sliding", 64, 512),
+            ("sliding", 64, 512), ("full", 48, 1024)]
+        full, sliding = kc.mixers[0].geom.rotation, kc.mixers[1].geom.rotation
+        assert (len(full.freq), full.factor) == (32, 1.4158883083359672)
+        assert (len(sliding.freq), sliding.factor) == (64, 1.0)
+        assert (kc.hidden, kc.attn_kv_heads, kc.attn_head_dim, kc.window) == (
+            2048, 8, 128, 1024)
+        assert (kc.experts, kc.experts_here, kc.top_k, kc.expert_width,
+                kc.shared_width, kc.dense_width, kc.first_dense) == (
+                    256, 16, 8, 512, 512, 8192, 1)
+        assert (kc.route, kc.route_scale, kc.shared_gate) == (
+            "sigmoid", 2.5, False)
+        # three rings of 512 and two of 1,024 slots: 29.4 MB a lane
+        assert state_bytes_per_lane(published) == 4 * (
+            3 * (512 * 2049 + 1) + 2 * (1024 * 2049 + 1)) == 29_374_484
+
+    def cut(core, params):
+        """benchmarks/configs/laguna-xs2-r2d2-1chip.json: 448 million."""
+        assert sorted(core) == ["final_norm", "in_proj"] + [
+            f"layer_{i}" for i in range(1, 6)]
+        full = 2 * 2048 * 48 * 128 + 2 * 2048 * 1024 + 2048 * 48
+        sliding = 2 * 2048 * 64 * 128 + 2 * 2048 * 1024 + 2048 * 64
+        assert count(core["layer_1"]["gqa"]) == full == 29_458_432
+        assert count(core["layer_5"]["gqa"]) == full
+        assert count(core["layer_1"]["ffn"]) == 3 * 2048 * 8192
+        assert count(core["layer_1"]) == 79_794_176
+        for i in (2, 3, 4, 5):
+            moe = core[f"layer_{i}"]["moe"]
+            assert sorted(moe) == ["experts", "router", "shared"]
+            assert count(moe["router"]) == 2048 * 256 + 256
+            assert count(moe["experts"]) == 16 * 3 * 2048 * 512
+            assert count(moe["shared"]) == 3 * 2048 * 512
+        for i in (2, 3, 4):
+            assert count(core[f"layer_{i}"]["gqa"]) == sliding == 37_879_808
+            assert count(core[f"layer_{i}"]) == 91_885_824
+        assert count(core["layer_5"]) == 83_464_448
+        assert count(core) == 443_636_736
+        assert count(params) == 447_915_176  # x 20 B = 8.96 GB
+        experts = sum(count(core[f"layer_{i}"]["moe"]["experts"])
+                      for i in (2, 3, 4, 5))
+        assert round(100 * experts / count(params)) == 45
+        # every leaf bears a name benchmarks/weights_core.py fills
+        assert leaf_names(core) == {"kernel", "scale", "gate", "up", "down",
+                                    "select_bias"}
+
+    def state(state):
+        # every ring, of either length, holds the two steps' keys in its two
+        # newest slots
+        for i, slots in ((1, 16), (2, 8), (3, 8)):
+            keys = np.asarray(aged(state)[f"layer_{i}"]["k"])
+            assert keys.shape == (2, slots, 2, 16)
+            assert np.abs(keys[:, -1]).max() > 0
+            assert np.abs(keys[:, -2]).max() > 0
+            assert not np.any(keys[:, :-2])
+
+
 # what is a family's own: `core` (the tiny core and the published one),
-# `cut` (the published cut's parameter tree), `rows` (a fused run's `learn`
-# rows; returns the name of its live-key counter, if it has one) and `state`
-# (a lane's state after two ticks)
+# `cut` (the published cut's parameter tree) and `state` (a lane's state after
+# two ticks); a fused run's rows: tests/test_core_training_fused.py
 EXPECTED = {"kimi_linear": KimiLinear, "deepseek_v3": DeepSeekV3,
-            "qwen3_next": Qwen3Next, "ouro": Ouro, "lfm2_moe": Lfm2}
+            "qwen3_next": Qwen3Next, "ouro": Ouro, "lfm2_moe": Lfm2,
+            "laguna": Laguna}
 
 
 @families
@@ -294,7 +331,7 @@ def test_the_core_comes_from_the_files_model_type(tmp_path, family):
 
 
 @pytest.mark.parametrize(
-    "family", ["deepseek_v3", "lfm2_moe", "ouro", "qwen3_next"])
+    "family", ["deepseek_v3", "laguna", "lfm2_moe", "ouro", "qwen3_next"])
 def test_the_published_cut_is_so_many_million_parameters(tmp_path, family):
     """The byte count of the family's benchmarks/configs/*-r2d2-1chip.json,
     from `jax.eval_shape` of the program's own init: nothing is allocated."""
@@ -310,26 +347,6 @@ def test_the_published_cut_is_so_many_million_parameters(tmp_path, family):
     # the heads read the core's hidden size
     assert params["value_hidden"]["w_mu"].shape == (2048, 512)
     EXPECTED[family].cut(core, params)
-
-
-@families
-def test_fused_segment_trains_with_the_core(tmp_path, family):
-    from rainbow_iqn_apex_tpu.train_anakin_r2d2 import train_anakin_r2d2
-
-    cfg = cf.tiny_config(tmp_path, family)
-    summary = train_anakin_r2d2(cfg, max_frames=4 * 8 * 12)
-    assert summary["learn_steps"] > 4
-    learn = [r for r in cf.metric_rows(cfg.results_dir, cfg.run_id)
-             if r["kind"] == "learn"]
-    assert all(np.isfinite(r["loss"]) for r in learn)
-    live_keys = EXPECTED[family].rows(learn)
-    if live_keys:
-        # freeway has no terminals: the trained slice's 8 queries see the 4
-        # burn-in keys and their own causal half, of 4 + 8 slots
-        assert all(r[live_keys] == pytest.approx((8 * 4 + 36) / (8 * 12))
-                   for r in learn)
-    assert learn[0]["core_state_bytes_per_lane"] == state_bytes_per_lane(
-        make_core(cfg))
 
 
 @families

@@ -49,9 +49,17 @@ def lfm2_moe(info):
     assert "loop_passes" not in info
 
 
+def laguna(info):
+    assert 0.0 < float(info["attn_live_key_share_full"]) < 1.0
+    assert 0.0 < float(info["attn_live_key_share_sliding"]) < float(
+        info["attn_live_key_share_full"])
+    assert float(info["attn_band_key_share"]) == 1.0  # 12 steps: one block
+    assert 0.0 <= float(info["moe_row_fill_share"]) <= 1.0
+
+
 # what a family's step reports beside the loss
 COUNTERS = {f.__name__: f for f in (
-    kimi_linear, deepseek_v3, qwen3_next, ouro, lfm2_moe)}
+    kimi_linear, deepseek_v3, qwen3_next, ouro, lfm2_moe, laguna)}
 
 
 @pytest.mark.parametrize("family", sorted(cf.FAMILIES))

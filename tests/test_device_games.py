@@ -184,6 +184,37 @@ def test_freeway_truncates_not_terminates():
     assert bool(trunc)
 
 
+@pytest.mark.parametrize("cap,ticks", [(0, 500), (7, 7), (3414, 3414)])
+def test_the_tick_cap_sets_the_time_limit_of_a_game_that_has_one(cap, ticks):
+    """`Config.device_game_tick_cap` through `make_device_game`: 0 leaves the
+    game's own limit, and a game that ends no episode by time refuses one."""
+    game = make_device_game("freeway", cap)
+    assert game.cap == ticks
+    s = game.init(jax.random.PRNGKey(0))._replace(t=jnp.int32(ticks - 2))
+    s, _, _, trunc = game.step(s, jnp.int32(0), jax.random.PRNGKey(1))
+    assert not bool(trunc)
+    assert bool(game.step(s, jnp.int32(0), jax.random.PRNGKey(2))[3])
+    with pytest.raises(ValueError, match="no time limit of its own"):
+        make_device_game("breakout", cap or 9)
+
+
+def test_the_fused_trainer_hands_the_games_tick_cap_on(tmp_path):
+    """A fused R2D2 run of 96 ticks on freeway: under the game's own limit of
+    500 no episode ends; with `device_game_tick_cap` 11 every lane's do, so
+    the run has returns to report."""
+    import core_families as cf
+    from rainbow_iqn_apex_tpu.train_anakin_r2d2 import train_anakin_r2d2
+
+    cfg = cf.tiny_config(tmp_path, "ouro", core_config="", lstm_size=16)
+    assert cfg.device_game_tick_cap == 0
+    own = train_anakin_r2d2(cfg, max_frames=4 * 8 * 12)
+    assert np.isnan(own["train_return_mean"])
+    capped = train_anakin_r2d2(
+        cfg.replace(device_game_tick_cap=11, run_id="capped"),
+        max_frames=4 * 8 * 12)
+    assert np.isfinite(capped["train_return_mean"])
+
+
 def test_freeway_scripted_crossing_scores():
     """Going up forever must eventually score (+1) despite collisions."""
     game = FreewayGame(cap=10_000)
