@@ -100,8 +100,25 @@ shared expert is added as it is or weighed by a sigmoid gate (`shared_gate`),
 and a family without one says `shared_width` 0: the layer then holds no
 `shared` leaf and runs no `moe_shared` op, and its output is the held
 experts' part alone.
-No capacity: the row buffer is chosen, by the count, among sizes of which the
-largest holds every assignment, so no token is ever dropped.  Where the
+No capacity: the row buffer is chosen, by the count of held assignments, as
+the smallest rung of a ladder that holds them (`EXPERT_ROWS`): no rows at all
+(the held part is zeros: nothing is gathered, multiplied or scattered), the
+token count, and on top the rows that hold every assignment, so no token is
+ever dropped.  The token count, because that is what a layer holds wherever
+its router chooses alike for all its tokens and one of a token's choices is
+held; a layer that holds more takes the top rung.  A rung with rows is a
+branch of a `switch`, and what a branch costs is paid whichever branch runs:
+under a gradient a `switch` hands the backward pass what EVERY branch saved
+(its gathered rows, its products, its mask), made and zero-filled for the
+branches not taken, and a branch is 3 to 90 MB of program at the published
+widths, which a chip's peak memory counts.  So the ladder has two rungs with
+rows and not one a likely count (a third made the Kimi-Linear core's program
+half a gigabyte larger on the chip: PERF.md, PR 49), and the top rung, whose
+rows are `top_k` times the other's and which runs where a router spreads,
+keeps nothing for the backward pass: it makes its rows again there from the
+layer's input, sort and kernels (`jax.checkpoint`), a third forward where it
+runs and no buffer where it does not.  The rung at the token count keeps
+what it computed, as any layer under the stack's `nn.remat` does.  Where the
 layer has few tokens (`FEW_ROWS`: the actor's 16 lanes, an eval rollout, a
 tiny sequence pass) it sorts nothing: it walks the held experts, skips every
 one no token chose, and runs one that has rows on all the tokens from that
@@ -130,9 +147,15 @@ from rainbow_iqn_apex_tpu.obs import device_scopes
 
 HI = jax.lax.Precision.HIGHEST
 NEG = -1e30
-# row buffers of the grouped product, as multiples of the token count; the
-# last is top_k, which holds every assignment
-EXPERT_ROWS = (0.5, 2.0)
+# the rungs of the grouped product's row buffer, as multiples of the token
+# count, under the one that holds every assignment (top_k times it): none,
+# and the token count, which is what a layer holds where its router chooses
+# alike for all its tokens and one of a token's choices is held (what a
+# seeded selection bias deals, and what an untrained router collapses to
+# within a few learn steps).  Two rungs with rows and no more: each is a
+# branch whose saved rows are made on every learn step and whose program, up
+# to 90 MB at the published widths, a chip's peak memory counts
+EXPERT_ROWS = (0.0, 1.0)
 # up to so many rows (tokens x the choices that can be held) the expert layer
 # sorts nothing and walks the held experts its tokens chose
 FEW_ROWS = 1024
@@ -681,6 +704,9 @@ class _MoE(nn.Module):
         cd = self.compute_dtype
 
         def with_rows(rows):
+            if not rows:  # nothing held: no row gathered, multiplied or added
+                return lambda weights, x, *_: jnp.zeros_like(x)
+
             def run(weights, x, order, w_sorted, group_sizes, n_held):
                 tok = order[:rows] // k
                 # rows past the held assignments belong to no group: the
@@ -692,7 +718,12 @@ class _MoE(nn.Module):
                     weights, xs, group_sizes.astype(jnp.int32), cd)
                 ys = jnp.where(live, ys * w_sorted[:rows, None], 0.0)
                 return jnp.zeros_like(x).at[tok].add(ys)
-            return run
+            # the top rung saves nothing for the backward pass and makes its
+            # rows again there, from the `switch`'s own operands: its rows
+            # are top_k times the token count, and a `switch` under a
+            # gradient makes and fills what every branch saved on every learn
+            # step, whichever ran
+            return jax.checkpoint(run) if rows == most else run
 
         with jax.named_scope(device_scopes.MOE_EXPERTS):
             if few:
@@ -714,10 +745,14 @@ class _MoE(nn.Module):
         load = jnp.bincount(idx.reshape(-1), length=kc.experts)
         if few:  # nothing is buffered: the rows that hold every assignment
             rows_taken = most
+            fill = n_held / most
             self.sow(STATS, "moe_act_touched_expert_share",
                      jnp.mean(group_sizes > 0, dtype=jnp.float32))
         else:
             rows_taken = jnp.asarray(sizes, jnp.int32)[pick]
+            # the empty rung masks no row: 1.0 there, not 0 / 0
+            fill = jnp.where(rows_taken > 0,
+                             n_held / jnp.maximum(rows_taken, 1), 1.0)
         self.sow(STATS, "moe_held_assign_share", n_held / (n * k))
         self.sow(STATS, "moe_expert_load_max_over_mean",
                  load.max() / (n * k / kc.experts))
@@ -725,7 +760,7 @@ class _MoE(nn.Module):
                  (n_held - jnp.minimum(n_held, rows_taken)).astype(jnp.float32))
         # the share of the taken buffer's rows that hold an assignment: the
         # rest of the gather, the products' rows and the scatter is masked
-        self.sow(STATS, "moe_row_fill_share", n_held / rows_taken)
+        self.sow(STATS, "moe_row_fill_share", fill)
         return y.reshape(*lead, f)
 
 

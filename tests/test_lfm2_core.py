@@ -241,20 +241,21 @@ def test_eight_key_value_heads_under_thirty_two_against_the_reference():
     close(aged({"mha": new})["mha"]["k"][:, -10:], k)
 
 
-@pytest.mark.parametrize("experts,chosen_held,rows", [
-    (16, 0, 300),  # few held assignments: the small buffer (0.5 n) is taken
-    (8, 1, 1200),  # one held expert a token: over 0.5 n, so the largest
+@pytest.mark.parametrize("experts,chosen_held", [
+    (16, 0),  # some held assignments, under the token count: that rung
+    (8, 1),  # one held expert a token: the same rung, every row live
 ])
-def test_row_fill_share_by_the_buffer_the_switch_took(
-        experts, chosen_held, rows):
+def test_row_fill_share_by_the_buffer_the_switch_took(experts, chosen_held):
     """`moe_row_fill_share` = held assignments over the rows of the buffer
-    taken.  600 tokens, 2 a token, 2 held: buffers of 300 and 1200 rows.  An
-    untrained router over 16 experts sends the 2 held ones some 150
-    assignments; a selection bias on one held and one absent expert sends
-    them 600, half of the 1200-row buffer, as the cell's 7,680 of 15,360."""
+    taken.  600 tokens, 2 a token, 2 held: buffers of 0, 600 and 1200 rows.
+    An untrained router over 16 experts sends the 2 held ones some 150
+    assignments, a quarter of the 600-row buffer; a selection bias on one
+    held and one absent expert sends them 600, which fill it, as the cell's
+    7,680 fill its 7,680 rows."""
     cc = tiny_cc(num_experts=experts)
     cfg = lfm2.Lfm2Config.from_dict(cc)
     n, k = 600, cfg.top_k
+    rows = n  # the rung both cases take
     x = jax.random.normal(jax.random.PRNGKey(0), (n, cfg.hidden))
     p, run = cf.expert_layer(cfg, x)
     if chosen_held:
@@ -262,12 +263,14 @@ def test_row_fill_share_by_the_buffer_the_switch_took(
             jnp.asarray([1, 5])].set(2.0)
     y, stats = run(p, x)
     n_held = float(stats["moe_held_assign_share"]) * n * k
-    assert (n_held > 300) == (rows == 1200)
+    assert 0 < n_held <= rows
     assert float(stats["moe_row_fill_share"]) == pytest.approx(n_held / rows)
     assert float(stats["moe_tokens_dropped"]) == 0.0
     if chosen_held:
-        assert n_held == 600 and float(stats["moe_row_fill_share"]) == 0.5
+        assert n_held == 600 and float(stats["moe_row_fill_share"]) == 1.0
         assert float(stats["moe_expert_load_max_over_mean"]) == experts / k
+    else:
+        assert n_held < 300
     close(y, ref.moe_ffn(p, cc, x, (0, 2), ref.plain_dot))
 
 

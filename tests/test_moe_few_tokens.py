@@ -185,14 +185,17 @@ def test_a_tick_at_the_published_lfm2_sizes_casts_no_stack_of_kernels():
 def test_the_many_token_path_traces_the_primitives_it_did():
     """7,680 tokens (a learn step's batch) at the published LFM2 sizes: the
     sort, the `switch` over the row buffers, the grouped products and the
-    counters trace to the sequence of primitives recorded from the tree
-    before the few-token branch changed (PR 43's)."""
+    counters trace to the recorded sequence of primitives: PR 43's, which the
+    few-token branch left as they were (PR 44), but for the ladder (PR 49:
+    an empty rung and two rungs with rows, three grouped products each, the
+    top one under a `checkpoint`)."""
     _, eqs = _published_lfm2_jaxpr(7680)
     with open(os.path.join(
             cf.HERE, "fixtures", "moe_many_token_primitives.json")) as f:
         recorded = json.load(f)
     assert [eq.primitive.name for eq in eqs] == recorded["primitives"]
-    assert recorded["primitives"].count("ragged_dot_general") == 9
+    assert recorded["primitives"].count("ragged_dot_general") == 6
+    assert recorded["primitives"].count("remat2") == 1
 
 
 @pytest.mark.parametrize("routing,share", [
